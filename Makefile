@@ -119,11 +119,11 @@ test:
 bench-smoke:
 	JAX_PLATFORMS=cpu $(PY) bench.py
 
-# ratcheted perf gate (docs/performance.md#bench-ratchet): committed
-# bench records must stay above the floors in analysis/bench_floors.json.
-# Pure JSONL comparison — no jax import, no TPU; a real TPU bench run
-# appends evidence to BENCH_LOCAL.jsonl and `bench.py --update-floors`
-# ratchets the floors up.
+# ratcheted perf gate (docs/performance.md#bench-ratchet): the records
+# in BENCH_LOCAL.jsonl (a run-time file `bench.py --loadlab` appends to;
+# none yet = zero records, warnings only) must stay inside the floors in
+# analysis/bench_floors.json. Pure JSONL comparison — no jax import, no
+# TPU; `bench.py --update-floors` ratchets the floors.
 bench-check:
 	$(PY) bench.py --check
 

@@ -854,7 +854,7 @@ class SpecRankRule(Rule):
                     spec_env.setdefault(node.targets[0].id, a)
         for node in ast.walk(sf.tree):
             if not (isinstance(node, ast.Call)
-                    and _terminal(node.func) in ("shard_map", "_shard_map")
+                    and _terminal(node.func) == "shard_map"
                     and node.args):
                 continue
             kw = {k.arg: k.value for k in node.keywords}
@@ -917,8 +917,7 @@ class SpecRankRule(Rule):
         for node in ast.walk(sf.tree):
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Call)
-                    and _terminal(node.func.func)
-                    in ("shard_map", "_shard_map")):
+                    and _terminal(node.func.func) == "shard_map"):
                 continue
             kw = {k.arg: k.value for k in node.func.keywords}
             in_specs = kw.get("in_specs")

@@ -80,7 +80,7 @@ COLLECTIVES: dict[str, int] = {
     "axis_size": 0,
 }
 
-SHARD_MAP_NAMES = {"shard_map", "_shard_map", "pmap", "xmap"}
+SHARD_MAP_NAMES = {"shard_map", "pmap", "xmap"}
 PARTITION_SPEC_NAMES = {"P", "PartitionSpec"}
 
 #: attribute reads that survive donation (aval metadata, not the buffer)

@@ -6,8 +6,10 @@ topology discovery, executable compile-or-load cache, execution with device
 buffers, HBM stats into health/metrics, all behind the provider pattern so
 the Container wires it like any datasource.
 
-Backend: JAX's PJRT runtime (libtpu on TPU, CPU plugin for dev/CI —
-``TPU_PJRT_PLUGIN``/``JAX_PLATFORMS`` selects, SURVEY §7 phase 3).
+Backend: JAX's PJRT runtime (libtpu on TPU, the CPU client for dev/CI —
+``JAX_PLATFORMS`` selects, SURVEY §7 phase 3). ``TPU_PJRT_PLUGIN`` is a
+PATH to a plugin ``.so`` for the native binding (native/pjrt.py), never a
+platform name.
 """
 
 from gofr_tpu.datasource.tpu.client import TPUClient, new_tpu

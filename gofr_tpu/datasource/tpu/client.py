@@ -1,10 +1,10 @@
 """TPUClient: device mesh ownership + executable cache + execution.
 
 Design (SURVEY §7 phase 3):
-- ``connect`` discovers devices through PJRT (via JAX), builds the named
-  mesh from ``TPU_MESH`` (parallel/mesh.py), enables the persistent XLA
-  compilation cache (``TPU_COMPILE_CACHE_DIR``) — the "migration-style
-  version bookkeeping for compiled-executable caches" of SURVEY §5.4.
+- ``connect`` discovers devices through PJRT (via JAX — ``JAX_PLATFORMS``
+  selects the platform) and builds the named mesh from ``TPU_MESH``
+  (parallel/mesh.py). The persistent compilation cache is placed by
+  ``ops/backend.configure_compile_cache`` (``JAX_COMPILATION_CACHE_DIR``).
 - ``compile(name, fn, *abstract_args)`` lowers+compiles ahead-of-time and
   stores the LoadedExecutable under ``name`` (keyed cache, compile-or-load).
 - ``execute(name, *args)`` runs it, wrapped in a span, recording duty-cycle
@@ -35,6 +35,7 @@ from typing import Any
 
 import jax
 
+from gofr_tpu.ops.backend import configure_compile_cache
 from gofr_tpu.parallel.mesh import AXIS_ORDER, MeshSpec, build_mesh
 
 
@@ -188,14 +189,10 @@ class TPUClient:
     def __init__(
         self,
         mesh_spec: str | MeshSpec | None = None,
-        platform: str | None = None,
-        compile_cache_dir: str | None = None,
         breaker_threshold: int = 3,
         breaker_cooldown_s: float = 30.0,
     ) -> None:
         self.mesh_spec = mesh_spec
-        self.platform = platform
-        self.compile_cache_dir = compile_cache_dir
         self._logger: Any = None
         self._metrics: Any = None
         self._tracer: Any = None
@@ -224,8 +221,6 @@ class TPUClient:
     def from_config(cls, config: Any) -> "TPUClient":
         return cls(
             mesh_spec=config.get("TPU_MESH"),
-            platform=config.get("TPU_PJRT_PLUGIN"),
-            compile_cache_dir=config.get("TPU_COMPILE_CACHE_DIR"),
             breaker_threshold=int(
                 config.get_or_default("TPU_BREAKER_THRESHOLD", "3")
             ),
@@ -245,13 +240,9 @@ class TPUClient:
         self._tracer = tracer
 
     def connect(self) -> None:
-        if self.compile_cache_dir:
-            jax.config.update("jax_compilation_cache_dir", self.compile_cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+        configure_compile_cache()
         self._probe_native_binding()
-        self._all_devices = (
-            jax.devices(self.platform) if self.platform else jax.devices()
-        )
+        self._all_devices = jax.devices()
         self._rebuild_mesh()
         if self._logger:
             kinds = {d.device_kind for d in self._devices}

@@ -154,6 +154,7 @@ class ServingStack:
         self.migrators: dict[str, KVMigrator] = {}
         self.exporters: dict[str, Any] = {}
         self.killed: list[str] = []
+        self._replicas_built = 0  # picks each new replica's device
         self.pool = SimulatedPoolDriver(
             self.router, self._build_replica, on_reap=self._on_reap
         )
@@ -181,10 +182,22 @@ class ServingStack:
     # -- the pool factory (runs on the autoscaler thread too) ---------------
     def _build_replica(self, role: str, rid: str,
                        preemptible: bool = False) -> LocalReplica:
+        import jax
+
         migrator = KVMigrator(rid, self.router.prefix_index)
+        # one replica per device, round-robin: each engine copies the
+        # weights onto its own chip instead of sharing one params tree
+        # (on a one-device host they all land on that device)
+        devices = jax.local_devices()
+        with self._mu:
+            device = devices[self._replicas_built % len(devices)]
+            self._replicas_built += 1
         lora = None
         if self.config.adapters:
-            lora = AdapterRegistry(max_active=max(len(self.config.adapters) + 1, 2))
+            lora = AdapterRegistry(
+                max_active=max(len(self.config.adapters) + 1, 2),
+                device=device,
+            )
             for i, adapter_id in enumerate(self.config.adapters):
                 lora.register(make_adapter(
                     self.model_cfg, adapter_id, rank=2, seed=1000 + i
@@ -208,6 +221,7 @@ class ServingStack:
             kv_migrator=migrator,
             lora=lora,
             tenants=self.tenant_registry,
+            device=device,
         )
         exporter = None
         if self.config.export_dir:

@@ -31,6 +31,10 @@ import jax.numpy as jnp
 from gofr_tpu.ops.attention import attention, decode_attention
 from gofr_tpu.ops.flash_attention import flash_attention
 from gofr_tpu.ops.norms import rms_norm
+from gofr_tpu.ops.paged_attention import (
+    paged_decode_attention,
+    paged_decode_attention_q,
+)
 from gofr_tpu.ops.rope import apply_rope, rope_table
 
 
@@ -361,12 +365,9 @@ def _layer_cached(
         else:
             k_all = jax.lax.dynamic_update_slice(k_all, k[None], (layer, 0, 0, 0, 0))
             v_all = jax.lax.dynamic_update_slice(v_all, v[None], (layer, 0, 0, 0, 0))
-        use_flash_auto = (
-            cfg.attn_impl == "auto"
-            and S % 128 == 0
-            and jax.default_backend() == "tpu"
-        )
-        if cfg.attn_impl == "flash" or use_flash_auto:
+        if cfg.attn_impl == "flash" or (cfg.attn_impl == "auto" and S % 128 == 0):
+            # compiled kernel on a TPU, ops.attention on the CPU
+            # (ops/backend.py)
             attn = flash_attention(q, k, v, cache_len, causal=True)
         else:
             attn = attention(q, k, v, causal=True, kv_len=cache_len)
@@ -576,8 +577,6 @@ def decode_step_paged(
     pages = jnp.where(active, block_tables[b_idx, pos // page], trash_page)  # [B]
     offsets = jnp.where(active, pos % page, 0)
 
-    use_kernel = jax.default_backend() == "tpu"
-
     def body(h, xs):
         lp, kc, vc = xs  # kc/vc: [N_pages, Hkv, page, Dh]
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
@@ -595,14 +594,8 @@ def decode_step_paged(
         kc = kc.at[pages, :, offsets].set(k)
         vc = vc.at[pages, :, offsets].set(v)
 
-        if use_kernel:
-            from gofr_tpu.ops.paged_attention import paged_decode_attention
-
-            attn = paged_decode_attention(q, kc, vc, block_tables, seq_lens)
-        else:
-            from gofr_tpu.ops.paged_attention import paged_decode_attention_ref
-
-            attn = paged_decode_attention_ref(q, kc, vc, block_tables, seq_lens)
+        # Mosaic kernel on a TPU, gather reference on the CPU
+        attn = paged_decode_attention(q, kc, vc, block_tables, seq_lens)
 
         h = h + _mm(attn.reshape(B, 1, H * Dh), lp["wo"])
         hn = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
@@ -644,13 +637,6 @@ def decode_step_paged_q(
     pages = jnp.where(active, block_tables[b_idx, pos // page], trash_page)
     offsets = jnp.where(active, pos % page, 0)
 
-    from gofr_tpu.ops.paged_attention import (
-        paged_decode_attention_q,
-        paged_decode_attention_ref,
-    )
-
-    use_kernel = jax.default_backend() == "tpu"
-
     def body(h, xs):
         lp, kc, vc, ksc, vsc = xs
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
@@ -668,14 +654,9 @@ def decode_step_paged_q(
         ksc = ksc.at[pages, :, offsets, 0].set(ks)
         vsc = vsc.at[pages, :, offsets, 0].set(vs)
 
-        if use_kernel:
-            attn = paged_decode_attention_q(
-                q, kc, vc, ksc, vsc, block_tables, seq_lens
-            )
-        else:  # off-TPU: XLA gather reference beats the interpreted kernel
-            attn = paged_decode_attention_ref(
-                q, kc, vc, block_tables, seq_lens, k_scale=ksc, v_scale=vsc
-            )
+        attn = paged_decode_attention_q(
+            q, kc, vc, ksc, vsc, block_tables, seq_lens
+        )
         h = h + _mm(attn.reshape(B, 1, H * Dh), lp["wo"])
         hn = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(_mm(hn, lp["w_gate"]).astype(jnp.float32)).astype(hn.dtype)

@@ -9,14 +9,18 @@ plain C ABI via ctypes (no pybind11 in the image).
 Build model: shared objects are compiled on first use with ``g++`` into
 ``native/_build/`` and re-used while their source hash matches (the
 "compile-or-load executable cache" idea of SURVEY §5.4 applied to our own
-native code). When no compiler is available the callers fall back to the
-pure-Python implementations in :mod:`gofr_tpu.native.fallback`.
+native code). When the build fails the callers run the pure-Python twins
+in :mod:`gofr_tpu.native.fallback` — loudly: the compiler's stderr is
+logged once per library, and ``backend`` on every wrapper (surfaced in
+the engine's health as ``scheduler_backend``) says which runtime is live.
 """
 
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import importlib.util
+import logging
 import os
 import subprocess
 import threading
@@ -27,6 +31,7 @@ _BUILD_DIR = os.path.join(_NATIVE_DIR, "_build")
 
 _lock = threading.Lock()
 _cache: dict[str, ctypes.CDLL | None] = {}
+_log = logging.getLogger(__name__)
 
 GOFR_OK = 0
 GOFR_E_BADHANDLE = -1
@@ -63,35 +68,35 @@ def _source_hash(*paths: str) -> str:
 
 
 def pjrt_include_dirs() -> list[str]:
-    """Locate the PJRT C API headers (shipped in the image's tensorflow).
-    ``GOFR_PJRT_INCLUDE_DIRS`` (colon-separated) short-circuits the
-    tensorflow import — required under the ASan tier, where importing
-    TF's pybind11 dependency chain trips the sanitizer's exception
-    interceptor before our code even runs."""
+    """Locate the PJRT C API headers (shipped in the installed
+    tensorflow wheel). The package is located, never imported: only its
+    header tree is wanted, and the import costs seconds and drags TF's
+    runtime into the serving process. ``GOFR_PJRT_INCLUDE_DIRS``
+    (colon-separated) overrides the lookup."""
     env = os.environ.get("GOFR_PJRT_INCLUDE_DIRS")
     if env:
         return [d for d in env.split(":") if d]
-    dirs = []
-    try:
-        import tensorflow  # noqa: F401  (cpu wheel, only used for headers)
-
-        tf_inc = os.path.join(os.path.dirname(tensorflow.__file__), "include")
+    spec = importlib.util.find_spec("tensorflow")
+    roots = (spec.submodule_search_locations or []) if spec else []
+    for root in roots:
+        tf_inc = os.path.join(root, "include")
         if os.path.exists(os.path.join(tf_inc, "xla/pjrt/c/pjrt_c_api.h")):
-            dirs.append(tf_inc)
-    except Exception:
-        pass
-    return dirs
+            return [tf_inc]
+    return []
 
 
 def build_library(name: str, sources: list[str], extra_flags: list[str] | None = None,
                   libs: list[str] | None = None) -> str | None:
     """Compile `sources` (relative to native/) into _build/<name>-<hash>.so.
 
-    Returns the path, or None if the toolchain is unavailable or the
-    compile fails (callers fall back to Python implementations).
+    Returns the path, or None if a source is missing, the toolchain is
+    unavailable or the compile fails — each logged with its cause
+    (callers then run the Python twins; ``_load`` memoizes, so once).
     """
     srcs = [os.path.join(_NATIVE_DIR, s) for s in sources]
-    if not all(os.path.exists(s) for s in srcs):
+    missing = [s for s in srcs if not os.path.exists(s)]
+    if missing:
+        _log.error("native build of %s: missing source %s", name, missing)
         return None
     # sanitizer tier (SURVEY §5.2): GOFR_NATIVE_EXTRA_CXXFLAGS joins the
     # build AND the cache tag, so asan and release artifacts never collide
@@ -113,7 +118,15 @@ def build_library(name: str, sources: list[str], extra_flags: list[str] | None =
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(out + ".tmp", out)
-    except Exception:
+    except subprocess.CalledProcessError as exc:
+        _log.error(
+            "native build of %s failed (exit %s): %s\n%s", name,
+            exc.returncode, " ".join(cmd),
+            exc.stderr.decode("utf-8", "replace"),
+        )
+        return None
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        _log.error("native build of %s did not run: %s", name, exc)
         return None
     return out
 
@@ -128,8 +141,8 @@ def _load(name: str, sources: list[str], extra_flags: list[str] | None = None,
         if path is not None:
             try:
                 lib = ctypes.CDLL(path)
-            except OSError:
-                lib = None
+            except OSError as exc:
+                _log.error("native library %s did not load: %s", path, exc)
         _cache[name] = lib
         return lib
 

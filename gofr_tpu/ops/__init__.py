@@ -2,7 +2,10 @@
 
 All ops are pure jax (traced once under jit, static shapes, fused by XLA);
 the hot attention paths have Pallas TPU kernels in ops/flash_attention.py and
-ops/paged_attention.py with jax fallbacks selected at trace time.
+ops/paged_attention.py. ops/backend.py decides, at trace time and in one
+place, what each runs: the Mosaic-compiled kernel on a TPU, the XLA
+reference on the CPU (the Pallas interpreter when a test asks), an error
+anywhere else.
 """
 
 from gofr_tpu.ops.norms import layer_norm, rms_norm

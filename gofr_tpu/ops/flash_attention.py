@@ -13,8 +13,10 @@ GQA is handled in the BlockSpec index maps: q head ``h`` reads kv head
 
 Reference parity note (SURVEY §5.7): the reference framework (gofr, pure Go)
 has no attention; this kernel is the TPU-native hot-op the north-star serving
-path requires. Falls back to interpret mode off-TPU so CI (8 virtual CPU
-devices, tests/conftest.py) exercises the same code path.
+path requires. On a TPU the kernel compiles through Mosaic; on the CPU the
+call computes ``ops.attention.attention`` unless ``interpret=True`` asks for
+the Pallas interpreter (tests do); any other platform is an error
+(``ops/backend.py``).
 
 ``flash_attention`` is declared in the kernel contract table
 (``gofr_tpu/analysis/kernel_contracts.KERNELS``) and replayed by the
@@ -32,7 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gofr_tpu.jax_compat import PallasTPUCompilerParams
+from gofr_tpu.ops.attention import attention
+from gofr_tpu.ops.backend import INTERPRET, REFERENCE, kernel_mode
 
 NEG_INF = -1e30
 
@@ -134,8 +137,9 @@ def flash_attention(
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    mode = kernel_mode(interpret)
+    if mode == REFERENCE:
+        return attention(q, k, v, causal=causal, kv_len=kv_len, scale=scale)
 
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
@@ -199,7 +203,7 @@ def flash_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_t.shape, q.dtype),
-        compiler_params=PallasTPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -207,6 +211,6 @@ def flash_attention(
             bytes_accessed=int(q.size * 2 + k.size * 2 + v.size * 2),
             transcendentals=int(B * H * Sq * Sk),
         ),
-        interpret=interpret,
+        interpret=mode == INTERPRET,
     )(kv_len, q_t, k_t, v_t)
     return out.transpose(0, 2, 1, 3)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from gofr_tpu.jax_compat import shard_map
 from gofr_tpu.parallel.mesh import require_axis
 
 
@@ -203,7 +202,7 @@ def moe_ffn_ep(
         capacity=cap,
     )
     espec = P(axis)
-    out, f, p = shard_map(
+    out, f, p = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis), P(), espec, espec, espec),

@@ -9,7 +9,9 @@ continuous-batching engine admit by *token* budget instead of reserving
 max_seq_len rows per slot.
 
 Two implementations with one contract:
-- ``paged_decode_attention_ref`` — pure-XLA gather fallback (CI, CPU);
+- ``paged_decode_attention_ref`` — pure-XLA gather reference: the oracle
+  the kernels are tested against, and what both entries compute on the
+  CPU unless a test passes ``interpret=True`` (``ops/backend.py``);
 - ``paged_decode_attention`` / ``paged_decode_attention_q`` — one Pallas
   kernel (bf16 or int8-with-scales pools) whose grid walks
   (batch, kv_head, page) with the page axis innermost, carrying the
@@ -36,7 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gofr_tpu.jax_compat import PallasTPUCompilerParams
+from gofr_tpu.ops.backend import COMPILED, INTERPRET, REFERENCE, kernel_mode
 
 NEG_INF = -1e30
 
@@ -57,7 +59,7 @@ def paged_decode_attention_ref(
     v_scale: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Gather-based reference: materializes [B, M*page] K/V. Correctness
-    oracle + off-TPU fallback. int8 pools carry per-vector absmax scales
+    oracle + the CPU path. int8 pools carry per-vector absmax scales
     and dequantize AFTER the gather — only the owned pages widen, never
     the whole pool."""
     B, H, Dh = q.shape
@@ -223,7 +225,7 @@ def _paged_attention_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_t.shape, q.dtype),
-        compiler_params=PallasTPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -257,10 +259,13 @@ def paged_decode_attention(
     dims equal to full array dims (page, Dh) — the Mosaic tiling rule."""
     Dh = q.shape[-1]
     scale_v = scale if scale is not None else 1.0 / math.sqrt(Dh)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    mode = kernel_mode(interpret)
+    if mode == REFERENCE:
+        return paged_decode_attention_ref(
+            q, k_pool, v_pool, block_tables, seq_lens, scale=scale_v
+        )
     return _paged_attention_call(
-        q, k_pool, v_pool, block_tables, seq_lens, scale_v, interpret
+        q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET
     )
 
 
@@ -278,21 +283,25 @@ def paged_decode_attention_q(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Pallas paged decode attention over int8 pools (same kernel,
-    dequantizing in VMEM). Off-TPU, and for page sizes below the int8
-    Mosaic tile (:data:`INT8_MIN_PAGE` sublanes), falls back to the
-    gather reference — ServingEngine validates the page size up front so
-    the production path never lands in the fallback silently."""
+    dequantizing in VMEM). A compiled call with pages below the int8
+    Mosaic tile (:data:`INT8_MIN_PAGE` sublanes) is an error — the
+    gather reference it used to drop to inverts the bandwidth win int8
+    exists for (ServingEngine validates the page size up front)."""
     Dh = q.shape[-1]
     page = k_pool.shape[2]
     scale_v = scale if scale is not None else 1.0 / math.sqrt(Dh)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if not interpret and page < INT8_MIN_PAGE:
+    mode = kernel_mode(interpret)
+    if mode == COMPILED and page < INT8_MIN_PAGE:
+        raise ValueError(
+            f"int8 paged attention needs page >= {INT8_MIN_PAGE} to compile "
+            f"(the int8 Mosaic tile); got page={page}"
+        )
+    if mode == REFERENCE:
         return paged_decode_attention_ref(
             q, k_pool, v_pool, block_tables, seq_lens,
             scale=scale_v, k_scale=k_scale, v_scale=v_scale,
         )
     return _paged_attention_call(
-        q, k_pool, v_pool, block_tables, seq_lens, scale_v, interpret,
+        q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET,
         k_scale=k_scale, v_scale=v_scale,
     )

@@ -31,7 +31,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from gofr_tpu.ops.attention import NEG_INF, gqa_repeat
 from gofr_tpu.parallel.mesh import require_axis
 
-from gofr_tpu.jax_compat import shard_map as _shard_map
 
 
 def _block_accumulate(q, k, v, acc, m, l, q_start, k_start, scale):
@@ -126,7 +125,7 @@ def ring_attention(
     fn = functools.partial(
         ring_attention_sharded, axis_name=axis, axis_size=n, scale=scale
     )
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
@@ -187,7 +186,7 @@ def ulysses_attention(
     fn = functools.partial(
         ulysses_attention_sharded, axis_name=axis, axis_size=n, scale=scale
     )
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from gofr_tpu.jax_compat import pcast, shard_map
 from gofr_tpu.parallel.mesh import require_axis
 
 
@@ -73,14 +72,14 @@ def pipeline_apply(
 
         # carries become pp-varying after the first ppermute: mark the
         # replicated zeros as varying up front so scan's carry types match
-        outs0 = pcast(jnp.zeros_like(x_mb), (axis,), to="varying")
-        recv0 = pcast(jnp.zeros_like(x_mb[0]), (axis,), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(x_mb), (axis,), to="varying")
+        recv0 = jax.lax.pcast(jnp.zeros_like(x_mb[0]), (axis,), to="varying")
         (recv, outs), _ = jax.lax.scan(tick, (recv0, outs0), jnp.arange(T))
         # only the last stage accumulated real outputs; broadcast over pp
         mask = (stage == n - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, P()),

@@ -40,6 +40,12 @@ from gofr_tpu.http.errors import (
 )
 from gofr_tpu.models import llama
 from gofr_tpu.native.runtime import QueueFull, Scheduler
+from gofr_tpu.ops.backend import (
+    COMPILED,
+    configure_compile_cache,
+    kernel_mode,
+    require_requested_backend,
+)
 from gofr_tpu.serving import batch as batch_ops
 from gofr_tpu.serving.dedup import DedupEntry, DedupRegistry, ReplayGap, ReplayStream
 from gofr_tpu.serving.shed import QueueWaitEstimator
@@ -420,9 +426,23 @@ class ServingEngine:
         kv_migrator: Any = None,
         lora: Any = None,
         tenants: Any = None,
+        device: Any = None,
     ) -> None:
+        # a server that quietly serves from a backend nobody asked for
+        # (jax drops to CPU when libtpu finds no chip) is refused here
+        require_requested_backend()
+        configure_compile_cache()
         self.model_cfg = cfg
-        self.params = params
+        # the one device this engine lives on: weights are copied there
+        # (a no-op when already resident) and everything the engine
+        # builds — KV, DecodeState, RNG, re-uploaded cache entries, the
+        # loop thread's default device — follows through _device_scope.
+        # None leaves placement to jax (its default device, or whatever
+        # shardings the param leaves carry).
+        self._device = device
+        self.params = (
+            params if device is None else jax.device_put(params, device)
+        )
         self.config = engine_config or EngineConfig()
         if self.config.role not in ("prefill", "decode", "unified"):
             raise ValueError(
@@ -555,18 +575,20 @@ class ServingEngine:
         # the shared helper so the supervisor's warm restart rebuilds
         # EXACTLY this, never a hand-copied drift of it
         self._init_runtime_state()
-        self.rng = jax.random.PRNGKey(seed)
-        # per-REQUEST key root for prefill first-token sampling. The
-        # shared self.rng stream is split by decode/spec dispatches too,
-        # so a request's draw would depend on how many device steps
-        # interleaved before its admission — which depends on jit-cache
-        # warmth and thread timing (the test_spec_concurrent flake: warm
-        # caches shift the interleave and a sampled row draws EOS as its
-        # first prefill token). fold_in(root, rid) pins each request's
-        # first token to its id alone: same submit order → same tokens,
-        # standalone or mid-suite, and a requeued/warm-restarted request
-        # re-prefills to the identical first token.
-        self._rng_root = jax.random.PRNGKey(seed)
+        with self._device_scope():
+            self.rng = jax.random.PRNGKey(seed)
+            # per-REQUEST key root for prefill first-token sampling. The
+            # shared self.rng stream is split by decode/spec dispatches
+            # too, so a request's draw would depend on how many device
+            # steps interleaved before its admission — which depends on
+            # jit-cache warmth and thread timing (the test_spec_concurrent
+            # flake: warm caches shift the interleave and a sampled row
+            # draws EOS as its first prefill token). fold_in(root, rid)
+            # pins each request's first token to its id alone: same submit
+            # order → same tokens, standalone or mid-suite, and a
+            # requeued/warm-restarted request re-prefills to the identical
+            # first token.
+            self._rng_root = jax.random.PRNGKey(seed)
         # detokenization + stream emission run OFF the engine thread on
         # this single-worker executor, so a slow tokenizer or a blocking
         # stream_cb overlaps the device block instead of stalling it. ONE
@@ -2013,10 +2035,18 @@ class ServingEngine:
         return self._dedup.stats()
 
     # ------------------------------------------------------------- the loop
+    def _device_scope(self) -> Any:
+        """Context in which arrays this engine creates land on its
+        device. ``jax.default_device`` is thread-local, so every thread
+        that builds engine state (constructor, loop thread, a
+        supervisor's warm restart) enters it. A no-op without a device."""
+        return jax.default_device(self._device)
+
     def _loop(self) -> None:
         me = threading.current_thread()
         try:
-            self._loop_body(me)
+            with self._device_scope():
+                self._loop_body(me)
         except _ThreadRetired:
             return  # quarantined thread thawed: exit, touch nothing
         except BaseException as exc:
@@ -2347,6 +2377,10 @@ class ServingEngine:
             # keeps the per-key reuse counts the byte-pressure eviction
             # orders by (host dict write, zero device work)
             self.timeline.observe_prefix_reuse(key)
+            if self._device is not None:
+                # a colocated peer's push/fetch hands over arrays on ITS
+                # device; a no-op for entries already resident here
+                value = jax.device_put(value, self._device)
         return value, tier
 
     def _record_prefix_tier(self, req: _Request, tier: str) -> None:
@@ -2465,7 +2499,7 @@ class ServingEngine:
                 if fetched is not None:
                     from gofr_tpu.serving.kv_spill import _to_device
 
-                    cached = _to_device(fetched)
+                    cached = _to_device(fetched, self._device)
                     prefix_tier = "remote"
                     # pay the transfer once per replica, not per request
                     self._prefix_cache.put(cache_key, cached)
@@ -2688,7 +2722,8 @@ class ServingEngine:
                     from gofr_tpu.serving.kv_spill import _to_device
 
                     for start, end, val in fetched:
-                        val = _to_device(val)  # async upload, no sync
+                        # async upload, no sync
+                        val = _to_device(val, self._device)
                         if (end >= total and
                                 val[0].shape[-1] != self.model_cfg.vocab_size):
                             break  # peer's preempt placeholder: same
@@ -4142,19 +4177,15 @@ class ServingEngine:
 
         B, S = self.config.max_slots, self.config.max_seq_len
         page = self.config.kv_page_size
-        if self.config.kv_dtype == "int8" and page < INT8_MIN_PAGE:
-            import jax as _jax
-
-            if _jax.default_backend() == "tpu":
-                # below the int8 Mosaic tile the kernel would silently
-                # fall back to the full-gather reference, INVERTING the
-                # bandwidth win int8 exists for (code-review r4)
-                raise ValueError(
-                    f"TPU_KV_DTYPE=int8 with TPU_KV_LAYOUT=paged needs "
-                    f"TPU_KV_PAGE_SIZE>={INT8_MIN_PAGE} on TPU (got "
-                    f"{page}): smaller pages violate the int8 Mosaic "
-                    "tile and lose the halved-bandwidth kernel path"
-                )
+        if (self.config.kv_dtype == "int8" and page < INT8_MIN_PAGE
+                and kernel_mode() == COMPILED):
+            # below the int8 Mosaic tile the kernel cannot compile (it
+            # raises); fail at construction, not at the first decode
+            raise ValueError(
+                f"TPU_KV_DTYPE=int8 with TPU_KV_LAYOUT=paged needs "
+                f"TPU_KV_PAGE_SIZE>={INT8_MIN_PAGE} on TPU (got "
+                f"{page}): smaller pages violate the int8 Mosaic tile"
+            )
         num_pages = self.config.kv_num_pages or (B * S + page - 1) // page
         return PagedKVCache(
             self.model_cfg, num_pages=num_pages, page_size=page,
@@ -4184,12 +4215,13 @@ class ServingEngine:
         here are host MIRRORS: authoritative for admission/recovery
         rebuilds, advanced at each consume."""
         B = self.config.max_slots
-        if self.config.kv_layout == "paged":
-            self.paged_cache = self._make_paged_cache()
-            self.cache = None
-        else:
-            self.paged_cache = None
-            self.cache = self._make_dense_cache()
+        with self._device_scope():
+            if self.config.kv_layout == "paged":
+                self.paged_cache = self._make_paged_cache()
+                self.cache = None
+            else:
+                self.paged_cache = None
+                self.cache = self._make_dense_cache()
         self.cache_len = np.zeros(B, np.int32)  # host mirror (committed tokens)
         self.last_token = np.zeros(B, np.int32)
         self.temperature = np.ones(B, np.float32)

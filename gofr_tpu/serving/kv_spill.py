@@ -62,19 +62,14 @@ def _to_host(value: Any) -> Any:
     return np.asarray(value)
 
 
-def _to_device(value: Any) -> Any:
-    """Re-upload a host pytree as device arrays: ``jnp.asarray`` is an
-    ASYNC host→device put (no sync) — safe on the engine thread; the
-    transfer overlaps the in-flight block and lands by its sync."""
-    import jax.numpy as jnp
+def _to_device(value: Any, device: Any = None) -> Any:
+    """Put a pytree of host (or another device's) arrays on ``device``
+    (None: the calling thread's default device). ``jax.device_put`` is
+    ASYNC (no sync) — safe on the engine thread; the transfer overlaps
+    the in-flight block and lands by its sync."""
+    import jax
 
-    if isinstance(value, tuple):
-        return tuple(_to_device(v) for v in value)
-    if isinstance(value, list):
-        return [_to_device(v) for v in value]
-    if isinstance(value, dict):
-        return {k: _to_device(v) for k, v in value.items()}
-    return jnp.asarray(value)
+    return jax.device_put(value, device)
 
 
 def _host_bytes(value: Any) -> int:

@@ -117,10 +117,13 @@ class AdapterRegistry:
     """
 
     def __init__(self, *, max_active: int = 8, metrics: Any = None,
-                 logger: Any = None) -> None:
+                 logger: Any = None, device: Any = None) -> None:
         if max_active < 2:
             raise ValueError("TPU_LORA_MAX_ACTIVE must be >= 2 (slot 0 is base)")
         self.max_active = int(max_active)
+        # where the device tables live: the device of the engine this
+        # registry serves (None: jax's default device)
+        self._device = device
         self._metrics = metrics
         self._logger = logger
         self._mu = threading.Lock()
@@ -227,6 +230,14 @@ class AdapterRegistry:
             self._b_table = jnp.pad(self._b_table, ((0, 0), (0, pad_r), (0, 0)))
             self._rank_max = r
 
+    def _upload_on_device(self, adapter_id: str, slot: int) -> None:
+        """:meth:`_upload` with the registry's device as the worker
+        thread's default (thread-local; jax's own default when None)."""
+        import jax
+
+        with jax.default_device(self._device):
+            self._upload(adapter_id, slot)
+
     def _upload(self, adapter_id: str, slot: int) -> None:
         """The lora-upload worker: materialize one adapter into its table
         slot. Runs OFF the engine thread (the kv-spill pattern); the
@@ -305,7 +316,7 @@ class AdapterRegistry:
             except AdapterBusy:
                 return  # admission-time acquire retries with pins drained
             self._upload_slot[adapter_id] = slot
-            fut = self._exec.submit(self._upload, adapter_id, slot)
+            fut = self._exec.submit(self._upload_on_device, adapter_id, slot)
             self._uploads[adapter_id] = fut
             fut.add_done_callback(
                 lambda f, aid=adapter_id: self._upload_done(aid, f)

@@ -49,19 +49,10 @@ def test_tolerance_band_absorbs_noise():
     assert len(check_records(bad, FLOORS)[0]) == 1
 
 
-def test_best_recorded_suffix_matches_the_floor():
-    # the tunnel-proof carry-forward line counts as evidence
-    records = [rec(
-        "llama_decode_tokens_per_sec_8b-int8_bs128_tpu_best_recorded", 5509.26
-    )]
-    violations, warnings = check_records(records, FLOORS)
-    assert violations == [] and warnings == []
-
-
 def test_best_value_wins_over_an_errored_line():
     records = [
         rec("llama_decode_tokens_per_sec_8b-int8_bs128_tpu", None,
-            error="tunnel down"),
+            error="chip lost"),
         rec("llama_decode_tokens_per_sec_8b-int8_bs128_tpu", 5700.0),
         rec("llama_decode_tokens_per_sec_8b-int8_bs128_tpu", 4000.0),
     ]
@@ -172,9 +163,10 @@ def test_min_direction_round_trips_through_the_floors_file(tmp_path):
 
 
 def test_bench_py_check_entrypoint_needs_no_backend():
-    """`bench.py --check` is the CI gate: it must run (and pass against the
-    committed BENCH_LOCAL.jsonl) without initializing any jax backend —
-    JAX_PLATFORMS deliberately unset here."""
+    """`bench.py --check` is the CI gate: it must run without initializing
+    any jax backend — JAX_PLATFORMS deliberately unset here — and a
+    checkout with no BENCH_LOCAL.jsonl (a run-time file) is zero records:
+    warnings, exit 0."""
     import os
 
     env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}

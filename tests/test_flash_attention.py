@@ -27,7 +27,7 @@ def test_matches_dense(causal, gqa):
     kv_len = jnp.array([S, S - 37], jnp.int32)
 
     ref = attention(q, k, v, causal=causal, kv_len=kv_len)
-    out = flash_attention(q, k, v, kv_len, causal=causal)
+    out = flash_attention(q, k, v, kv_len, causal=causal, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -35,7 +35,9 @@ def test_blocks_smaller_than_seq():
     B, S, H, D = 1, 512, 2, 64
     q, k, v = _rand((B, S, H, D), 0), _rand((B, S, H, D), 1), _rand((B, S, H, D), 2)
     ref = attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    out = flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128, interpret=True
+    )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
@@ -45,7 +47,7 @@ def test_bf16_inputs():
     k = _rand((B, S, H, D), 1, jnp.bfloat16)
     v = _rand((B, S, H, D), 2, jnp.bfloat16)
     ref = attention(q, k, v, causal=True)
-    out = flash_attention(q, k, v, causal=True)
+    out = flash_attention(q, k, v, causal=True, interpret=True)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=2e-2
@@ -58,7 +60,7 @@ def test_fully_masked_row_is_zero():
     B, S, H, D = 2, 128, 2, 64
     q, k, v = _rand((B, S, H, D), 0), _rand((B, S, H, D), 1), _rand((B, S, H, D), 2)
     kv_len = jnp.array([S, 0], jnp.int32)
-    out = flash_attention(q, k, v, kv_len, causal=True)
+    out = flash_attention(q, k, v, kv_len, causal=True, interpret=True)
     assert not np.any(np.isnan(np.asarray(out)))
     np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
 
@@ -66,13 +68,22 @@ def test_fully_masked_row_is_zero():
 def test_rejects_ragged_blocks():
     q = _rand((1, 100, 2, 64), 0)
     with pytest.raises(ValueError):
-        flash_attention(q, q, q, block_q=64, block_k=64)
+        flash_attention(q, q, q, block_q=64, block_k=64, interpret=True)
 
 
-def test_llama_prefill_flash_matches_dense():
+def test_llama_prefill_flash_matches_dense(monkeypatch):
     """End-to-end: the flagship model's prefill with the flash path vs the
-    dense path (cfg.attn_impl toggles; SURVEY §7 phase 4 hot path)."""
+    dense path (cfg.attn_impl toggles; SURVEY §7 phase 4 hot path). On the
+    CPU the model's flash call computes the dense reference
+    (ops/backend.py), so the kernel is put under the interpreter here."""
+    import functools
+
     from gofr_tpu.models import llama
+
+    monkeypatch.setattr(
+        llama, "flash_attention",
+        functools.partial(flash_attention, interpret=True),
+    )
 
     base = dict(
         vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,

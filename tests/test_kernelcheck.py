@@ -395,7 +395,7 @@ def test_shard_map_in_specs_arity_mismatch_flagged(tmp_path):
     findings = lint_tree(tmp_path, {
         "gofr_tpu/parallel/x.py": (
             "from jax.sharding import PartitionSpec as P\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def body(a, b):\n"
             "    return a, b\n"
             "def wrap(mesh, x, y, z):\n"
@@ -413,7 +413,7 @@ def test_partition_spec_arity_exceeds_declared_rank_flagged(tmp_path):
     findings = lint_tree(tmp_path, {
         "gofr_tpu/parallel/x.py": (
             "from jax.sharding import PartitionSpec as P\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def body(a,  # [B, S, D]\n"
             "         b):  # [B, D]\n"
             "    return a, b\n"
@@ -431,7 +431,7 @@ def test_out_specs_vs_returned_tuple_flagged(tmp_path):
     findings = lint_tree(tmp_path, {
         "gofr_tpu/parallel/x.py": (
             "from jax.sharding import PartitionSpec as P\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def body(a, b):\n"
             "    return a, b\n"
             "def wrap(mesh, x, y):\n"
@@ -446,7 +446,7 @@ def test_call_arity_vs_in_specs_flagged(tmp_path):
     findings = lint_tree(tmp_path, {
         "gofr_tpu/parallel/x.py": (
             "from jax.sharding import PartitionSpec as P\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def wrap(mesh, fn, x, y, z):\n"
             "    return shard_map(fn, mesh=mesh, in_specs=(P(), P()),\n"
             "                     out_specs=P())(x, y, z)\n"
@@ -462,7 +462,7 @@ def test_partial_bound_inner_and_trailing_spec_clean(tmp_path):
         "gofr_tpu/parallel/x.py": (
             "import functools\n"
             "from jax.sharding import PartitionSpec as P\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def inner(q,  # [B, S, H, D]\n"
             "          k,  # [B, S, H, D]\n"
             "          v,  # [B, S, H, D]\n"
@@ -487,7 +487,7 @@ def test_unresolvable_spec_pytree_skipped(tmp_path):
         "gofr_tpu/parallel/x.py": (
             "import jax\n"
             "from jax.sharding import PartitionSpec as P\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def wrap(mesh, stage_params, x_mb, axis):\n"
             "    def body(stage_local, x):\n"
             "        return x\n"

@@ -11,12 +11,6 @@ from gofr_tpu.ops import moe as moe_ops
 from gofr_tpu.parallel import build_mesh
 from gofr_tpu.parallel.mesh import MeshSpec
 
-from conftest import requires_modern_shard_map
-
-# the expert-parallel programs hard-abort (not fail) this jaxlib's XLA
-# compiler when built through the experimental shard_map fallback
-pytestmark = requires_modern_shard_map
-
 
 @pytest.fixture(scope="module")
 def ep_mesh():

@@ -6,12 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_modern_shard_map
-
-# pipeline-parallel programs need the modern SPMD partitioner (old jaxlib:
-# 'PartitionId instruction is not supported' / NotImplementedError)
-pytestmark = requires_modern_shard_map
-
 from gofr_tpu.models import llama
 from gofr_tpu.models.train import make_pp_train_step, sharded_train_step
 from gofr_tpu.parallel import build_mesh

@@ -35,51 +35,6 @@ pytestmark = pytest.mark.skipif(
 )
 
 
-def _spmd_rope_partitioner_broken() -> bool:
-    """Probe for the XLA GSPMD mis-partitioning this image's jax (0.4.37,
-    CPU backend) exhibits: on a 2D (fsdp×tp) mesh, a small column-sharded
-    projection followed by the RoPE rotate-half pattern (reshape →
-    split/concat of elementwise-computed halves along the sharded last
-    axis) produces silently WRONG numerics — sharded vs unsharded logits
-    diverge by O(1), not reduction noise (f32 + highest matmul precision
-    keeps honest runs at ~1e-6). Not a repo regression: the same model
-    code is exact on 1D (tp-only or fsdp-only) meshes, and the repro
-    below is pure jax/jnp. Token-equality tests skip while the probe
-    trips so a fixed jax re-enables them automatically — no silent red,
-    no rotting skip."""
-    import jax.numpy as jnp
-    from jax.sharding import Mesh as _Mesh
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    mesh = _Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("fsdp", "tp"))
-    rng = np.random.RandomState(0)
-    h = jnp.asarray(rng.randn(1, 8, 64), jnp.float32)
-    wk = jnp.asarray(rng.randn(64, 16), jnp.float32)
-    c = jnp.asarray(rng.randn(1, 8, 1, 4), jnp.float32)
-
-    def rotate_half(h_in, w):
-        k = (h_in @ w).reshape(1, 8, 2, 8)
-        x1, x2 = jnp.split(k, 2, axis=-1)
-        return jnp.concatenate([x1 * c - x2 * c, x2 * c + x1 * c], axis=-1)
-
-    f = jax.jit(rotate_half)
-    ref = np.asarray(f(h, wk), np.float64)
-    sharded = np.asarray(
-        f(h, jax.device_put(wk, NamedSharding(mesh, PartitionSpec("fsdp", "tp")))),
-        np.float64,
-    )
-    return bool(np.abs(ref - sharded).max() > 1e-3)
-
-
-requires_exact_spmd = pytest.mark.skipif(
-    len(jax.devices()) >= 8 and _spmd_rope_partitioner_broken(),
-    reason="XLA SPMD partitioner bug in this jax build (rotate-half "
-    "pattern mis-partitioned on a 2D mesh → sharded numerics silently "
-    "wrong; see _spmd_rope_partitioner_broken): token-equality vs the "
-    "unsharded engine cannot hold",
-)
-
-
 @pytest.fixture(scope="module")
 def tp_setup():
     # dims divisible by tp=4 and fsdp=2: vocab 320, d_model 64, kv-proj 32
@@ -111,7 +66,6 @@ def test_sharded_params_actually_sharded(tp_setup):
     assert shard_shape == (cfg.n_layers, cfg.d_model // 2, cfg.d_model // 4)
 
 
-@requires_exact_spmd
 def test_tp_engine_matches_unsharded(tp_setup):
     cfg, params, sharded, _ = tp_setup
     ref = _make_engine(cfg, params)
@@ -124,7 +78,6 @@ def test_tp_engine_matches_unsharded(tp_setup):
         ref.stop(), tp.stop()
 
 
-@requires_exact_spmd
 def test_tp_engine_paged_layout(tp_setup):
     """Paged KV on top of tp-sharded weights: same greedy tokens."""
     cfg, params, sharded, _ = tp_setup

@@ -49,7 +49,7 @@ def test_mesh_axis_unknown_collective_axis_name(tmp_path):
         "gofr_tpu/parallel/mesh.py": MESH_DECL,
         "gofr_tpu/parallel/cp.py": (
             "import jax\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def body(x):\n"
             '    return jax.lax.psum(x, "fsdp")\n'  # not in this mesh
             "def wrap(x, mesh):\n"
@@ -145,7 +145,7 @@ def test_collective_inside_shard_map_body_clean(tmp_path):
         "gofr_tpu/parallel/mesh.py": MESH_DECL,
         "gofr_tpu/parallel/good.py": (
             "import jax\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def wrap(x, mesh):\n"
             "    def body(v):\n"
             '        return jax.lax.psum(v, "tp")\n'
@@ -160,7 +160,7 @@ def test_collective_in_lambda_passed_to_shard_map_clean(tmp_path):
         "gofr_tpu/parallel/mesh.py": MESH_DECL,
         "gofr_tpu/parallel/good.py": (
             "import jax\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def wrap(x, mesh):\n"
             '    return shard_map(lambda v: jax.lax.psum(v, "tp"), '
             "mesh=mesh)(x)\n"
@@ -176,7 +176,7 @@ def test_collective_axis_parameter_convention_clean(tmp_path):
         "gofr_tpu/parallel/mesh.py": MESH_DECL,
         "gofr_tpu/parallel/good.py": (
             "import jax, functools\n"
-            "from gofr_tpu.jax_compat import shard_map\n"
+            "from jax import shard_map\n"
             "def ring_sharded(x, *, axis_name):\n"
             "    return jax.lax.pmean(x, axis_name)\n"
             "def ring(x, mesh, axis):\n"
