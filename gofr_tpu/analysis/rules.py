@@ -224,6 +224,7 @@ METRIC_REGISTER_METHODS = {
 # method -> index of the first label argument (k, v alternating)
 METRIC_USE_METHODS = {
     "increment_counter": 1,
+    "add_counter": 2,
     "delta_updown_counter": 2,
     "record_histogram": 2,
     "set_gauge": 2,

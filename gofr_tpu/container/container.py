@@ -200,8 +200,23 @@ class Container:
         # small fraction of the device step time
         m.new_gauge(
             "app_decode_host_ms_per_step",
-            "Host-side time per decode step (dispatch bookkeeping + block "
-            "consume, excluding the device sync wait), milliseconds",
+            "Host-side time per decode step (the block's fold, dispatch and "
+            "commit spans, not its sync wait), milliseconds",
+        )
+        # the step loop's own account (serving/engine.py _phase, docs/
+        # observability.md "Engine step spans"): where the loop thread's
+        # time goes, and what each dispatch issues
+        m.new_counter(
+            "app_engine_phase_seconds_total",
+            "Seconds the engine loop thread spent in each phase of the "
+            "step loop, each instant charged to the innermost phase open "
+            "(label phase=step|preempt|plan|admit|prefill|prefill_sync|"
+            "fold|dispatch|sync|commit|wait)",
+        )
+        m.new_counter(
+            "app_step_tokens_total",
+            "Token positions issued to the device per dispatch (label "
+            "kind=decode|prefill|padding)",
         )
         m.new_gauge(
             "app_decode_block_size",
@@ -339,8 +354,8 @@ class Container:
         )
         m.new_gauge(
             "app_engine_duty_cycle",
-            "Fraction of wall time the engine loop spent doing work "
-            "(heartbeat-derived, over the telemetry poll interval)",
+            "Fraction of wall time the engine loop spent doing work (its "
+            "phase account but wait, over the telemetry poll interval)",
         )
         # multi-tenant serving plane (serving/tenancy.py + serving/
         # lora.py, docs/serving.md "Multi-tenancy"): preemptions of

@@ -243,6 +243,10 @@ class Manager:
     def increment_counter(self, name: str, *labels: str, **label_kw: Any) -> None:
         self._record(name, (Counter, UpDownCounter), "add", 1.0, labels, label_kw)
 
+    def add_counter(self, name: str, value: float, *labels: str, **label_kw: Any) -> None:
+        """A counter of amounts (seconds, tokens), not of events."""
+        self._record(name, (Counter,), "add", value, labels, label_kw)
+
     def delta_updown_counter(self, name: str, value: float, *labels: str, **label_kw: Any) -> None:
         self._record(name, (UpDownCounter,), "add", value, labels, label_kw)
 

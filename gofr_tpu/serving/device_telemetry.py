@@ -10,7 +10,8 @@ daemon thread and NEVER on the engine thread:
   CPU — simply report no devices), exported as ``app_tpu_hbm_bytes``
   (labels ``device``, ``kind=used|limit``) and ``app_tpu_hbm_util``;
 - **engine duty cycle** from the loop thread's cumulative busy counter
-  (``ServingEngine.busy_seconds()``, stamped beside the heartbeat):
+  (``ServingEngine.busy_seconds()``: the step loop's phase account but
+  ``wait``):
   Δbusy/Δwall over the poll interval, exported as
   ``app_engine_duty_cycle``.
 

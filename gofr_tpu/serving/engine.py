@@ -379,18 +379,23 @@ class _Inflight:
     never donated anywhere — holding it here cannot alias a donated
     carry (the round-4 use-after-donate shape)."""
 
-    __slots__ = ("packed", "rows", "dispatched_at", "steps", "host_s",
-                 "prefill_rows", "last_logits")
+    __slots__ = ("packed", "rows", "dispatched_at", "steps", "blk",
+                 "dispatch_s", "prefill_rows", "last_logits")
 
     def __init__(self, packed: Any, rows: list, dispatched_at: float,
-                 steps: int = 1, host_s: float = 0.0,
+                 steps: int = 1, blk: int = 0,
                  prefill_rows: list | None = None,
                  last_logits: Any = None) -> None:
         self.packed = packed
         self.rows = rows
         self.dispatched_at = dispatched_at
         self.steps = steps
-        self.host_s = host_s  # host-side time spent building the dispatch
+        # the block's sequence number: what joins its gofr.step.dispatch,
+        # .sync and .commit spans, and a request's first_blk/last_blk
+        self.blk = blk
+        # seconds of the block's dispatch span (the fold inside it
+        # included), set when that span closes
+        self.dispatch_s = 0.0
         # ragged dispatches only: the prefill-chunk rows this block ran —
         # (slot, req, cursor, start, n_tokens, final, chunk_index) — plus
         # the device-resident last-position logits (retained ONLY for the
@@ -406,6 +411,47 @@ def _block_sync(value: Any) -> np.ndarray:
     monkeypatch it to count syncs, and gofrlint's host-sync rule keeps any
     other materialization out of the hot functions."""
     return np.asarray(value)  # gofrlint: disable=host-sync -- the one sanctioned block-sync point
+
+
+# the step loop's phases (docs/observability.md "Engine step spans"):
+# "step" is one loop iteration, the others nest inside it. Each is a
+# gofr.step[.<phase>] span on the profiler's clock and a key of
+# ServingEngine._phase_s
+STEP_PHASES = ("step", "preempt", "plan", "admit", "prefill", "prefill_sync",
+               "fold", "dispatch", "sync", "commit", "wait")
+_SPAN_NAMES = {p: "gofr.step" if p == "step" else f"gofr.step.{p}"
+               for p in STEP_PHASES}
+
+
+class _StepPhase:
+    """One open phase of the step loop (``ServingEngine._phase``): a
+    ``jax.profiler.TraceAnnotation`` — an event on the ``/host:*`` plane of
+    any profiler session, on the device trace's clock, and an inactive
+    TraceMe otherwise — and a segment of the engine's phase accumulator.
+    Keyword values are integers (or short constant strings) the caller
+    already holds: a span never reads the device."""
+
+    __slots__ = ("_engine", "_phase", "_outer", "_span", "_t0", "seconds")
+
+    def __init__(self, engine: "ServingEngine", phase: str, kw: dict) -> None:
+        self._engine = engine
+        self._phase = phase
+        self._span = jax.profiler.TraceAnnotation(_SPAN_NAMES[phase], **kw)
+        self.seconds = 0.0  # the span's whole duration, once it has closed
+
+    def __enter__(self) -> "_StepPhase":
+        self._span.__enter__()
+        self._outer = self._engine._phase_state[0]
+        self._t0 = self._engine._phase_switch(self._phase)
+        return self
+
+    def set(self, **kw: Any) -> None:
+        """Keywords known only once the span's work is under way."""
+        self._span.set_metadata(**kw)
+
+    def __exit__(self, *exc: Any) -> None:
+        self.seconds = self._engine._phase_switch(self._outer) - self._t0
+        self._span.__exit__(*exc)
 
 
 class ServingEngine:
@@ -562,11 +608,19 @@ class ServingEngine:
         # like the detok executor — a warm restart must not erase the
         # record of the requests it swept.
         self.timeline = TimelineRecorder(self.config.requestz_capacity)
-        # engine duty cycle: cumulative busy seconds stamped by the loop
-        # thread (single writer); the device-telemetry poller reads the
-        # delta over its interval (serving/device_telemetry.py)
-        self._busy_s = 0.0
-        self._iter_t0 = time.monotonic()  # rebased at each loop iteration
+        # the step loop's one time account (_phase): seconds the loop
+        # thread spent in each phase, every instant charged to the
+        # innermost phase open. _phase_state is (open phase, when it
+        # opened or resumed, seconds closed outside "wait") — one tuple,
+        # swapped whole by the loop thread, so busy_seconds() reads a
+        # consistent snapshot from any thread without a lock
+        self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
+        self._phase_state: tuple[str | None, float, float] = (
+            None, time.monotonic(), 0.0)
+        self._phase_counted = dict(self._phase_s)  # what the counter has
+        self._step_iter = 0  # loop iterations: gofr.step's iter=
+        self._blk_seq = 0    # dispatched blocks: blk= on their spans
+        self._retires = 0    # rows retired: a commit span's retired=
         # optional DeviceTelemetry poller backref: health_check embeds its
         # last sample, the membership announcer reads HBM headroom off it
         self.device_telemetry: Any = None
@@ -1329,21 +1383,47 @@ class ServingEngine:
 
     def busy_seconds(self) -> float:
         """Cumulative seconds the loop thread spent doing work (not
-        waiting): the device-telemetry poller derives the engine duty
-        cycle from the delta over its poll interval."""
-        return self._busy_s
+        waiting): every closed phase but ``wait``, plus the elapsed part
+        of the phase open now — so a caller that saw its request complete
+        sees its work here already. The device-telemetry poller derives
+        the engine duty cycle from the delta over its poll interval."""
+        phase, since, busy = self._phase_state
+        if phase is not None and phase != "wait":
+            busy += time.monotonic() - since
+        return busy
 
-    def _flush_busy(self) -> None:
-        """Fold the running iteration's elapsed work time into the busy
-        counter and rebase. Called at each iteration's end AND from
-        _finish before a terminal settlement is queued — a caller that
-        observed its request complete must observe busy_seconds() > 0,
-        even when the whole generation fit inside the loop's very first
-        iteration (a prefill whose first token is EOS). Engine-thread
-        only: _finish and the loop share the single writer."""
+    def _phase(self, phase: str, **kw: Any) -> _StepPhase:
+        """``with self._phase("dispatch", blk=n) as span:`` — one phase of
+        the step loop as a span on the profiler's clock and a segment of
+        ``_phase_s``. Engine thread only; closes on any unwind."""
+        return _StepPhase(self, phase, kw)
+
+    def _phase_switch(self, phase: str | None) -> float:
+        """Charge the time since the last switch to the phase that was
+        open and make ``phase`` the open one. Only the loop's owner keeps
+        the account: a retired thread unwinding through its spans must
+        not write into its replacement's (with no loop thread at all —
+        direct calls, tests — the caller owns it)."""
         now = time.monotonic()
-        self._busy_s += now - self._iter_t0
-        self._iter_t0 = now
+        if self._thread is None or threading.current_thread() is self._thread:
+            was, since, busy = self._phase_state
+            if was is not None:
+                self._phase_s[was] += now - since
+                if was != "wait":
+                    busy += now - since
+            self._phase_state = (phase, now, busy)
+        return now
+
+    def _count_phases(self) -> None:
+        """Carry the account into app_engine_phase_seconds_total, once a
+        loop iteration rather than at every span."""
+        for phase, total in self._phase_s.items():
+            delta = total - self._phase_counted[phase]
+            if delta > 0.0:
+                self._phase_counted[phase] = total
+                self._metrics.add_counter(
+                    "app_engine_phase_seconds_total", delta, phase=phase
+                )
 
     @property
     def in_cold_dispatch(self) -> bool:
@@ -1356,14 +1436,15 @@ class ServingEngine:
     @contextlib.contextmanager
     def _cold_dispatch(self, *key: Any) -> Any:
         """Context manager marking a possibly-compiling dispatch section
-        (keyed by executable signature). The key is warmed only when the
-        section completes, so a dispatch that faults keeps its grace."""
+        (keyed by executable signature); yields whether it is the
+        signature's first use. The key is warmed only when the section
+        completes, so a dispatch that faults keeps its grace."""
         if key in self._warmed:
-            yield
+            yield False
             return
         self._cold_key = key
         try:
-            yield
+            yield True
         finally:
             # only the loop's current owner may clear the marker: a
             # retired (quarantined) thread thawing out of its dispatch
@@ -2070,12 +2151,15 @@ class ServingEngine:
 
     def _loop_body(self, me: threading.Thread) -> None:
         cfg = self.config
+        # a replaced thread may have left a phase open in the account:
+        # this thread's first gofr.step is a root
+        self._phase_state = (None, time.monotonic(), self._phase_state[2])
         # the identity guard retires a quarantined thread: after a warm
         # restart that could not join it, self._thread points at the NEW
         # loop thread — the old one must exit the moment it thaws instead
         # of racing the replacement over rebuilt state
         while self._running and me is self._thread:
-            self.heartbeat = self._iter_t0 = time.monotonic()
+            self.heartbeat = time.monotonic()
             chaos.maybe_fail("engine.step")
             if not self._running or me is not self._thread:
                 # stopped or replaced while hung at the chaos point: re-check
@@ -2083,44 +2167,16 @@ class ServingEngine:
                 # (a warm_restart waiting in join() has already swept the
                 # queue this iteration would admit from)
                 continue
+            self._step_iter += 1
             try:
-                # the preemption ladder runs BEFORE the plan: a freed
-                # slot is admitted in this same iteration, so a waiting
-                # higher class pays at most one loop latency
-                did_work = self._maybe_preempt()
-                if self._reclaiming:
-                    # a reclamation notice sheds batch-class rows NOW
-                    # (warm page-out, retriable failure) so the remaining
-                    # drain budget serves interactive streams only
-                    did_work |= self._reclaim_sweep()
-                plan = self._plan_step()
-                did_work |= self._admit(plan)
-                if any(s is not None for s in self.slots):
-                    did_work |= self._decode_step(plan)
-                elif self._inflight_q:
-                    # drain: every row of the in-flight blocks retired while
-                    # they ran; their tokens are stale by construction
-                    self._consume_block(self._inflight_q.popleft())
-                    did_work = True
-                else:
-                    self._last_consume_t = None  # idle gap must not skew TPOT
-                # duty-cycle accounting: the iteration so far was WORK
-                # (dispatches, syncs, bookkeeping); the wake wait below is
-                # idle. The telemetry poller divides the busy delta by
-                # wall time (app_engine_duty_cycle). _iter_t0, not the
-                # heartbeat — progress points re-stamp that mid-iteration,
-                # and _finish flushes the running iteration's slice early
-                # so a settled request always implies recorded busy time.
-                self._flush_busy()
-                if not did_work:
-                    if (self._draining and not self._inflight_q
-                            and not any(s is not None for s in self.slots)
-                            and self._sched.stats()["queue_depth"] == 0):
-                        # drained dry: every accepted request reached a
-                        # terminal state; drain() is waiting on this
-                        self._idle.set()
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                # one gofr.step a loop iteration; mono_ns puts RequestTimeline's
+                # clock (and /requestz's stamps) beside the profiler's, so an
+                # operator's trace aligns without a harness
+                with self._phase("step", iter=self._step_iter,
+                                 mono_ns=time.monotonic_ns()):
+                    self._loop_step()
+                if self._metrics:
+                    self._count_phases()
             except Exception as exc:  # the step must never kill the loop
                 # a retired thread's step error is noise from quarantined
                 # state — it must not _fail_all (that would sweep the
@@ -2138,38 +2194,83 @@ class ServingEngine:
                 # dedicated engine thread, bounded by idle_sleep_s
                 time.sleep(cfg.idle_sleep_s)
 
+    def _loop_step(self) -> None:
+        """One iteration's work, each part a phase (_phase). What the
+        iteration spends outside a named phase is charged to "step"."""
+        # the preemption ladder runs BEFORE the plan: a freed slot is
+        # admitted in this same iteration, so a waiting higher class
+        # pays at most one loop latency
+        with self._phase("preempt"):
+            did_work = self._maybe_preempt()
+            if self._reclaiming:
+                # a reclamation notice sheds batch-class rows NOW
+                # (warm page-out, retriable failure) so the remaining
+                # drain budget serves interactive streams only
+                did_work |= self._reclaim_sweep()
+        plan = self._plan_step()
+        with self._phase("admit") as span:
+            did_work |= self._admit(plan, span)
+        if any(s is not None for s in self.slots):
+            did_work |= self._decode_step(plan)
+        elif self._inflight_q:
+            # drain: every row of the in-flight blocks retired while
+            # they ran; their tokens are stale by construction
+            self._consume_block(self._inflight_q.popleft())
+            did_work = True
+        else:
+            self._last_consume_t = None  # idle gap must not skew TPOT
+        if not did_work:
+            if (self._draining and not self._inflight_q
+                    and not any(s is not None for s in self.slots)
+                    and self._sched.stats()["queue_depth"] == 0):
+                # drained dry: every accepted request reached a
+                # terminal state; drain() is waiting on this
+                self._idle.set()
+            # the one phase busy_seconds() leaves out: the telemetry
+            # poller divides the busy delta by wall time
+            # (app_engine_duty_cycle)
+            with self._phase("wait"):
+                self._wake.wait(timeout=0.05)
+            self._wake.clear()
+
     # -- admission -------------------------------------------------------------
     def _plan_step(self) -> StepPlan:
         """Assemble this iteration's step plan (serving/stepplan.py):
         decode rows reserved first, chunk grants for partially-prefilled
         cursors, an admission quota out of the leftover budget."""
-        decode_rows = sum(
-            1 for slot, req in enumerate(self.slots)
-            if req is not None and slot not in self._cursors
-        )
-        free_slots = sum(1 for s in self.slots if s is None)
-        plan = self._planner.plan(
-            decode_rows=decode_rows,
-            cursors=list(self._cursors.values()),
-            free_slots=free_slots,
-            queue_depth=self._sched.pending(),
-        )
-        if self._metrics:
-            # set on CHANGE (including the drop back to zero at idle —
-            # a frozen non-zero gauge would report phantom load forever),
-            # skipped in steady state to keep per-iteration host cost flat
-            snapshot = (plan.prefill_tokens, decode_rows, len(self._cursors))
-            if snapshot != self._plan_gauges:
-                self._plan_gauges = snapshot
-                self._metrics.set_gauge(
-                    "app_step_plan_prefill_tokens", plan.prefill_tokens
-                )
-                self._metrics.set_gauge(
-                    "app_step_plan_decode_rows", decode_rows
-                )
-                self._metrics.set_gauge(
-                    "app_step_plan_cursors", len(self._cursors)
-                )
+        with self._phase("plan") as span:
+            decode_rows = sum(
+                1 for slot, req in enumerate(self.slots)
+                if req is not None and slot not in self._cursors
+            )
+            free_slots = sum(1 for s in self.slots if s is None)
+            queue_depth = self._sched.pending()
+            span.set(decode_rows=decode_rows, cursors=len(self._cursors),
+                     queue=queue_depth)
+            plan = self._planner.plan(
+                decode_rows=decode_rows,
+                cursors=list(self._cursors.values()),
+                free_slots=free_slots,
+                queue_depth=queue_depth,
+            )
+            if self._metrics:
+                # set on CHANGE (including the drop back to zero at idle —
+                # a frozen non-zero gauge would report phantom load
+                # forever), skipped in steady state to keep per-iteration
+                # host cost flat
+                snapshot = (plan.prefill_tokens, decode_rows,
+                            len(self._cursors))
+                if snapshot != self._plan_gauges:
+                    self._plan_gauges = snapshot
+                    self._metrics.set_gauge(
+                        "app_step_plan_prefill_tokens", plan.prefill_tokens
+                    )
+                    self._metrics.set_gauge(
+                        "app_step_plan_decode_rows", decode_rows
+                    )
+                    self._metrics.set_gauge(
+                        "app_step_plan_cursors", len(self._cursors)
+                    )
         return plan
 
     def _route_chunked(self, prompt_len: int) -> bool:
@@ -2182,7 +2283,7 @@ class ServingEngine:
         return (prompt_len > self._chunk_tokens
                 or prompt_len > max(self._buckets()))
 
-    def _admit(self, plan: StepPlan | None = None) -> bool:
+    def _admit(self, plan: StepPlan, span: _StepPhase) -> bool:
         # bind ONCE: a warm restart that replaces this thread mid-admit
         # swaps self._sched for a rebuilt one — the pairs delivered below
         # belong to THIS scheduler, and releases/requeues must never land
@@ -2198,10 +2299,8 @@ class ServingEngine:
         # canceled-but-queued request resolves only through an admit
         # delivery); max(…, 1) covers a submit that raced in after the
         # plan read its queue depth
-        cap = max(plan.admit_cap, 1) if plan is not None else (
-            self.config.admission_per_step
-        )
-        pairs, canceled_ids = sched.admit(cap)
+        pairs, canceled_ids = sched.admit(max(plan.admit_cap, 1))
+        span.set(admitted=len(pairs))
         # the admit call itself can hang (native mutex held under a wedged
         # step); a thread thawing out of it retired would otherwise process
         # the old scheduler's pairs against the REPLACEMENT engine's state
@@ -2426,158 +2525,169 @@ class ServingEngine:
         ids = req.serve_ids
         S = len(ids)
         bucket = batch_ops.pad_bucket(S, self._buckets())
-        tokens = np.full((1, bucket), self.tokenizer.pad_id, np.int32)
-        tokens[0, :S] = ids
-        seq_len = jnp.array([S], jnp.int32)
+        with self._phase("prefill", rid=req.id, bucket=bucket,
+                         tokens=S) as phase:
+            tokens = np.full((1, bucket), self.tokenizer.pad_id, np.int32)
+            tokens[0, :S] = ids
+            seq_len = jnp.array([S], jnp.int32)
 
-        if self.paged_cache is not None:
-            # page reservation first: OutOfBlocks must requeue BEFORE any
-            # device work (the request keeps its place; pool pressure is a
-            # transient, not an error) — unless the prompt can NEVER fit,
-            # which must fail the request, not livelock the admit loop
-            from gofr_tpu.serving.kv_cache import OutOfBlocks
+            if self.paged_cache is not None:
+                # page reservation first: OutOfBlocks must requeue BEFORE any
+                # device work (the request keeps its place; pool pressure is a
+                # transient, not an error) — unless the prompt can NEVER fit,
+                # which must fail the request, not livelock the admit loop
+                from gofr_tpu.serving.kv_cache import OutOfBlocks
 
-            if self.paged_cache.pages_needed(bucket) > self.paged_cache.num_pages:
-                # permanent, not transient: however empty the pool gets,
-                # this prompt can NEVER fit — a 429 would invite clients to
-                # retry forever; 413 / FAILED_PRECONDITION says "shrink it"
-                raise ErrorRequestEntityTooLarge(
-                    f"prompt needs {self.paged_cache.pages_needed(bucket)} KV pages; "
-                    f"pool has {self.paged_cache.num_pages} in total"
-                )
-            try:
-                self.paged_cache.alloc_slot(
-                    slot, seq_id=req.id, prompt_len=S, reserve_tokens=bucket
-                )
-            except OutOfBlocks:
-                raise _RequeueRequest() from None
-
-        cache_key = None
-        cached = None
-        prefix_tier = None
-        if self._prefix_cache is not None:
-            # sampling params are NOT in the key: the cached value is the
-            # pre-sampling prefill output, shared across temperatures.
-            # A STRING key keeps the injected-cache contract (the container
-            # Cache protocol declares str keys; a datasource-backed cache
-            # can serialize it directly).
-            import hashlib as _hashlib
-
-            digest = _hashlib.blake2b(
-                np.asarray(ids, np.int32).tobytes(), digest_size=16
-            ).hexdigest()
-            # the adapter id is part of the key BY CONSTRUCTION: a
-            # cross-adapter KV hit is impossible however the cache is
-            # shared/migrated (docs/serving.md "Multi-tenancy")
-            cache_key = (
-                f"prefill:{bucket}:{S}:{digest}:{req.adapter_id or '-'}"
-            )
-            cached, prefix_tier = self._cache_lookup(cache_key)
-            if cached is None and self._kv_migrator is not None:
-                # disaggregated handoff first (the router named the
-                # prefill source — no heartbeat-advertisement wait), then
-                # the advisory cluster tier: another replica advertises
-                # this exact prefill — migrate its slabs instead of
-                # recomputing (either failure stays a compute miss)
-                fetched = None
-                # the fetch is bounded by what the request has left: an
-                # expired one degrades to a compute miss without a fetch
-                budget = req.remaining(time.perf_counter())
-                if req.handoff_from is not None:
-                    fetched = self._kv_migrator.fetch_one_handoff(
-                        cache_key, req.handoff_from, deadline=budget
+                if self.paged_cache.pages_needed(bucket) > self.paged_cache.num_pages:
+                    # permanent, not transient: however empty the pool gets,
+                    # this prompt can NEVER fit — a 429 would invite clients to
+                    # retry forever; 413 / FAILED_PRECONDITION says "shrink it"
+                    raise ErrorRequestEntityTooLarge(
+                        f"prompt needs {self.paged_cache.pages_needed(bucket)} KV pages; "
+                        f"pool has {self.paged_cache.num_pages} in total"
                     )
-                if fetched is None:
-                    fetched = self._kv_migrator.fetch_one(
-                        cache_key, deadline=budget
+                try:
+                    self.paged_cache.alloc_slot(
+                        slot, seq_id=req.id, prompt_len=S, reserve_tokens=bucket
                     )
-                # the fetch can block (remote transport timeout): a warm
-                # restart may have retired this thread meanwhile — the
-                # put below would poison the cache the restart just
-                # reset (the same hazard as the compute-path put)
-                self._check_retired()
-                if fetched is not None:
-                    from gofr_tpu.serving.kv_spill import _to_device
+                except OutOfBlocks:
+                    raise _RequeueRequest() from None
 
-                    cached = _to_device(fetched, self._device)
-                    prefix_tier = "remote"
-                    # pay the transfer once per replica, not per request
-                    self._prefix_cache.put(cache_key, cached)
-            self._record_prefix_tier(req, prefix_tier)
+            cache_key = None
+            cached = None
+            prefix_tier = None
+            if self._prefix_cache is not None:
+                # sampling params are NOT in the key: the cached value is the
+                # pre-sampling prefill output, shared across temperatures.
+                # A STRING key keeps the injected-cache contract (the container
+                # Cache protocol declares str keys; a datasource-backed cache
+                # can serialize it directly).
+                import hashlib as _hashlib
 
-        tl = req.timeline
-        if tl is not None:
-            tl.stamp("prefill_start")
-        span = self._req_span(
-            "prefill",
-            f"serve.prefill b{bucket}" + (" (prefix hit)" if cached else ""),
-            req,
-        )
-        if tl is not None:
-            pspan = tl.spans.get("prefill")
-            if pspan is not None:
-                pspan.set_attribute("prefill.bucket", bucket)
-                pspan.set_attribute("prefill.prefix_hit", cached is not None)
-                pspan.set_attribute("prefix_tier", prefix_tier or "miss")
-                pspan.set_attribute("tokens.prompt", S)
-        # bind the KV storage ONCE, before the long dispatch: a warm
-        # restart that replaces this thread mid-compute swaps
-        # self.paged_cache/self.cache for rebuilt ones — re-reading them
-        # after the dispatch would donate the REPLACEMENT engine's pools
-        # from a quarantined thread
-        pc, dense = self.paged_cache, self.cache
-        with span, self._cold_dispatch("prefill", bucket, cached is not None):
-            if cached is not None:
-                last_logits, k_slab, v_slab = cached
-            else:
-                last_logits, k_slab, v_slab = batch_ops.prefill_compute(
-                    cfg, self.params, jnp.asarray(tokens), seq_len
+                digest = _hashlib.blake2b(
+                    np.asarray(ids, np.int32).tobytes(), digest_size=16
+                ).hexdigest()
+                # the adapter id is part of the key BY CONSTRUCTION: a
+                # cross-adapter KV hit is impossible however the cache is
+                # shared/migrated (docs/serving.md "Multi-tenancy")
+                cache_key = (
+                    f"prefill:{bucket}:{S}:{digest}:{req.adapter_id or '-'}"
                 )
-            self._check_retired()  # replaced during the compute: no writes
-            # ...including the prefix cache: a retired thread thawing out
-            # of a device-loss hang would insert DEAD slabs into the cache
-            # warm_restart just reset, poisoning every future hit on this
-            # prefix
-            if cached is None and cache_key is not None:
-                # slabs are fresh, never-donated arrays: safe to retain
-                self._prefix_cache.put(cache_key, (last_logits, k_slab, v_slab))
-            if pc is not None:
-                pc.write_prefill(slot, k_slab, v_slab)
-            elif dense.quantized:
-                self.cache = batch_ops.insert_slot_quantized(
-                    dense, k_slab, v_slab, jnp.int32(slot)
-                )
-            else:
-                dense.k, dense.v = batch_ops.insert_slot(
-                    dense.k, dense.v, k_slab, v_slab, jnp.int32(slot)
-                )
-            # sample the first token with this request's params, keyed by
-            # request id (NOT the shared stream — see _rng_root above).
-            # The row's LoRA delta applies HERE, at the sampling site —
-            # cached entries stay base-model logits (adapter-scoped keys
-            # already make cross-adapter hits impossible).
-            key = jax.random.fold_in(self._rng_root, req.id)
-            from gofr_tpu.ops.sampling import sample_logits
+                cached, prefix_tier = self._cache_lookup(cache_key)
+                if cached is None and self._kv_migrator is not None:
+                    # disaggregated handoff first (the router named the
+                    # prefill source — no heartbeat-advertisement wait), then
+                    # the advisory cluster tier: another replica advertises
+                    # this exact prefill — migrate its slabs instead of
+                    # recomputing (either failure stays a compute miss)
+                    fetched = None
+                    # the fetch is bounded by what the request has left: an
+                    # expired one degrades to a compute miss without a fetch
+                    budget = req.remaining(time.perf_counter())
+                    if req.handoff_from is not None:
+                        fetched = self._kv_migrator.fetch_one_handoff(
+                            cache_key, req.handoff_from, deadline=budget
+                        )
+                    if fetched is None:
+                        fetched = self._kv_migrator.fetch_one(
+                            cache_key, deadline=budget
+                        )
+                    # the fetch can block (remote transport timeout): a warm
+                    # restart may have retired this thread meanwhile — the
+                    # put below would poison the cache the restart just
+                    # reset (the same hazard as the compute-path put)
+                    self._check_retired()
+                    if fetched is not None:
+                        from gofr_tpu.serving.kv_spill import _to_device
 
-            first = sample_logits(
-                self._lora_adjusted(req, last_logits, ids[-1]), key,
-                temperature=jnp.float32(req.temperature),
-                top_k=jnp.int32(req.top_k),
-                top_p=jnp.float32(req.top_p),
+                        cached = _to_device(fetched, self._device)
+                        prefix_tier = "remote"
+                        # pay the transfer once per replica, not per request
+                        self._prefix_cache.put(cache_key, cached)
+                self._record_prefix_tier(req, prefix_tier)
+            phase.set(route="bucketed" if cached is None else "prefix_hit")
+
+            tl = req.timeline
+            if tl is not None:
+                tl.stamp("prefill_start")
+            span = self._req_span(
+                "prefill",
+                f"serve.prefill b{bucket}" + (" (prefix hit)" if cached else ""),
+                req,
             )
-            first_id = int(first[0])
+            if tl is not None:
+                pspan = tl.spans.get("prefill")
+                if pspan is not None:
+                    pspan.set_attribute("prefill.bucket", bucket)
+                    pspan.set_attribute("prefill.prefix_hit", cached is not None)
+                    pspan.set_attribute("prefix_tier", prefix_tier or "miss")
+                    pspan.set_attribute("tokens.prompt", S)
+            # bind the KV storage ONCE, before the long dispatch: a warm
+            # restart that replaces this thread mid-compute swaps
+            # self.paged_cache/self.cache for rebuilt ones — re-reading them
+            # after the dispatch would donate the REPLACEMENT engine's pools
+            # from a quarantined thread
+            pc, dense = self.paged_cache, self.cache
+            with span, self._cold_dispatch("prefill", bucket, cached is not None):
+                if cached is not None:
+                    last_logits, k_slab, v_slab = cached
+                else:
+                    last_logits, k_slab, v_slab = batch_ops.prefill_compute(
+                        cfg, self.params, jnp.asarray(tokens), seq_len
+                    )
+                self._check_retired()  # replaced during the compute: no writes
+                # ...including the prefix cache: a retired thread thawing out
+                # of a device-loss hang would insert DEAD slabs into the cache
+                # warm_restart just reset, poisoning every future hit on this
+                # prefix
+                if cached is None and cache_key is not None:
+                    # slabs are fresh, never-donated arrays: safe to retain
+                    self._prefix_cache.put(cache_key, (last_logits, k_slab, v_slab))
+                if pc is not None:
+                    pc.write_prefill(slot, k_slab, v_slab)
+                elif dense.quantized:
+                    self.cache = batch_ops.insert_slot_quantized(
+                        dense, k_slab, v_slab, jnp.int32(slot)
+                    )
+                else:
+                    dense.k, dense.v = batch_ops.insert_slot(
+                        dense.k, dense.v, k_slab, v_slab, jnp.int32(slot)
+                    )
+                # sample the first token with this request's params, keyed by
+                # request id (NOT the shared stream — see _rng_root above).
+                # The row's LoRA delta applies HERE, at the sampling site —
+                # cached entries stay base-model logits (adapter-scoped keys
+                # already make cross-adapter hits impossible).
+                key = jax.random.fold_in(self._rng_root, req.id)
+                from gofr_tpu.ops.sampling import sample_logits
 
-        # the dispatch is back: a warm restart may have replaced this
-        # thread while it sat in the compile — commit nothing if so (the
-        # request was requeued; the successor thread redoes the prefill)
-        self._check_retired()
-        # progress stamp: a multi-prefill admission can legitimately
-        # outlast TPU_ENGINE_STALL_S in one loop iteration — the watchdog
-        # must see "slow but moving", not "hung"; a truly stuck dispatch
-        # stamps nothing anywhere (and a first-call jit compile widens the
-        # threshold via _cold_dispatch above)
-        self.heartbeat = time.monotonic()
-        self._commit_prefilled(slot, req, first_id, S)
+                first = sample_logits(
+                    self._lora_adjusted(req, last_logits, ids[-1]), key,
+                    temperature=jnp.float32(req.temperature),
+                    top_k=jnp.int32(req.top_k),
+                    top_p=jnp.float32(req.top_p),
+                )
+                # the engine thread's other read of the device, after
+                # the block's one sync: it returns when the block in
+                # flight, this prefill and the sampler's programs have
+                # run, and no next block is queued meanwhile. (The wait
+                # itself can come earlier in this prefill, where the eager
+                # sampler queues its programs behind the running block:
+                # PERF.md §5.)
+                with self._phase("prefill_sync", rid=req.id):
+                    first_id = int(first[0])
+
+            # the dispatch is back: a warm restart may have replaced this
+            # thread while it sat in the compile — commit nothing if so (the
+            # request was requeued; the successor thread redoes the prefill)
+            self._check_retired()
+            # progress stamp: a multi-prefill admission can legitimately
+            # outlast TPU_ENGINE_STALL_S in one loop iteration — the watchdog
+            # must see "slow but moving", not "hung"; a truly stuck dispatch
+            # stamps nothing anywhere (and a first-call jit compile widens the
+            # threshold via _cold_dispatch above)
+            self.heartbeat = time.monotonic()
+            self._commit_prefilled(slot, req, first_id, S)
 
     def _commit_prefilled(self, slot: int, req: _Request, first_id: int,
                           resident: int) -> None:
@@ -2650,176 +2760,180 @@ class ServingEngine:
         the _admit cleanup contract."""
         ids = req.serve_ids  # prompt + emitted tokens (preempt resume)
         total = len(ids)
-        pc = self.paged_cache
-        if pc is not None and pc.pages_needed(total) > pc.num_pages:
-            raise ErrorRequestEntityTooLarge(
-                f"prompt needs {pc.pages_needed(total)} KV pages; "
-                f"pool has {pc.num_pages} in total"
-            )
+        with self._phase("prefill", rid=req.id, bucket=0,
+                         tokens=total) as phase:
+            pc = self.paged_cache
+            if pc is not None and pc.pages_needed(total) > pc.num_pages:
+                raise ErrorRequestEntityTooLarge(
+                    f"prompt needs {pc.pages_needed(total)} KV pages; "
+                    f"pool has {pc.num_pages} in total"
+                )
 
-        # probe the prefix cache for the longest chain of cached
-        # chunk-boundary prefixes (each entry holds that chunk's K/V delta
-        # slab + the prefix's last-position logits). The boundary keys are
-        # computed ONCE per tenancy and ride the cursor — the per-chunk
-        # PUT at consume reuses them instead of re-digesting the prefix.
-        hits: list[tuple[int, int, Any]] = []
-        pos = 0
-        cache_keys: dict[tuple[int, int], str] | None = None
-        tiers: set[str] = set()
-        if self._prefix_cache is not None and self._chunk_cache_enabled:
-            boundaries = self._chunk_cache_keys(ids, req.adapter_id)
-            cache_keys = {(s, e): k for s, e, k in boundaries}
-            for start, end, key in boundaries:
-                val, tier = self._cache_lookup(key)
-                if val is None:
-                    break
-                if end >= total and val[0].shape[-1] != self.model_cfg.vocab_size:
-                    # a preemption page-out stored this span with a
-                    # PLACEHOLDER logits column (the paged-out row never
-                    # had last-position logits to give). Its KV is good
-                    # as a NON-final link, but it must never serve as the
-                    # chain's final entry — the zero-dispatch admit below
-                    # would sample this request's first token from
-                    # garbage. Stop the walk; the tail chunk recomputes
-                    # and samples fresh.
-                    break
-                hits.append((start, end, val))
-                tiers.add(tier)
-                pos = end
-            if pos < total and self._kv_migrator is not None:
-                # disaggregated handoff first: the router named the
-                # prefill source, and the fetch runs under the kv.handoff
-                # two-phase-commit discipline — a COMPLETE, contiguity-
-                # audited chain or nothing (a torn handoff must never
-                # commit a partial chain it believed complete). A source
-                # or transport failure returns [] and the normal
-                # advisory tiers below degrade to re-prefill.
-                remaining = [b for b in boundaries if b[0] >= pos]
-                fetched = []
-                # bounded by the request's remaining deadline, exactly
-                # like the monolithic path's handoff/advisory fetches
-                budget = req.remaining(time.perf_counter())
-                if req.handoff_from is not None:
-                    fetched = self._kv_migrator.fetch_handoff(
-                        remaining, req.handoff_from, deadline=budget
-                    )
-                if not fetched:
-                    # cluster tier: migrate the longest advertised
-                    # chunk-boundary chain from the owning replica. The
-                    # fetch is advisory and contiguous-from-pos by
-                    # contract — a torn transfer keeps the fetched prefix
-                    # and the planner's chunk grants compute the rest
-                    # (never a double-prefill: committed spans stay
-                    # contiguous).
-                    fetched = self._kv_migrator.fetch_chain(
-                        remaining, deadline=budget
-                    )
-                # the fetch can block (remote transport timeout): a
-                # retired thread must not put dead slabs into the
-                # replacement engine's freshly-reset cache
-                self._check_retired()
-                if fetched:
-                    from gofr_tpu.serving.kv_spill import _to_device
-
-                    for start, end, val in fetched:
-                        # async upload, no sync
-                        val = _to_device(val, self._device)
-                        if (end >= total and
-                                val[0].shape[-1] != self.model_cfg.vocab_size):
-                            break  # peer's preempt placeholder: same
-                            # final-entry guard as the local walk above
-                        hits.append((start, end, val))
-                        pos = end
-                        # pay the transfer once per replica: later
-                        # requests sharing this prefix hit locally
-                        self._prefix_cache.put(
-                            cache_keys[(start, end)], val
+            # probe the prefix cache for the longest chain of cached
+            # chunk-boundary prefixes (each entry holds that chunk's K/V delta
+            # slab + the prefix's last-position logits). The boundary keys are
+            # computed ONCE per tenancy and ride the cursor — the per-chunk
+            # PUT at consume reuses them instead of re-digesting the prefix.
+            hits: list[tuple[int, int, Any]] = []
+            pos = 0
+            cache_keys: dict[tuple[int, int], str] | None = None
+            tiers: set[str] = set()
+            if self._prefix_cache is not None and self._chunk_cache_enabled:
+                boundaries = self._chunk_cache_keys(ids, req.adapter_id)
+                cache_keys = {(s, e): k for s, e, k in boundaries}
+                for start, end, key in boundaries:
+                    val, tier = self._cache_lookup(key)
+                    if val is None:
+                        break
+                    if end >= total and val[0].shape[-1] != self.model_cfg.vocab_size:
+                        # a preemption page-out stored this span with a
+                        # PLACEHOLDER logits column (the paged-out row never
+                        # had last-position logits to give). Its KV is good
+                        # as a NON-final link, but it must never serve as the
+                        # chain's final entry — the zero-dispatch admit below
+                        # would sample this request's first token from
+                        # garbage. Stop the walk; the tail chunk recomputes
+                        # and samples fresh.
+                        break
+                    hits.append((start, end, val))
+                    tiers.add(tier)
+                    pos = end
+                if pos < total and self._kv_migrator is not None:
+                    # disaggregated handoff first: the router named the
+                    # prefill source, and the fetch runs under the kv.handoff
+                    # two-phase-commit discipline — a COMPLETE, contiguity-
+                    # audited chain or nothing (a torn handoff must never
+                    # commit a partial chain it believed complete). A source
+                    # or transport failure returns [] and the normal
+                    # advisory tiers below degrade to re-prefill.
+                    remaining = [b for b in boundaries if b[0] >= pos]
+                    fetched = []
+                    # bounded by the request's remaining deadline, exactly
+                    # like the monolithic path's handoff/advisory fetches
+                    budget = req.remaining(time.perf_counter())
+                    if req.handoff_from is not None:
+                        fetched = self._kv_migrator.fetch_handoff(
+                            remaining, req.handoff_from, deadline=budget
                         )
-                    tiers.add("remote")
-            self._record_prefix_tier(
-                req,
-                "remote" if "remote" in tiers
-                else "host" if "host" in tiers
-                else "device" if hits else "miss",
-            )
+                    if not fetched:
+                        # cluster tier: migrate the longest advertised
+                        # chunk-boundary chain from the owning replica. The
+                        # fetch is advisory and contiguous-from-pos by
+                        # contract — a torn transfer keeps the fetched prefix
+                        # and the planner's chunk grants compute the rest
+                        # (never a double-prefill: committed spans stay
+                        # contiguous).
+                        fetched = self._kv_migrator.fetch_chain(
+                            remaining, deadline=budget
+                        )
+                    # the fetch can block (remote transport timeout): a
+                    # retired thread must not put dead slabs into the
+                    # replacement engine's freshly-reset cache
+                    self._check_retired()
+                    if fetched:
+                        from gofr_tpu.serving.kv_spill import _to_device
 
-        from gofr_tpu.serving.kv_cache import OutOfBlocks
-
-        if hits and pc is not None:
-            try:
-                pc.alloc_slot(slot, seq_id=req.id, prompt_len=0,
-                              reserve_tokens=pos)
-            except OutOfBlocks:
-                raise _RequeueRequest() from None
-
-        tl = req.timeline
-        if tl is not None:
-            tl.stamp("prefill_start")
-        for start, end, (_logits, k_slab, v_slab) in hits:
-            if pc is not None:
-                pc.write_span(slot, start, k_slab, v_slab)
-            else:
-                dense = self.cache
-                dense.k, dense.v = batch_ops.insert_chunk(
-                    dense.k, dense.v, k_slab, v_slab,
-                    jnp.int32(slot), jnp.int32(start),
+                        for start, end, val in fetched:
+                            # async upload, no sync
+                            val = _to_device(val, self._device)
+                            if (end >= total and
+                                    val[0].shape[-1] != self.model_cfg.vocab_size):
+                                break  # peer's preempt placeholder: same
+                                # final-entry guard as the local walk above
+                            hits.append((start, end, val))
+                            pos = end
+                            # pay the transfer once per replica: later
+                            # requests sharing this prefix hit locally
+                            self._prefix_cache.put(
+                                cache_keys[(start, end)], val
+                            )
+                        tiers.add("remote")
+                self._record_prefix_tier(
+                    req,
+                    "remote" if "remote" in tiers
+                    else "host" if "host" in tiers
+                    else "device" if hits else "miss",
                 )
-        if hits:
-            if pc is not None:
-                pc.advance_slot(slot, pos)
-            if tl is not None:
-                tl.chunk(0, pos, prefix_hit=True)
-            if self._metrics:
-                self._metrics.record_histogram(
-                    "app_prefill_chunk_tokens", pos, kind="prefix_hit",
-                )
 
-        if pos >= total:
-            # the WHOLE prompt was cached at chunk boundaries: sample the
-            # first token from the cached last-position logits and admit
-            # straight to decode — zero prefill dispatches (the admission-
-            # path sync mirrors the monolithic prefix-hit path)
-            span = self._req_span("prefill", "serve.prefill chunked (prefix hit)", req)
+            from gofr_tpu.serving.kv_cache import OutOfBlocks
+
+            if hits and pc is not None:
+                try:
+                    pc.alloc_slot(slot, seq_id=req.id, prompt_len=0,
+                                  reserve_tokens=pos)
+                except OutOfBlocks:
+                    raise _RequeueRequest() from None
+
+            tl = req.timeline
             if tl is not None:
-                pspan = tl.spans.get("prefill")
-                if pspan is not None:
-                    pspan.set_attribute("prefill.prefix_hit", True)
-                    pspan.set_attribute(
-                        "prefix_tier", tl.prefix_tier or "device"
+                tl.stamp("prefill_start")
+            for start, end, (_logits, k_slab, v_slab) in hits:
+                if pc is not None:
+                    pc.write_span(slot, start, k_slab, v_slab)
+                else:
+                    dense = self.cache
+                    dense.k, dense.v = batch_ops.insert_chunk(
+                        dense.k, dense.v, k_slab, v_slab,
+                        jnp.int32(slot), jnp.int32(start),
                     )
-            with span:
-                last_logits = hits[-1][2][0]
-                key = jax.random.fold_in(self._rng_root, req.id)
-                from gofr_tpu.ops.sampling import sample_logits
+            if hits:
+                if pc is not None:
+                    pc.advance_slot(slot, pos)
+                if tl is not None:
+                    tl.chunk(0, pos, prefix_hit=True)
+                if self._metrics:
+                    self._metrics.record_histogram(
+                        "app_prefill_chunk_tokens", pos, kind="prefix_hit",
+                    )
 
-                first = sample_logits(
-                    self._lora_adjusted(req, last_logits, ids[-1]), key,
-                    temperature=jnp.float32(req.temperature),
-                    top_k=jnp.int32(req.top_k),
-                    top_p=jnp.float32(req.top_p),
-                )
-                first_id = int(first[0])
-            self._check_retired()
-            self._commit_prefilled(slot, req, first_id, total)
-            return
+            phase.set(route="chunked" if pos < total else "prefix_hit")
+            if pos >= total:
+                # the WHOLE prompt was cached at chunk boundaries: sample the
+                # first token from the cached last-position logits and admit
+                # straight to decode — zero prefill dispatches (the admission-
+                # path sync mirrors the monolithic prefix-hit path)
+                span = self._req_span("prefill", "serve.prefill chunked (prefix hit)", req)
+                if tl is not None:
+                    pspan = tl.spans.get("prefill")
+                    if pspan is not None:
+                        pspan.set_attribute("prefill.prefix_hit", True)
+                        pspan.set_attribute(
+                            "prefix_tier", tl.prefix_tier or "device"
+                        )
+                with span:
+                    last_logits = hits[-1][2][0]
+                    key = jax.random.fold_in(self._rng_root, req.id)
+                    from gofr_tpu.ops.sampling import sample_logits
 
-        cursor = ChunkCursor(req=req, slot=slot, total=total,
-                             seq=self._cursor_seq, priority=req.priority)
-        self._cursor_seq += 1
-        cursor.cache_keys = cache_keys
-        cursor.committed = cursor.dispatched = pos
-        cursor.prefix_hit = pos
-        cursor.chunk_index = 1 if hits else 0
-        cursor.allocated = bool(hits and pc is not None)
-        req.slot = slot
-        self.slots[slot] = req
-        self.cache_len[slot] = pos
-        self.last_token[slot] = 0
-        self.temperature[slot] = req.temperature
-        self.top_k[slot] = req.top_k
-        self.top_p[slot] = req.top_p
-        self.adapter_idx[slot] = req.adapter_slot
-        self._cursors[slot] = cursor
+                    first = sample_logits(
+                        self._lora_adjusted(req, last_logits, ids[-1]), key,
+                        temperature=jnp.float32(req.temperature),
+                        top_k=jnp.int32(req.top_k),
+                        top_p=jnp.float32(req.top_p),
+                    )
+                    with self._phase("prefill_sync", rid=req.id):
+                        first_id = int(first[0])
+                self._check_retired()
+                self._commit_prefilled(slot, req, first_id, total)
+                return
+
+            cursor = ChunkCursor(req=req, slot=slot, total=total,
+                                 seq=self._cursor_seq, priority=req.priority)
+            self._cursor_seq += 1
+            cursor.cache_keys = cache_keys
+            cursor.committed = cursor.dispatched = pos
+            cursor.prefix_hit = pos
+            cursor.chunk_index = 1 if hits else 0
+            cursor.allocated = bool(hits and pc is not None)
+            req.slot = slot
+            self.slots[slot] = req
+            self.cache_len[slot] = pos
+            self.last_token[slot] = 0
+            self.temperature[slot] = req.temperature
+            self.top_k[slot] = req.top_k
+            self.top_p[slot] = req.top_p
+            self.adapter_idx[slot] = req.adapter_slot
+            self._cursors[slot] = cursor
 
     def _cursor_requeue(self, slot: int, req: _Request,
                         cursor: ChunkCursor) -> None:
@@ -3074,8 +3188,10 @@ class ServingEngine:
         self._check_retired()  # replaced during a long _admit: unwind first
         if self.config.spec_tokens > 0:
             return self._spec_step()
-        inflight = self._dispatch_decode(plan)
+        with self._phase("dispatch") as span:
+            inflight = self._dispatch_decode(plan, span)
         if inflight is not None:
+            inflight.dispatch_s = span.seconds
             self._inflight_q.append(inflight)
         did = inflight is not None
         if self._inflight_q and (
@@ -3112,159 +3228,170 @@ class ServingEngine:
         max_seq = self.config.max_seq_len
         self._pending_admit.clear()  # host state is authoritative in spec mode
 
-        rows: list[tuple[int, _Request]] = []
-        now = time.perf_counter()
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            if req.canceled:
-                self._retire(slot, "cancel")
-                continue
-            if req.expired(now):
-                # abandon mid-stream: free the slot for live requests and
-                # resolve with the tokens produced so far
-                self._retire(slot, "deadline_exceeded")
-                continue
-            if (len(req.tokens) >= req.max_new_tokens
-                    or len(req.prompt_ids) + len(req.tokens) >= max_seq):
-                continue  # retires at the next consume's limit checks
-            rows.append((slot, req))
-        if not rows:
-            return False
+        with self._phase("dispatch") as span:
+            rows: list[tuple[int, _Request]] = []
+            now = time.perf_counter()
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                if req.canceled:
+                    self._retire(slot, "cancel")
+                    continue
+                if req.expired(now):
+                    # abandon mid-stream: free the slot for live requests and
+                    # resolve with the tokens produced so far
+                    self._retire(slot, "deadline_exceeded")
+                    continue
+                if (len(req.tokens) >= req.max_new_tokens
+                        or len(req.prompt_ids) + len(req.tokens) >= max_seq):
+                    continue  # retires at the next consume's limit checks
+                rows.append((slot, req))
+            if not rows:
+                return False
 
-        B = self.config.max_slots
-        chunk = np.full((B, T), -1, np.int32)
-        for slot, req in rows:
-            chunk[slot, 0] = self.last_token[slot]
-            room = min(
-                req.max_new_tokens - len(req.tokens),
-                max_seq - 1 - (len(req.prompt_ids) + len(req.tokens)),
-            )
-            if req.temperature == 0 and room > 1 and K > 0:
-                draft = llama._prompt_lookup_draft(
-                    req.prompt_ids + req.tokens, self.config.spec_ngram,
-                    min(K, room - 1),
+            B = self.config.max_slots
+            chunk = np.full((B, T), -1, np.int32)
+            for slot, req in rows:
+                chunk[slot, 0] = self.last_token[slot]
+                room = min(
+                    req.max_new_tokens - len(req.tokens),
+                    max_seq - 1 - (len(req.prompt_ids) + len(req.tokens)),
                 )
-                chunk[slot, 1 : 1 + len(draft)] = draft
-
-        pc = self.paged_cache
-        if pc is not None:
-            slot_ids = [s for s, _ in rows]
-            if not pc.try_reserve_chunk(slot_ids, T):
-                # pool pressure: fall back to single-position coverage per
-                # row (chunk tails spill to the trash page; zero drafts
-                # still verify position 0 = a plain decode step). A row
-                # that can't even cover one more token retires with what
-                # it has, like the non-spec path.
-                kept = []
-                for slot, req in rows:
-                    if pc.try_reserve_chunk([slot], 1):
-                        chunk[slot, 1:] = -1
-                        kept.append((slot, req))
-                    else:
-                        if self._logger:
-                            self._logger.warn(
-                                f"KV pool exhausted; retiring request "
-                                f"{req.id} early"
-                            )
-                        req.kv_exhausted = True
-                        self._retire(slot, "kv_exhausted")
-                rows = kept
-                if not rows:
-                    return True
-
-        mask = np.zeros(B, bool)
-        for slot, _ in rows:
-            mask[slot] = True
-        # counted AFTER the reservation fallback may have cleared drafts
-        drafted_total = int((chunk[mask, 1:] >= 0).sum())
-        # spec mode re-uploads the [B] sampling params per chunk: three
-        # tiny host→device copies (no sync) against a K+1-position verify
-        # forward — not worth a dirty-tracking cache
-        temp_d = jnp.asarray(self.temperature.copy())
-        topk_d = jnp.asarray(self.top_k.copy())
-        topp_d = jnp.asarray(self.top_p.copy())
-        if self._mask_host is None or not np.array_equal(mask, self._mask_host):
-            self._mask_dev = jnp.asarray(mask)
-            self._mask_host = mask
-        chunk_d = jnp.asarray(chunk)
-        start_d = jnp.asarray(np.maximum(self.cache_len, 1))
-
-        t0 = time.perf_counter()
-        with self._cold_dispatch(
-            "spec", "paged" if pc is not None else "dense",
-            pc.quantized if pc is not None else self.cache.quantized,
-        ):
-            if pc is not None:
-                cap = np.zeros(B, np.int32)
-                for slot, _ in rows:
-                    cap[slot] = pc.owned_capacity(slot)
-                cap_d = jnp.asarray(cap)
-                # unpack into LOCALS (and the pre-bound pc, which a
-                # restart never mutates): a retired thread's unpack must
-                # not clobber the replacement engine's state — self.*
-                # commits happen only after the retirement check below
-                if pc.quantized:
-                    (packed, pc.k_pool, pc.v_pool, pc.ks_pool,
-                     pc.vs_pool, new_rng) = batch_ops.verify_and_sample_paged_q(
-                        cfg, self.params, pc.k_pool, pc.v_pool,
-                        pc.ks_pool, pc.vs_pool, pc.tables_device(), chunk_d,
-                        start_d, self._mask_dev, cap_d,
-                        temp_d, topk_d, topp_d, self.rng,
+                if req.temperature == 0 and room > 1 and K > 0:
+                    draft = llama._prompt_lookup_draft(
+                        req.prompt_ids + req.tokens, self.config.spec_ngram,
+                        min(K, room - 1),
                     )
-                else:
-                    (packed, pc.k_pool, pc.v_pool, new_rng) = (
-                        batch_ops.verify_and_sample_paged(
+                    chunk[slot, 1 : 1 + len(draft)] = draft
+
+            pc = self.paged_cache
+            if pc is not None:
+                slot_ids = [s for s, _ in rows]
+                if not pc.try_reserve_chunk(slot_ids, T):
+                    # pool pressure: fall back to single-position coverage per
+                    # row (chunk tails spill to the trash page; zero drafts
+                    # still verify position 0 = a plain decode step). A row
+                    # that can't even cover one more token retires with what
+                    # it has, like the non-spec path.
+                    kept = []
+                    for slot, req in rows:
+                        if pc.try_reserve_chunk([slot], 1):
+                            chunk[slot, 1:] = -1
+                            kept.append((slot, req))
+                        else:
+                            if self._logger:
+                                self._logger.warn(
+                                    f"KV pool exhausted; retiring request "
+                                    f"{req.id} early"
+                                )
+                            req.kv_exhausted = True
+                            self._retire(slot, "kv_exhausted")
+                    rows = kept
+                    if not rows:
+                        return True
+
+            mask = np.zeros(B, bool)
+            for slot, _ in rows:
+                mask[slot] = True
+            # counted AFTER the reservation fallback may have cleared drafts
+            drafted_total = int((chunk[mask, 1:] >= 0).sum())
+            # spec mode re-uploads the [B] sampling params per chunk: three
+            # tiny host→device copies (no sync) against a K+1-position verify
+            # forward — not worth a dirty-tracking cache
+            temp_d = jnp.asarray(self.temperature.copy())
+            topk_d = jnp.asarray(self.top_k.copy())
+            topp_d = jnp.asarray(self.top_p.copy())
+            if self._mask_host is None or not np.array_equal(mask, self._mask_host):
+                self._mask_dev = jnp.asarray(mask)
+                self._mask_host = mask
+            chunk_d = jnp.asarray(chunk)
+            start_d = jnp.asarray(np.maximum(self.cache_len, 1))
+
+            t0 = time.perf_counter()
+            with self._cold_dispatch(
+                "spec", "paged" if pc is not None else "dense",
+                pc.quantized if pc is not None else self.cache.quantized,
+            ) as cold:
+                if pc is not None:
+                    cap = np.zeros(B, np.int32)
+                    for slot, _ in rows:
+                        cap[slot] = pc.owned_capacity(slot)
+                    cap_d = jnp.asarray(cap)
+                    # unpack into LOCALS (and the pre-bound pc, which a
+                    # restart never mutates): a retired thread's unpack must
+                    # not clobber the replacement engine's state — self.*
+                    # commits happen only after the retirement check below
+                    if pc.quantized:
+                        (packed, pc.k_pool, pc.v_pool, pc.ks_pool,
+                         pc.vs_pool, new_rng) = batch_ops.verify_and_sample_paged_q(
                             cfg, self.params, pc.k_pool, pc.v_pool,
-                            pc.tables_device(), chunk_d, start_d,
-                            self._mask_dev, cap_d,
+                            pc.ks_pool, pc.vs_pool, pc.tables_device(), chunk_d,
+                            start_d, self._mask_dev, cap_d,
                             temp_d, topk_d, topp_d, self.rng,
                         )
+                    else:
+                        (packed, pc.k_pool, pc.v_pool, new_rng) = (
+                            batch_ops.verify_and_sample_paged(
+                                cfg, self.params, pc.k_pool, pc.v_pool,
+                                pc.tables_device(), chunk_d, start_d,
+                                self._mask_dev, cap_d,
+                                temp_d, topk_d, topp_d, self.rng,
+                            )
+                        )
+                    new_cache = self.cache  # dense path untouched
+                else:
+                    packed, new_cache, new_rng = batch_ops.verify_and_sample(
+                        cfg, self.params, self.cache, chunk_d, start_d,
+                        temp_d, topk_d, topp_d, self.rng,
                     )
-                new_cache = self.cache  # dense path untouched
-            else:
-                packed, new_cache, new_rng = batch_ops.verify_and_sample(
-                    cfg, self.params, self.cache, chunk_d, start_d,
-                    temp_d, topk_d, topp_d, self.rng,
-                )
 
-            # accepted tokens + per-row accept count come back as ONE
-            # packed [B, T+1] array: one sync per chunk, like the plain
-            # path's one sync per block
+            self._blk_seq += 1
+            blk = self._blk_seq
+            span.set(blk=blk, kind="spec", rows=len(rows), steps=T,
+                     kv_tokens=int(self.cache_len[mask].sum()),
+                     chunk_rows=0, chunk_tokens=0, cold=int(cold))
+            self._count_step_tokens(len(rows) * T, 0, B * T)
+        # accepted tokens + per-row accept count come back as ONE packed
+        # [B, T+1] array: one sync per chunk, like the plain path's one
+        # sync per block
+        with self._phase("sync", blk=blk):
             packed_np = _block_sync(packed)
-        # the sync returned: a warm restart may have replaced this thread
-        # while the chunk verified — commit nothing to rebuilt state if so
-        self._check_retired()
-        out_np = packed_np[:, :-1]
-        na_np = packed_np[:, -1]
-        self.cache, self.rng = new_cache, new_rng
-        self.heartbeat = time.monotonic()  # the sync returned: progress
-        step_time = time.perf_counter() - t0
+        with self._phase("commit", blk=blk) as commit:
+            # the sync returned: a warm restart may have replaced this thread
+            # while the chunk verified — commit nothing to rebuilt state if so
+            self._check_retired()
+            out_np = packed_np[:, :-1]
+            na_np = packed_np[:, -1]
+            self.cache, self.rng = new_cache, new_rng
+            self.heartbeat = time.monotonic()  # the sync returned: progress
+            step_time = time.perf_counter() - t0
 
-        n_active = 0
-        accepted_total = 0
-        emitted_total = 0
-        for slot, req in rows:
-            n_active += 1
-            accepted_total += int(na_np[slot])
-            committed = 0
-            for i in range(int(na_np[slot]) + 1):
-                committed += 1
-                self._commit_token(slot, req, int(out_np[slot, i]))
-                if self.slots[slot] is not req:
-                    break  # retired mid-chunk: discard the tail
-            emitted_total += committed
-            if req.timeline is not None:
-                req.timeline.block(committed)
-            # chunk position 0 (the previously emitted token) plus the
-            # accepted drafts are now resident KV; the bonus token commits
-            # as the NEXT chunk's position 0 — so residency advances by the
-            # emitted count even when the row retired mid-chunk (harmless:
-            # the slot was freed)
-            if self.slots[slot] is req:
-                self.cache_len[slot] += committed
-                if pc is not None:
-                    pc.advance_slot(slot, committed)
+            n_active = 0
+            accepted_total = 0
+            emitted_total = 0
+            retires = self._retires
+            for slot, req in rows:
+                n_active += 1
+                accepted_total += int(na_np[slot])
+                committed = 0
+                for i in range(int(na_np[slot]) + 1):
+                    committed += 1
+                    self._commit_token(slot, req, int(out_np[slot, i]))
+                    if self.slots[slot] is not req:
+                        break  # retired mid-chunk: discard the tail
+                emitted_total += committed
+                if req.timeline is not None:
+                    req.timeline.block(committed, blk=blk)
+                # chunk position 0 (the previously emitted token) plus the
+                # accepted drafts are now resident KV; the bonus token commits
+                # as the NEXT chunk's position 0 — so residency advances by the
+                # emitted count even when the row retired mid-chunk (harmless:
+                # the slot was freed)
+                if self.slots[slot] is req:
+                    self.cache_len[slot] += committed
+                    if pc is not None:
+                        pc.advance_slot(slot, committed)
+            commit.set(tokens=emitted_total, retired=self._retires - retires)
 
         self.spec_stats["dispatches"] += 1
         self.spec_stats["accepted"] += accepted_total
@@ -3322,14 +3449,18 @@ class ServingEngine:
             self.adapter_idx,
         )
 
-    def _dispatch_decode(self, plan: StepPlan | None = None) -> _Inflight | None:
+    def _dispatch_decode(self, plan: StepPlan | None,
+                         span: _StepPhase) -> _Inflight | None:
+        """Build and launch the next block inside ``span``, the
+        iteration's gofr.step.dispatch: a span that dispatched a block
+        carries its number (blk) and what the block holds; one that found
+        no row to run carries nothing."""
         cfg = self.model_cfg
         chaos.maybe_fail("decode.dispatch")
         self._maybe_device_loss()
         # a hang at the chaos point can outlive a warm restart: re-check
         # ownership BEFORE reading slots/pools that may since be rebuilt
         self._check_retired()
-        host_t0 = time.perf_counter()
 
         rows: list[tuple[int, _Request]] = []
         now = time.perf_counter()
@@ -3436,27 +3567,29 @@ class ServingEngine:
         # donated scatter — steady state uploads nothing per block
         state = self._dec_state
         if state is None:
-            state = self._make_device_state()
+            with self._phase("fold", n=len(rows)):
+                state = self._make_device_state()
         elif self._pending_admit:
             items = sorted(self._pending_admit.items())
             self._pending_admit.clear()
-            idx = np.fromiter((s for s, _ in items), np.int32, len(items))
-            state = batch_ops.admit_decode_state(
-                state, jnp.asarray(idx),
-                jnp.asarray(np.fromiter((v[0] for _, v in items), np.int32,
-                                        len(items))),
-                jnp.asarray(np.fromiter((v[1] for _, v in items), np.int32,
-                                        len(items))),
-                jnp.asarray(np.fromiter((v[2] for _, v in items), np.int32,
-                                        len(items))),
-                jnp.asarray(np.fromiter((v[3] for _, v in items), np.int32,
-                                        len(items))),
-                jnp.asarray(self.temperature[idx]),
-                jnp.asarray(self.top_k[idx]),
-                jnp.asarray(self.top_p[idx]),
-                jnp.asarray(np.fromiter((v[4] for _, v in items), np.int32,
-                                        len(items))),
-            )
+            with self._phase("fold", n=len(items)):
+                idx = np.fromiter((s for s, _ in items), np.int32, len(items))
+                state = batch_ops.admit_decode_state(
+                    state, jnp.asarray(idx),
+                    jnp.asarray(np.fromiter((v[0] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(np.fromiter((v[1] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(np.fromiter((v[2] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(np.fromiter((v[3] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(self.temperature[idx]),
+                    jnp.asarray(self.top_k[idx]),
+                    jnp.asarray(self.top_p[idx]),
+                    jnp.asarray(np.fromiter((v[4] for _, v in items),
+                                            np.int32, len(items))),
+                )
         # NOTE: self._dec_state is NOT updated here — the scatter donated
         # the old buffers, and the commit happens in one place after the
         # block dispatch (a failed dispatch resets it via _fail_all)
@@ -3475,13 +3608,13 @@ class ServingEngine:
         last_logits = None
         lora = self._lora.tables() if self._lora is not None else None
         if chunk_rows:
-            (packed, last_logits, new_cache, new_state, prefill_rows) = (
-                self._dispatch_ragged(cfg, pc, state, mask_d, chunk_rows, N)
-            )
+            (packed, last_logits, new_cache, new_state, prefill_rows,
+             cold) = self._dispatch_ragged(
+                cfg, pc, state, mask_d, chunk_rows, N)
         elif pc is not None:
             tables_d = pc.tables_device()
             with self._cold_dispatch("decode", "paged", pc.quantized, N,
-                                     lora is not None):
+                                     lora is not None) as cold:
                 if pc.quantized:
                     (packed, pc.k_pool, pc.v_pool, pc.ks_pool, pc.vs_pool,
                      new_state) = batch_ops.decode_block_paged_q(
@@ -3500,7 +3633,7 @@ class ServingEngine:
         else:
             with self._cold_dispatch("decode", "dense",
                                      self.cache.quantized, N,
-                                     lora is not None):
+                                     lora is not None) as cold:
                 packed, new_cache, new_state = batch_ops.decode_block(
                     cfg, self.params, self.cache, state, mask_d, N,
                     lora=lora,
@@ -3518,10 +3651,32 @@ class ServingEngine:
             if (prefill_rows and self._prefix_cache is not None
                 and self._chunk_cache_enabled) else None
         )
+        self._blk_seq += 1
+        chunk_tokens = sum(n for *_, n in chunk_rows)
+        # cache_len is the committed mirror: rows with a block in flight
+        # are resident N positions further on the device
+        span.set(blk=self._blk_seq, kind="ragged" if chunk_rows else "decode",
+                 rows=len(rows), steps=N, kv_tokens=int(self.cache_len[mask].sum()),
+                 chunk_rows=len(chunk_rows), chunk_tokens=chunk_tokens,
+                 cold=int(cold))
+        self._count_step_tokens(
+            len(rows) * N, chunk_tokens,
+            self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),
+        )
         return _Inflight(
-            packed, rows, t0, steps=N, host_s=t0 - host_t0,
+            packed, rows, t0, steps=N, blk=self._blk_seq,
             prefill_rows=prefill_rows, last_logits=keep_logits,
         )
+
+    def _count_step_tokens(self, decode: int, prefill: int, issued: int) -> None:
+        """app_step_tokens_total at the point of issue: of the positions a
+        dispatch computes, those that serve a decode row, those that are
+        prompt, and the padding that is neither."""
+        if self._metrics:
+            for kind, n in (("decode", decode), ("prefill", prefill),
+                            ("padding", issued - decode - prefill)):
+                if n:
+                    self._metrics.add_counter("app_step_tokens_total", n, kind=kind)
 
     def _dispatch_ragged(self, cfg: Any, pc: Any, state: Any, mask_d: Any,
                          chunk_rows: list, N: int) -> tuple:
@@ -3532,7 +3687,8 @@ class ServingEngine:
         whose chunk completes the prompt get their first token sampled on
         device and are folded into the donated DecodeState inside the
         dispatch; the host reads everything back at the block's single
-        sync."""
+        sync. The tuple's last member says whether this was the
+        executable's first use."""
         B = self.config.max_slots
         C = self._chunk_tokens
         chunk = np.full((B, C), -1, np.int32)
@@ -3583,7 +3739,7 @@ class ServingEngine:
             cactive_d = jnp.asarray(cactive)
             kvcap_d = jnp.asarray(kvcap)
             with self._cold_dispatch("ragged", "paged", pc.quantized, N,
-                                     lora is not None):
+                                     lora is not None) as cold:
                 if pc.quantized:
                     (packed, last_logits, pc.k_pool, pc.v_pool, pc.ks_pool,
                      pc.vs_pool, new_state) = batch_ops.ragged_step_paged_q(
@@ -3607,7 +3763,7 @@ class ServingEngine:
         else:
             with self._cold_dispatch("ragged", "dense",
                                      self.cache.quantized, N,
-                                     lora is not None):
+                                     lora is not None) as cold:
                 (packed, last_logits, new_cache,
                  new_state) = batch_ops.ragged_step(
                     cfg, self.params, self.cache, state, chunk_d, start_d,
@@ -3635,127 +3791,132 @@ class ServingEngine:
                 span.set_attribute(
                     "prefix_tier", req.timeline.prefix_tier or "miss"
                 )
-        return packed, last_logits, new_cache, new_state, prefill_rows
+        return packed, last_logits, new_cache, new_state, prefill_rows, cold
 
     def _consume_block(self, rec: _Inflight) -> None:
         # declared unpack site (kernel_contracts.UNPACK_SITES): the
         # column offsets below are checked against the 'ragged' pack
         # layout — tokens | done | n_valid | first — by kernelcheck
-        packed = _block_sync(rec.packed)  # THE one sync for N device steps
-        # the sync returned: a warm restart may have replaced this thread
-        # while it waited — its tokens belong to requests already settled
-        # or requeued, so commit nothing (and don't stamp a heartbeat that
-        # would mask the REPLACEMENT thread's health)
-        self._check_retired()
-        self.heartbeat = time.monotonic()  # the sync returned: progress
-        now = time.perf_counter()
-        step_time = now - (
-            self._last_consume_t if self._last_consume_t is not None
-            else rec.dispatched_at
-        )
-        self._last_consume_t = now
-
-        n_active = 0
-        for slot, req in rec.rows:
-            if self.slots[slot] is not req:
-                continue  # retired (and possibly re-admitted) since dispatch
-            n_active += 1
-            n_valid = int(packed[slot, rec.steps + 1])
-            device_done = bool(packed[slot, rec.steps])
-            committed = 0
-            for i in range(n_valid):
-                self._commit_token(slot, req, int(packed[slot, i]))
-                committed += 1
-                if self.slots[slot] is not req:
-                    break  # retired mid-block: discard the tail tokens
-            if req.timeline is not None:
-                # flight-recorder stamp at the block's ONE host sync:
-                # COMMITTED tokens only (a mid-block retire discards the
-                # tail — the spec path's `committed` twin), no extra
-                # device read, and no timestamp passed (`now` is
-                # perf_counter; the timeline's clock is monotonic)
-                req.timeline.block(committed)
-            if self.slots[slot] is not req:
-                continue
-            # committed residency advances by what the device actually
-            # emitted (the device carry already did)
-            self.cache_len[slot] += n_valid
-            if self.paged_cache is not None:
-                self.paged_cache.advance_slot(slot, n_valid)
-            if req.kv_exhausted:
-                # clamped at dispatch time: retire with the pool-pressure
-                # reason, but only once NO younger in-flight block still
-                # carries tokens for this row (decode_sync_every >= 2 can
-                # have several) — retiring earlier would discard tokens
-                # the client paid for via the consume identity check
-                if not self._slot_in_flight(slot, req):
-                    self._retire(slot, "kv_exhausted")
-            elif device_done:
-                # defensive: _commit_token's own stop/limit chain normally
-                # retired the row on its last committed token already —
-                # this catches a host/device divergence rather than
-                # leaving a device-frozen row parked in a slot forever
-                self._retire(
-                    slot,
-                    "stop" if req.tokens and req.tokens[-1] in req.stop_ids
-                    else "length",
-                )
-
-        # -- prefill-chunk rows (ragged dispatches only): commit each
-        # chunk's residency, feed the chunk-prefix cache, and admit rows
-        # whose prompt just finished — their device-sampled first token
-        # rides the same packed sync in the trailing column
-        for slot, req, cursor, start_pos, n, fin, idx in rec.prefill_rows:
-            if (self.slots[slot] is not req
-                    or self._cursors.get(slot) is not cursor):
-                continue  # retired/requeued since dispatch: stale chunk
-            n_active += 1
-            cursor.committed = start_pos + n
-            self.cache_len[slot] = cursor.committed
-            if self.paged_cache is not None:
-                self.paged_cache.advance_slot(slot, n)
-            tl = req.timeline
-            if tl is not None:
-                tl.chunk(idx, n, prefix_hit=False, start=start_pos)
-                tl.end_span(f"prefill_chunk:{idx}")
-            if self._metrics:
-                self._metrics.record_histogram(
-                    "app_prefill_chunk_tokens", n, kind="compute",
-                )
-            # only whole-chunk-aligned spans have a precomputed key: the
-            # lookup walk probes exactly (k*C, k*C+C|total), and the paged
-            # extraction needs a page-aligned start — the planner
-            # guarantees this shape; a missing key (future policy drift)
-            # skips the put instead of failing the engine loop
-            put_key = (
-                cursor.cache_keys.get((start_pos, start_pos + n))
-                if cursor.cache_keys is not None else None
+        with self._phase("sync", blk=rec.blk):
+            packed = _block_sync(rec.packed)  # THE one sync for N device steps
+        with self._phase("commit", blk=rec.blk) as span:
+            # the sync returned: a warm restart may have replaced this thread
+            # while it waited — its tokens belong to requests already settled
+            # or requeued, so commit nothing (and don't stamp a heartbeat that
+            # would mask the REPLACEMENT thread's health)
+            self._check_retired()
+            self.heartbeat = time.monotonic()  # the sync returned: progress
+            now = time.perf_counter()
+            step_time = now - (
+                self._last_consume_t if self._last_consume_t is not None
+                else rec.dispatched_at
             )
-            if (self._prefix_cache is not None and self._chunk_cache_enabled
-                    and rec.last_logits is not None and put_key is not None):
-                # chunk-prefix cache PUT: the chunk's K/V just became
-                # resident — extract its slab (pure device reads, no sync;
-                # the slices/gathers are fresh buffers safe to retain) and
-                # store it with the prefix's last-position logits, so a
-                # later prompt sharing this prefix skips the chunk
+            self._last_consume_t = now
+
+            n_active = tokens = 0
+            retires = self._retires
+            for slot, req in rec.rows:
+                if self.slots[slot] is not req:
+                    continue  # retired (and possibly re-admitted) since dispatch
+                n_active += 1
+                n_valid = int(packed[slot, rec.steps + 1])
+                device_done = bool(packed[slot, rec.steps])
+                committed = 0
+                for i in range(n_valid):
+                    self._commit_token(slot, req, int(packed[slot, i]))
+                    committed += 1
+                    if self.slots[slot] is not req:
+                        break  # retired mid-block: discard the tail tokens
+                if req.timeline is not None:
+                    # flight-recorder stamp at the block's ONE host sync:
+                    # COMMITTED tokens only (a mid-block retire discards the
+                    # tail — the spec path's `committed` twin), no extra
+                    # device read, and no timestamp passed (`now` is
+                    # perf_counter; the timeline's clock is monotonic)
+                    req.timeline.block(committed, blk=rec.blk)
+                tokens += committed
+                if self.slots[slot] is not req:
+                    continue
+                # committed residency advances by what the device actually
+                # emitted (the device carry already did)
+                self.cache_len[slot] += n_valid
                 if self.paged_cache is not None:
-                    k_slab, v_slab = self.paged_cache.read_span(
-                        slot, start_pos, start_pos + n
+                    self.paged_cache.advance_slot(slot, n_valid)
+                if req.kv_exhausted:
+                    # clamped at dispatch time: retire with the pool-pressure
+                    # reason, but only once NO younger in-flight block still
+                    # carries tokens for this row (decode_sync_every >= 2 can
+                    # have several) — retiring earlier would discard tokens
+                    # the client paid for via the consume identity check
+                    if not self._slot_in_flight(slot, req):
+                        self._retire(slot, "kv_exhausted")
+                elif device_done:
+                    # defensive: _commit_token's own stop/limit chain normally
+                    # retired the row on its last committed token already —
+                    # this catches a host/device divergence rather than
+                    # leaving a device-frozen row parked in a slot forever
+                    self._retire(
+                        slot,
+                        "stop" if req.tokens and req.tokens[-1] in req.stop_ids
+                        else "length",
                     )
-                else:
-                    k_slab = self.cache.k[:, slot, start_pos : start_pos + n]
-                    v_slab = self.cache.v[:, slot, start_pos : start_pos + n]
-                self._prefix_cache.put(
-                    put_key,
-                    (rec.last_logits[slot : slot + 1], k_slab, v_slab),
+
+            # -- prefill-chunk rows (ragged dispatches only): commit each
+            # chunk's residency, feed the chunk-prefix cache, and admit rows
+            # whose prompt just finished — their device-sampled first token
+            # rides the same packed sync in the trailing column
+            for slot, req, cursor, start_pos, n, fin, idx in rec.prefill_rows:
+                if (self.slots[slot] is not req
+                        or self._cursors.get(slot) is not cursor):
+                    continue  # retired/requeued since dispatch: stale chunk
+                n_active += 1
+                cursor.committed = start_pos + n
+                self.cache_len[slot] = cursor.committed
+                if self.paged_cache is not None:
+                    self.paged_cache.advance_slot(slot, n)
+                tl = req.timeline
+                if tl is not None:
+                    tl.chunk(idx, n, prefix_hit=False, start=start_pos)
+                    tl.end_span(f"prefill_chunk:{idx}")
+                if self._metrics:
+                    self._metrics.record_histogram(
+                        "app_prefill_chunk_tokens", n, kind="compute",
+                    )
+                # only whole-chunk-aligned spans have a precomputed key: the
+                # lookup walk probes exactly (k*C, k*C+C|total), and the paged
+                # extraction needs a page-aligned start — the planner
+                # guarantees this shape; a missing key (future policy drift)
+                # skips the put instead of failing the engine loop
+                put_key = (
+                    cursor.cache_keys.get((start_pos, start_pos + n))
+                    if cursor.cache_keys is not None else None
                 )
-            if fin:
-                self._cursors.pop(slot, None)
-                first_id = int(packed[slot, rec.steps + 2])
-                self._commit_first_token(slot, req, first_id)
+                if (self._prefix_cache is not None and self._chunk_cache_enabled
+                        and rec.last_logits is not None and put_key is not None):
+                    # chunk-prefix cache PUT: the chunk's K/V just became
+                    # resident — extract its slab (pure device reads, no sync;
+                    # the slices/gathers are fresh buffers safe to retain) and
+                    # store it with the prefix's last-position logits, so a
+                    # later prompt sharing this prefix skips the chunk
+                    if self.paged_cache is not None:
+                        k_slab, v_slab = self.paged_cache.read_span(
+                            slot, start_pos, start_pos + n
+                        )
+                    else:
+                        k_slab = self.cache.k[:, slot, start_pos : start_pos + n]
+                        v_slab = self.cache.v[:, slot, start_pos : start_pos + n]
+                    self._prefix_cache.put(
+                        put_key,
+                        (rec.last_logits[slot : slot + 1], k_slab, v_slab),
+                    )
+                if fin:
+                    self._cursors.pop(slot, None)
+                    first_id = int(packed[slot, rec.steps + 2])
+                    self._commit_first_token(slot, req, first_id)
+
+            span.set(tokens=tokens, retired=self._retires - retires)
 
         if self._metrics and n_active:
-            host_ms = (rec.host_s + (time.perf_counter() - now)) * 1e3
             self._metrics.record_histogram(
                 "app_tpot_seconds", step_time / rec.steps
             )
@@ -3765,15 +3926,18 @@ class ServingEngine:
             self._metrics.set_gauge(
                 "app_batch_occupancy", n_active / self.config.max_slots
             )
+            if self.paged_cache is not None:
+                kv = self.paged_cache.stats()
+                self._metrics.set_gauge(
+                    "app_kv_cache_pages_used",
+                    kv["total_blocks"] - kv["free_blocks"],
+                )
+            # the hot loop's success metric: host time per decode step —
+            # the block's fold + dispatch + commit spans, not the sync
+            # wait — must stay a small fraction of decode_step_ms
             self._metrics.set_gauge(
-                "app_kv_cache_pages_used",
-                int(sum(int(self.cache_len[s]) for s, _ in rec.rows)),
-            )
-            # the tentpole's success metric: host time per decode step
-            # (dispatch bookkeeping + this consume, excluding the sync
-            # wait) must stay a small fraction of decode_step_ms
-            self._metrics.set_gauge(
-                "app_decode_host_ms_per_step", host_ms / rec.steps
+                "app_decode_host_ms_per_step",
+                (rec.dispatch_s + span.seconds) * 1e3 / rec.steps,
             )
             self._metrics.set_gauge("app_decode_block_size", rec.steps)
             with self._detok_mu:
@@ -3927,6 +4091,7 @@ class ServingEngine:
                     dspan.set_attribute(
                         "kv.pages", (resident + page - 1) // page
                     )
+        self._retires += 1
         self.slots[slot] = None
         self.cache_len[slot] = 0
         self.adapter_idx[slot] = 0
@@ -4034,10 +4199,6 @@ class ServingEngine:
         self._try_resolve(req, exc=ErrorDeadlineExceeded())
 
     def _finish(self, req: _Request, reason: str) -> None:
-        # flush the running iteration's busy slice BEFORE the settlement
-        # is queued: once the caller observes its result, the duty-cycle
-        # counter must already show the work that produced it
-        self._flush_busy()
         now = time.perf_counter()
         self._shed.observe_request(now - req.created)
         if reason == "deadline_exceeded" and self._metrics:

@@ -53,6 +53,7 @@ class RequestTimeline:
     __slots__ = (
         "request_id", "trace_id", "created_unix", "prompt_tokens",
         "phases", "decode_blocks", "decode_tokens", "last_block_at",
+        "first_blk", "last_blk",
         "prefill_chunks", "prefix_tier", "finish_reason", "terminal_at",
         "terminal_marks", "spans", "tenant", "_t0",
     )
@@ -68,6 +69,11 @@ class RequestTimeline:
         self.decode_blocks = 0
         self.decode_tokens = 0
         self.last_block_at: float | None = None
+        # the engine's sequence numbers (blk on its gofr.step.dispatch /
+        # .sync / .commit spans) of the first and the last block that
+        # carried this row: the join from a request to a device trace
+        self.first_blk: int | None = None
+        self.last_blk: int | None = None
         # chunked-prefill record (continuous batching): one entry per
         # committed prefill chunk — {index, tokens, prefix_hit, ms}. A
         # monolithic (single-bucket) prefill leaves this empty; the
@@ -100,12 +106,17 @@ class RequestTimeline:
         requeued admission keeps its original queue-wait truth)."""
         self.phases.setdefault(phase, time.monotonic() if t is None else t)
 
-    def block(self, n_tokens: int, t: float | None = None) -> None:
+    def block(self, n_tokens: int, t: float | None = None,
+              blk: int | None = None) -> None:
         """One consumed decode block: committed token count for this row
-        at the block's single host sync."""
+        at the block's single host sync, and the block's number."""
         self.decode_blocks += 1
         self.decode_tokens += int(n_tokens)
         self.last_block_at = time.monotonic() if t is None else t
+        if blk is not None:
+            if self.first_blk is None:
+                self.first_blk = blk
+            self.last_blk = blk
 
     def chunk(self, index: int, n_tokens: int, prefix_hit: bool = False,
               start: int = 0) -> None:
@@ -219,6 +230,9 @@ class RequestTimeline:
                 ),
             },
         }
+        if self.first_blk is not None:
+            out["decode"]["first_blk"] = self.first_blk
+            out["decode"]["last_blk"] = self.last_blk
         if self.prefill_chunks:
             # snapshot (list() of the live list): the engine thread may
             # append a chunk while /requestz serializes an in-flight row

@@ -280,6 +280,9 @@ class _RecMetrics:
     def increment_counter(self, name, *a, **kw):
         self.counters[name] = self.counters.get(name, 0) + 1
 
+    def add_counter(self, *a, **kw):
+        pass
+
     def set_gauge(self, *a, **kw):
         pass
 

@@ -1,0 +1,326 @@
+"""The engine's step spans (docs/observability.md "Engine step spans"): every
+phase of the step loop as a ``gofr.step[.<phase>]`` event in a profiler
+trace, the one phase account behind ``busy_seconds()`` and
+``app_engine_phase_seconds_total``, and the block numbers that join a
+request to its blocks. Tiny widths on the CPU; the traces are read with the
+benchmark's own reader (``benchmarks/harness/host_spans.py``), so that what
+the engine writes and what the benchmark parses are held to each other."""
+
+import threading
+import time
+from types import SimpleNamespace
+
+import jax
+import pytest
+
+from benchmarks.harness import host_spans, trace_reduce
+from gofr_tpu.config import MapConfig
+from gofr_tpu.container.container import Container
+from gofr_tpu.models import llama
+from gofr_tpu.serving import ByteTokenizer, EngineConfig, ServingEngine
+from gofr_tpu.serving import batch as batch_ops
+from gofr_tpu.serving import engine as engine_mod
+
+KINDS = {
+    # dispatch kind -> engine settings, the open request's prompt, the
+    # phases that kind of engine can show
+    "decode": (dict(), "open", set(engine_mod.STEP_PHASES)),
+    # a prompt longer than a chunk prefills through ragged dispatches
+    "ragged": (dict(prefill_chunk_tokens=16), "a long open prompt of three chunks",
+               set(engine_mod.STEP_PHASES)),
+    # speculative decoding keeps its decode state on the host: no fold
+    "spec": (dict(spec_tokens=2, multi_step=None), "open", set(engine_mod.STEP_PHASES) - {"fold"}),
+}
+STEPS = 4
+
+
+@pytest.fixture(scope="module")
+def model():
+    cfg = llama.LlamaConfig.tiny(vocab_size=300)
+    return cfg, llama.init_params(cfg, jax.random.PRNGKey(0))
+
+
+def make_engine(model, metrics=None, **kw):
+    settings = dict(max_slots=3, max_seq_len=128, prefill_buckets=(16,), multi_step=STEPS,
+                    kv_layout="paged", kv_page_size=8)
+    settings.update(kw)
+    cfg, params = model
+    return ServingEngine(cfg, params, EngineConfig(**settings), ByteTokenizer(), metrics=metrics)
+
+
+def drive(engine, open_prompt):
+    """One open request, and one admitted while the first is mid-block."""
+    decoding = threading.Event()
+    first = engine.submit(open_prompt, max_new_tokens=22, temperature=0.0,
+                          stream_cb=lambda tid, piece, done: len(piece) >= 0 and decoding.set())
+    assert decoding.wait(120)
+    second = engine.submit("late", max_new_tokens=7, temperature=0.0)
+    return [first.result(timeout=120), second.result(timeout=120)], [first.request_id, second.request_id]
+
+
+def read_spans(trace_dir):
+    """The trace's engine spans by start, without the iteration the trace's
+    end cut: a span still open when the session stops is not written, so
+    that iteration's inner spans come without their gofr.step."""
+    events = host_spans.load_host_events(trace_reduce.find_xplane(str(trace_dir)))
+    spans = sorted((host_spans.parse(e) for e in events), key=lambda s: (s.start_ns, -s.dur_ns))
+    whole = max(s.end_ns for s in spans if s.phase == "step")
+    return [s for s in spans if s.end_ns <= whole]
+
+
+def depths(spans):
+    """Nesting depth of every span, thread by thread; raises if two spans
+    of a thread overlap without one holding the other."""
+    out = []
+    threads = {}
+    for s in spans:
+        threads.setdefault(s.thread, []).append(s)
+    for group in threads.values():
+        stack = []
+        for s in group:
+            while stack and stack[-1].end_ns <= s.start_ns:
+                stack.pop()
+            assert not stack or s.end_ns <= stack[-1].end_ns, (s, stack[-1])
+            out.append((s, len(stack), stack[-1].phase if stack else None))
+            stack.append(s)
+    return out
+
+
+@pytest.fixture(scope="module", params=list(KINDS))
+def traced(request, model, tmp_path_factory):
+    """One engine of each dispatch kind, driven once to compile and once
+    under ``jax.profiler.trace``."""
+    kind = request.param
+    settings, prompt, phases = KINDS[kind]
+    engine = make_engine(model, **settings)
+    finished = []
+    finish = engine._finish
+    engine._finish = lambda req, reason: (finished.append(req), finish(req, reason))[1]
+    syncs = []
+    patch = pytest.MonkeyPatch()
+    block_sync = engine_mod._block_sync
+    patch.setattr(engine_mod, "_block_sync", lambda value: (syncs.append(1), block_sync(value))[1])
+    engine.start()
+    try:
+        drive(engine, prompt)
+        deadline = time.monotonic() + 30
+        while (engine._inflight_q or engine._phase_state[0] != "wait") and time.monotonic() < deadline:
+            time.sleep(0.02)  # the pipeline drains its last block: a span cut by the trace's start has no parent
+        del finished[:], syncs[:]
+        trace_dir = tmp_path_factory.mktemp(f"trace-{kind}")
+        with jax.profiler.trace(str(trace_dir)):
+            time.sleep(0.12)  # an idle engine waits
+            results, ids = drive(engine, prompt)
+            time.sleep(0.06)
+        views = [engine.timeline.get(i).to_dict() for i in ids]
+    finally:
+        engine.stop()
+        patch.undo()
+    return SimpleNamespace(kind=kind, phases=phases, spans=read_spans(trace_dir), results=results,
+                           views=views, requests=list(finished), syncs=len(syncs))
+
+
+def test_every_phase_is_a_span_nested_under_a_step(traced):
+    seen = depths(traced.spans)
+    assert {s.phase for s, _, _ in seen} == traced.phases
+    for s, depth, parent in seen:
+        if s.phase == "step":
+            assert depth == 0 and s.kw["iter"] > 0 and s.kw["mono_ns"] > 0
+        else:
+            assert depth >= 1, s
+    parents = {(s.phase, parent) for s, _, parent in seen}
+    assert ("prefill_sync", "prefill") in parents and ("prefill", "admit") in parents
+    if "fold" in traced.phases:
+        assert ("fold", "dispatch") in parents
+    # the iteration's number and the host's clock ride every gofr.step
+    iters = [s.kw["iter"] for s in traced.spans if s.phase == "step"]
+    assert iters == sorted(set(iters))
+
+
+def test_dispatch_kind_and_keywords(traced):
+    blocks = [s for s in traced.spans if s.phase == "dispatch" and "blk" in s.kw]
+    assert traced.kind in {s.kw["kind"] for s in blocks}
+    for s in blocks:
+        assert set(s.kw) == {"blk", "kind", "rows", "steps", "kv_tokens", "chunk_rows", "chunk_tokens", "cold"}
+        assert s.kw["cold"] == 0  # the same traffic ran once before the trace
+        assert s.kw["steps"] == (3 if traced.kind == "spec" else STEPS)
+        assert (s.kw["chunk_rows"] > 0) == (s.kw["kind"] == "ragged")
+    assert any(s.kw["rows"] == 2 and s.kw["kv_tokens"] > 0 for s in blocks)  # both rows in one block
+    routes = {s.kw.get("route") for s in traced.spans if s.phase == "prefill"}
+    assert routes == ({"chunked", "bucketed"} if traced.kind == "ragged" else {"bucketed"})
+
+
+def test_a_blocks_dispatch_sync_and_commit_carry_one_number(traced):
+    by_phase = {p: [s.kw["blk"] for s in traced.spans if s.phase == p and "blk" in s.kw]
+                for p in ("dispatch", "sync", "commit")}
+    assert by_phase["dispatch"] == sorted(set(by_phase["dispatch"]))  # one span a block, in order
+    assert by_phase["sync"] == by_phase["commit"] == by_phase["dispatch"]
+    assert traced.syncs == len(by_phase["sync"])  # _block_sync: still once a block, inside its span
+
+
+def test_rows_times_steps_is_what_the_requests_were_dispatched(traced):
+    blocks = [s for s in traced.spans if s.phase == "dispatch" and "blk" in s.kw]
+    commits = [s for s in traced.spans if s.phase == "commit"]
+    committed = sum(len(r.tokens) - 1 for r in traced.requests)  # the first token is the prefill's
+    assert sum(s.kw["tokens"] for s in commits) == committed
+    assert sum(s.kw["retired"] for s in commits) == len(traced.requests) == 2
+    if traced.kind == "spec":
+        # a verify chunk computes rows x (drafts + 1) positions and keeps a prefix of each
+        assert sum(s.kw["rows"] * s.kw["steps"] for s in blocks) >= committed
+    else:
+        # committed tokens plus those discarded at retire: every step a row was dispatched for
+        assert sum(s.kw["rows"] * s.kw["steps"] for s in blocks) == sum(r.dispatched for r in traced.requests)
+        assert sum(r.dispatched for r in traced.requests) >= committed
+
+
+def test_requestz_joins_a_request_to_its_blocks(traced):
+    seen = {s.kw["blk"] for s in traced.spans if s.phase == "commit"}
+    for view in traced.views:
+        decode = view["decode"]
+        assert decode["first_blk"] <= decode["last_blk"]
+        assert {decode["first_blk"], decode["last_blk"]} <= seen
+        assert decode["last_blk"] - decode["first_blk"] + 1 >= decode["blocks"] >= 1
+
+
+# ------------------------------------------------------------ the account
+def test_phase_account_covers_the_loop_and_busy_leaves_wait_out(model):
+    container = Container(MapConfig({"LOG_LEVEL": "ERROR"}, use_env=False))
+    metrics = container.metrics_manager
+    engine = make_engine(model, metrics=metrics)
+    t0 = time.monotonic()
+    engine.start()
+    try:
+        drive(engine, "open")
+        time.sleep(0.3)  # and an idle stretch
+    finally:
+        engine.stop()
+    wall = time.monotonic() - t0
+    account = dict(engine._phase_s)
+    assert set(account) == set(engine_mod.STEP_PHASES)
+    assert sum(account.values()) >= 0.95 * wall
+    assert sum(account.values()) <= wall
+    assert account["wait"] >= 0.25
+    assert engine.busy_seconds() == pytest.approx(sum(account.values()) - account["wait"])
+    assert engine._phase_state[0] is None  # nothing left open
+    # the counter is the account, carried over once an iteration
+    counter = metrics.get("app_engine_phase_seconds_total")
+    for phase in ("wait", "sync", "prefill", "dispatch", "commit"):
+        assert counter.value({"phase": phase}) == pytest.approx(account[phase], rel=0.05), phase
+    tokens = metrics.get("app_step_tokens_total")
+    decode = tokens.value({"kind": "decode"})
+    assert decode >= 22 + 7 - 2 and decode % STEPS == 0
+    assert tokens.value({"kind": "padding"}) > 0 and tokens.value({"kind": "prefill"}) == 0
+    # the host's share of a block: its fold, dispatch and commit spans
+    assert 0 < metrics.get("app_decode_host_ms_per_step").value() < 1e3
+    container.close()
+
+
+def test_kv_pages_gauge_counts_pages_not_tokens(model):
+    container = Container(MapConfig({"LOG_LEVEL": "ERROR"}, use_env=False))
+    metrics = container.metrics_manager
+    engine = make_engine(model, metrics=metrics, kv_num_pages=64)
+    seen = []
+    consume = engine._consume_block
+
+    def watching(rec):
+        consume(rec)
+        kv = engine.paged_cache.stats()
+        seen.append((metrics.get("app_kv_cache_pages_used").value(), kv["total_blocks"] - kv["free_blocks"],
+                     int(engine.cache_len.sum())))
+
+    engine._consume_block = watching
+    engine.start()
+    try:
+        engine.submit("a prompt of some length", max_new_tokens=12, temperature=0.0).result(timeout=120)
+    finally:
+        engine.stop()
+    assert seen and all(gauge == pages for gauge, pages, _ in seen)
+    assert any(tokens > pages > 0 for _, pages, tokens in seen)  # 8 tokens a page: never the same number
+    container.close()
+
+
+def test_busy_seconds_counts_the_open_phase(model):
+    """A caller that saw its request complete sees the work in
+    busy_seconds() already, with no flush by the engine."""
+    engine = make_engine(model)
+    engine.start()
+    try:
+        engine.submit("busy", max_new_tokens=2).result(timeout=120)
+        assert engine.busy_seconds() > 0.0
+        time.sleep(0.2)  # idle now: only the closed phases but wait
+        idle = engine.busy_seconds()
+        time.sleep(0.2)
+        assert engine.busy_seconds() - idle < 0.05
+    finally:
+        engine.stop()
+
+
+# ------------------------------------------------------------- unwinding
+def test_a_step_that_raises_leaves_no_span_open(model, tmp_path, monkeypatch):
+    engine = make_engine(model)
+    real = batch_ops.decode_block_paged
+    raised = []
+
+    def failing(*a, **kw):
+        if not raised:
+            raised.append(1)
+            raise RuntimeError("injected dispatch failure")
+        return real(*a, **kw)
+
+    engine.start()
+    try:
+        engine.submit("warm", max_new_tokens=6).result(timeout=120)
+        monkeypatch.setattr(batch_ops, "decode_block_paged", failing)
+        with jax.profiler.trace(str(tmp_path)):
+            with pytest.raises(RuntimeError, match="injected"):
+                engine.submit("doomed", max_new_tokens=6).result(timeout=120)
+            assert engine.submit("after", max_new_tokens=6).result(timeout=120).completion_tokens == 6
+    finally:
+        engine.stop()
+    seen = depths(read_spans(tmp_path))  # raises on a span left open across its parent's end
+    assert raised and all(depth == 0 for s, depth, _ in seen if s.phase == "step")
+    failed = [s for s, _, _ in seen if s.phase == "dispatch" and "blk" not in s.kw]
+    assert failed  # the span of the dispatch that raised closed without a block
+    assert engine._phase_state[0] is None
+
+
+def test_a_retired_thread_unwinds_without_touching_the_account(model, monkeypatch):
+    """A quarantined loop thread thaws inside its dispatch span after a
+    warm restart: its spans close, the replacement's account is not
+    written by it, and the replacement's next gofr.step is a root."""
+    engine = make_engine(model)
+    hold, pinned = threading.Event(), threading.Event()
+    real = batch_ops.decode_block_paged
+
+    def hanging(*a, **kw):
+        if not pinned.is_set():
+            pinned.set()
+            hold.wait(60)
+        return real(*a, **kw)
+
+    engine.start()
+    try:
+        engine.submit("warm", max_new_tokens=6).result(timeout=120)
+        monkeypatch.setattr(batch_ops, "decode_block_paged", hanging)
+        doomed = engine.submit("held in flight", max_new_tokens=30)
+        assert pinned.wait(60)
+        old = engine._thread
+        assert engine.warm_restart(join_timeout=0.2) is True
+        with pytest.raises(Exception):
+            doomed.result(timeout=30)
+        assert engine.submit("fresh", max_new_tokens=3).result(timeout=120).completion_tokens >= 1
+        time.sleep(0.15)
+        assert engine._phase_state[0] == "wait"  # the replacement idles in its own wait
+        before = dict(engine._phase_s)
+        hold.set()
+        old.join(timeout=60)
+        assert not old.is_alive()
+        after = dict(engine._phase_s)
+        # the old thread left dispatch and step behind it: neither was charged by its unwind
+        assert after["dispatch"] == before["dispatch"] and after["step"] == before["step"]
+        assert engine._phase_state[0] == "wait"
+        assert engine.submit("again", max_new_tokens=3).result(timeout=120).completion_tokens >= 1
+    finally:
+        hold.set()
+        engine.stop()
+    assert engine._phase_state[0] is None

@@ -59,6 +59,9 @@ class RecordingMetrics:
     def increment_counter(self, name, *labels, **kw) -> None:
         self.counters[name] = self.counters.get(name, 0) + 1
 
+    def add_counter(self, name, value, *labels, **kw) -> None:
+        self.counters[name] = self.counters.get(name, 0) + value
+
     def set_gauge(self, name, value, *labels, **kw) -> None:
         self.gauges[name] = value
 
