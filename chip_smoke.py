@@ -102,36 +102,55 @@ def check_kernels(self_test: bool) -> None:
     say(f"kernel flash_attention S={fs} H={H}/{Hkv}x{D}: max|err|={e:.4g} (tol {tol})")
     check(e <= tol, f"flash_attention disagrees with ops.attention ({e} > {tol})")
 
-    qd = jax.random.normal(keys[3], (B, H, D), dtype)
-    lens = [1, 17, 300, 511, 777, 1000, S - 1, S][-B:] if not self_test else [1, S - 3]
-    seq_lens = jnp.asarray(lens, jnp.int32)
-    for page, quantized in ((16, False), (32, True), (128, True)):
-        M = S // page
-        N = B * M + 1
-        kf = jax.random.normal(keys[4], (N, Hkv, page, D), dtype)
-        vf = jax.random.normal(keys[5], (N, Hkv, page, D), dtype)
-        tables = jnp.asarray(
-            np.random.default_rng(SEED).permutation(N - 1).reshape(B, M), jnp.int32
-        )
-        if quantized:
-            kq, ks = llama.quantize_kv(kf)
-            vq, vs = llama.quantize_kv(vf)
-            ks, vs = ks[..., None], vs[..., None]
-            out = paged_decode_attention_q(
-                qd, kq, vq, ks, vs, tables, seq_lens, interpret=interpret
+    # the paged decode kernel sizes its blocks from the shapes it sees, so
+    # it is compiled at every shape class served: Llama-3-8B under the
+    # engine's defaults, and the two benchmark configurations' heads,
+    # slots and table widths (benchmarks/cells/) — grouped-query with many
+    # short rows, full multi-head with few long ones
+    if self_test:
+        paged_cases = [("grouped", H, Hkv, B, S, ((16, False), (32, True))),
+                       ("multi-head", H, H, 3, S, ((16, False), (32, True)))]
+    else:
+        paged_cases = [
+            ("8B heads, 8 x 1024", H, Hkv, B, S, ((16, False), (32, True), (128, True))),
+            ("mistral7b.chat, 32 x 768", 32, 8, 32, 768, ((16, False), (32, True))),
+            ("deepseek7b.gen, 6 x 1024", 32, 32, 6, 1024, ((16, False), (32, True))),
+        ]
+    for label, pH, pHkv, pB, pS, pools in paged_cases:
+        qd = jax.random.normal(keys[3], (pB, pH, D), dtype)
+        # empty slots (length 1), a page boundary + 1, ragged, the full table
+        ragged = [17, pS // 3, pS // 2 - 1, pS - pS // 4 + 9, pS - 24, pS - 3, pS]
+        k_live = min(len(ragged), pB - 1)
+        seq_lens = jnp.asarray([1] * (pB - k_live) + ragged[-k_live:], jnp.int32)
+        for page, quantized in pools:
+            M = pS // page
+            N = pB * M + 1
+            kf = jax.random.normal(keys[4], (N, pHkv, page, D), dtype)
+            vf = jax.random.normal(keys[5], (N, pHkv, page, D), dtype)
+            tables = jnp.asarray(
+                np.random.default_rng(SEED).permutation(N - 1).reshape(pB, M), jnp.int32
             )
-            ref = paged_decode_attention_ref(
-                qd, kq, vq, tables, seq_lens, k_scale=ks, v_scale=vs
-            )
-        else:
-            out = paged_decode_attention(
-                qd, kf, vf, tables, seq_lens, interpret=interpret
-            )
-            ref = paged_decode_attention_ref(qd, kf, vf, tables, seq_lens)
-        e = err(out, ref)
-        name = "paged_decode_attention" + ("_q int8" if quantized else " bf16")
-        say(f"kernel {name} page={page}: max|err|={e:.4g} (tol {tol})")
-        check(e <= tol, f"{name} page={page} disagrees with its reference ({e} > {tol})")
+            if quantized:
+                kq, ks = llama.quantize_kv(kf)
+                vq, vs = llama.quantize_kv(vf)
+                ks, vs = ks[..., None], vs[..., None]
+                out = paged_decode_attention_q(
+                    qd, kq, vq, ks, vs, tables, seq_lens, interpret=interpret
+                )
+                ref = paged_decode_attention_ref(
+                    qd, kq, vq, tables, seq_lens, k_scale=ks, v_scale=vs
+                )
+            else:
+                out = paged_decode_attention(
+                    qd, kf, vf, tables, seq_lens, interpret=interpret
+                )
+                ref = paged_decode_attention_ref(qd, kf, vf, tables, seq_lens)
+            e = err(out, ref)
+            name = "paged_decode_attention" + ("_q int8" if quantized else " bf16")
+            say(f"kernel {name} [{label}] H={pH}/{pHkv} B={pB} M={M} page={page}: "
+                f"max|err|={e:.4g} (tol {tol})")
+            check(e <= tol,
+                  f"{name} [{label}] page={page} disagrees with its reference ({e} > {tol})")
 
 
 # ------------------------------------------------- which attention path ran

@@ -378,6 +378,11 @@ KERNELS: tuple[KernelContract, ...] = (
             Ret("vs_pool", like="vs_pool"),
         ),
     ),
+    # The two entries of the one paged decode kernel: a program a row, the
+    # pools left in HBM (PER-LAYER ranks: [N_pages, Hkv, page, Dh], scales
+    # [..., 1]), pages fetched by the kernel's own DMAs in a loop whose
+    # trip count is the row's length. Tile sizes are worked out inside
+    # from these shapes, so neither entry has a parameter for them.
     KernelContract(
         "paged_decode_attention", _PAGED_ATTN,
         params=("q", "k_pool", "v_pool", "block_tables", "seq_lens",

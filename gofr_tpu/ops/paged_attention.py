@@ -1,7 +1,7 @@
 """Paged (block-table) decode attention for TPU.
 
 The decode-side companion of ops/flash_attention.py: K/V live in a pooled
-page table (``[N_pages, page_size, Hkv, Dh]``) shared by every sequence in
+page table (``[N_pages, Hkv, page_size, Dh]``) shared by every sequence in
 the server, and each sequence addresses its pages through an int32 block
 table — the vLLM/ragged-paged-attention layout (SURVEY §5.7 lever (a),
 PAPERS.md: ragged paged attention kernel for TPU). This is what lets the
@@ -13,13 +13,19 @@ Two implementations with one contract:
   the kernels are tested against, and what both entries compute on the
   CPU unless a test passes ``interpret=True`` (``ops/backend.py``);
 - ``paged_decode_attention`` / ``paged_decode_attention_q`` — one Pallas
-  kernel (bf16 or int8-with-scales pools) whose grid walks
-  (batch, kv_head, page) with the page axis innermost, carrying the
-  online-softmax state in VMEM scratch. The page index feeds the K/V
-  BlockSpec index maps from scalar-prefetched block tables, so only the
-  pages a sequence actually owns are streamed from HBM; pages past the
-  sequence length are skipped with ``@pl.when``. int8 pools stream at
-  half width and dequantize in VMEM (per-vector absmax scales).
+  kernel (bf16 or int8-with-scales pools) with one program a row and no
+  grid axis over pages. The pools stay in HBM; the kernel loops over the
+  blocks of pages the row owns (``cdiv(seq_len, pages_per_block * page)``
+  trips, read from the scalar-prefetched lengths and block tables) and
+  fetches each block itself, one DMA a page — a page of the pool is
+  contiguous for all KV heads — into a double buffer in VMEM, so that
+  block *i + 1* (at a row's end, the next row's first block) is in
+  flight while block *i* is computed. The online softmax runs in float32
+  over every KV head of the block. A row of length 1 (an empty slot)
+  costs one page. ``pages_per_block`` is worked out from the shapes the
+  call sees (:func:`_pages_per_block`). int8 pages arrive with their
+  per-vector absmax scales (two more copies a page) and dequantize in
+  VMEM.
 
 The jitted entries are declared in the kernel contract table
 (``gofr_tpu/analysis/kernel_contracts.KERNELS``; note the PER-LAYER
@@ -89,69 +95,170 @@ def paged_decode_attention_ref(
     return out.astype(q.dtype)
 
 
+# VMEM the K and V page buffers may hold between them, both slots of the
+# double buffer counted: a quarter of the 16 MiB a Mosaic kernel gets on a
+# v5e, which leaves room for the float32 working set of one head's block
+_KV_VMEM_BUDGET = 4 * 1024 * 1024
+# A block is also the unit of compute: the last block of a row is computed
+# whole and masked, so a longer block wastes work on every short row. 128
+# tokens is one MXU tile of K^T (or V) a head.
+_BLOCK_TOKENS = 128
+_LANES = 128  # the running max and sum are kept a lane row wide
+
+
+def _pages_per_block(Hkv: int, page: int, Dh: int, itemsize: int,
+                     quantized: bool, M: int) -> int:
+    """Pages one DMA batch and one compute block hold, from the shapes the
+    call sees: as many as fit :data:`_KV_VMEM_BUDGET` (two slots of K and
+    V, plus the float32 scales of an int8 pool, as wide as it), at most
+    :data:`_BLOCK_TOKENS` tokens, never more than the table is wide."""
+    page_bytes = Hkv * page * Dh * (itemsize + (4 if quantized else 0))
+    fit = _KV_VMEM_BUDGET // (4 * page_bytes)
+    return max(1, min(fit, _BLOCK_TOKENS // page, M))
+
+
 def _paged_kernel(
     seq_lens_ref,  # SMEM [B] (scalar prefetch)
     tables_ref,  # SMEM [B, M] (scalar prefetch)
-    q_ref,  # VMEM [1, 1, group, Dh]  ([B, Hkv, group, Dh] layout)
-    k_ref,  # VMEM [1, 1, page, Dh]   (page j of this sequence, kv head g)
-    v_ref,  # VMEM [1, 1, page, Dh]
-    *rest,  # quantized: ks_ref, vs_ref, o_ref, scratches; else o_ref, scratches
+    q_ref,  # VMEM [1, Hkv, group, Dh]: this row's queries
+    k_hbm,  # HBM [N, Hkv, page, Dh]: the whole pool, never copied whole
+    v_hbm,
+    *rest,  # quantized: ks_hbm, vs_hbm first; then o_ref and the scratches
     scale: float,
-    page: int,
+    ppb: int,
     quantized: bool,
 ):
-    """One kernel for both pool widths: with ``quantized`` the K/V blocks
-    arrive int8 plus per-vector scale blocks and dequantize in VMEM."""
+    """One program a row. The row's pages arrive ``ppb`` at a time by DMAs
+    this kernel issues (one a page: a page is contiguous for all KV heads),
+    into the slot of a double buffer that is not being computed on; the
+    last block of a row starts the first block of the next row. The loop
+    runs ``cdiv(seq_len, ppb * page)`` times: a row costs the pages it
+    owns. With ``quantized`` the pages arrive int8 with their per-vector
+    scales and dequantize in VMEM."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scratch, l_scratch, acc_scratch = rest
+        ks_hbm, vs_hbm, o_ref, *scratch = rest
+        k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref, m_scr, l_scr, acc_scr = scratch
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf), (vs_hbm, vs_buf))
     else:
-        o_ref, m_scratch, l_scratch, acc_scratch = rest
-
+        o_ref, *scratch = rest
+        k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr = scratch
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    Hkv, page, Dh = k_hbm.shape[1:]
+    group = q_ref.shape[2]
+    bk = ppb * page
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    B = pl.num_programs(0)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
+    def row_pages(row):
+        # a row of length 0 is read as one of length 1 (it costs one page,
+        # and every program has a block to wait for); one longer than its
+        # table stops where the table does
+        return jnp.clip(pl.cdiv(seq_lens_ref[row], page), 1, tables_ref.shape[1])
+
+    def block_pages(row, blk):
+        return jnp.minimum(row_pages(row) - blk * ppb, ppb)
+
+    def start_block(row, blk, slot):
+        def one(j, _):
+            pid = tables_ref[row, blk * ppb + j]
+            for hbm, buf in streams:
+                pltpu.make_async_copy(hbm.at[pid], buf.at[slot, j], sem.at[slot]).start()
+            return _
+        jax.lax.fori_loop(0, block_pages(row, blk), one, None)
+
+    def wait_block(n, slot):
+        # every copy of the block before any is read: the streams share the
+        # slot's semaphore, so a count of one stream's bytes proves nothing
+        # about that stream
+        def one(j, _):
+            for hbm, buf in streams:
+                pltpu.make_async_copy(hbm.at[0], buf.at[slot, j], sem.at[slot]).wait()
+            return _
+        jax.lax.fori_loop(0, n, one, None)
+
+    @pl.when(b == 0)
+    def _prime():
+        slot_ref[0] = 0
+        start_block(0, 0, 0)
 
     seq_len = seq_lens_ref[b]
+    nb = pl.cdiv(row_pages(b), ppb)
+    slot0 = slot_ref[0]
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j * page < seq_len)
-    def _step():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)  # [group, Dh]
-        k = k_ref[0, 0, :, :].astype(jnp.float32)  # [page, Dh]
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0, :, :]  # [page, 1] scale broadcasts over Dh
-            v = v * vs_ref[0, 0, :, :]
+    def block(i, _):
+        slot = (slot0 + i) % 2
+        last = i + 1 == nb
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [group, page]
-        s = s * scale
-        k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(k_pos < seq_len, s, NEG_INF)
+        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < B))
+        def _prefetch():
+            row = jnp.where(last, jnp.minimum(b + 1, B - 1), b)
+            start_block(row, jnp.where(last, 0, i + 1), 1 - slot)
 
-        m_prev = m_scratch[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        correction = jnp.exp(m_prev - m_new)
-        l_scratch[:, 0:1] = correction * l_scratch[:, 0:1] + jnp.sum(
-            p, axis=-1, keepdims=True
-        )
-        acc_scratch[:] = acc_scratch[:] * correction + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scratch[:, 0:1] = m_new
+        n = block_pages(b, i)
+        wait_block(n, slot)
 
-    @pl.when(j == nj - 1)
-    def _finish():
-        denom = l_scratch[:, 0:1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0, 0, :, :] = (acc_scratch[:] / denom).astype(o_ref.dtype)
+        # the pages of the block this row does not own were not fetched and
+        # hold whatever the slot held before: K's are masked below, but a
+        # zero weight times a stale NaN in V is NaN
+        def clear(j, _):
+            v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+            if quantized:
+                vs_buf[slot, j] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
+            return _
+        jax.lax.fori_loop(n, ppb, clear, None)
+
+        k_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (group, bk), 1)
+        nn = (((1,), (0,)), ((), ()))  # [group, bk] x [bk, Dh]
+        for h in range(Hkv):
+            q = q_ref[0, h]  # [group, Dh]
+            k = k_buf[slot, :, h].reshape(bk, Dh)
+            v = v_buf[slot, :, h].reshape(bk, Dh)
+            if quantized:
+                k = k.astype(jnp.float32) * ks_buf[slot, :, h].reshape(bk, Dh)
+                v = v.astype(jnp.float32) * vs_buf[slot, :, h].reshape(bk, Dh)
+            # products of two bf16 values are exact in the float32 the MXU
+            # accumulates in, so bf16 pools go in as they are; anything
+            # wider is computed in float32
+            mxu = jnp.promote_types(q.dtype, k.dtype)
+            s = jax.lax.dot_general(
+                q.astype(mxu), k.astype(mxu), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale  # [group, bk]
+            s = jnp.where(k_pos < seq_len, s, NEG_INF)
+
+            m_prev = m_scr[h, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            correction = jnp.exp(m_prev - m_new)
+            l_scr[h, :, 0:1] = correction * l_scr[h, :, 0:1] + jnp.sum(
+                p, axis=-1, keepdims=True
+            )
+            if v.dtype == jnp.float32:
+                pv = jax.lax.dot_general(p, v, nn, preferred_element_type=jnp.float32)
+            else:
+                # the weights as two bf16 terms (16 bits of mantissa) against
+                # V as it is stored: both products exact, and on the v5e the
+                # pair runs faster than one product of either width
+                p_hi = p.astype(v.dtype)
+                p_lo = (p - p_hi.astype(jnp.float32)).astype(v.dtype)
+                pv = jax.lax.dot_general(
+                    p_hi, v, nn, preferred_element_type=jnp.float32
+                ) + jax.lax.dot_general(
+                    p_lo, v, nn, preferred_element_type=jnp.float32
+                )
+            acc_scr[h] = acc_scr[h] * correction + pv
+            m_scr[h, :, 0:1] = m_new
+        return _
+
+    jax.lax.fori_loop(0, nb, block, None)
+    slot_ref[0] = (slot0 + nb) % 2
+
+    denom = l_scr[:, :, 0:1]
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _paged_attention_call(
@@ -171,53 +278,37 @@ def _paged_attention_call(
     M = block_tables.shape[1]
     group = H // Hkv
     quantized = k_scale is not None
+    ppb = _pages_per_block(Hkv, page, Dh, k_pool.dtype.itemsize, quantized, M)
 
-    # [B, Hkv, group, Dh] so each program sees its kv-head's query group
+    # [B, Hkv, group, Dh]: a program sees its row's queries by kv head
     q_t = q.reshape(B, Hkv, group, Dh)
     kernel = functools.partial(
-        _paged_kernel, scale=scale_v, page=page, quantized=quantized
+        _paged_kernel, scale=scale_v, ppb=ppb, quantized=quantized
     )
-
-    def _kv_index(b, g, j, seq_lens, tables):
-        # Clamp j to the sequence's last owned page: iterations past
-        # seq_len repeat the previous index, and Mosaic's pipeline elides
-        # DMAs whose block index didn't change — so a 50-token sequence
-        # streams ceil(50/page) pages, not M (the compute for the repeats
-        # is skipped by the @pl.when in the kernel body).
-        last = jnp.maximum(pl.cdiv(seq_lens[b], page) - 1, 0)
-        return (tables[b, jnp.minimum(j, last)], g, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, group, Dh),
-            lambda b, g, j, seq_lens, tables: (b, g, 0, 0),
-        ),
-        # page j of sequence b: the scalar-prefetched block table drives
-        # the HBM->VMEM DMA — this is the "paged" part
-        pl.BlockSpec((1, 1, page, Dh), _kv_index),
-        pl.BlockSpec((1, 1, page, Dh), _kv_index),
-    ]
-    operands = [q_t, k_pool, v_pool]
-    kv_elem = 1 if quantized else k_pool.dtype.itemsize
+    row_spec = pl.BlockSpec(
+        (1, Hkv, group, Dh), lambda b, seq_lens, tables: (b, 0, 0, 0)
+    )
+    pools = [k_pool, v_pool]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, page, 1), _kv_index),
-            pl.BlockSpec((1, 1, page, 1), _kv_index),
-        ]
-        operands += [k_scale, v_scale]
+        # a scale a vector, spread over the vector's lanes: Mosaic cannot
+        # slice an HBM ref whose minor dim is narrower than a lane row, and
+        # XLA already pads a [..., 1] operand of a custom call to that size
+        pools += [jnp.broadcast_to(s, k_pool.shape) for s in (k_scale, v_scale)]
+    page_buffers = [
+        pltpu.VMEM((2, ppb) + pool.shape[1:], pool.dtype) for pool in pools
+    ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # seq_lens, block_tables
-        grid=(B, Hkv, M),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, group, Dh),
-            lambda b, g, j, seq_lens, tables: (b, g, 0, 0),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, Dh), jnp.float32),
+        grid=(B,),
+        in_specs=[row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=row_spec,
+        scratch_shapes=page_buffers + [
+            pltpu.SemaphoreType.DMA((2,)),  # one a slot
+            pltpu.SMEM((1,), jnp.int32),  # the slot the next row starts in
+            pltpu.VMEM((Hkv, group, _LANES), jnp.float32),  # m
+            pltpu.VMEM((Hkv, group, _LANES), jnp.float32),  # l
+            pltpu.VMEM((Hkv, group, Dh), jnp.float32),  # acc
         ],
     )
 
@@ -226,19 +317,11 @@ def _paged_attention_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_t.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        cost_estimate=pl.CostEstimate(
-            flops=int(4 * B * H * M * page * Dh),
-            # K AND V pools (+ both scale arrays when quantized)
-            bytes_accessed=int(
-                q.size * 2
-                + 2 * B * M * page * Hkv * (Dh * kv_elem + (4 if quantized else 0))
-            ),
-            transcendentals=int(B * H * M * page),
+            # rows in order: each starts the next one's first block
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32), *operands)
+    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32), q_t, *pools)
     return out.reshape(B, H, Dh)
 
 
@@ -254,9 +337,10 @@ def paged_decode_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Pallas paged decode attention; contract identical to
-    :func:`paged_decode_attention_ref`. Streams only owned pages. The
-    [N, Hkv, page, Dh] pool layout keeps every BlockSpec's trailing two
-    dims equal to full array dims (page, Dh) — the Mosaic tiling rule."""
+    :func:`paged_decode_attention_ref`. Streams only owned pages. In the
+    [N, Hkv, page, Dh] pool layout a page is one contiguous piece for all
+    KV heads — one DMA — whose trailing two dims (page, Dh) are whole
+    Mosaic tiles."""
     Dh = q.shape[-1]
     scale_v = scale if scale is not None else 1.0 / math.sqrt(Dh)
     mode = kernel_mode(interpret)

@@ -76,6 +76,120 @@ class TestPagedAttentionOps:
                                    rtol=2e-5, atol=2e-5)
 
 
+def _paged_case(heads, pool, max_len, seed=0):
+    """Served types at a small width: bf16 queries over a bf16 pool of
+    16-token pages or an int8 pool (with scales) of 32-token pages, tables
+    ``max_len`` tokens wide. Returns (page, args for the entry up to the
+    tables, kwargs for the reference)."""
+    from gofr_tpu.models.llama import quantize_kv
+
+    Hkv, group = heads
+    page = {"bf16": 16, "int8": 32}[pool]
+    Dh, B, M = 32, 8, max_len // page
+    N = B * M + 1
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (B, Hkv * group, Dh), jnp.bfloat16)
+    k_pool = jax.random.normal(kk, (N, Hkv, page, Dh), jnp.bfloat16)
+    v_pool = jax.random.normal(kv, (N, Hkv, page, Dh), jnp.bfloat16)
+    tables = jnp.asarray(
+        np.random.default_rng(seed).permutation(N - 1).reshape(B, M), jnp.int32
+    )
+    if pool == "bf16":
+        return page, (q, k_pool, v_pool, tables), {}
+    k_q, ks = quantize_kv(k_pool)
+    v_q, vs = quantize_kv(v_pool)
+    ks, vs = ks[..., None], vs[..., None]
+    return page, (q, k_q, v_q, ks, vs, tables), {"k_scale": ks, "v_scale": vs}
+
+
+class TestPagedKernelShapes:
+    """The loop kernel against the gather reference over both head classes
+    (grouped: Hkv 8 x 4 queries; full multi-head: Hkv 4 x 1) and both pool
+    kinds, each with rows that end everywhere a block can end."""
+
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    @pytest.mark.parametrize("heads", [(8, 4), (4, 1)], ids=["gqa8x4", "mha4x1"])
+    def test_kernel_matches_ref_ragged(self, heads, pool):
+        from gofr_tpu.ops.paged_attention import (
+            _pages_per_block,
+            paged_decode_attention_q,
+        )
+
+        page, args, ref_kw = _paged_case(heads, pool, max_len=320)
+        M = args[-1].shape[1]
+        block = page * _pages_per_block(
+            heads[0], page, 32, args[1].dtype.itemsize, pool == "int8", M
+        )
+        assert block == 128 and M * page > 2 * block  # several blocks a row
+        # an empty slot, one page exactly, a page boundary - 1 and + 1, a
+        # block boundary and + 1, a length no multiple of the block, the
+        # whole table
+        seq_lens = jnp.array(
+            [1, page, page - 1, page + 1, block, block + 1, 200, M * page], jnp.int32
+        )
+        ref = paged_decode_attention_ref(*args[:3], args[-1], seq_lens, **ref_kw)
+        entry = paged_decode_attention if pool == "bf16" else paged_decode_attention_q
+        out = entry(*args, seq_lens, interpret=True)
+        assert out.dtype == ref.dtype == jnp.bfloat16
+        # two roundings of a bf16 result (8 bits) of magnitude < 4
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=2e-2
+        )
+
+    def test_unowned_pages_are_never_read(self):
+        """NaN in every page no row owns, the pages that unused block-table
+        entries point at among them, and (TPU interpret mode) in VMEM the
+        kernel has not written: the result is the clean pool's."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        from gofr_tpu.ops.paged_attention import _paged_attention_call
+
+        B, S, H, Hkv, Dh, page = 3, 192, 4, 2, 16, 8
+        q, _, _, k_pool, v_pool, tables = _random_pool(
+            jax.random.PRNGKey(3), B, S, H, Hkv, Dh, page
+        )
+        lens = [1, 70, 129]  # 1, 9 and 17 of 24 pages owned
+        seq_lens = jnp.array(lens, jnp.int32)
+        ref = paged_decode_attention_ref(q, k_pool, v_pool, tables, seq_lens)
+
+        owned = np.zeros(k_pool.shape[0], bool)
+        for b, n in enumerate(lens):
+            owned[np.asarray(tables)[b, : -(-n // page)]] = True
+        assert not owned[0] and owned.sum() == 1 + 9 + 17
+        poison = jnp.asarray(~owned)[:, None, None, None]
+        k_bad = jnp.where(poison, jnp.nan, k_pool)
+        v_bad = jnp.where(poison, jnp.nan, v_pool)
+        out = _paged_attention_call(
+            q, k_bad, v_bad, tables, seq_lens, 1.0 / np.sqrt(Dh),
+            pltpu.InterpretParams(),
+        )
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-5)
+
+    def test_grid_has_no_page_axis(self):
+        """One program a row: the page loop is inside the kernel, so the
+        grid does not grow with the block table's width."""
+        B, M, page = 3, 24, 8
+        q, _, _, k_pool, v_pool, tables = _random_pool(
+            jax.random.PRNGKey(4), B, M * page, 4, 2, 16, page
+        )
+        jaxpr = jax.make_jaxpr(
+            lambda *a: paged_decode_attention(*a, interpret=True)
+        )(q, k_pool, v_pool, tables, jnp.full((B,), 5, jnp.int32))
+
+        def calls(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from calls(sub)
+
+        (call,) = calls(jaxpr.jaxpr)
+        grid = tuple(call.params["grid_mapping"].grid)
+        assert grid == (B,) and M not in grid
+
+
 class TestPagedKVCache:
     def test_accounting_roundtrip(self):
         cfg = llama.LlamaConfig.tiny()
