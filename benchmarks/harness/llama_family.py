@@ -10,8 +10,12 @@ embedding, f32 norms) — the program's ``init_params`` makes every leaf in
 bf16 first and peaks 7 GB over the settled size. The plain reference
 (``reference.py``) reads the same arrays and nothing the program made.
 
-A later configuration of another architecture brings a factory of its
-own and names it in its file's ``factory`` key.
+``lowered_programs(engine, prompt_sizes)`` is the family's lowering: the
+programs of ``serving/batch.py`` this family's cells warm up, as text.
+
+A later configuration of another architecture brings a module of its own
+with its factory and its ``lowered_programs``, names the factory in its
+file's ``factory`` key, and a plain reference under ``reference``.
 """
 
 from __future__ import annotations
@@ -103,10 +107,55 @@ def build(config: dict[str, Any], seed: int) -> tuple[Any, dict]:
     return cfg, make_weights(config, seed)
 
 
-# the engine's ByteTokenizer, restated so that the reference needs nothing
-# of the program: BOS, then one id per UTF-8 byte offset by the specials
-BOS_ID, EOS_ID, BYTE_OFFSET = 1, 2, 3
+def lowered_programs(engine: Any, prompt_sizes: list[int]) -> tuple[dict[str, str], tuple[str, ...]]:
+    """The family's lowering: the harness finds it by this name in the
+    module of the configuration's ``factory``. Lower (not compile) the
+    engine's own jitted programs at the argument shapes the cell's
+    warm-up uses: program name -> lowered text, and the names of those
+    that must hold a compiled kernel on the chip. The runner counts the
+    Mosaic custom calls in each — which attention path each of the cell's
+    programs takes.
 
+    ``chip_smoke.py:attention_paths`` keeps a copy of these signatures
+    (the benchmark may not import the program's scripts): when a
+    signature of ``serving/batch.py`` moves, change the two together."""
+    from gofr_tpu.serving import batch as batch_ops
 
-def prompt_ids(prompt: str) -> list[int]:
-    return [BOS_ID] + [b + BYTE_OFFSET for b in prompt.encode("utf-8")]
+    if engine.paged_cache is None:
+        raise ValueError("this family lowers the paged KV layout; set kv_layout to paged in the cell")
+    cfg, ec = engine.model_cfg, engine.config
+    B, C, steps = ec.max_slots, engine._chunk_tokens, engine._block_steps
+
+    def ab(tree: Any) -> Any:
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+    def vec(dtype: Any, *shape: int) -> Any:
+        return jax.ShapeDtypeStruct(shape or (B,), dtype)
+
+    i32, f32 = jnp.int32, jnp.float32
+    params, key = ab(engine.params), ab(engine._rng_root)
+    state = batch_ops.DecodeState(
+        vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
+        vec(i32), vec(f32), key, vec(i32),
+    )
+    texts: dict[str, str] = {}
+    chunked = False
+    for n in prompt_sizes:
+        if engine._route_chunked(n):
+            chunked = True
+            continue
+        b = batch_ops.pad_bucket(n, engine._buckets())
+        texts[f"prefill_compute[{b}]"] = batch_ops.prefill_compute.lower(
+            cfg, params, vec(i32, 1, b), vec(i32, 1)).as_text()
+    pc = engine.paged_cache
+    kp, vp = ab(pc.k_pool), ab(pc.v_pool)
+    tables = vec(i32, B, pc.max_pages_per_seq)
+    texts["decode_block_paged"] = batch_ops.decode_block_paged.lower(
+        cfg, params, kp, vp, state, tables, vec(jnp.bool_), steps).as_text()
+    if chunked:
+        row = (vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(i32), vec(f32),
+               vec(i32), vec(f32), vec(i32), key, vec(jnp.bool_), steps)
+        texts["ragged_step_paged"] = batch_ops.ragged_step_paged.lower(
+            cfg, params, kp, vp, state, tables, vec(i32, B, C), vec(i32),
+            vec(jnp.bool_), *row).as_text()
+    return texts, ("decode_block_paged",)

@@ -2,7 +2,11 @@
 configuration, one traffic mix, one cell or one per-layer metric is a file
 of its own, found by the name in the manifest:
 
-    configuration   the ``file`` its entry gives (benchmarks/configs/*.json)
+    configuration   the ``file`` its entry gives (benchmarks/configs/*.json);
+                    the file names its ``factory``, whose module also holds
+                    the family's ``lowered_programs``, and its ``reference``:
+                    the runner knows one architecture from another through
+                    those alone
     traffic mix     benchmarks/traffic/<traffic>.json
     cell settings   benchmarks/cells/<workload name>.json
     per-layer metric  benchmarks/layer_metrics/<metric name>.py : read(run)
@@ -69,3 +73,28 @@ def resolve(dotted: str) -> Any:
     """``package.module:function`` -> the function (a config's factory)."""
     module, _, attr = dotted.partition(":")
     return getattr(importlib.import_module(module), attr)
+
+
+def lowering(config: dict[str, Any]) -> Callable[..., Any]:
+    """The configuration's lowering: ``lowered_programs`` in its factory's
+    module, the one way to name it. Called with the engine and the
+    warm-up's prompt sizes, it returns (program name -> lowered text, the
+    names that must hold a compiled kernel on the chip)."""
+    return resolve(config["factory"].partition(":")[0] + ":lowered_programs")
+
+
+def reference_module(config: dict[str, Any]) -> Any:
+    """The plain reference the configuration's file names: ``reference``
+    is the module's path from the root of the checkout, ``a/b/c.py``, and
+    the module is ``a.b.c`` as an import gives it — one object a file.
+    Its contract: ``served_gaps(config, weights, prompt, served, pad_len=,
+    control_bits=)``, ``pad_to(n, multiple)`` and, for the tests,
+    ``logits(config, weights, ids, weight_bits=)``."""
+    path = config.get("reference") or ""
+    if not path.endswith(".py") or path.startswith("/") or ".." in path.split("/"):
+        raise ValueError(f"reference {path!r}: a module's path from the root of the checkout, a/b/c.py")
+    module = importlib.import_module(path[:-3].replace("/", "."))
+    missing = [name for name in ("served_gaps", "pad_to", "logits") if not callable(getattr(module, name, None))]
+    if missing:
+        raise AttributeError(f"reference {path} lacks {', '.join(missing)}")
+    return module

@@ -24,8 +24,8 @@ import time
 import urllib.request
 from typing import Any
 
-from benchmarks.harness import loadgen, stats, traffic
-from benchmarks.harness.manifest import Manifest, resolve
+from benchmarks.harness import loadgen, stats, tokens, traffic
+from benchmarks.harness.manifest import Manifest, lowering, reference_module, resolve
 
 MOSAIC_CALL = "tpu_custom_call"  # what a Mosaic-compiled pallas_call lowers to
 TRACE_DIR = ".bench_trace"       # inside the checkout, git-ignored, removed after the run
@@ -141,52 +141,15 @@ def engine_config(cell: dict[str, Any]) -> Any:
     return EngineConfig(**settings)
 
 
-def attention_paths(engine: Any, prompt_sizes: list[int]) -> dict[str, int]:
-    """Lower (not compile) the engine's own jitted programs at its real
-    argument shapes and count Mosaic custom calls in each — which
-    attention path each of the cell's programs takes (chip_smoke.py's
-    check, for the paged layout and this cell's buckets)."""
-    import jax
-    import jax.numpy as jnp
-
-    from gofr_tpu.serving import batch as batch_ops
-
-    cfg, ec = engine.model_cfg, engine.config
-    B, C, steps = ec.max_slots, engine._chunk_tokens, engine._block_steps
-
-    def ab(tree: Any) -> Any:
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
-
-    def vec(dtype: Any, *shape: int) -> Any:
-        return jax.ShapeDtypeStruct(shape or (B,), dtype)
-
-    i32, f32 = jnp.int32, jnp.float32
-    params, key = ab(engine.params), ab(engine._rng_root)
-    state = batch_ops.DecodeState(
-        vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
-        vec(i32), vec(f32), key, vec(i32),
-    )
-    paths: dict[str, int] = {}
-    chunked = False
-    for n in prompt_sizes:
-        if engine._route_chunked(n):
-            chunked = True
-            continue
-        b = batch_ops.pad_bucket(n, engine._buckets())
-        paths[f"prefill_compute[{b}]"] = batch_ops.prefill_compute.lower(
-            cfg, params, vec(i32, 1, b), vec(i32, 1)).as_text().count(MOSAIC_CALL)
-    pc = engine.paged_cache
-    kp, vp = ab(pc.k_pool), ab(pc.v_pool)
-    tables = vec(i32, B, pc.max_pages_per_seq)
-    paths["decode_block_paged"] = batch_ops.decode_block_paged.lower(
-        cfg, params, kp, vp, state, tables, vec(jnp.bool_), steps).as_text().count(MOSAIC_CALL)
-    if chunked:
-        row = (vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(i32), vec(f32),
-               vec(i32), vec(f32), vec(i32), key, vec(jnp.bool_), steps)
-        paths["ragged_step_paged"] = batch_ops.ragged_step_paged.lower(
-            cfg, params, kp, vp, state, tables, vec(i32, B, C), vec(i32),
-            vec(jnp.bool_), *row).as_text().count(MOSAIC_CALL)
-    return paths
+def mosaic_calls(config: dict[str, Any], engine: Any, prompt_sizes: list[int]) -> tuple[dict[str, int], list[str]]:
+    """Which attention path each of the cell's programs takes: the
+    configuration's lowering gives the engine's own jitted programs as
+    lowered text, at the shapes the warm-up uses; counted here are the
+    Mosaic custom calls in each. Also returned: the programs that must
+    hold a compiled kernel and lowered without one."""
+    texts, must_hold = lowering(config)(engine, prompt_sizes)
+    paths = {name: text.count(MOSAIC_CALL) for name, text in texts.items()}
+    return paths, [name for name in must_hold if not paths.get(name)]
 
 
 def http_json(url: str, timeout: float = 30.0) -> Any:
@@ -409,9 +372,9 @@ def check_outputs(config: dict[str, Any], weights: Any, schedule: dict[str, Any]
                   eos_id: int, control_bits: int | None = None) -> dict[str, Any]:
     """Compare a seeded sample of what the window served with the plain
     reference: the widest gap by which a served token's reference logit
-    lies below the reference's best. Greedy tokens only — all are."""
-    from benchmarks.harness import llama_family, reference
-
+    lies below the reference's best. Greedy tokens only — all are. The
+    reference is the module the configuration's file names."""
+    reference = reference_module(config)
     by_index = {r["index"]: r for r in schedule["requests"]}
     sample = pick_sample(records, seed, int(limits.get("sample_requests", 4)))
     # one padded length for the whole mix: the reference compiles once
@@ -420,7 +383,7 @@ def check_outputs(config: dict[str, Any], weights: Any, schedule: dict[str, Any]
     worst, worst_control, n_tokens = 0.0, None, 0
     per_request = []
     for rec in sample:
-        prompt = llama_family.prompt_ids(by_index[rec["index"]]["prompt"])
+        prompt = tokens.prompt_ids(by_index[rec["index"]]["prompt"])
         served = served_tokens(rec, eos_id)
         gaps = reference.served_gaps(config, weights, prompt, served, pad_len=padded,
                                      control_bits=control_bits)
@@ -433,8 +396,8 @@ def check_outputs(config: dict[str, Any], weights: Any, schedule: dict[str, Any]
             entry["control_gap_max"] = float(gaps["control"].max())
             worst_control = max(worst_control or 0.0, entry["control_gap_max"])
         per_request.append(entry)
-    out = {"sampled_requests": len(sample), "sampled_tokens": n_tokens,
-           "gap_max": worst, "per_request": per_request}
+    out = {"reference": f"{config['reference']} ({reference.__name__})", "sampled_requests": len(sample),
+           "sampled_tokens": n_tokens, "gap_max": worst, "per_request": per_request}
     if control_bits is not None:
         out["control_gap_max"] = worst_control
     return out
@@ -474,6 +437,7 @@ def _run(manifest: Manifest, workload: dict, config: dict, spec: dict, cell: dic
 
     devices = jax.devices()
     device = devices[0]
+    older = jax.live_arrays()  # not this run's to free
     if device.platform != platform or len(devices) < int(workload["chips"]):
         print(f"benchmark: cell {workload['name']} needs {workload['chips']} {platform} device(s); "
               f"jax found {len(devices)} x {device.platform} ({device.device_kind}); no result",
@@ -488,7 +452,7 @@ def _run(manifest: Manifest, workload: dict, config: dict, spec: dict, cell: dic
     from gofr_tpu.serving.handlers import register_generation_routes
     from gofr_tpu.testutil import get_free_port
 
-    from benchmarks.harness import llama_family, peaks
+    from benchmarks.harness import peaks
 
     cache_dir = configure_compile_cache()
     # small programs (slot inserts, samplers) are cached too: every run is
@@ -521,8 +485,6 @@ def _run(manifest: Manifest, workload: dict, config: dict, spec: dict, cell: dic
         metrics=app.container.metrics_manager, logger=app.container.logger,
         tracer=app.container.tracer, seed=seed & 0x7FFFFFFF,
     )
-    if engine.paged_cache is None:
-        raise ValueError("the harness drives the paged KV layout; set kv_layout to paged in the cell")
     register_generation_routes(app, engine)
     telemetry = DeviceTelemetry(engine, metrics=app.container.metrics_manager,
                                 logger=app.container.logger, interval_s=1.0)
@@ -533,12 +495,12 @@ def _run(manifest: Manifest, workload: dict, config: dict, spec: dict, cell: dic
 
     warm = warmup_requests(engine, spec)
     t = time.monotonic()
-    paths = attention_paths(engine, [r["prompt_tokens"] for r in warm])
+    paths, bare = mosaic_calls(config, engine, [r["prompt_tokens"] for r in warm])
     say(t_start, "attention paths (Mosaic custom calls per program): "
                  + ", ".join(f"{k}={v}" for k, v in paths.items())
                  + f" [{time.monotonic() - t:.2f}s]")
-    if platform == "tpu" and not paths.get("decode_block_paged"):
-        raise RuntimeError("decode_block_paged lowered without the Mosaic paged-attention kernel")
+    if platform == "tpu" and bare:
+        raise RuntimeError(f"{', '.join(bare)} lowered without the Mosaic kernel its configuration says it holds")
 
     thread = threading.Thread(target=app.run, name="bench-app", daemon=True)
     thread.start()
@@ -602,24 +564,22 @@ def _run(manifest: Manifest, workload: dict, config: dict, spec: dict, cell: dic
                  f"p90 {summary.get('ttft_p90_ms', 0.0):.1f} ms, tpot p90 {summary.get('tpot_p90_ms', 0.0):.2f} ms, "
                  f"tok_s {summary['tok_s']:.1f}; samples: "
                  f"ttft {summary.get('ttft_samples', 0)}, tpot {summary.get('tpot_samples', 0)}")
-    say(t_start, f"hbm in use {mem.get('bytes_in_use', 0) / 1e9:.2f}GB peak {mem.get('peak_bytes_in_use', 0) / 1e9:.2f}GB "
-                 f"of {mem.get('bytes_limit', 0) / 1e9:.2f}GB; kv pages {health.get('kv_pages')}; "
-                 f"scheduler {health.get('scheduler_backend')}")
-
     # ------------------------------------- free the program's state, then check
     vocab, eos_id = cfg.vocab_size, tokenizer.eos_id
-    pc = engine.paged_cache
-    for pool in (pc.k_pool, pc.v_pool):
-        pool.delete()
-    del app, engine, telemetry, pc
+    freed = free_device_state(params, older)
+    del app, engine, telemetry
     gc.collect()
+    say(t_start, f"hbm in use {mem.get('bytes_in_use', 0) / 1e9:.2f}GB peak {mem.get('peak_bytes_in_use', 0) / 1e9:.2f}GB "
+                 f"of {mem.get('bytes_limit', 0) / 1e9:.2f}GB, after freeing every device array beside the weights "
+                 f"({freed / 1e9:.2f}GB) {_mem(device).get('bytes_in_use', 0) / 1e9:.2f}GB; "
+                 f"kv pages {health.get('kv_pages')}; scheduler {health.get('scheduler_backend')}")
     t = time.monotonic()
     limits = cell["correct"]
     due = stats.due_in_window(records, t0, t1)
     faults = structural_faults(due, vocab, eos_id)
     check = check_outputs(config, params, schedule, due, seed, limits, eos_id, control_bits)
-    say(t_start, f"reference over {check['sampled_requests']} requests, {check['sampled_tokens']} served tokens: "
-                 f"{time.monotonic() - t:.2f}s")
+    say(t_start, f"reference {check['reference']} over {check['sampled_requests']} requests, "
+                 f"{check['sampled_tokens']} served tokens: {time.monotonic() - t:.2f}s")
     checks = {
         "gap_max": {"value": check["gap_max"], "limit": float(limits["gap_max"])},
         "failed": {"value": summary["failed"], "limit": 0},
@@ -684,6 +644,27 @@ def _run(manifest: Manifest, workload: dict, config: dict, spec: dict, cell: dic
 
 def _mem(device: Any) -> dict[str, Any]:
     return device.memory_stats() or {}
+
+
+def free_device_state(keep: Any, older: Any = ()) -> int:
+    """Delete every device array the process holds whose buffer is none
+    of ``keep``'s (the weights), but for the arrays of ``older`` (those
+    alive before the run began: in a run of the command none that matter,
+    in a test process other tests'). The runtime knows every array, so a
+    cache of any layout goes — one pool or one a layer type, a latent pool,
+    recurrent state — whatever holds it. One device: an array is one
+    buffer. Returns the bytes deleted."""
+    import jax
+
+    kept = {leaf.unsafe_buffer_pointer() for leaf in jax.tree.leaves(keep)}
+    spared = {id(array) for array in older}
+    freed = 0
+    for array in jax.live_arrays():
+        if id(array) in spared or array.is_deleted() or array.unsafe_buffer_pointer() in kept:
+            continue
+        freed += int(array.nbytes)
+        array.delete()
+    return freed
 
 
 def _sleep_until(t: float) -> None:

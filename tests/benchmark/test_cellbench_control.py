@@ -5,9 +5,12 @@
   configurations state): its widest gap must pass a limit that the
   program's own gap stays under;
 * a token altered where it is produced: the harness drives a whole run
-  with the timed path broken underneath and ``correct`` reads false.
+  with the timed path broken underneath and ``correct`` reads false — in
+  a cell of each tiny configuration, so also through a reference that the
+  configuration's file names and ``benchmarks/harness/`` does not.
 """
 
+import importlib
 import time
 
 import numpy as np
@@ -62,3 +65,29 @@ def test_an_altered_token_reads_not_correct(root):
 def test_the_same_run_unbroken_reads_correct(root):
     code, result = runner.run_cell(root, "tiny.open", 5, 2.0, False, time.monotonic(), platform="cpu")
     assert code == 0 and result["correct"] is True
+
+
+@pytest.fixture
+def named_only(monkeypatch):
+    """The second tiny configuration's modules, with the harness's own
+    reference set to fail if anything asks it."""
+    def asked(*args, **kwargs):
+        raise AssertionError("benchmarks.harness.reference was asked")
+
+    for name in ("served_gaps", "logits", "pad_to"):
+        monkeypatch.setattr(reference, name, asked)
+    family = importlib.import_module("tests.benchmark.tiny_family")
+    del family.CALLS[:]
+    return family
+
+
+@pytest.mark.parametrize("fault, correct", [(None, True), (_alter_tokens, False)], ids=["unbroken", "a_token_altered"])
+def test_a_cell_is_decided_by_the_reference_its_file_names_and_by_no_other(root, named_only, capsys, fault, correct):
+    code, result = runner.run_cell(root, "tiny2.open", 2**31 + 21, 2.0, False, time.monotonic(),
+                                   platform="cpu", fault=fault)
+    assert code == 0 and result["attempted"] == 12 and result["failed"] == 0
+    assert result["correct"] is correct
+    assert (result["checks"]["gap_max"]["value"] <= result["checks"]["gap_max"]["limit"]) is correct
+    # the factory and the lowering were the file's too, and the reference ran once a sampled request
+    assert named_only.CALLS[:2] == ["factory", "lowering"] and named_only.CALLS.count("served_gaps") == 3
+    assert "reference tests/benchmark/tiny_family.py (tests.benchmark.tiny_family) over 3 requests" in capsys.readouterr().err

@@ -55,6 +55,36 @@ def test_served_tokens_agree_with_the_plain_reference(open_run):
     assert checks["sampled_tokens"]["value"] >= checks["sampled_tokens"]["limit_min"]
 
 
+def test_the_run_names_its_reference_and_frees_what_the_engine_held(root, monkeypatch, capsys):
+    """Before the reference runs, every device array of the run but the
+    weights is gone — the runtime's list of live arrays, no field's name —
+    and the weights, and the arrays older than the run, are whole."""
+    import jax
+
+    held, seen = {}, {}
+    check_outputs = runner.check_outputs
+    bystander = jax.numpy.arange(7.0)  # another test's array, alive before the run
+    older = jax.live_arrays()
+
+    def spy(config, weights, *args, **kwargs):
+        engine = held["engine"]
+        pools = [x for x in vars(engine.paged_cache).values() if isinstance(x, jax.Array)]
+        seen["pools_deleted"] = len(pools) >= 2 and all(x.is_deleted() for x in pools)
+        seen["left_to_free"] = runner.free_device_state(weights, older)
+        seen["weights_live"] = not any(x.is_deleted() for x in jax.tree.leaves(weights))
+        return check_outputs(config, weights, *args, **kwargs)
+
+    monkeypatch.setattr(runner, "check_outputs", spy)
+    code, result = runner.run_cell(root, "tiny.open", 13, 1.0, False, time.monotonic(), platform="cpu",
+                                   fault=lambda engine: held.update(engine=engine))
+    assert code == 0 and result["correct"] is True
+    assert seen == {"pools_deleted": True, "left_to_free": 0, "weights_live": True}
+    assert float(bystander.sum()) == 21.0
+    err = capsys.readouterr().err
+    assert "reference benchmarks/harness/reference.py (benchmarks.harness.reference) over" in err
+    assert "after freeing every device array beside the weights" in err
+
+
 def test_closed_loop_traced_run_goes_through_the_chunked_path(root):
     code, result = runner.run_cell(root, "tiny.closed", 7, 2.0, True, time.monotonic(), platform="cpu")
     assert code == 0 and result["correct"] is True and result["attempted"] >= 3
