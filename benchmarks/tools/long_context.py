@@ -1,0 +1,91 @@
+"""A builder's tool, not the command: the one place the window binds at
+published width. An engine of the configuration's family with a few long
+slots serves ONE prompt longer than ``sliding_window`` through chunked
+prefill and then decodes; the served tokens are compared with the
+configuration's plain reference (and its int4 control) as a cell's are.
+
+    python3 benchmarks/tools/long_context.py <config name> <seed> <prompt tokens> <new tokens> [slots] [slot length] [chunk]
+
+One JSON line on standard output, also appended to
+chiprun_out/long_context.jsonl. Needs a TPU.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import random
+import sys
+import threading
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def main(argv: list[str]) -> int:
+    sys.path.insert(0, ROOT)
+    import jax
+
+    from benchmarks.harness import runner, tokens, traffic
+    from benchmarks.harness.manifest import Manifest, reference_module, resolve
+    from gofr_tpu.serving import ByteTokenizer, EngineConfig, ServingEngine
+
+    name, seed, n_prompt, n_new = argv[0], int(argv[1]), int(argv[2]), int(argv[3])
+    slots, length, chunk = (int(a) for a in (argv[4:7] + ["4", "8192", "64"][len(argv[4:7]):]))
+    t_start = time.monotonic()
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(f"long_context: needs a TPU, jax found {device.platform}; no result", file=sys.stderr)
+        return 3
+    config = Manifest(ROOT).config(name)
+    older = jax.live_arrays()
+    cfg, params = resolve(config["factory"])(config, seed)
+    jax.block_until_ready(params)
+    engine = ServingEngine(cfg, params, EngineConfig(
+        kv_layout="paged", kv_page_size=16, kv_dtype="bf16", prefix_cache_entries=0, max_slots=slots,
+        max_seq_len=length, prefill_buckets=(32, 64, 128, 256), prefill_chunk_tokens=chunk,
+    ), ByteTokenizer(cfg.vocab_size), seed=seed & 0x7FFFFFFF)
+    prompt = traffic._prompt_text(random.Random(f"bench:long:{seed}"), n_prompt)
+    served: list[int] = []
+    done = threading.Event()
+
+    def on_token(token_id: int, piece: str, last: bool) -> None:
+        if token_id is not None and token_id >= 0 and not last:
+            served.append(int(token_id))
+        if last:
+            done.set()
+
+    engine.start()
+    try:
+        t = time.monotonic()
+        future = engine.submit(prompt, max_new_tokens=n_new, temperature=0.0, stream_cb=on_token)
+        result = future.result(timeout=1800)
+        done.wait(60)
+        seconds = time.monotonic() - t
+    finally:
+        engine.stop()
+    runner.say(t_start, f"served {result.completion_tokens} tokens after a prompt of {result.prompt_tokens} "
+                        f"in {seconds:.1f}s ({result.finish_reason}); streamed {len(served)}")
+    ids = tokens.prompt_ids(prompt)
+    if result.finish_reason == "stop":
+        served.append(ByteTokenizer(cfg.vocab_size).eos_id)
+    freed = runner.free_device_state(params, older)
+    del engine
+    reference = reference_module(config)
+    t = time.monotonic()
+    gaps = reference.served_gaps(config, params, ids, served, pad_len=reference.pad_to(len(ids) + len(served), 512),
+                                 control_bits=4)
+    line = {"config": name, "seed": seed, "prompt_tokens": len(ids), "served": len(served),
+            "window": config.get("sliding_window"), "slots": slots, "slot_length": length, "chunk": chunk,
+            "gap_max": float(gaps["served"].max()), "mismatch": int((gaps["served"] > 0).sum()),
+            "control_gap_max": float(gaps["control"].max()), "freed_gb": freed / 1e9,
+            "serve_s": seconds, "reference_s": time.monotonic() - t}
+    print(json.dumps(line), flush=True)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "long_context.jsonl"), "a", encoding="utf-8") as fh:
+        fh.write(json.dumps(line) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -101,6 +101,20 @@ def check_kernels(self_test: bool) -> None:
             attention(q, k, v, causal=True, kv_len=kv_len)[:, :n])
     say(f"kernel flash_attention S={fs} H={H}/{Hkv}x{D}: max|err|={e:.4g} (tol {tol})")
     check(e <= tol, f"flash_attention disagrees with ops.attention ({e} > {tol})")
+    # with a window (a traced scalar, a scanned layer's own): one that
+    # leaves whole blocks of 128 keys below it and cuts inside a block, at
+    # the heads of the window layers served (command-a-plus: 128 / 8)
+    wH, wHkv, ws, window = (H, Hkv, 256, 40) if self_test else (128, 8, 512, 200)
+    q = jax.random.normal(keys[0], (1, ws, wH, D), dtype)
+    k = jax.random.normal(keys[1], (1, ws, wHkv, D), dtype)
+    v = jax.random.normal(keys[2], (1, ws, wHkv, D), dtype)
+    n = ws - 55
+    kv_len = jnp.array([n], jnp.int32)
+    w = jnp.int32(window)
+    e = err(flash_attention(q, k, v, kv_len, causal=True, interpret=interpret, window=w)[:, :n],
+            attention(q, k, v, causal=True, kv_len=kv_len, window=w)[:, :n])
+    say(f"kernel flash_attention window={window} S={ws} H={wH}/{wHkv}x{D}: max|err|={e:.4g} (tol {tol})")
+    check(e <= tol, f"flash_attention with a window disagrees with ops.attention ({e} > {tol})")
 
     # the paged decode kernel sizes its blocks from the shapes it sees, so
     # it is compiled at every shape class served: Llama-3-8B under the
@@ -115,6 +129,8 @@ def check_kernels(self_test: bool) -> None:
             ("8B heads, 8 x 1024", H, Hkv, B, S, ((16, False), (32, True), (128, True))),
             ("mistral7b.chat, 32 x 768", 32, 8, 32, 768, ((16, False), (32, True))),
             ("deepseek7b.gen, 6 x 1024", 32, 32, 6, 1024, ((16, False), (32, True))),
+            # 16 queries a KV head; its window layers pass a window (below)
+            ("commandaplus.wide, 64 x 1024", 128, 8, 64, 1024, ((16, False),)),
         ]
     for label, pH, pHkv, pB, pS, pools in paged_cases:
         qd = jax.random.normal(keys[3], (pB, pH, D), dtype)
@@ -145,6 +161,18 @@ def check_kernels(self_test: bool) -> None:
                     qd, kf, vf, tables, seq_lens, interpret=interpret
                 )
                 ref = paged_decode_attention_ref(qd, kf, vf, tables, seq_lens)
+                # the same pools through the kernel with a window: one that
+                # starts long rows past their first block and cuts inside a
+                # page, and one wider than every row (a full-attention layer)
+                for window in (pS // 4 + 5, 1 << 30):
+                    w = jnp.int32(window)
+                    e = err(paged_decode_attention(qd, kf, vf, tables, seq_lens,
+                                                   interpret=interpret, window=w),
+                            paged_decode_attention_ref(qd, kf, vf, tables, seq_lens, window=w))
+                    say(f"kernel paged_decode_attention bf16 window={window} [{label}] "
+                        f"H={pH}/{pHkv} B={pB} M={M} page={page}: max|err|={e:.4g} (tol {tol})")
+                    check(e <= tol, f"paged_decode_attention window={window} [{label}] "
+                                    f"disagrees with its reference ({e} > {tol})")
             e = err(out, ref)
             name = "paged_decode_attention" + ("_q int8" if quantized else " bf16")
             say(f"kernel {name} [{label}] H={pH}/{pHkv} B={pB} M={M} page={page}: "

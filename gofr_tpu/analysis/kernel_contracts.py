@@ -385,8 +385,11 @@ KERNELS: tuple[KernelContract, ...] = (
     # from these shapes, so neither entry has a parameter for them.
     KernelContract(
         "paged_decode_attention", _PAGED_ATTN,
+        # window: None builds the kernel without the argument; a scalar
+        # (traced: a scanned layer's own) starts a row's loop at the block
+        # that holds seq_len - window
         params=("q", "k_pool", "v_pool", "block_tables", "seq_lens",
-                "scale", "interpret"),
+                "scale", "interpret", "window"),
         static=("scale", "interpret"),
         returns=(Ret("out", like="q"),),
     ),
@@ -400,7 +403,7 @@ KERNELS: tuple[KernelContract, ...] = (
     KernelContract(
         "flash_attention", _FLASH,
         params=("q", "k", "v", "kv_len", "causal", "scale", "block_q",
-                "block_k", "interpret"),
+                "block_k", "interpret", "window"),
         static=("causal", "block_q", "block_k", "interpret"),
         returns=(Ret("out", like="q"),),
     ),

@@ -303,16 +303,18 @@ def run_matrix() -> dict:
     lp = sds((N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)
     lp8 = sds((N_PAGES + 1, Hkv, PAGE, Dh), jnp.int8)
     lps = sds((N_PAGES + 1, Hkv, PAGE, 1), jnp.float32)
-    cases.append(_eval_case(
-        pa_mod.paged_decode_attention.__wrapped__,
-        C["paged_decode_attention"], "paged",
-        {
-            "q": sds((3, cfg.n_heads, Dh), cfg.dtype),
-            "k_pool": lp, "v_pool": lp,
-            "block_tables": sds((3, M), jnp.int32), "seq_lens": vec(3),
-            "scale": None, "interpret": True,
-        },
-    ))
+    # the window is None (the kernel without the argument) or a scalar
+    for variant, window in (("paged", None), ("paged.window", sds((), jnp.int32))):
+        cases.append(_eval_case(
+            pa_mod.paged_decode_attention.__wrapped__,
+            C["paged_decode_attention"], variant,
+            {
+                "q": sds((3, cfg.n_heads, Dh), cfg.dtype),
+                "k_pool": lp, "v_pool": lp,
+                "block_tables": sds((3, M), jnp.int32), "seq_lens": vec(3),
+                "scale": None, "interpret": True, "window": window,
+            },
+        ))
     cases.append(_eval_case(
         pa_mod.paged_decode_attention_q.__wrapped__,
         C["paged_decode_attention_q"], "paged.q",
@@ -323,17 +325,19 @@ def run_matrix() -> dict:
             "scale": None, "interpret": True,
         },
     ))
-    cases.append(_eval_case(
-        flash_mod.flash_attention.__wrapped__, C["flash_attention"],
-        "flash",
-        {
-            "q": sds((2, 8, cfg.n_heads, Dh), cfg.dtype),
-            "k": sds((2, 8, cfg.n_heads, Dh), cfg.dtype),
-            "v": sds((2, 8, cfg.n_heads, Dh), cfg.dtype),
-            "kv_len": None, "causal": True, "scale": None,
-            "block_q": 128, "block_k": 128, "interpret": True,
-        },
-    ))
+    for variant, window in (("flash", None), ("flash.window", sds((), jnp.int32))):
+        cases.append(_eval_case(
+            flash_mod.flash_attention.__wrapped__, C["flash_attention"],
+            variant,
+            {
+                "q": sds((2, 8, cfg.n_heads, Dh), cfg.dtype),
+                "k": sds((2, 8, cfg.n_heads, Dh), cfg.dtype),
+                "v": sds((2, 8, cfg.n_heads, Dh), cfg.dtype),
+                "kv_len": None, "causal": True, "scale": None,
+                "block_q": 128, "block_k": 128, "interpret": True,
+                "window": window,
+            },
+        ))
 
     return {"mode": "matrix", "cases": cases, "violations": []}
 

@@ -218,6 +218,12 @@ class Container:
             "Token positions issued to the device per dispatch (label "
             "kind=decode|prefill|padding)",
         )
+        m.new_counter(
+            "app_moe_expert_rows_total",
+            "Rows routed to each routed expert this replica holds, over "
+            "decode steps and layers, read with each block's tokens (label "
+            "expert=the expert's published index; sparse-expert models only)",
+        )
         m.new_gauge(
             "app_decode_block_size",
             "Decode steps fused per device dispatch (TPU_BATCH_MULTI_STEP)",

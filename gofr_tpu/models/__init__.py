@@ -2,6 +2,9 @@
 
 The serving framework's model zoo (BASELINE.json configs):
 - llama: decoder-only LLM family (Llama-3 shapes; flagship)
+- cohere2_moe: parallel-block decoder with sparse and shared experts, window
+  and full attention by layer type (served on the paged path, also as one
+  chip's share of an expert-parallel deployment)
 - bert: encoder embedder (/embed endpoint)
 - whisper: encoder-decoder ASR (async Pub/Sub path)
 
@@ -11,6 +14,6 @@ scanned (lax.scan) so compile time is flat in depth; weights are bf16 by
 default with f32 accumulation inside ops.
 """
 
-from gofr_tpu.models import llama, bert
+from gofr_tpu.models import bert, cohere2_moe, llama
 
-__all__ = ["llama", "bert"]
+__all__ = ["llama", "cohere2_moe", "bert"]

@@ -139,6 +139,17 @@ def param_bytes(params: dict) -> int:
     return sum(int(p.size) * p.dtype.itemsize for p in jax.tree.leaves(params))
 
 
+# what serving/batch.model_of asks of a served module beside its programs
+def step_stats_len(cfg: LlamaConfig) -> int:
+    """int32 counters a paged decode step returns after the pools: none."""
+    return 0
+
+
+def unserved(engine_config: Any, lora: Any) -> str | None:
+    """What an engine asks for that this model has no program for: nothing."""
+    return None
+
+
 # ------------------------------------------------------- weight-only int8
 def _quantize_body(w: jnp.ndarray, axis: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     # jitted (below) so XLA fuses abs/div/round/clip/convert into one pass

@@ -37,6 +37,7 @@ def attention(
     q_offset: jnp.ndarray | int = 0,
     kv_len: jnp.ndarray | None = None,  # [B] valid KV length per row
     scale: float | None = None,
+    window: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
     """Dense attention, GQA-native. Queries are grouped as
     ``[B, Sq, Hkv, G, D]`` and contracted against the *unexpanded* KV —
@@ -44,7 +45,8 @@ def attention(
     [B, S, H, D] copies would double-to-quadruple HBM traffic in the hot
     path (the step is bandwidth-bound). ``q_offset`` is the absolute
     position of q[0] (for chunked prefill); ``kv_len`` masks right-padded
-    KV."""
+    KV. ``window`` (causal only; a traced scalar is fine) keeps the keys
+    ``q_pos - window < k_pos <= q_pos``."""
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -63,14 +65,19 @@ def attention(
         if off.ndim == 0:
             q_pos = jnp.arange(Sq)[:, None] + off  # [Sq, 1]
             k_pos = jnp.arange(Sk)[None, :]
-            mask = (k_pos <= q_pos)[None, None, None, :, :]
+            mask = k_pos <= q_pos
+            if window is not None:
+                mask &= k_pos > q_pos - window
+            mask = mask[None, None, None, :, :]
         else:
             # per-ROW offsets (chunk verify over a shared cache): row b's
             # query i sits at absolute position off[b] + i
             q_pos = off[:, None] + jnp.arange(Sq)[None, :]  # [B, Sq]
-            mask = (
-                jnp.arange(Sk)[None, None, :] <= q_pos[:, :, None]
-            )[:, None, None, :, :]  # [B, 1, 1, Sq, Sk]
+            k_pos = jnp.arange(Sk)[None, None, :]
+            mask = k_pos <= q_pos[:, :, None]
+            if window is not None:
+                mask &= k_pos > q_pos[:, :, None] - window
+            mask = mask[:, None, None, :, :]  # [B, 1, 1, Sq, Sk]
     if kv_len is not None:
         valid = jnp.arange(Sk)[None, :] < kv_len[:, None]  # [B, Sk]
         valid = valid[:, None, None, None, :]

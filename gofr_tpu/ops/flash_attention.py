@@ -42,6 +42,18 @@ NEG_INF = -1e30
 
 def _flash_kernel(
     kv_len_ref,  # SMEM [B] (scalar prefetch) — valid kv length per batch row
+    *refs,  # windowed: window_ref SMEM [1] (scalar prefetch) first; then
+    # q_ref, k_ref, v_ref, o_ref and the three scratches, as _flash_body has them
+    windowed: bool,
+    **static,
+):
+    window = refs[0][0] if windowed else None
+    _flash_body(kv_len_ref, window, *refs[windowed:], **static)
+
+
+def _flash_body(
+    kv_len_ref,
+    window,  # None, or the scalar: query i sees keys i - window < j <= i
     q_ref,  # VMEM [1, 1, block_q, D]  ([B, H, S, D] layout)
     k_ref,  # VMEM [1, 1, block_k, D]
     v_ref,  # VMEM [1, 1, block_k, D]
@@ -76,6 +88,8 @@ def _flash_kernel(
     in_band = k_start < kv_len
     if causal:
         in_band = jnp.logical_and(in_band, k_start <= q_start + block_q - 1)
+    if window is not None:  # some key of the block is inside the first query's window
+        in_band = jnp.logical_and(in_band, k_start + block_k - 1 > q_start - window)
 
     @pl.when(in_band)
     def _step():
@@ -93,6 +107,8 @@ def _flash_kernel(
         mask = k_pos < kv_len
         if causal:
             mask = jnp.logical_and(mask, k_pos <= q_pos)
+        if window is not None:
+            mask = jnp.logical_and(mask, k_pos > q_pos - window)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scratch[:, 0:1]  # [bq, 1]
@@ -130,16 +146,20 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    window: jnp.ndarray | None = None,  # scalar int32 (may be traced)
 ) -> jnp.ndarray:
     """Flash attention. Same contract as ops.attention.attention with
     q_offset=0 (prefill): right-padded K/V masked by ``kv_len``; causal over
-    absolute positions. Returns [B, Sq, H, D] in q's dtype."""
+    absolute positions; with ``window`` (causal only) query i sees keys
+    ``i - window < j <= i`` and blocks wholly below the window are skipped.
+    ``window=None`` is the kernel without the argument. Returns
+    [B, Sq, H, D] in q's dtype."""
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     mode = kernel_mode(interpret)
     if mode == REFERENCE:
-        return attention(q, k, v, causal=causal, kv_len=kv_len, scale=scale)
+        return attention(q, k, v, causal=causal, kv_len=kv_len, scale=scale, window=window)
 
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
@@ -156,8 +176,13 @@ def flash_attention(
 
     group = H // Hkv
 
+    windowed = window is not None
+    prefetch = [kv_len]
+    if windowed:
+        prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
     kernel = functools.partial(
         _flash_kernel,
+        windowed=windowed,
         causal=causal,
         scale=scale,
         block_q=block_q,
@@ -171,25 +196,25 @@ def flash_attention(
     v_t = v.transpose(0, 2, 1, 3)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # kv_len
+        num_scalar_prefetch=len(prefetch),  # kv_len, and the window if there is one
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, block_q, D),
-                lambda b, h, iq, ik, kv_len: (b, h, iq, 0),
+                lambda b, h, iq, ik, *_: (b, h, iq, 0),
             ),
             pl.BlockSpec(
                 (1, 1, block_k, D),
-                lambda b, h, iq, ik, kv_len: (b, h // group, ik, 0),
+                lambda b, h, iq, ik, *_: (b, h // group, ik, 0),
             ),
             pl.BlockSpec(
                 (1, 1, block_k, D),
-                lambda b, h, iq, ik, kv_len: (b, h // group, ik, 0),
+                lambda b, h, iq, ik, *_: (b, h // group, ik, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
             (1, 1, block_q, D),
-            lambda b, h, iq, ik, kv_len: (b, h, iq, 0),
+            lambda b, h, iq, ik, *_: (b, h, iq, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -212,5 +237,5 @@ def flash_attention(
             transcendentals=int(B * H * Sq * Sk),
         ),
         interpret=mode == INTERPRET,
-    )(kv_len, q_t, k_t, v_t)
+    )(*prefetch, q_t, k_t, v_t)
     return out.transpose(0, 2, 1, 3)

@@ -210,3 +210,76 @@ def moe_ffn_ep(
         axis_names={axis},
     )(x, w_router, w_gate, w_up, w_down)
     return (out, f, p) if return_stats else out
+
+
+# ----------------------------------------------------- dropless, for serving
+def sigmoid_topk_gates(
+    h: jnp.ndarray,  # [T, D]
+    w_router: jnp.ndarray,  # [D, E] float32: every published expert
+    k: int,
+) -> jnp.ndarray:
+    """The sigmoid rule with ``norm_topk_prob``: scores ``sigmoid(W_r h)``
+    in float32 (the product at ``highest``: a bf16 pass can swap the 8th
+    and 9th expert), the ``k`` largest kept and divided by their sum.
+    Returns the gates [T, E] float32, zero off the chosen experts."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    top_s, top_i = jax.lax.top_k(scores, k)
+    top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    onehot = jax.nn.one_hot(top_i, scores.shape[-1], dtype=jnp.float32)  # [T, k, E]
+    return jnp.einsum("tke,tk->te", onehot, top_s)
+
+
+def held_experts(
+    h: jnp.ndarray,  # [T, D]
+    gates: jnp.ndarray,  # [T, E] over every published expert
+    experts: dict,  # w_gate/w_up [held, D, F], w_down [held, F, D]
+    shared: dict,  # the same three, [n_shared, ...]
+    first: Any,  # index of the first held expert among the published ones
+    mm: Any = jnp.matmul,  # the product for the weights' storage (llama._mm for int8)
+    layer: Any = None,  # the stacks are [L, held, ...] and this (traced) layer's is meant
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One chip's part of a dropless expert layer: the sum over the HELD
+    experts ``first .. first + held`` of ``g_e · FFN_e(h)``, plus the mean
+    of the shared experts, which every chip computes alike. Every held
+    expert runs over every row with its gate as the weight (zero where the
+    row chose another expert): exact, no capacity, no drop, and at serving
+    batch sizes an expert's three matrices are read once either way. With
+    ``first=0`` and every expert held this is the whole layer; the shares
+    of all chips, the shared part counted once, add up to it. Returns the
+    float32 sum [T, D] and the held experts' gates [T, held].
+
+    Inside a scan over layers, hand the stacks over whole with ``layer``:
+    a matrix is then ONE dynamic slice of its stack with one product to
+    read it, which XLA fuses; the scan's own slice of a layer's experts
+    has as many readers as experts and is copied out first (268 MB a
+    matrix kind at 16 experts of 4096 x 4096)."""
+
+    def ffn(w: dict, e: int) -> jnp.ndarray:
+        gate = jax.nn.silu(mm(h, _at(w["w_gate"], e, layer)).astype(jnp.float32)).astype(h.dtype)
+        return mm(gate * mm(h, _at(w["w_up"], e, layer)), _at(w["w_down"], e, layer)).astype(jnp.float32)
+
+    axis = 0 if layer is None else 1
+    held = jax.tree.leaves(experts["w_gate"])[0].shape[axis]
+    n_shared = jax.tree.leaves(shared["w_gate"])[0].shape[axis]
+    g = jax.lax.dynamic_slice_in_dim(gates, first, held, axis=1)  # [T, held]
+    y = jnp.zeros(h.shape, jnp.float32)
+    for e in range(held):
+        y = y + g[:, e:e + 1] * ffn(experts, e)
+    for e in range(n_shared):
+        y = y + ffn(shared, e) / n_shared
+    return y, g
+
+
+def _at(w: Any, e: int, layer: Any) -> Any:
+    """Expert ``e``'s matrix of a stack [held, ...] — or of layer ``layer``
+    of a stack [L, held, ...] — plain or ``{"q", "s"}`` int8."""
+    if layer is None:
+        return jax.tree.map(lambda a: a[e], w)
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(
+            a.reshape((-1,) + a.shape[2:]), layer * a.shape[1] + e, 0, keepdims=False),
+        w,
+    )

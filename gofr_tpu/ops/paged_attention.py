@@ -63,11 +63,13 @@ def paged_decode_attention_ref(
     scale: float | None = None,
     k_scale: jnp.ndarray | None = None,  # int8 pools: [N, Hkv, page, 1] f32
     v_scale: jnp.ndarray | None = None,
+    window: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
     """Gather-based reference: materializes [B, M*page] K/V. Correctness
     oracle + the CPU path. int8 pools carry per-vector absmax scales
     and dequantize AFTER the gather — only the owned pages widen, never
-    the whole pool."""
+    the whole pool. With ``window`` the query (at position ``seq_len - 1``)
+    sees the last ``window`` positions only."""
     B, H, Dh = q.shape
     Hkv = k_pool.shape[1]
     page = k_pool.shape[2]
@@ -89,7 +91,10 @@ def paged_decode_attention_ref(
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32), k.astype(jnp.float32))
     s = s * scale
     pos = jnp.arange(M * page)[None, :]  # [1, S]
-    s = jnp.where((pos < seq_lens[:, None])[:, None, :], s, NEG_INF)
+    seen = pos < seq_lens[:, None]
+    if window is not None:
+        seen &= pos >= seq_lens[:, None] - window
+    s = jnp.where(seen[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhs,bshd->bhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
@@ -120,6 +125,18 @@ def _pages_per_block(Hkv: int, page: int, Dh: int, itemsize: int,
 def _paged_kernel(
     seq_lens_ref,  # SMEM [B] (scalar prefetch)
     tables_ref,  # SMEM [B, M] (scalar prefetch)
+    *refs,  # windowed: window_ref SMEM [1] (scalar prefetch) first; then as _paged_body
+    windowed: bool,
+    **static,
+):
+    window = refs[0][0] if windowed else None
+    _paged_body(seq_lens_ref, tables_ref, window, *refs[windowed:], **static)
+
+
+def _paged_body(
+    seq_lens_ref,
+    tables_ref,
+    window,  # None, or the scalar: a row sees its last ``window`` positions
     q_ref,  # VMEM [1, Hkv, group, Dh]: this row's queries
     k_hbm,  # HBM [N, Hkv, page, Dh]: the whole pool, never copied whole
     v_hbm,
@@ -134,7 +151,9 @@ def _paged_kernel(
     last block of a row starts the first block of the next row. The loop
     runs ``cdiv(seq_len, ppb * page)`` times: a row costs the pages it
     owns. With ``quantized`` the pages arrive int8 with their per-vector
-    scales and dequantize in VMEM."""
+    scales and dequantize in VMEM. With a ``window`` the loop starts at the
+    block that holds position ``seq_len - window`` and that block masks
+    below it: a row costs the pages its window covers."""
     if quantized:
         ks_hbm, vs_hbm, o_ref, *scratch = rest
         k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref, m_scr, l_scr, acc_scr = scratch
@@ -158,6 +177,13 @@ def _paged_kernel(
     def block_pages(row, blk):
         return jnp.minimum(row_pages(row) - blk * ppb, ppb)
 
+    def first_block(row):
+        if window is None:
+            return 0
+        # never past the row's last block (a row of length 0 is read as 1)
+        lo = jnp.clip(seq_lens_ref[row] - window, 0, (row_pages(row) - 1) * page)
+        return lo // bk
+
     def start_block(row, blk, slot):
         def one(j, _):
             pid = tables_ref[row, blk * ppb + j]
@@ -179,23 +205,24 @@ def _paged_kernel(
     @pl.when(b == 0)
     def _prime():
         slot_ref[0] = 0
-        start_block(0, 0, 0)
+        start_block(0, first_block(0), 0)
 
     seq_len = seq_lens_ref[b]
     nb = pl.cdiv(row_pages(b), ppb)
+    b0 = first_block(b)
     slot0 = slot_ref[0]
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def block(i, _):
-        slot = (slot0 + i) % 2
+        slot = (slot0 + i - b0) % 2
         last = i + 1 == nb
 
         @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < B))
         def _prefetch():
             row = jnp.where(last, jnp.minimum(b + 1, B - 1), b)
-            start_block(row, jnp.where(last, 0, i + 1), 1 - slot)
+            start_block(row, jnp.where(last, first_block(row), i + 1), 1 - slot)
 
         n = block_pages(b, i)
         wait_block(n, slot)
@@ -227,7 +254,10 @@ def _paged_kernel(
                 q.astype(mxu), k.astype(mxu), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # [group, bk]
-            s = jnp.where(k_pos < seq_len, s, NEG_INF)
+            seen = k_pos < seq_len
+            if window is not None:
+                seen = jnp.logical_and(seen, k_pos >= seq_len - window)
+            s = jnp.where(seen, s, NEG_INF)
 
             m_prev = m_scr[h, :, 0:1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -253,8 +283,8 @@ def _paged_kernel(
             m_scr[h, :, 0:1] = m_new
         return _
 
-    jax.lax.fori_loop(0, nb, block, None)
-    slot_ref[0] = (slot0 + nb) % 2
+    jax.lax.fori_loop(b0, nb, block, None)
+    slot_ref[0] = (slot0 + nb - b0) % 2
 
     denom = l_scr[:, :, 0:1]
     denom = jnp.where(denom == 0.0, 1.0, denom)
@@ -271,8 +301,10 @@ def _paged_attention_call(
     interpret: bool,
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
+    window: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Shared pallas_call plumbing for both pool widths."""
+    """Shared pallas_call plumbing for both pool widths. ``window=None``
+    builds the kernel without the argument."""
     B, H, Dh = q.shape
     Hkv, page = k_pool.shape[1], k_pool.shape[2]
     M = block_tables.shape[1]
@@ -282,12 +314,15 @@ def _paged_attention_call(
 
     # [B, Hkv, group, Dh]: a program sees its row's queries by kv head
     q_t = q.reshape(B, Hkv, group, Dh)
+    windowed = window is not None
+    prefetch = [seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32)]
+    if windowed:
+        prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
     kernel = functools.partial(
-        _paged_kernel, scale=scale_v, ppb=ppb, quantized=quantized
+        _paged_kernel, windowed=windowed, scale=scale_v, ppb=ppb,
+        quantized=quantized,
     )
-    row_spec = pl.BlockSpec(
-        (1, Hkv, group, Dh), lambda b, seq_lens, tables: (b, 0, 0, 0)
-    )
+    row_spec = pl.BlockSpec((1, Hkv, group, Dh), lambda b, *_: (b, 0, 0, 0))
     pools = [k_pool, v_pool]
     if quantized:
         # a scale a vector, spread over the vector's lanes: Mosaic cannot
@@ -299,7 +334,7 @@ def _paged_attention_call(
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # seq_lens, block_tables
+        num_scalar_prefetch=len(prefetch),  # seq_lens, block_tables[, window]
         grid=(B,),
         in_specs=[row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=row_spec,
@@ -321,7 +356,7 @@ def _paged_attention_call(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32), q_t, *pools)
+    )(*prefetch, q_t, *pools)
     return out.reshape(B, H, Dh)
 
 
@@ -335,9 +370,11 @@ def paged_decode_attention(
     *,
     scale: float | None = None,
     interpret: bool | None = None,
+    window: jnp.ndarray | None = None,  # scalar int32 (may be traced)
 ) -> jnp.ndarray:
     """Pallas paged decode attention; contract identical to
-    :func:`paged_decode_attention_ref`. Streams only owned pages. In the
+    :func:`paged_decode_attention_ref`. Streams only owned pages — with a
+    ``window``, only those that hold a row's last ``window`` positions. In the
     [N, Hkv, page, Dh] pool layout a page is one contiguous piece for all
     KV heads — one DMA — whose trailing two dims (page, Dh) are whole
     Mosaic tiles."""
@@ -346,10 +383,12 @@ def paged_decode_attention(
     mode = kernel_mode(interpret)
     if mode == REFERENCE:
         return paged_decode_attention_ref(
-            q, k_pool, v_pool, block_tables, seq_lens, scale=scale_v
+            q, k_pool, v_pool, block_tables, seq_lens, scale=scale_v,
+            window=window,
         )
     return _paged_attention_call(
-        q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET
+        q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET,
+        window=window,
     )
 
 

@@ -39,6 +39,7 @@ tier-1 matrix both fail otherwise, by design.
 from __future__ import annotations
 
 import dataclasses
+import sys
 from functools import partial
 from typing import Any
 
@@ -49,17 +50,33 @@ from gofr_tpu.models import llama
 from gofr_tpu.ops.sampling import sample_logits, stop_eval
 
 
+def model_of(cfg: Any) -> Any:
+    """The module that serves ``cfg``: the one its class is defined in
+    (``LlamaConfig`` → ``models/llama.py``, ``Cohere2MoeConfig`` →
+    ``models/cohere2_moe.py``). Every program here reaches its model
+    through this one lookup, at trace time. What a served module holds:
+    ``KVCache`` (the dense cache, also a bucketed prefill's scratch),
+    ``prefill``, ``decode_step_paged`` and ``decode_chunk_paged`` with
+    ``llama``'s arguments, ``step_stats_len(cfg)`` — how many int32
+    counters its paged step returns after the pools (0: none) — and
+    ``unserved(engine_config, lora)``, the sentence that refuses an engine
+    the model has no program for. The dense, int8 and speculative programs
+    call the functions ``llama`` has for them by the same names."""
+    return sys.modules[type(cfg).__module__]
+
+
 @partial(jax.jit, static_argnums=0)
 def prefill_compute(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     tokens: jnp.ndarray,  # [1, S_bucket] right-padded
     seq_len: jnp.ndarray,  # [1]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run prefill WITHOUT a persistent cache: returns (last_logits [1,V],
     k_slab, v_slab [L, S_bucket, Hkv, Dh]) for scatter into a slot."""
-    scratch = llama.KVCache.create(cfg, 1, max_len=tokens.shape[1])
-    last, cache = llama.prefill(cfg, params, tokens, scratch, seq_len)
+    model = model_of(cfg)
+    scratch = model.KVCache.create(cfg, 1, max_len=tokens.shape[1])
+    last, cache = model.prefill(cfg, params, tokens, scratch, seq_len)
     return last, cache.k[:, 0], cache.v[:, 0]
 
 
@@ -227,6 +244,25 @@ def _pack_block(toks: jnp.ndarray, done: jnp.ndarray,
     )
 
 
+def _append_stats(packed: jnp.ndarray, stats: jnp.ndarray) -> jnp.ndarray:
+    """A model's block counters (``step_stats_len`` of them, summed over
+    the block's steps) ride the same host read: laid out, zero-padded, in
+    whole rows below the B rows of ``packed``. A model without counters
+    leaves ``packed`` as it is. :func:`block_stats` is the host's inverse."""
+    n, width = stats.shape[0], packed.shape[1]
+    if n == 0:
+        return packed
+    rows = -(-n // width)
+    tail = jnp.zeros(rows * width, jnp.int32).at[:n].set(stats)
+    return jnp.concatenate([packed, tail.reshape(rows, width)], axis=0)
+
+
+def block_stats(packed: Any, n_rows: int, n: int) -> Any:
+    """The counters :func:`_append_stats` laid below the ``n_rows`` rows of
+    a block's packed result (host side: ``packed`` is the numpy copy)."""
+    return packed[n_rows:].reshape(-1)[:n]
+
+
 def _lora_delta(
     embedding: jnp.ndarray,  # [V, D] — the model's token embedding table
     a_tab: jnp.ndarray,      # [n_adapters, D, r]
@@ -302,7 +338,7 @@ def _block_step(st: DecodeState, active, logits, params=None, lora=None):
 
 @partial(jax.jit, static_argnums=(0, 5), donate_argnums=(2, 3))
 def decode_block(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     cache: llama.KVCache,  # donated (bf16 or int8 dense)
     state: DecodeState,  # donated
@@ -327,7 +363,7 @@ def decode_block(
         cache, st = carry
         live = active & ~st.done
         step_len = jnp.where(live, st.seq_len + 1, oob)
-        logits, cache = llama.decode_step(
+        logits, cache = model_of(cfg).decode_step(
             cfg, params, st.last_token, cache, step_len
         )
         st, out = _block_step(st, active, logits, params, lora)
@@ -339,9 +375,37 @@ def decode_block(
     return _pack_block(jnp.transpose(toks), state.done, active), cache, state
 
 
+def _paged_steps(cfg, params, k_pool, v_pool, state, block_tables, active,
+                 steps, lora):
+    """The N-step decode scan over the bf16 page pool that
+    :func:`decode_block_paged` and :func:`ragged_step_paged` share: each
+    step is the model's ``decode_step_paged`` and the shared sampling
+    tail. Returns (tokens [B, steps], k_pool, v_pool, state, the model's
+    counters summed over the steps — length 0 for a model without)."""
+    model = model_of(cfg)
+
+    def step(carry, _):
+        kp, vp, st, stats = carry
+        live = active & ~st.done
+        step_len = jnp.where(live, st.seq_len + 1, 1)
+        logits, kp, vp, *counted = model.decode_step_paged(
+            cfg, params, st.last_token, kp, vp, block_tables, step_len, live
+        )
+        if counted:  # the model's counters for this step, after the pools
+            stats = stats + counted[0]
+        st, out = _block_step(st, active, logits, params, lora)
+        return (kp, vp, st, stats), out
+
+    stats = jnp.zeros(model.step_stats_len(cfg), jnp.int32)
+    (k_pool, v_pool, state, stats), toks = jax.lax.scan(
+        step, (k_pool, v_pool, state, stats), None, length=steps
+    )
+    return jnp.transpose(toks), k_pool, v_pool, state, stats
+
+
 @partial(jax.jit, static_argnums=(0, 7), donate_argnums=(2, 3, 4))
 def decode_block_paged(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     k_pool: jnp.ndarray,  # [L, N_pages+1, Hkv, page, Dh] donated (+1: trash)
     v_pool: jnp.ndarray,  # donated
@@ -355,26 +419,16 @@ def decode_block_paged(
     the trash page (llama.decode_step_paged's ``active`` redirect), so a
     mid-block stop never writes a live page."""
 
-    def step(carry, _):
-        kp, vp, st = carry
-        live = active & ~st.done
-        step_len = jnp.where(live, st.seq_len + 1, 1)
-        logits, kp, vp = llama.decode_step_paged(
-            cfg, params, st.last_token, kp, vp, block_tables, step_len, live
-        )
-        st, out = _block_step(st, active, logits, params, lora)
-        return (kp, vp, st), out
-
-    (k_pool, v_pool, state), toks = jax.lax.scan(
-        step, (k_pool, v_pool, state), None, length=steps
+    toks, k_pool, v_pool, state, stats = _paged_steps(
+        cfg, params, k_pool, v_pool, state, block_tables, active, steps, lora
     )
-    packed = _pack_block(jnp.transpose(toks), state.done, active)
+    packed = _append_stats(_pack_block(toks, state.done, active), stats)
     return packed, k_pool, v_pool, state
 
 
 @partial(jax.jit, static_argnums=(0, 9), donate_argnums=(2, 3, 4, 5, 6))
 def decode_block_paged_q(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     k_pool: jnp.ndarray,  # int8, donated
     v_pool: jnp.ndarray,
@@ -393,7 +447,7 @@ def decode_block_paged_q(
         kp, vp, ksp, vsp, st = carry
         live = active & ~st.done
         step_len = jnp.where(live, st.seq_len + 1, 1)
-        logits, kp, vp, ksp, vsp = llama.decode_step_paged_q(
+        logits, kp, vp, ksp, vsp = model_of(cfg).decode_step_paged_q(
             cfg, params, st.last_token, kp, vp, ksp, vsp, block_tables,
             step_len, live,
         )
@@ -494,7 +548,7 @@ def _pack_ragged(toks: jnp.ndarray, done: jnp.ndarray, active: jnp.ndarray,
 
 @partial(jax.jit, static_argnums=(0, 16), donate_argnums=(2, 3))
 def ragged_step(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     cache: llama.KVCache,      # donated (bf16 or int8 dense)
     state: DecodeState,        # donated
@@ -522,7 +576,7 @@ def ragged_step(
     [B, steps+3] — see :func:`_pack_ragged` — last_logits [B, V], cache,
     state); ``last_logits`` stays on device unless the engine retains it
     for the chunk-prefix cache."""
-    logits_c, cache = llama.decode_chunk.__wrapped__(
+    logits_c, cache = model_of(cfg).decode_chunk.__wrapped__(
         cfg, params, chunk, cache, chunk_start
     )
     state, first, last_logits = _fold_finished_prefill(
@@ -538,7 +592,7 @@ def ragged_step(
         cache, st = carry
         live = decode_active & ~st.done
         step_len = jnp.where(live, st.seq_len + 1, oob)
-        logits, cache = llama.decode_step(
+        logits, cache = model_of(cfg).decode_step(
             cfg, params, st.last_token, cache, step_len
         )
         st, out = _block_step(st, decode_active, logits, params, lora)
@@ -555,7 +609,7 @@ def ragged_step(
 
 @partial(jax.jit, static_argnums=(0, 20), donate_argnums=(2, 3, 4))
 def ragged_step_paged(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     k_pool: jnp.ndarray,       # donated
     v_pool: jnp.ndarray,       # donated
@@ -582,7 +636,7 @@ def ragged_step_paged(
     """Paged twin of :func:`ragged_step`: chunk writes route through the
     block tables (inactive rows and beyond-capacity positions divert to
     the trash page), decode appends likewise."""
-    logits_c, k_pool, v_pool = llama.decode_chunk_paged.__wrapped__(
+    logits_c, k_pool, v_pool = model_of(cfg).decode_chunk_paged.__wrapped__(
         cfg, params, chunk, k_pool, v_pool, block_tables, chunk_start,
         chunk_active, kv_capacity,
     )
@@ -590,29 +644,19 @@ def ragged_step_paged(
         state, logits_c, chunk, chunk_start, finish, new_len, budgets,
         stops, temps, topks, topps, rids, rng_root, adapters, params, lora,
     )
-
-    def step(carry, _):
-        kp, vp, st = carry
-        live = decode_active & ~st.done
-        step_len = jnp.where(live, st.seq_len + 1, 1)
-        logits, kp, vp = llama.decode_step_paged(
-            cfg, params, st.last_token, kp, vp, block_tables, step_len, live
-        )
-        st, out = _block_step(st, decode_active, logits, params, lora)
-        return (kp, vp, st), out
-
-    (k_pool, v_pool, state), toks = jax.lax.scan(
-        step, (k_pool, v_pool, state), None, length=steps
+    toks, k_pool, v_pool, state, stats = _paged_steps(
+        cfg, params, k_pool, v_pool, state, block_tables, decode_active,
+        steps, lora,
     )
-    packed = _pack_ragged(
-        jnp.transpose(toks), state.done, decode_active, first
+    packed = _append_stats(
+        _pack_ragged(toks, state.done, decode_active, first), stats
     )
     return packed, last_logits, k_pool, v_pool, state
 
 
 @partial(jax.jit, static_argnums=(0, 22), donate_argnums=(2, 3, 4, 5, 6))
 def ragged_step_paged_q(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     k_pool: jnp.ndarray,       # int8, donated
     v_pool: jnp.ndarray,
@@ -641,7 +685,7 @@ def ragged_step_paged_q(
            jnp.ndarray, DecodeState]:
     """int8 twin of :func:`ragged_step_paged`."""
     logits_c, k_pool, v_pool, ks_pool, vs_pool = (
-        llama.decode_chunk_paged_q.__wrapped__(
+        model_of(cfg).decode_chunk_paged_q.__wrapped__(
             cfg, params, chunk, k_pool, v_pool, ks_pool, vs_pool,
             block_tables, chunk_start, chunk_active, kv_capacity,
         )
@@ -655,7 +699,7 @@ def ragged_step_paged_q(
         kp, vp, ksp, vsp, st = carry
         live = decode_active & ~st.done
         step_len = jnp.where(live, st.seq_len + 1, 1)
-        logits, kp, vp, ksp, vsp = llama.decode_step_paged_q(
+        logits, kp, vp, ksp, vsp = model_of(cfg).decode_step_paged_q(
             cfg, params, st.last_token, kp, vp, ksp, vsp, block_tables,
             step_len, live,
         )
@@ -740,7 +784,7 @@ def _accept_and_bonus(
 
 @partial(jax.jit, static_argnums=0, donate_argnums=(2,))
 def verify_and_sample(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     cache: llama.KVCache,  # donated (bf16 or int8 dense)
     chunk: jnp.ndarray,  # [B, T]
@@ -753,7 +797,7 @@ def verify_and_sample(
     """Speculative engine step, dense cache: chunk-verify forward + draft
     acceptance + bonus sampling in ONE dispatch. Returns
     (tokens [B, T], n_accept [B], cache, rng)."""
-    logits, cache = llama.decode_chunk.__wrapped__(
+    logits, cache = model_of(cfg).decode_chunk.__wrapped__(
         cfg, params, chunk, cache, start_len
     )
     out, n_accept, rng = _accept_and_bonus(
@@ -767,7 +811,7 @@ def verify_and_sample(
 
 @partial(jax.jit, static_argnums=0, donate_argnums=(2, 3))
 def verify_and_sample_paged(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     k_pool: jnp.ndarray,  # donated
     v_pool: jnp.ndarray,  # donated
@@ -782,7 +826,7 @@ def verify_and_sample_paged(
     rng: jax.Array,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jax.Array]:
     """Paged twin of :func:`verify_and_sample`."""
-    logits, k_pool, v_pool = llama.decode_chunk_paged.__wrapped__(
+    logits, k_pool, v_pool = model_of(cfg).decode_chunk_paged.__wrapped__(
         cfg, params, chunk, k_pool, v_pool, block_tables, start_len,
         active, kv_capacity,
     )
@@ -797,7 +841,7 @@ def verify_and_sample_paged(
 
 @partial(jax.jit, static_argnums=0, donate_argnums=(2, 3, 4, 5))
 def verify_and_sample_paged_q(
-    cfg: llama.LlamaConfig,
+    cfg: Any,  # a served model's config (model_of)
     params: dict,
     k_pool: jnp.ndarray,  # int8, donated
     v_pool: jnp.ndarray,
@@ -816,7 +860,7 @@ def verify_and_sample_paged_q(
            jnp.ndarray, jax.Array]:
     """int8-paged twin of :func:`verify_and_sample`."""
     logits, k_pool, v_pool, ks_pool, vs_pool = (
-        llama.decode_chunk_paged_q.__wrapped__(
+        model_of(cfg).decode_chunk_paged_q.__wrapped__(
             cfg, params, chunk, k_pool, v_pool, ks_pool, vs_pool,
             block_tables, start_len, active, kv_capacity,
         )

@@ -459,7 +459,7 @@ class ServingEngine:
 
     def __init__(
         self,
-        cfg: llama.LlamaConfig,
+        cfg: Any,  # a served model's config: LlamaConfig, Cohere2MoeConfig
         params: dict,
         engine_config: EngineConfig | None = None,
         tokenizer: Tokenizer | None = None,
@@ -558,6 +558,15 @@ class ServingEngine:
             raise ValueError(
                 f"TPU_KV_DTYPE={self.config.kv_dtype!r}: must be bf16 or int8"
             )
+        # the model's module (batch_ops.model_of) says what it has no
+        # program for yet, in a sentence, before anything is built
+        model = batch_ops.model_of(cfg)
+        refusal = model.unserved(self.config, lora)
+        if refusal:
+            raise ValueError(refusal)
+        # int32 counters the model's paged step adds to a block's packed
+        # result (cohere2_moe: rows per held expert); 0 for most
+        self._stats_len = model.step_stats_len(cfg)
         if self.config.spec_tokens < 0:
             raise ValueError("TPU_SPEC_TOKENS must be >= 0")
         if (self.config.multi_step is not None and self.config.multi_step > 1
@@ -721,7 +730,7 @@ class ServingEngine:
     @classmethod
     def from_checkpoint(
         cls,
-        cfg: llama.LlamaConfig,
+        cfg: Any,
         checkpoint_dir: str,
         *,
         step: int | None = None,
@@ -736,14 +745,15 @@ class ServingEngine:
         from gofr_tpu.checkpoint import CheckpointError, CheckpointManager
 
         mgr = CheckpointManager(checkpoint_dir)
-        abstract = jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+        abstract = jax.eval_shape(
+            lambda: batch_ops.model_of(cfg).init_params(cfg, jax.random.PRNGKey(0)))
         if mgr.latest_step() is None:  # raises on a corrupt manifest
             if seed_key is None:
                 raise CheckpointError(
                     f"no committed checkpoints in {checkpoint_dir} "
                     "(pass seed_key for random-init fallback)"
                 )
-            params = llama.init_params(cfg, seed_key)
+            params = batch_ops.model_of(cfg).init_params(cfg, seed_key)
         else:
             # corruption in an EXISTING checkpoint propagates: silently
             # serving random weights would be worse than failing startup
@@ -3659,6 +3669,9 @@ class ServingEngine:
                  rows=len(rows), steps=N, kv_tokens=int(self.cache_len[mask].sum()),
                  chunk_rows=len(chunk_rows), chunk_tokens=chunk_tokens,
                  cold=int(cold))
+        window = getattr(cfg, "sliding_window", None)
+        if window:  # rows whose window layers no longer see their first key
+            span.set(win_rows=int((self.cache_len[mask] > window).sum()))
         self._count_step_tokens(
             len(rows) * N, chunk_tokens,
             self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),
@@ -3667,6 +3680,19 @@ class ServingEngine:
             packed, rows, t0, steps=N, blk=self._blk_seq,
             prefill_rows=prefill_rows, last_logits=keep_logits,
         )
+
+    def _count_expert_rows(self, span: _StepPhase, rows: Any) -> None:
+        """A block's expert counters, read with its tokens: row-expert
+        pairs the held experts took over the block's decode steps and
+        layers (``moe_rows``), the fullest expert's (``moe_max``), and
+        app_moe_expert_rows_total by the expert's published index."""
+        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()))
+        if self._metrics:
+            first = self.model_cfg.first_expert
+            for e, n in enumerate(rows.tolist()):
+                if n:
+                    self._metrics.add_counter(
+                        "app_moe_expert_rows_total", n, expert=str(first + e))
 
     def _count_step_tokens(self, decode: int, prefill: int, issued: int) -> None:
         """app_step_tokens_total at the point of issue: of the positions a
@@ -3915,6 +3941,9 @@ class ServingEngine:
                     self._commit_first_token(slot, req, first_id)
 
             span.set(tokens=tokens, retired=self._retires - retires)
+            if self._stats_len:
+                self._count_expert_rows(span, batch_ops.block_stats(
+                    packed, self.config.max_slots, self._stats_len))
 
         if self._metrics and n_active:
             self._metrics.record_histogram(
@@ -4323,7 +4352,7 @@ class ServingEngine:
         """The one dense slot-cache constructor, shared by __init__ and
         donation-failure recovery so the rebuilt cache can never drift
         from the one the engine started with."""
-        return llama.KVCache.create(
+        return batch_ops.model_of(self.model_cfg).KVCache.create(
             self.model_cfg, self.config.max_slots,
             max_len=self.config.max_seq_len,
             kv_dtype="int8" if self.config.kv_dtype == "int8" else None,

@@ -511,7 +511,7 @@ def test_new_jitted_kernel_without_contract_flagged(tmp_path):
             " 'block_q', 'block_k', 'interpret'))\n"
             "def flash_attention(q, k, v, kv_len=None, *, causal=True,\n"
             "                    scale=None, block_q=128, block_k=128,\n"
-            "                    interpret=None):\n"
+            "                    interpret=None, window=None):\n"
             "    return q\n"
             "\n"
             "@jax.jit\n"
@@ -596,7 +596,7 @@ def test_matching_kernel_file_clean(tmp_path):
             " 'block_q', 'block_k', 'interpret'))\n"
             "def flash_attention(q, k, v, kv_len=None, *, causal=True,\n"
             "                    scale=None, block_q=128, block_k=128,\n"
-            "                    interpret=None):\n"
+            "                    interpret=None, window=None):\n"
             "    return q\n"
         ),
     }, rules=[KernelContractCoverageRule(anchor=None)])

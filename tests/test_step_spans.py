@@ -58,6 +58,16 @@ def drive(engine, open_prompt):
     return [first.result(timeout=120), second.result(timeout=120)], [first.request_id, second.request_id]
 
 
+def settles_in(engine, phase, timeout=30.0):
+    """Bounded poll: the loop thread reaches ``phase`` (and stays there
+    for an idle engine) within ``timeout`` seconds, however many workers
+    share the host."""
+    deadline = time.monotonic() + timeout
+    while engine._phase_state[0] != phase and time.monotonic() < deadline:
+        time.sleep(0.01)
+    return engine._phase_state[0] == phase
+
+
 def read_spans(trace_dir):
     """The trace's engine spans by start, without the iteration the trace's
     end cut: a span still open when the session stops is not written, so
@@ -309,8 +319,7 @@ def test_a_retired_thread_unwinds_without_touching_the_account(model, monkeypatc
         with pytest.raises(Exception):
             doomed.result(timeout=30)
         assert engine.submit("fresh", max_new_tokens=3).result(timeout=120).completion_tokens >= 1
-        time.sleep(0.15)
-        assert engine._phase_state[0] == "wait"  # the replacement idles in its own wait
+        assert settles_in(engine, "wait")  # the replacement idles in its own wait
         before = dict(engine._phase_s)
         hold.set()
         old.join(timeout=60)
@@ -318,7 +327,7 @@ def test_a_retired_thread_unwinds_without_touching_the_account(model, monkeypatc
         after = dict(engine._phase_s)
         # the old thread left dispatch and step behind it: neither was charged by its unwind
         assert after["dispatch"] == before["dispatch"] and after["step"] == before["step"]
-        assert engine._phase_state[0] == "wait"
+        assert settles_in(engine, "wait")
         assert engine.submit("again", max_new_tokens=3).result(timeout=120).completion_tokens >= 1
     finally:
         hold.set()
