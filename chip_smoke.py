@@ -79,6 +79,8 @@ def check_kernels(self_test: bool) -> None:
         paged_decode_attention,
         paged_decode_attention_q,
         paged_decode_attention_ref,
+        paged_kv_append,
+        paged_kv_append_ref,
     )
 
     interpret = self_test
@@ -157,8 +159,33 @@ def check_kernels(self_test: bool) -> None:
                     qd, kq, vq, tables, seq_lens, k_scale=ks, v_scale=vs
                 )
             else:
+                # as the decode step calls the two bf16 kernels: the whole
+                # pools (two layers; the other holds NaN, so a page read
+                # from the wrong layer shows) and a traced layer index
+                layer = jnp.int32(1)
+                kw = jnp.stack([jnp.full_like(kf, jnp.nan), kf])
+                vw = jnp.stack([jnp.full_like(vf, jnp.nan), vf])
+                # the append: every row writes the slot of its last position
+                # (an empty slot the trash page N - 1, as an inactive row)
+                pos = seq_lens - 1
+                live = seq_lens > 1
+                pages = jnp.where(live, tables[jnp.arange(pB), pos // page], N - 1)
+                offsets = jnp.where(live, pos % page, 0)
+                k_new = jax.random.normal(keys[1], (pB, pHkv, D), dtype)
+                v_new = jax.random.normal(keys[2], (pB, pHkv, D), dtype)
+                want = paged_kv_append_ref(kw, vw, k_new, v_new, layer, pages, offsets)
+                kw, vw = paged_kv_append(kw, vw, k_new, v_new, layer, pages, offsets,
+                                         interpret=interpret)
+                # bit for bit on every page but the trash page (garbage by contract)
+                same = all(bool(jnp.array_equal(got[1, :N - 1], exp[1, :N - 1]))
+                           and bool(jnp.all(jnp.isnan(got[0])))
+                           for got, exp in zip((kw, vw), want))
+                say(f"kernel paged_kv_append bf16 [{label}] Hkv={pHkv} B={pB} page={page}: "
+                    f"{'equal to' if same else 'DIFFERS from'} .at[].set")
+                check(same, f"paged_kv_append [{label}] page={page} differs from its reference")
+                kf, vf = kw[1], vw[1]  # the references read the appended layer
                 out = paged_decode_attention(
-                    qd, kf, vf, tables, seq_lens, interpret=interpret
+                    qd, kw, vw, tables, seq_lens, interpret=interpret, layer=layer
                 )
                 ref = paged_decode_attention_ref(qd, kf, vf, tables, seq_lens)
                 # the same pools through the kernel with a window: one that
@@ -166,8 +193,8 @@ def check_kernels(self_test: bool) -> None:
                 # page, and one wider than every row (a full-attention layer)
                 for window in (pS // 4 + 5, 1 << 30):
                     w = jnp.int32(window)
-                    e = err(paged_decode_attention(qd, kf, vf, tables, seq_lens,
-                                                   interpret=interpret, window=w),
+                    e = err(paged_decode_attention(qd, kw, vw, tables, seq_lens,
+                                                   interpret=interpret, window=w, layer=layer),
                             paged_decode_attention_ref(qd, kf, vf, tables, seq_lens, window=w))
                     say(f"kernel paged_decode_attention bf16 window={window} [{label}] "
                         f"H={pH}/{pHkv} B={pB} M={M} page={page}: max|err|={e:.4g} (tol {tol})")

@@ -379,19 +379,36 @@ KERNELS: tuple[KernelContract, ...] = (
         ),
     ),
     # The two entries of the one paged decode kernel: a program a row, the
-    # pools left in HBM (PER-LAYER ranks: [N_pages, Hkv, page, Dh], scales
-    # [..., 1]), pages fetched by the kernel's own DMAs in a loop whose
-    # trip count is the row's length. Tile sizes are worked out inside
-    # from these shapes, so neither entry has a parameter for them.
+    # pools left in HBM, pages fetched by the kernel's own DMAs in a loop
+    # whose trip count is the row's length. Tile sizes are worked out
+    # inside from these shapes, so neither entry has a parameter for them.
+    # POOL RANKS: the bf16 entry with ``layer`` takes the WHOLE pools
+    # [L, N_pages, Hkv, page, Dh] and reads that layer's pages (what the
+    # decode step passes: XLA never slices a pool); with ``layer=None``,
+    # and always in the int8 entry, the pools are ONE layer's
+    # [N_pages, Hkv, page, Dh] (int8 scales [..., 1]).
     KernelContract(
         "paged_decode_attention", _PAGED_ATTN,
         # window: None builds the kernel without the argument; a scalar
         # (traced: a scanned layer's own) starts a row's loop at the block
-        # that holds seq_len - window
+        # that holds seq_len - window. layer: None, or the scalar (traced:
+        # the layer scan's index) that the page DMAs address
         params=("q", "k_pool", "v_pool", "block_tables", "seq_lens",
-                "scale", "interpret", "window"),
+                "scale", "interpret", "window", "layer"),
         static=("scale", "interpret"),
         returns=(Ret("out", like="q"),),
+    ),
+    # The decode step's append: one token's K/V a row into
+    # [layer, pages[b], :, offsets[b]] of the whole pools, by a Pallas call
+    # aliased over both (a read-modify-write of the page a row writes; no
+    # two live rows share a page). Not donated here — the serving programs
+    # that inline it donate the pools, and the aliasing makes it in place.
+    KernelContract(
+        "paged_kv_append", _PAGED_ATTN,
+        params=("k_pool", "v_pool", "k_new", "v_new", "layer", "pages",
+                "offsets", "interpret"),
+        static=("interpret",),
+        returns=(Ret("k_pool", like="k_pool"), Ret("v_pool", like="v_pool")),
     ),
     KernelContract(
         "paged_decode_attention_q", _PAGED_ATTN,

@@ -299,22 +299,41 @@ def run_matrix() -> dict:
         },
     ))
 
-    # ops-level attention sees ONE layer's pool: [N+1, Hkv, page, Dh]
+    # ops-level attention sees ONE layer's pool ([N+1, Hkv, page, Dh]) or,
+    # given a layer index, the whole pools as the decode step passes them
     lp = sds((N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)
     lp8 = sds((N_PAGES + 1, Hkv, PAGE, Dh), jnp.int8)
     lps = sds((N_PAGES + 1, Hkv, PAGE, 1), jnp.float32)
+    kp, vp, _, _ = pools()
+    scalar = sds((), jnp.int32)
     # the window is None (the kernel without the argument) or a scalar
-    for variant, window in (("paged", None), ("paged.window", sds((), jnp.int32))):
+    for variant, pool_k, pool_v, window, layer in (
+        ("paged", lp, lp, None, None),
+        ("paged.window", lp, lp, scalar, None),
+        ("paged.layer", kp, vp, None, scalar),
+        ("paged.layer.window", kp, vp, scalar, scalar),
+    ):
         cases.append(_eval_case(
             pa_mod.paged_decode_attention.__wrapped__,
             C["paged_decode_attention"], variant,
             {
                 "q": sds((3, cfg.n_heads, Dh), cfg.dtype),
-                "k_pool": lp, "v_pool": lp,
+                "k_pool": pool_k, "v_pool": pool_v,
                 "block_tables": sds((3, M), jnp.int32), "seq_lens": vec(3),
                 "scale": None, "interpret": True, "window": window,
+                "layer": layer,
             },
         ))
+    cases.append(_eval_case(
+        pa_mod.paged_kv_append.__wrapped__, C["paged_kv_append"], "paged",
+        {
+            "k_pool": kp, "v_pool": vp,
+            "k_new": sds((3, Hkv, Dh), cfg.dtype),
+            "v_new": sds((3, Hkv, Dh), cfg.dtype),
+            "layer": scalar, "pages": vec(3), "offsets": vec(3),
+            "interpret": True,
+        },
+    ))
     cases.append(_eval_case(
         pa_mod.paged_decode_attention_q.__wrapped__,
         C["paged_decode_attention_q"], "paged.q",

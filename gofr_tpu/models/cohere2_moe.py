@@ -54,7 +54,7 @@ from gofr_tpu.ops.attention import attention
 from gofr_tpu.ops.flash_attention import flash_attention
 from gofr_tpu.ops.moe import held_experts, sigmoid_topk_gates
 from gofr_tpu.ops.norms import layer_norm
-from gofr_tpu.ops.paged_attention import paged_decode_attention
+from gofr_tpu.ops.paged_attention import paged_decode_attention, paged_kv_append
 from gofr_tpu.ops.rope import apply_rope_interleaved, rope_angles
 
 __all__ = [
@@ -279,7 +279,8 @@ def decode_step_paged(
     active: jnp.ndarray,  # [B] bool — inactive rows write the trash page
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step over the paged pool, as ``llama.decode_step_paged``
-    (same arguments, same trash-page redirect), and after the pools the
+    (same arguments, same trash-page redirect, the pools carried whole
+    through the layers and touched by the two kernels alone), and after the pools the
     step's counters: rows of ``active`` each held expert took, summed over
     the layers (:func:`step_stats_len`)."""
     B = tokens.shape[0]
@@ -294,19 +295,21 @@ def decode_step_paged(
     offsets = jnp.where(active, pos % page, 0)
     layers, stacks = _scanned(params)
 
-    def body(x, xs):
-        lp, kc, vc, layer, sliding = xs  # kc/vc: [N_pages, Hkv, page, Dh]
+    def body(carry, xs):
+        x, kp, vp = carry  # kp/vp: the whole pools, written by the append alone
+        lp, layer, sliding = xs
         h = layer_norm(x, lp["norm"], None, cfg.norm_eps)
         q, k, v, window = _qkv(cfg, h, lp, sliding, sin, cos)
-        kc = kc.at[pages, :, offsets].set(k[:, 0])
-        vc = vc.at[pages, :, offsets].set(v[:, 0])
-        # Mosaic kernel on a TPU, gather reference on the CPU
-        attn = paged_decode_attention(q[:, 0], kc, vc, block_tables, seq_lens, window=window)
+        # Mosaic kernels on a TPU, scatter and gather references on the CPU
+        kp, vp = paged_kv_append(kp, vp, k[:, 0], v[:, 0], layer, pages, offsets)
+        attn = paged_decode_attention(
+            q[:, 0], kp, vp, block_tables, seq_lens, window=window, layer=layer
+        )
         x, rows = _mix(cfg, x, h, attn.reshape(B, 1, H, Dh), lp, stacks, layer, active[:, None])
-        return x, (kc, vc, rows)
+        return (x, kp, vp), rows
 
-    x, (k_pool, v_pool, rows) = jax.lax.scan(
-        body, x, (layers, k_pool, v_pool, jnp.arange(cfg.n_layers), _sliding(cfg))
+    (x, k_pool, v_pool), rows = jax.lax.scan(
+        body, (x, k_pool, v_pool), (layers, jnp.arange(cfg.n_layers), _sliding(cfg))
     )
     return _logits(cfg, params, x)[:, 0], k_pool, v_pool, jnp.sum(rows, axis=0)
 

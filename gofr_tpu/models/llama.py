@@ -34,6 +34,7 @@ from gofr_tpu.ops.norms import rms_norm
 from gofr_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_q,
+    paged_kv_append,
 )
 from gofr_tpu.ops.rope import apply_rope, rope_table
 
@@ -574,8 +575,16 @@ def decode_step_paged(
     appends this step's K/V into each active row's current page slot and
     attends through the block tables (ops/paged_attention.py). Inactive
     rows write into the pool's LAST page (the trash page the cache manager
-    reserves) so the scatter never collides with a live page, and their
-    attention output is garbage the host ignores."""
+    reserves) so the append never touches a live page, and their
+    attention output is garbage the host ignores.
+
+    The pools ride the layer scan as CARRY and are written only by the
+    append kernel aliased over them (``paged_kv_append``) and read whole by
+    the attention kernel, each given the layer's index: XLA never slices,
+    scatters into or copies a pool. Any XLA op that writes one token into
+    a pool makes layout assignment swap the pool's KV-head and page axes,
+    and the pool is then transposed on entry, around every kernel call and
+    on exit — more than half of a decode step (PERF.md §6, PR 30)."""
     B = tokens.shape[0]
     page = k_pool.shape[3]
     trash_page = k_pool.shape[1] - 1  # reserved by PagedKVCache
@@ -585,11 +594,13 @@ def decode_step_paged(
     positions = pos[:, None]
     sin, cos = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
     b_idx = jnp.arange(B)
+    # each active row's decode position is a (page, offset) of its own
     pages = jnp.where(active, block_tables[b_idx, pos // page], trash_page)  # [B]
     offsets = jnp.where(active, pos % page, 0)
 
-    def body(h, xs):
-        lp, kc, vc = xs  # kc/vc: [N_pages, Hkv, page, Dh]
+    def body(carry, xs):
+        h, kp, vp = carry  # kp/vp: the whole pools
+        lp, layer = xs
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         q = _mm(hn, lp["wq"]).reshape(B, 1, H, Dh)
         k = _mm(hn, lp["wk"]).reshape(B, 1, Hkv, Dh)
@@ -598,23 +609,19 @@ def decode_step_paged(
         k = apply_rope(k, positions, sin, cos)[:, 0]  # [B, Hkv, Dh]
         v = v[:, 0]
 
-        # append: inactive rows were redirected to the trash page, so the
-        # scatter is conflict-free across rows (each active row's decode
-        # position is a distinct (page, offset)).
-        # kc.at[pages, :, offsets] (advanced idx split by a slice) -> [B, Hkv, Dh]
-        kc = kc.at[pages, :, offsets].set(k)
-        vc = vc.at[pages, :, offsets].set(v)
-
-        # Mosaic kernel on a TPU, gather reference on the CPU
-        attn = paged_decode_attention(q, kc, vc, block_tables, seq_lens)
+        # Mosaic kernels on a TPU, scatter and gather references on the CPU
+        kp, vp = paged_kv_append(kp, vp, k, v, layer, pages, offsets)
+        attn = paged_decode_attention(q, kp, vp, block_tables, seq_lens, layer=layer)
 
         h = h + _mm(attn.reshape(B, 1, H * Dh), lp["wo"])
         hn = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
         gate = jax.nn.silu(_mm(hn, lp["w_gate"]).astype(jnp.float32)).astype(hn.dtype)
         h = h + _mm(gate * _mm(hn, lp["w_up"]), lp["w_down"])
-        return h, (kc, vc)
+        return (h, kp, vp), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(body, x, (params["layers"], k_pool, v_pool))
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, k_pool, v_pool), (params["layers"], jnp.arange(cfg.n_layers))
+    )
     logits = _logits(cfg, params, x)[:, 0]  # [B, V]
     return logits, k_pool, v_pool
 

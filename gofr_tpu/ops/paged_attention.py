@@ -1,14 +1,16 @@
-"""Paged (block-table) decode attention for TPU.
+"""Paged (block-table) decode attention for TPU, and the decode step's
+append into the same pools.
 
 The decode-side companion of ops/flash_attention.py: K/V live in a pooled
-page table (``[N_pages, Hkv, page_size, Dh]``) shared by every sequence in
-the server, and each sequence addresses its pages through an int32 block
+page table (``[L, N_pages, Hkv, page_size, Dh]``, a layer's slice
+``[N_pages, Hkv, page_size, Dh]``) shared by every sequence in the
+server, and each sequence addresses its pages through an int32 block
 table — the vLLM/ragged-paged-attention layout (SURVEY §5.7 lever (a),
 PAPERS.md: ragged paged attention kernel for TPU). This is what lets the
 continuous-batching engine admit by *token* budget instead of reserving
 max_seq_len rows per slot.
 
-Two implementations with one contract:
+Reading, two implementations with one contract:
 - ``paged_decode_attention_ref`` — pure-XLA gather reference: the oracle
   the kernels are tested against, and what both entries compute on the
   CPU unless a test passes ``interpret=True`` (``ops/backend.py``);
@@ -25,13 +27,26 @@ Two implementations with one contract:
   costs one page. ``pages_per_block`` is worked out from the shapes the
   call sees (:func:`_pages_per_block`). int8 pages arrive with their
   per-vector absmax scales (two more copies a page) and dequantize in
-  VMEM.
+  VMEM. The kernel addresses ``pool[layer, page id]``: the bf16 entry
+  given a ``layer`` takes the WHOLE pools, as the decode step passes
+  them; without one (and in the int8 entry) the pools are one layer's.
+
+Writing: ``paged_kv_append`` (reference ``paged_kv_append_ref``, the
+scatter it replaced) puts one decode step's K/V of every row into the
+whole pools by a Pallas call aliased over them. Within a decode program
+the pools are written by that call alone and read by the kernel above
+alone, both by layer index, so XLA has no op on a pool and assigns it no
+layout of its own: any XLA write of one token into ``[..., Hkv, page, Dh]``
+makes layout assignment swap the KV-head and page axes, and the pool is
+then transposed on entry, around every kernel call and on exit (PERF.md
+§6, PR 30: more than half of a decode step).
 
 The jitted entries are declared in the kernel contract table
-(``gofr_tpu/analysis/kernel_contracts.KERNELS``; note the PER-LAYER
-pool ranks there — [N_pages, Hkv, page, Dh], no leading L) and
-replayed by the kerneltrace eval_shape matrix; a signature or rank
-change must update the table in the same commit.
+(``gofr_tpu/analysis/kernel_contracts.KERNELS``; note the pool ranks
+there) and replayed by the kerneltrace eval_shape matrix; a signature
+or rank change must update the table in the same commit. The benchmark
+finds the attention kernel's device events by the jitted wrapper's name,
+``paged_decode_attention``: no other entry's name may begin with it.
 """
 
 from __future__ import annotations
@@ -64,24 +79,26 @@ def paged_decode_attention_ref(
     k_scale: jnp.ndarray | None = None,  # int8 pools: [N, Hkv, page, 1] f32
     v_scale: jnp.ndarray | None = None,
     window: jnp.ndarray | int | None = None,
+    layer: jnp.ndarray | int | None = None,  # pools are [L, N_pages, ...]
 ) -> jnp.ndarray:
     """Gather-based reference: materializes [B, M*page] K/V. Correctness
     oracle + the CPU path. int8 pools carry per-vector absmax scales
     and dequantize AFTER the gather — only the owned pages widen, never
     the whole pool. With ``window`` the query (at position ``seq_len - 1``)
-    sees the last ``window`` positions only."""
+    sees the last ``window`` positions only. With ``layer`` the pools are
+    whole ([L, N_pages, Hkv, page, Dh]) and that layer's pages are read."""
     B, H, Dh = q.shape
-    Hkv = k_pool.shape[1]
-    page = k_pool.shape[2]
+    Hkv, page = k_pool.shape[-3], k_pool.shape[-2]
     M = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(Dh)
+    owned = block_tables if layer is None else (layer, block_tables)
 
     # [B, M, Hkv, page, Dh] -> [B, M*page, Hkv, Dh]
-    k = k_pool[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
-    v = v_pool[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
+    k = k_pool[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
+    v = v_pool[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
     if k_scale is not None:
-        ks = k_scale[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, 1)
-        vs = v_scale[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, 1)
+        ks = k_scale[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, 1)
+        vs = v_scale[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, 1)
         k = k.astype(jnp.float32) * ks
         v = v.astype(jnp.float32) * vs
     group = H // Hkv
@@ -125,20 +142,22 @@ def _pages_per_block(Hkv: int, page: int, Dh: int, itemsize: int,
 def _paged_kernel(
     seq_lens_ref,  # SMEM [B] (scalar prefetch)
     tables_ref,  # SMEM [B, M] (scalar prefetch)
+    layer_ref,  # SMEM [1] (scalar prefetch): the layer of the pools to read
     *refs,  # windowed: window_ref SMEM [1] (scalar prefetch) first; then as _paged_body
     windowed: bool,
     **static,
 ):
     window = refs[0][0] if windowed else None
-    _paged_body(seq_lens_ref, tables_ref, window, *refs[windowed:], **static)
+    _paged_body(seq_lens_ref, tables_ref, layer_ref[0], window, *refs[windowed:], **static)
 
 
 def _paged_body(
     seq_lens_ref,
     tables_ref,
+    layer,  # the scalar: pages are read from this layer of the pools
     window,  # None, or the scalar: a row sees its last ``window`` positions
     q_ref,  # VMEM [1, Hkv, group, Dh]: this row's queries
-    k_hbm,  # HBM [N, Hkv, page, Dh]: the whole pool, never copied whole
+    k_hbm,  # HBM [L, N, Hkv, page, Dh]: the whole pool, never copied or sliced
     v_hbm,
     *rest,  # quantized: ks_hbm, vs_hbm first; then o_ref and the scratches
     scale: float,
@@ -162,7 +181,7 @@ def _paged_body(
         o_ref, *scratch = rest
         k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr = scratch
         streams = ((k_hbm, k_buf), (v_hbm, v_buf))
-    Hkv, page, Dh = k_hbm.shape[1:]
+    Hkv, page, Dh = k_hbm.shape[2:]
     group = q_ref.shape[2]
     bk = ppb * page
     b = pl.program_id(0)
@@ -188,7 +207,7 @@ def _paged_body(
         def one(j, _):
             pid = tables_ref[row, blk * ppb + j]
             for hbm, buf in streams:
-                pltpu.make_async_copy(hbm.at[pid], buf.at[slot, j], sem.at[slot]).start()
+                pltpu.make_async_copy(hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
             return _
         jax.lax.fori_loop(0, block_pages(row, blk), one, None)
 
@@ -198,7 +217,7 @@ def _paged_body(
         # about that stream
         def one(j, _):
             for hbm, buf in streams:
-                pltpu.make_async_copy(hbm.at[0], buf.at[slot, j], sem.at[slot]).wait()
+                pltpu.make_async_copy(hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
             return _
         jax.lax.fori_loop(0, n, one, None)
 
@@ -302,11 +321,14 @@ def _paged_attention_call(
     k_scale: jnp.ndarray | None = None,
     v_scale: jnp.ndarray | None = None,
     window: jnp.ndarray | None = None,
+    layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Shared pallas_call plumbing for both pool widths. ``window=None``
-    builds the kernel without the argument."""
+    builds the kernel without the argument. The kernel sees whole pools
+    and a layer index: with ``layer=None`` the pools are one layer's, given
+    a leading axis of one here (a bitcast)."""
     B, H, Dh = q.shape
-    Hkv, page = k_pool.shape[1], k_pool.shape[2]
+    Hkv, page = k_pool.shape[-3], k_pool.shape[-2]
     M = block_tables.shape[1]
     group = H // Hkv
     quantized = k_scale is not None
@@ -315,7 +337,18 @@ def _paged_attention_call(
     # [B, Hkv, group, Dh]: a program sees its row's queries by kv head
     q_t = q.reshape(B, Hkv, group, Dh)
     windowed = window is not None
-    prefetch = [seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32)]
+    pools = [k_pool, v_pool]
+    if quantized:
+        # a scale a vector, spread over the vector's lanes: Mosaic cannot
+        # slice an HBM ref whose minor dim is narrower than a lane row, and
+        # XLA already pads a [..., 1] operand of a custom call to that size
+        pools += [jnp.broadcast_to(s, k_pool.shape) for s in (k_scale, v_scale)]
+    if layer is None:
+        pools, layer = [pool[None] for pool in pools], 0
+    prefetch = [
+        seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+    ]
     if windowed:
         prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
     kernel = functools.partial(
@@ -323,18 +356,12 @@ def _paged_attention_call(
         quantized=quantized,
     )
     row_spec = pl.BlockSpec((1, Hkv, group, Dh), lambda b, *_: (b, 0, 0, 0))
-    pools = [k_pool, v_pool]
-    if quantized:
-        # a scale a vector, spread over the vector's lanes: Mosaic cannot
-        # slice an HBM ref whose minor dim is narrower than a lane row, and
-        # XLA already pads a [..., 1] operand of a custom call to that size
-        pools += [jnp.broadcast_to(s, k_pool.shape) for s in (k_scale, v_scale)]
     page_buffers = [
-        pltpu.VMEM((2, ppb) + pool.shape[1:], pool.dtype) for pool in pools
+        pltpu.VMEM((2, ppb) + pool.shape[2:], pool.dtype) for pool in pools
     ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),  # seq_lens, block_tables[, window]
+        num_scalar_prefetch=len(prefetch),  # seq_lens, block_tables, layer[, window]
         grid=(B,),
         in_specs=[row_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=row_spec,
@@ -363,7 +390,7 @@ def _paged_attention_call(
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, H, Dh]
-    k_pool: jnp.ndarray,  # [N_pages, Hkv, page, Dh]
+    k_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, Dh] with ``layer``, else one layer's
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,  # [B, M] int32
     seq_lens: jnp.ndarray,  # [B]
@@ -371,24 +398,27 @@ def paged_decode_attention(
     scale: float | None = None,
     interpret: bool | None = None,
     window: jnp.ndarray | None = None,  # scalar int32 (may be traced)
+    layer: jnp.ndarray | None = None,  # scalar int32 (may be traced)
 ) -> jnp.ndarray:
     """Pallas paged decode attention; contract identical to
     :func:`paged_decode_attention_ref`. Streams only owned pages — with a
     ``window``, only those that hold a row's last ``window`` positions. In the
-    [N, Hkv, page, Dh] pool layout a page is one contiguous piece for all
-    KV heads — one DMA — whose trailing two dims (page, Dh) are whole
-    Mosaic tiles."""
+    [..., N, Hkv, page, Dh] pool layout a page is one contiguous piece for
+    all KV heads — one DMA — whose trailing two dims (page, Dh) are whole
+    Mosaic tiles. With ``layer`` the pools are whole and stay in HBM as
+    they are: the page DMAs address ``[layer, page id]``, so a decode step
+    hands every layer the same two buffers and XLA slices nothing."""
     Dh = q.shape[-1]
     scale_v = scale if scale is not None else 1.0 / math.sqrt(Dh)
     mode = kernel_mode(interpret)
     if mode == REFERENCE:
         return paged_decode_attention_ref(
             q, k_pool, v_pool, block_tables, seq_lens, scale=scale_v,
-            window=window,
+            window=window, layer=layer,
         )
     return _paged_attention_call(
         q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET,
-        window=window,
+        window=window, layer=layer,
     )
 
 
@@ -409,7 +439,10 @@ def paged_decode_attention_q(
     dequantizing in VMEM). A compiled call with pages below the int8
     Mosaic tile (:data:`INT8_MIN_PAGE` sublanes) is an error — the
     gather reference it used to drop to inverts the bandwidth win int8
-    exists for (ServingEngine validates the page size up front)."""
+    exists for (ServingEngine validates the page size up front). The
+    pools are ONE layer's: XLA widens the ``[..., 1]`` scales to the
+    pool's shape before the call, which nobody wants done to a whole
+    pool a layer (R10)."""
     Dh = q.shape[-1]
     page = k_pool.shape[2]
     scale_v = scale if scale is not None else 1.0 / math.sqrt(Dh)
@@ -428,3 +461,153 @@ def paged_decode_attention_q(
         q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET,
         k_scale=k_scale, v_scale=v_scale,
     )
+
+
+# ------------------------------------------------------------- the append
+# VMEM the append's page buffers may hold (K and V of every row of a
+# chunk): at the served shapes (32 rows x 32 KiB, 6 x 128 KiB, 64 x 32 KiB
+# a pool) one chunk is the whole batch
+_APPEND_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+def paged_kv_append_ref(
+    k_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, Dh]
+    v_pool: jnp.ndarray,
+    k_new: jnp.ndarray,  # [B, Hkv, Dh] this step's K of every row
+    v_new: jnp.ndarray,
+    layer: jnp.ndarray | int,
+    pages: jnp.ndarray,  # [B] page id a row writes (inactive rows: the trash page)
+    offsets: jnp.ndarray,  # [B] slot in that page
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The append as an XLA scatter: the oracle, and the CPU path.
+    ``pool.at[layer, pages, :, offsets]`` (advanced indices split by a
+    slice) addresses [B, Hkv, Dh]."""
+    return (
+        k_pool.at[layer, pages, :, offsets].set(k_new.astype(k_pool.dtype)),
+        v_pool.at[layer, pages, :, offsets].set(v_new.astype(v_pool.dtype)),
+    )
+
+
+def _append_kernel(
+    layer_ref,  # SMEM [1] (scalar prefetch)
+    pages_ref,  # SMEM [B] (scalar prefetch)
+    offsets_ref,  # SMEM [B] (scalar prefetch)
+    k_new_ref,  # VMEM [R, Hkv, Dh]: this chunk's rows
+    v_new_ref,
+    k_in,  # HBM [L, N, Hkv, page, Dh]: the pools, aliased to the outputs
+    v_in,
+    k_out,  # the same two buffers
+    v_out,
+    k_buf,  # VMEM [R, Hkv, page, Dh]
+    v_buf,
+    sem,  # DMA (2,): reads, writes
+):
+    """One program a chunk of ``R`` rows. A DMA cannot write one token's
+    row into a page (a slice of 1 along the tiled page axis), so a row's
+    append is a read-modify-write of the page it writes: every row's page
+    is fetched (all reads in flight at once, one wait), slot ``offset`` is
+    replaced in VMEM under an iota mask, and the pages go back the same
+    way. Sound because NO TWO LIVE ROWS OWN THE SAME PAGE IN A STEP: the
+    allocator hands a page to one sequence, and the prefix cache copies
+    shared slabs into owned pages (serving/kv_cache.py) — only the trash
+    page is written by several rows, and its content is garbage by
+    contract."""
+    R, Hkv, page, Dh = k_buf.shape
+    c = pl.program_id(0)
+    rows = jnp.minimum(R, pages_ref.shape[0] - c * R)
+    layer = layer_ref[0]
+    streams = ((k_in, k_out, k_buf, k_new_ref), (v_in, v_out, v_buf, v_new_ref))
+
+    def each_row(fn):
+        def one(r, _):
+            fn(r, c * R + r)  # the row's place in the chunk, and in the batch
+            return _
+        jax.lax.fori_loop(0, rows, one, None)
+
+    def read(r, row):
+        for src, _, buf, _ in streams:
+            pltpu.make_async_copy(src.at[layer, pages_ref[row]], buf.at[r], sem.at[0]).start()
+
+    def read_done(r, row):
+        for src, _, buf, _ in streams:
+            pltpu.make_async_copy(src.at[0, 0], buf.at[r], sem.at[0]).wait()
+
+    def patch(r, row):
+        hit = jax.lax.broadcasted_iota(jnp.int32, (page, Dh), 0) == offsets_ref[row]
+        for _, _, buf, new_ref in streams:
+            for h in range(Hkv):
+                new = jnp.broadcast_to(new_ref[r, pl.ds(h, 1), :], (page, Dh))
+                buf[r, h] = jnp.where(hit, new, buf[r, h])
+
+    def write(r, row):
+        for _, dst, buf, _ in streams:
+            pltpu.make_async_copy(buf.at[r], dst.at[layer, pages_ref[row]], sem.at[1]).start()
+
+    def write_done(r, row):
+        for _, dst, buf, _ in streams:
+            pltpu.make_async_copy(buf.at[r], dst.at[0, 0], sem.at[1]).wait()
+
+    for phase in (read, read_done, patch, write, write_done):
+        each_row(phase)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_kv_append(
+    k_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, Dh]
+    v_pool: jnp.ndarray,
+    k_new: jnp.ndarray,  # [B, Hkv, Dh]
+    v_new: jnp.ndarray,
+    layer: jnp.ndarray,  # scalar int32 (may be traced)
+    pages: jnp.ndarray,  # [B] int32
+    offsets: jnp.ndarray,  # [B] int32
+    *,
+    interpret: bool | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One decode step's K/V of every row into ``[layer, pages[b], :,
+    offsets[b]]`` of the pools, by a Pallas call aliased over both whole
+    pools; contract identical to :func:`paged_kv_append_ref`, given that
+    rows that share a page (the trash page) leave garbage in it. Inside a
+    program that donates the pools this writes in place, and — the reason
+    it is a kernel — leaves XLA no op that writes into a pool: an XLA
+    scatter or dynamic-update-slice of one token makes layout assignment
+    swap the pool's KV-head and page axes, and the pool is then transposed
+    around every call of the attention kernel."""
+    mode = kernel_mode(interpret)
+    if mode == REFERENCE:
+        return paged_kv_append_ref(k_pool, v_pool, k_new, v_new, layer, pages, offsets)
+    B = k_new.shape[0]
+    Hkv, page, Dh = k_pool.shape[2:]
+    page_bytes = Hkv * page * Dh * k_pool.dtype.itemsize
+    R = max(1, min(B, _APPEND_VMEM_BUDGET // (2 * page_bytes)))
+    row_spec = pl.BlockSpec((R, Hkv, Dh), lambda c, *_: (c, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # layer, pages, offsets
+        grid=(pl.cdiv(B, R),),
+        in_specs=[row_spec, row_spec, hbm, hbm],
+        out_specs=[hbm, hbm],
+        scratch_shapes=[
+            pltpu.VMEM((R, Hkv, page, Dh), k_pool.dtype),
+            pltpu.VMEM((R, Hkv, page, Dh), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    k_pool, v_pool = pl.pallas_call(
+        _append_kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+        ],
+        # operands count the scalar prefetches: 3 scalars, 2 new rows, pools
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=mode == INTERPRET,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), pages.astype(jnp.int32),
+        offsets.astype(jnp.int32), k_new.astype(k_pool.dtype),
+        v_new.astype(v_pool.dtype), k_pool, v_pool,
+    )
+    return k_pool, v_pool

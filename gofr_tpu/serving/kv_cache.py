@@ -9,8 +9,16 @@ actually resident, not by worst-case slots. SURVEY §5.7 lever (a).
 
 Host side (this class): page accounting, block tables, seq lens.
 Device side: scatter prefilled slabs into owned pages (_write_pages); the
-decode-step append lives inside llama.decode_step_paged (per layer), and
-the read path is ops/paged_attention.py.
+decode-step append is ops/paged_attention.paged_kv_append — a Pallas call
+aliased over the whole pools, called per layer by the model's
+decode_step_paged, which rewrites the page a row writes and so relies on
+NO TWO LIVE ROWS OWNING THE SAME PAGE IN A STEP (the allocator gives a
+page to one sequence; a prefix-cache hit copies slabs into owned pages,
+below; the allocator's copy-on-write fork is used by no serving path, and
+one that forks must copy a shared last page before a step writes it; only
+the trash page is shared) — and the read path is
+paged_decode_attention in the same module, given the whole pools and a
+layer index. Inside a decode program XLA neither slices nor writes a pool.
 
 shardcheck retrace/donation zone: the pool buffers are donated through
 every _write_pages*/decode dispatch and MUST be rebound in the same
@@ -127,8 +135,9 @@ class PagedKVCache:
         [L, N+1, Hkv, page, Dh]: trailing (page, Dh) are full dims in the
         pallas BlockSpecs (ops/paged_attention.py) — Mosaic tiling rule.
         The extra LAST page is the trash page: inactive rows' decode
-        appends are redirected there (llama.decode_step_paged), so the
-        scatter never has conflicting writes to a live page."""
+        appends are redirected there (the model's decode_step_paged), so
+        the append never writes a live page for a row that does not own
+        it."""
         cfg = self.cfg
         shape = (
             cfg.n_layers, self.num_pages + 1, cfg.n_kv_heads,

@@ -1,0 +1,313 @@
+"""The decode step's append and the layer-indexed paged kernel: the pools
+ride the layer scan as carry and are touched only by the two Pallas calls
+(``ops/paged_attention.paged_kv_append`` aliased over both pools,
+``paged_decode_attention`` given the whole pools and a layer index).
+
+On the CPU: the append's kernel (``interpret=True``) against the scatter it
+replaced, and whole decode blocks of both served families through the
+kernels against the references and the dense path. For the chip: the
+compiled ``decode_block_paged`` holds no XLA op that makes, slices or
+updates a pool — what keeps a later edit from bringing the transposes
+around the kernel back (PERF.md §6, PR 30).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from gofr_tpu.models import cohere2_moe as cm
+from gofr_tpu.models import llama
+from gofr_tpu.ops import paged_attention as pa
+from gofr_tpu.serving import batch as batch_ops
+
+PAGE = 4
+STEPS = 4
+SLOT_PAGES = 4  # a row's table: 16 positions
+
+
+# ------------------------------------------------------ the append alone
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("rows", [1, 5, 11], ids=["one-row", "one-chunk", "chunks-with-a-ragged-last"])
+def test_the_append_kernel_writes_what_the_scatter_wrote(rows, dtype, monkeypatch):
+    """Every live page bit for bit as ``pool.at[layer, pages, :, offsets]
+    .set``; rows sent to the trash page (the last) leave garbage there and
+    nowhere else; the other layers are untouched."""
+    L, N, Hkv, Dh = 3, 14, 2, 16
+    if rows == 11:  # four rows a chunk: three programs, the last with three rows
+        monkeypatch.setattr(pa, "_APPEND_VMEM_BUDGET", 4 * 2 * Hkv * PAGE * Dh * jnp.dtype(dtype).itemsize)
+    keys = jax.random.split(jax.random.PRNGKey(rows), 4)
+    k_pool = jax.random.normal(keys[0], (L, N, Hkv, PAGE, Dh), dtype)
+    v_pool = jax.random.normal(keys[1], (L, N, Hkv, PAGE, Dh), dtype)
+    k_new = jax.random.normal(keys[2], (rows, Hkv, Dh), dtype)
+    v_new = jax.random.normal(keys[3], (rows, Hkv, Dh), dtype)
+    rng = np.random.default_rng(rows)
+    pages = rng.permutation(N - 1)[:rows].astype(np.int32)
+    pages[1::4] = N - 1  # inactive rows: all on the trash page
+    offsets = rng.integers(0, PAGE, rows).astype(np.int32)
+    offsets[0] = PAGE - 1
+    args = (k_new, v_new, jnp.int32(1), jnp.asarray(pages), jnp.asarray(offsets))
+    got = pa.paged_kv_append(k_pool, v_pool, *args, interpret=True)
+    want = pa.paged_kv_append_ref(k_pool, v_pool, *args)
+    for g, w, before in zip(got, want, (k_pool, v_pool)):
+        g, w, before = (np.asarray(a.astype(jnp.float32)) for a in (g, w, before))
+        assert (g[:, :N - 1] == w[:, :N - 1]).all()
+        assert (g[0] == before[0]).all() and (g[2] == before[2]).all()
+        assert not (g[1] == before[1]).all()
+
+
+def test_on_the_cpu_the_append_is_the_scatter():
+    pool = jnp.zeros((2, 3, 1, PAGE, 8), jnp.float32)
+    new = jnp.ones((1, 1, 8), jnp.float32)
+    k, v = pa.paged_kv_append(pool, pool, new, 2 * new, 1, jnp.asarray([2]), jnp.asarray([3]))
+    assert float(k.sum()) == 8 and float(v.sum()) == 16 and (np.asarray(k[1, 2, 0, 3]) == 1).all()
+
+
+@pytest.mark.parametrize("window", [None, 6], ids=["full", "window"])
+def test_the_kernel_reads_the_layer_it_is_given(window):
+    """The whole pools and a traced layer index against the reference on
+    that layer's slice; the other layers hold NaN."""
+    L, B, H, Hkv, Dh = 3, 3, 4, 2, 16
+    N = B * SLOT_PAGES + 1
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (B, H, Dh), jnp.float32)
+    layer_k = jax.random.normal(keys[1], (N, Hkv, PAGE, Dh), jnp.float32)
+    layer_v = jax.random.normal(keys[2], (N, Hkv, PAGE, Dh), jnp.float32)
+    nan = jnp.full_like(layer_k, jnp.nan)
+    k_pool, v_pool = jnp.stack([nan, nan, layer_k]), jnp.stack([nan, nan, layer_v])
+    tables = jnp.asarray(np.random.default_rng(2).permutation(N - 1).reshape(B, SLOT_PAGES), jnp.int32)
+    seq_lens = jnp.asarray([1, 9, 16], jnp.int32)
+    kw = {} if window is None else {"window": jnp.int32(window)}
+    want = pa.paged_decode_attention_ref(q, layer_k, layer_v, tables, seq_lens, **kw)
+    by_index = jax.jit(lambda layer: pa.paged_decode_attention(
+        q, k_pool, v_pool, tables, seq_lens, interpret=True, layer=layer, **kw))(jnp.int32(2))
+    np.testing.assert_allclose(np.asarray(by_index), np.asarray(want), rtol=2e-5, atol=2e-5)
+    ref_by_index = pa.paged_decode_attention_ref(q, k_pool, v_pool, tables, seq_lens, layer=2, **kw)
+    assert (np.asarray(ref_by_index) == np.asarray(want)).all()
+
+
+# --------------------------------------- whole decode blocks, both families
+FAMILIES = {
+    "llama": (llama, llama.LlamaConfig.tiny(vocab_size=300)),
+    # two periods of three window layers (8 positions) and a full one
+    "cohere2_moe": (cm, cm.Cohere2MoeConfig.tiny(vocab_size=300)),
+}
+# (resident prompt length, active, budget) a row. Row 0 always starts with 6
+# resident positions, so its four steps write positions 6..9: slot PAGE - 1
+# of its second page, then slot 0 of its third, and past cohere2's window
+ROWS = {
+    "a stopped and an inactive row": ((6, True, 9), (3, True, 2), (5, False, 9)),
+    "one row live": ((6, True, 9), (3, False, 9), (5, False, 9)),
+    "all rows live": ((6, True, 9), (3, True, 9), (7, True, 9)),
+}
+MODES = ("scatter", "append-kernel", "both-kernels")
+
+
+def _prompt(row: int, n: int) -> np.ndarray:
+    return np.random.default_rng(10 + row).integers(3, 259, n).astype(np.int32)
+
+
+@functools.lru_cache(maxsize=None)
+def _params(family: str):
+    model, cfg = FAMILIES[family]
+    return model.init_params(cfg, jax.random.PRNGKey(7))
+
+
+def _run_block(family: str, rows: tuple, mode: str, monkeypatch):
+    """Prefill each row's prompt into pages of its own, then one
+    ``decode_block_paged`` of four steps. ``mode`` says what the model's
+    step calls: the CPU references (the scatter this PR replaced and the
+    gather), the append's kernel under the interpreter with the gather, or
+    both kernels. Each mode runs under a config of its own (a static
+    argument of every jitted program), so none reuses another's trace."""
+    model, cfg = FAMILIES[family]
+    params = _params(family)
+    cfg = dataclasses.replace(cfg, max_seq_len=cfg.max_seq_len + 1 + MODES.index(mode))
+    if mode != "scatter":
+        monkeypatch.setattr(model, "paged_kv_append", functools.partial(pa.paged_kv_append, interpret=True))
+    if mode == "both-kernels":
+        monkeypatch.setattr(model, "paged_decode_attention",
+                            functools.partial(pa.paged_decode_attention, interpret=True))
+    B = len(rows)
+    n_pages = B * SLOT_PAGES
+    shape = (cfg.n_layers, n_pages + 1, cfg.n_kv_heads, PAGE, cfg.head_dim)
+    # pages nobody wrote hold noise, not zeros: a page fetched from the
+    # wrong place, or written where it should not be, shows
+    k_pool = jax.random.normal(jax.random.PRNGKey(1), shape, cfg.dtype)
+    v_pool = jax.random.normal(jax.random.PRNGKey(2), shape, cfg.dtype)
+    tables = np.random.default_rng(1).permutation(n_pages).reshape(B, SLOT_PAGES).astype(np.int32)
+    first = []
+    for b, (n, _, _) in enumerate(rows):
+        tokens = np.zeros((1, 8), np.int32)
+        tokens[0, :n] = _prompt(b, n)
+        last, k_slab, v_slab = batch_ops.prefill_compute(cfg, params, jnp.asarray(tokens), jnp.asarray([n]))
+        for t in range(n):
+            k_pool = k_pool.at[:, tables[b, t // PAGE], :, t % PAGE].set(k_slab[:, t])
+            v_pool = v_pool.at[:, tables[b, t // PAGE], :, t % PAGE].set(v_slab[:, t])
+        first.append(int(jnp.argmax(last[0])))
+    zeros = np.zeros(B, np.int32)
+    state = batch_ops.make_decode_state(
+        first, [n for n, _, _ in rows], np.zeros(B, bool), [budget for _, _, budget in rows],
+        zeros - 1, np.zeros(B, np.float32), zeros, np.ones(B, np.float32), jax.random.PRNGKey(0),
+    )
+    before = (np.asarray(k_pool), np.asarray(v_pool))
+    active = jnp.asarray([a for _, a, _ in rows])
+    packed, k_pool, v_pool, _ = batch_ops.decode_block_paged(
+        cfg, params, k_pool, v_pool, state, jnp.asarray(tables), active, STEPS)
+    return np.asarray(packed)[:B, :STEPS], (np.asarray(k_pool), np.asarray(v_pool)), before, tables, first
+
+
+@pytest.fixture(scope="module")
+def scatter_runs():
+    return {}
+
+
+@pytest.mark.parametrize("mode", MODES[1:])
+@pytest.mark.parametrize("rows", list(ROWS), ids=[r.replace(" ", "-") for r in ROWS])
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_block_through_the_kernels_is_the_block_through_the_scatter(family, rows, mode, scatter_runs, monkeypatch):
+    key = (family, rows)
+    if key not in scatter_runs:
+        scatter_runs[key] = _run_block(family, ROWS[rows], "scatter", monkeypatch)
+    want_tokens, want_pools, before, tables, _ = scatter_runs[key]
+    tokens, pools, _, _, _ = _run_block(family, ROWS[rows], mode, monkeypatch)
+    assert (tokens == want_tokens).all()
+    for got, want, was in zip(pools, want_pools, before):
+        live, trash = slice(0, got.shape[1] - 1), got.shape[1] - 1
+        if mode == "append-kernel":  # the same arithmetic around it: bit for bit
+            assert (got[:, live] == want[:, live]).all()
+        else:
+            np.testing.assert_allclose(got[:, live], want[:, live], rtol=1e-5, atol=1e-5)
+        # written: the positions the live rows decoded, and nothing else
+        changed = np.argwhere((got[0, live] != was[0, live]).any(axis=(1, 3)))  # (page, slot)
+        expect = set()
+        for b, (n, active, budget) in enumerate(ROWS[rows]):
+            for pos in range(n, n + min(STEPS, budget) if active else n):
+                expect.add((int(tables[b, pos // PAGE]), pos % PAGE))
+        assert {(int(p), int(s)) for p, s in changed} == expect
+        if len(expect) < STEPS * len(ROWS[rows]):  # some row was redirected
+            assert (got[:, trash] != was[:, trash]).any()
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_block_s_tokens_are_the_dense_path_s(family, monkeypatch):
+    """Row 0's four tokens through both kernels, against the model's
+    ``prefill`` over prompt and tokens with a dense cache: greedy at every
+    position (for cohere2_moe the last two lie past the window)."""
+    model, cfg = FAMILIES[family]
+    tokens, _, _, _, first = _run_block(family, ROWS["all rows live"], "both-kernels", monkeypatch)
+    served = [first[0]] + [int(t) for t in tokens[0]]
+    ids = np.concatenate([_prompt(0, 6), served[:-1]]).astype(np.int32)
+    for n in range(6, len(ids) + 1):
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :n] = ids[:n]
+        cache = model.KVCache.create(cfg, 1, max_len=16)
+        last, _ = model.prefill(cfg, _params(family), jnp.asarray(padded), cache, jnp.asarray([n]))
+        assert int(jnp.argmax(last[0])) == served[n - 6]
+
+
+# ------------------------------------------------ compiled for the chip
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip (no chip attached): the TPU compiler is
+    installed here and compiles for it. Only inside this fixture, never at
+    import: a process keeps libtpu once it has loaded it."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu from describing one
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# tile-legal and narrow: Dh 128, page 16, two layers (cohere2_moe: a window
+# layer and a full one). The pools are the shapes of 32 slots x 1024
+# positions (32 MiB each; shapes only, nothing is allocated): a pool of a
+# few hundred KiB the compiler prefetches whole into fast memory, with
+# copies of its own
+CHIP_CONFIGS = {
+    "llama": llama.LlamaConfig(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=2, n_kv_heads=2, d_ff=512,
+        max_seq_len=131, dtype=jnp.bfloat16),
+    "cohere2_moe": cm.Cohere2MoeConfig.tiny(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=128,
+        d_ff=256, layer_types=(cm.SLIDING, cm.FULL), sliding_window=32, max_seq_len=131,
+        dtype=jnp.bfloat16),
+}
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+# ops that hand a buffer on as it is
+_PASSES_ON = {"parameter", "get-tuple-element", "tuple", "while", "bitcast", "custom-call",
+              "conditional", "call", "opt-barrier"}
+
+
+@pytest.mark.parametrize("family", list(CHIP_CONFIGS))
+def test_the_compiled_decode_block_leaves_the_pools_to_the_kernels(family, one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` by the chip's compiler: no copy, fusion,
+    slice, update or scatter whose result has the shape of a pool or of a
+    layer's slice of one — the pools enter, go round both scans and leave
+    as the buffers they came in, written by the append's custom call alone
+    — and the program's temporaries stay under one pool."""
+    from gofr_tpu.ops.backend import COMPILED
+
+    # the model's steps ask the backend which mode to take, and see the CPU
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    cfg = CHIP_CONFIGS[family]
+    model = batch_ops.model_of(cfg)
+    B, page, M = 32, 16, 64
+    N = B * M
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = on_chip(jax.eval_shape(lambda k: model.init_params(cfg, k), key))
+    pool_shape = (cfg.n_layers, N + 1, cfg.n_kv_heads, page, cfg.head_dim)
+    pool = jax.ShapeDtypeStruct(pool_shape, cfg.dtype, sharding=one_chip)
+    state = batch_ops.DecodeState(vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
+                                  vec(i32), vec(f32), on_chip(key), vec(i32))
+    # conftest.py asks for float32 products everywhere; the served program
+    # does not, and Mosaic takes bf16 operands at the default precision only
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.decode_block_paged.lower(
+            cfg, params, pool, pool, state, vec(i32, B, M), vec(jnp.bool_), STEPS).compile()
+
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2  # the append and the attention kernel
+    dims = ",".join(str(d) for d in pool_shape)
+    pool_like = {f"bf16[{dims}]", f"bf16[{dims.split(',', 1)[1]}]", f"bf16[1,{dims.split(',', 1)[1]}]"}
+    made = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON and any(shape in m.group(2) for shape in pool_like):
+            made.append(f"{m.group(1)} = {m.group(3)}")
+    assert not made, f"XLA ops that make, slice or update a pool: {made}"
+    appends = [line for line in text.splitlines() if "custom-call(" in line and f"bf16[{dims}]" in line.split("custom-call(")[0]]
+    assert appends and all("paged_kv_append" in line for line in appends)
+    pool_bytes = 2 * int(np.prod(pool_shape))
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
