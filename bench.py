@@ -82,11 +82,9 @@ def _acquire_backend() -> str:
 # phase 1: raw batched decode (headline)
 # --------------------------------------------------------------------------
 def _bench_decode(cfg: Any, params: Any, batch: int, prompt_len: int,
-                  decode_steps: int, kv_dtype: str | None = None) -> dict:
+                  decode_steps: int) -> dict:
     """Timed batched decode: prefill once, then one fused dispatch per
-    token, a single device_get sync at the end. ``kv_dtype="int8"``
-    exercises the quantized KV cache (half the dominant decode HBM
-    stream, double the resident KV capacity — models/llama.py KVCache)."""
+    token, a single device_get sync at the end."""
     import jax
     import jax.numpy as jnp
 
@@ -96,7 +94,7 @@ def _bench_decode(cfg: Any, params: Any, batch: int, prompt_len: int,
     cache_len_max = prompt_len + decode_steps + 8
     tokens = jax.random.randint(key, (batch, prompt_len), 0, cfg.vocab_size)
     seq_lens = jnp.full((batch,), prompt_len, jnp.int32)
-    cache = llama.KVCache.create(cfg, batch, max_len=cache_len_max, kv_dtype=kv_dtype)
+    cache = llama.KVCache.create(cfg, batch, max_len=cache_len_max)
 
     t0 = time.perf_counter()
     last, cache = llama.prefill(cfg, params, tokens, cache, seq_lens)
@@ -132,10 +130,8 @@ def _bench_decode(cfg: Any, params: Any, batch: int, prompt_len: int,
             continue
         weight_bytes += int(leaf.size) * leaf.dtype.itemsize
     mean_len = prompt_len + decode_steps / 2
-    kv_elem = 1 if kv_dtype == "int8" else 2
-    kv_bytes = 2 * cfg.n_layers * batch * mean_len * cfg.n_kv_heads * (
-        cfg.head_dim * kv_elem + (4 if kv_dtype == "int8" else 0)  # + f32 scales
-    )
+    # K and V, two bytes an element
+    kv_bytes = 2 * cfg.n_layers * batch * mean_len * cfg.n_kv_heads * cfg.head_dim * 2
     eff_gbps = (weight_bytes + n_embed_bytes + kv_bytes) / step_s / 1e9
 
     del cache
@@ -145,7 +141,6 @@ def _bench_decode(cfg: Any, params: Any, batch: int, prompt_len: int,
         "prefill_warm_s": round(prefill_warm_s, 2),
         "batch": batch,
         "decode_steps": decode_steps,
-        "kv_dtype": kv_dtype or "bf16",
     }
     device = jax.devices()[0]
     if device.platform == "tpu":  # a bandwidth share is a device number
@@ -313,10 +308,6 @@ def _engine_sustained(cfg: Any, params: Any, on_tpu: bool) -> tuple[dict, Any]:
             multi_step=(1 if int(os.environ.get("BENCH_SPEC_TOKENS", "0"))
                         else int(os.environ.get("BENCH_MULTI_STEP", "4"))),
             spec_tokens=int(os.environ.get("BENCH_SPEC_TOKENS", "0")),
-            # mirror the headline's KV policy (int8 on TPU by default)
-            kv_dtype=os.environ.get(
-                "BENCH_KV_DTYPE", "int8" if on_tpu else "bf16"
-            ),
         ),
         ByteTokenizer(cfg.vocab_size),
         metrics=_engine_metrics(),
@@ -1434,10 +1425,8 @@ def _run_benchmarks(platform: str, wall_start: float) -> bool:
     if model_kind == "8b-int8":
         cfg = llama.LlamaConfig(max_seq_len=2048, dtype=jnp.bfloat16)
         quantize = True
-        # int8 KV halves the per-step cache stream, and the freed HBM
-        # lets batch double (128 → 256) so the 8.56 GB weight stream
-        # amortizes over twice the tokens per step
-        batch, prompt_len, decode_steps = 256, 128, 64
+        # 128 rows: their bf16 KV (3.4 GB) beside the 8.56 GB of weights
+        batch, prompt_len, decode_steps = 128, 128, 64
     elif model_kind == "1b-bf16":
         cfg = llama.LlamaConfig(
             vocab_size=32128, d_model=2048, n_layers=16, n_heads=16,
@@ -1450,11 +1439,6 @@ def _run_benchmarks(platform: str, wall_start: float) -> bool:
         quantize = True  # exercise the same W8 code path as the headline
         batch, prompt_len, decode_steps = 4, 8, 4
 
-    kv_dtype = os.environ.get("BENCH_KV_DTYPE") or (
-        "int8" if model_kind == "8b-int8" else None
-    )
-    if kv_dtype == "bf16":
-        kv_dtype = None
     batch = int(os.environ.get("BENCH_BATCH", batch))
 
     # the headline phase is fail-safed like every other phase: an OOM
@@ -1466,8 +1450,7 @@ def _run_benchmarks(platform: str, wall_start: float) -> bool:
         params = jax.device_put(
             llama.init_params(cfg, jax.random.PRNGKey(0), quantize=quantize)
         )
-        stats = _bench_decode(cfg, params, batch, prompt_len, decode_steps,
-                              kv_dtype=kv_dtype)
+        stats = _bench_decode(cfg, params, batch, prompt_len, decode_steps)
         stats["model"] = model_kind
         stats["params"] = llama.param_count(params)
         stats["weight_gb"] = round(llama.param_bytes(params) / 1e9, 2)

@@ -72,12 +72,10 @@ def check_kernels(self_test: bool) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from gofr_tpu.models import llama
     from gofr_tpu.ops.attention import attention
     from gofr_tpu.ops.flash_attention import flash_attention
     from gofr_tpu.ops.paged_attention import (
         paged_decode_attention,
-        paged_decode_attention_q,
         paged_decode_attention_ref,
         paged_kv_append,
         paged_kv_append_ref,
@@ -124,88 +122,75 @@ def check_kernels(self_test: bool) -> None:
     # slots and table widths (benchmarks/cells/) — grouped-query with many
     # short rows, full multi-head with few long ones
     if self_test:
-        paged_cases = [("grouped", H, Hkv, B, S, ((16, False), (32, True))),
-                       ("multi-head", H, H, 3, S, ((16, False), (32, True)))]
+        paged_cases = [("grouped", H, Hkv, B, S), ("multi-head", H, H, 3, S)]
     else:
         paged_cases = [
-            ("8B heads, 8 x 1024", H, Hkv, B, S, ((16, False), (32, True), (128, True))),
-            ("mistral7b.chat, 32 x 768", 32, 8, 32, 768, ((16, False), (32, True))),
-            ("deepseek7b.gen, 6 x 1024", 32, 32, 6, 1024, ((16, False), (32, True))),
+            ("8B heads, 8 x 1024", H, Hkv, B, S),
+            ("mistral7b.chat, 32 x 768", 32, 8, 32, 768),
+            ("deepseek7b.gen, 6 x 1024", 32, 32, 6, 1024),
             # 16 queries a KV head; its window layers pass a window (below)
-            ("commandaplus.wide, 64 x 1024", 128, 8, 64, 1024, ((16, False),)),
+            ("commandaplus.wide, 64 x 1024", 128, 8, 64, 1024),
         ]
-    for label, pH, pHkv, pB, pS, pools in paged_cases:
+    page = 16  # the engine's default, and every cell's
+    for label, pH, pHkv, pB, pS in paged_cases:
         qd = jax.random.normal(keys[3], (pB, pH, D), dtype)
         # empty slots (length 1), a page boundary + 1, ragged, the full table
         ragged = [17, pS // 3, pS // 2 - 1, pS - pS // 4 + 9, pS - 24, pS - 3, pS]
         k_live = min(len(ragged), pB - 1)
         seq_lens = jnp.asarray([1] * (pB - k_live) + ragged[-k_live:], jnp.int32)
-        for page, quantized in pools:
-            M = pS // page
-            N = pB * M + 1
-            kf = jax.random.normal(keys[4], (N, pHkv, page, D), dtype)
-            vf = jax.random.normal(keys[5], (N, pHkv, page, D), dtype)
-            tables = jnp.asarray(
-                np.random.default_rng(SEED).permutation(N - 1).reshape(pB, M), jnp.int32
-            )
-            if quantized:
-                kq, ks = llama.quantize_kv(kf)
-                vq, vs = llama.quantize_kv(vf)
-                ks, vs = ks[..., None], vs[..., None]
-                out = paged_decode_attention_q(
-                    qd, kq, vq, ks, vs, tables, seq_lens, interpret=interpret
-                )
-                ref = paged_decode_attention_ref(
-                    qd, kq, vq, tables, seq_lens, k_scale=ks, v_scale=vs
-                )
-            else:
-                # as the decode step calls the two bf16 kernels: the whole
-                # pools (two layers; the other holds NaN, so a page read
-                # from the wrong layer shows) and a traced layer index
-                layer = jnp.int32(1)
-                kw = jnp.stack([jnp.full_like(kf, jnp.nan), kf])
-                vw = jnp.stack([jnp.full_like(vf, jnp.nan), vf])
-                # the append: every row writes the slot of its last position
-                # (an empty slot the trash page N - 1, as an inactive row)
-                pos = seq_lens - 1
-                live = seq_lens > 1
-                pages = jnp.where(live, tables[jnp.arange(pB), pos // page], N - 1)
-                offsets = jnp.where(live, pos % page, 0)
-                k_new = jax.random.normal(keys[1], (pB, pHkv, D), dtype)
-                v_new = jax.random.normal(keys[2], (pB, pHkv, D), dtype)
-                want = paged_kv_append_ref(kw, vw, k_new, v_new, layer, pages, offsets)
-                kw, vw = paged_kv_append(kw, vw, k_new, v_new, layer, pages, offsets,
-                                         interpret=interpret)
-                # bit for bit on every page but the trash page (garbage by contract)
-                same = all(bool(jnp.array_equal(got[1, :N - 1], exp[1, :N - 1]))
-                           and bool(jnp.all(jnp.isnan(got[0])))
-                           for got, exp in zip((kw, vw), want))
-                say(f"kernel paged_kv_append bf16 [{label}] Hkv={pHkv} B={pB} page={page}: "
-                    f"{'equal to' if same else 'DIFFERS from'} .at[].set")
-                check(same, f"paged_kv_append [{label}] page={page} differs from its reference")
-                kf, vf = kw[1], vw[1]  # the references read the appended layer
-                out = paged_decode_attention(
-                    qd, kw, vw, tables, seq_lens, interpret=interpret, layer=layer
-                )
-                ref = paged_decode_attention_ref(qd, kf, vf, tables, seq_lens)
-                # the same pools through the kernel with a window: one that
-                # starts long rows past their first block and cuts inside a
-                # page, and one wider than every row (a full-attention layer)
-                for window in (pS // 4 + 5, 1 << 30):
-                    w = jnp.int32(window)
-                    e = err(paged_decode_attention(qd, kw, vw, tables, seq_lens,
-                                                   interpret=interpret, window=w, layer=layer),
-                            paged_decode_attention_ref(qd, kf, vf, tables, seq_lens, window=w))
-                    say(f"kernel paged_decode_attention bf16 window={window} [{label}] "
-                        f"H={pH}/{pHkv} B={pB} M={M} page={page}: max|err|={e:.4g} (tol {tol})")
-                    check(e <= tol, f"paged_decode_attention window={window} [{label}] "
-                                    f"disagrees with its reference ({e} > {tol})")
-            e = err(out, ref)
-            name = "paged_decode_attention" + ("_q int8" if quantized else " bf16")
-            say(f"kernel {name} [{label}] H={pH}/{pHkv} B={pB} M={M} page={page}: "
-                f"max|err|={e:.4g} (tol {tol})")
-            check(e <= tol,
-                  f"{name} [{label}] page={page} disagrees with its reference ({e} > {tol})")
+        M = pS // page
+        N = pB * M + 1
+        kf = jax.random.normal(keys[4], (N, pHkv, page, D), dtype)
+        vf = jax.random.normal(keys[5], (N, pHkv, page, D), dtype)
+        tables = jnp.asarray(
+            np.random.default_rng(SEED).permutation(N - 1).reshape(pB, M), jnp.int32
+        )
+        # as the decode step calls the two kernels: the whole pools (two
+        # layers; the other holds NaN, so a page read from the wrong layer
+        # shows) and a traced layer index
+        layer = jnp.int32(1)
+        kw = jnp.stack([jnp.full_like(kf, jnp.nan), kf])
+        vw = jnp.stack([jnp.full_like(vf, jnp.nan), vf])
+        # the append: every row writes the slot of its last position
+        # (an empty slot the trash page N - 1, as an inactive row)
+        pos = seq_lens - 1
+        live = seq_lens > 1
+        pages = jnp.where(live, tables[jnp.arange(pB), pos // page], N - 1)
+        offsets = jnp.where(live, pos % page, 0)
+        k_new = jax.random.normal(keys[1], (pB, pHkv, D), dtype)
+        v_new = jax.random.normal(keys[2], (pB, pHkv, D), dtype)
+        want = paged_kv_append_ref(kw, vw, k_new, v_new, layer, pages, offsets)
+        kw, vw = paged_kv_append(kw, vw, k_new, v_new, layer, pages, offsets,
+                                 interpret=interpret)
+        # bit for bit on every page but the trash page (garbage by contract)
+        same = all(bool(jnp.array_equal(got[1, :N - 1], exp[1, :N - 1]))
+                   and bool(jnp.all(jnp.isnan(got[0])))
+                   for got, exp in zip((kw, vw), want))
+        say(f"kernel paged_kv_append bf16 [{label}] Hkv={pHkv} B={pB} page={page}: "
+            f"{'equal to' if same else 'DIFFERS from'} .at[].set")
+        check(same, f"paged_kv_append [{label}] page={page} differs from its reference")
+        kf, vf = kw[1], vw[1]  # the references read the appended layer
+        out = paged_decode_attention(
+            qd, kw, vw, tables, seq_lens, interpret=interpret, layer=layer
+        )
+        ref = paged_decode_attention_ref(qd, kf, vf, tables, seq_lens)
+        # the same pools through the kernel with a window: one that starts
+        # long rows past their first block and cuts inside a page, and one
+        # wider than every row (a full-attention layer)
+        for window in (pS // 4 + 5, 1 << 30):
+            w = jnp.int32(window)
+            e = err(paged_decode_attention(qd, kw, vw, tables, seq_lens,
+                                           interpret=interpret, window=w, layer=layer),
+                    paged_decode_attention_ref(qd, kf, vf, tables, seq_lens, window=w))
+            say(f"kernel paged_decode_attention bf16 window={window} [{label}] "
+                f"H={pH}/{pHkv} B={pB} M={M} page={page}: max|err|={e:.4g} (tol {tol})")
+            check(e <= tol, f"paged_decode_attention window={window} [{label}] "
+                            f"disagrees with its reference ({e} > {tol})")
+        e = err(out, ref)
+        say(f"kernel paged_decode_attention bf16 [{label}] H={pH}/{pHkv} B={pB} M={M} page={page}: "
+            f"max|err|={e:.4g} (tol {tol})")
+        check(e <= tol,
+              f"paged_decode_attention [{label}] page={page} disagrees with its reference ({e} > {tol})")
 
 
 # ------------------------------------------------- which attention path ran

@@ -1,8 +1,8 @@
 """The TPU-native flagship: continuous-batching LLM serving with paged
-KV, optional int8 cache and speculative decoding, behind /generate
+KV and optional speculative decoding, behind /generate
 (JSON + SSE streaming) and /v1/models.
 
-Environment knobs (all optional): TPU_KV_LAYOUT=paged, TPU_KV_DTYPE=int8,
+Environment knobs (all optional): TPU_KV_LAYOUT=paged,
 TPU_SPEC_TOKENS=6, TPU_BATCH_MAX_SLOTS, ... (serving/engine.py
 EngineConfig.from_config). Swap init_params for
 ServingEngine.from_hf("/path/to/llama") to serve real weights."""

@@ -169,12 +169,6 @@ KERNELS: tuple[KernelContract, ...] = (
         returns=(Ret("k_cache", like="k_cache"), Ret("v_cache", like="v_cache")),
     ),
     KernelContract(
-        "insert_slot_quantized", _BATCH,
-        params=("cache", "k_slab", "v_slab", "slot"),
-        donated=("cache",),
-        returns=(Ret("cache", like="cache"),),
-    ),
-    KernelContract(
         "admit_decode_state", _BATCH,
         params=(
             "state", "slots", "tokens", "lens", "budgets", "stops",
@@ -216,26 +210,6 @@ KERNELS: tuple[KernelContract, ...] = (
         arg_shapes=(("active", "B"),),
     ),
     KernelContract(
-        "decode_block_paged_q", _BATCH,
-        params=(
-            "cfg", "params", "k_pool", "v_pool", "ks_pool", "vs_pool",
-            "state", "block_tables", "active", "steps", "lora",
-        ),
-        donated=("k_pool", "v_pool", "ks_pool", "vs_pool", "state"),
-        static=("cfg", "steps"),
-        packed="block",
-        pack_helper="_pack_block",
-        returns=(
-            Ret("packed", shape="B,steps+2", dtype="int32"),
-            Ret("k_pool", like="k_pool"),
-            Ret("v_pool", like="v_pool"),
-            Ret("ks_pool", like="ks_pool"),
-            Ret("vs_pool", like="vs_pool"),
-            Ret("state", like="state"),
-        ),
-        arg_shapes=(("active", "B"),),
-    ),
-    KernelContract(
         "ragged_step", _BATCH,
         params=(
             "cfg", "params", "cache", "state", "chunk", "chunk_start",
@@ -267,28 +241,6 @@ KERNELS: tuple[KernelContract, ...] = (
             Ret("last_logits", shape="B,V", dtype="float32"),
             Ret("k_pool", like="k_pool"),
             Ret("v_pool", like="v_pool"),
-            Ret("state", like="state"),
-        ),
-        arg_shapes=(("chunk", "B,C"),),
-    ),
-    KernelContract(
-        "ragged_step_paged_q", _BATCH,
-        params=(
-            "cfg", "params", "k_pool", "v_pool", "ks_pool", "vs_pool",
-            "state", "block_tables", "chunk", "chunk_start",
-            "chunk_active", "kv_capacity",
-        ) + _RAGGED_TAIL,
-        donated=("k_pool", "v_pool", "ks_pool", "vs_pool", "state"),
-        static=("cfg", "steps"),
-        packed="ragged",
-        pack_helper="_pack_ragged",
-        returns=(
-            Ret("packed", shape="B,steps+3", dtype="int32"),
-            Ret("last_logits", shape="B,V", dtype="float32"),
-            Ret("k_pool", like="k_pool"),
-            Ret("v_pool", like="v_pool"),
-            Ret("ks_pool", like="ks_pool"),
-            Ret("vs_pool", like="vs_pool"),
             Ret("state", like="state"),
         ),
         arg_shapes=(("chunk", "B,C"),),
@@ -334,26 +286,6 @@ KERNELS: tuple[KernelContract, ...] = (
         arg_shapes=(("chunk", "B,T"),),
     ),
     KernelContract(
-        "verify_and_sample_paged_q", _BATCH,
-        params=(
-            "cfg", "params", "k_pool", "v_pool", "ks_pool", "vs_pool",
-            "block_tables", "chunk", "start_len", "active", "kv_capacity",
-            "temperature", "top_k", "top_p", "rng",
-        ),
-        donated=("k_pool", "v_pool", "ks_pool", "vs_pool"),
-        static=("cfg",),
-        packed="spec",
-        returns=(
-            Ret("packed", shape="B,T+1", dtype="int32"),
-            Ret("k_pool", like="k_pool"),
-            Ret("v_pool", like="v_pool"),
-            Ret("ks_pool", like="ks_pool"),
-            Ret("vs_pool", like="vs_pool"),
-            Ret("rng", like="rng"),
-        ),
-        arg_shapes=(("chunk", "B,T"),),
-    ),
-    KernelContract(
         "lora_adjust_logits", _BATCH,
         params=("embedding", "a_row", "b_row", "token", "logits"),
         returns=(Ret("logits", like="logits"),),
@@ -364,29 +296,14 @@ KERNELS: tuple[KernelContract, ...] = (
         donated=("k_pool", "v_pool"),
         returns=(Ret("k_pool", like="k_pool"), Ret("v_pool", like="v_pool")),
     ),
-    KernelContract(
-        "_write_pages_q", _KVC,
-        params=(
-            "k_pool", "v_pool", "ks_pool", "vs_pool", "k_slab", "v_slab",
-            "page_ids",
-        ),
-        donated=("k_pool", "v_pool", "ks_pool", "vs_pool"),
-        returns=(
-            Ret("k_pool", like="k_pool"),
-            Ret("v_pool", like="v_pool"),
-            Ret("ks_pool", like="ks_pool"),
-            Ret("vs_pool", like="vs_pool"),
-        ),
-    ),
-    # The two entries of the one paged decode kernel: a program a row, the
-    # pools left in HBM, pages fetched by the kernel's own DMAs in a loop
-    # whose trip count is the row's length. Tile sizes are worked out
-    # inside from these shapes, so neither entry has a parameter for them.
-    # POOL RANKS: the bf16 entry with ``layer`` takes the WHOLE pools
-    # [L, N_pages, Hkv, page, Dh] and reads that layer's pages (what the
-    # decode step passes: XLA never slices a pool); with ``layer=None``,
-    # and always in the int8 entry, the pools are ONE layer's
-    # [N_pages, Hkv, page, Dh] (int8 scales [..., 1]).
+    # The paged decode kernel: a program a row, the pools left in HBM,
+    # pages fetched by the kernel's own DMAs in a loop whose trip count is
+    # the row's length. Tile sizes are worked out inside from these shapes,
+    # so the entry has no parameter for them. POOL RANKS: with ``layer``
+    # the entry takes the WHOLE pools [L, N_pages, Hkv, page, Dh] and reads
+    # that layer's pages (what the decode step passes: XLA never slices a
+    # pool); with ``layer=None`` the pools are ONE layer's
+    # [N_pages, Hkv, page, Dh].
     KernelContract(
         "paged_decode_attention", _PAGED_ATTN,
         # window: None builds the kernel without the argument; a scalar
@@ -409,13 +326,6 @@ KERNELS: tuple[KernelContract, ...] = (
                 "offsets", "interpret"),
         static=("interpret",),
         returns=(Ret("k_pool", like="k_pool"), Ret("v_pool", like="v_pool")),
-    ),
-    KernelContract(
-        "paged_decode_attention_q", _PAGED_ATTN,
-        params=("q", "k_pool", "v_pool", "k_scale", "v_scale",
-                "block_tables", "seq_lens", "scale", "interpret"),
-        static=("scale", "interpret"),
-        returns=(Ret("out", like="q"),),
     ),
     KernelContract(
         "flash_attention", _FLASH,

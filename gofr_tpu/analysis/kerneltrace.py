@@ -3,7 +3,7 @@
 Two producers, one consumer:
 
 - :func:`run_matrix` ``jax.eval_shape``\\ s EVERY contract-table entry
-  across the config matrix (dense / paged / int8-quantized caches x
+  across the config matrix (dense / paged caches x
   base / LoRA x plain / ragged / speculative x B,N variants) and exports
   the observed (pytree, shape, dtype) signatures. Everything abstract is
   passed as an eval_shape ARGUMENT (``ShapeDtypeStruct`` pytrees); only
@@ -124,23 +124,11 @@ def run_matrix() -> dict:
         sds((ADAPTERS, RANK, V), jnp.float32),
     )
 
-    def dense_cache(B, quant=False):
+    def dense_cache(B):
         shape = (L, B, S_MAX, Hkv, Dh)
-        if quant:
-            return llama.KVCache(
-                sds(shape, jnp.int8), sds(shape, jnp.int8),
-                sds(shape[:-1], jnp.float32), sds(shape[:-1], jnp.float32),
-            )
         return llama.KVCache(sds(shape, cfg.dtype), sds(shape, cfg.dtype))
 
-    def pools(quant=False):
-        shape = (L, N_PAGES + 1, Hkv, PAGE, Dh)
-        dt = jnp.int8 if quant else cfg.dtype
-        kp, vp = sds(shape, dt), sds(shape, dt)
-        if not quant:
-            return kp, vp, None, None
-        sshape = shape[:-1] + (1,)
-        return kp, vp, sds(sshape, jnp.float32), sds(sshape, jnp.float32)
+    kp = vp = sds((L, N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)  # the pools
 
     def state(B):
         i = sds((B,), jnp.int32)
@@ -190,12 +178,6 @@ def run_matrix() -> dict:
         k_slab=sds((L, S_BUCKET, Hkv, Dh), cfg.dtype),
         v_slab=sds((L, S_BUCKET, Hkv, Dh), cfg.dtype),
         slot=sds((), jnp.int32))
-    add("insert_slot_quantized", "quantized",
-        unwrap("insert_slot_quantized"),
-        cache=dense_cache(1, quant=True),
-        k_slab=sds((L, S_BUCKET, Hkv, Dh), cfg.dtype),
-        v_slab=sds((L, S_BUCKET, Hkv, Dh), cfg.dtype),
-        slot=sds((), jnp.int32))
     add("insert_chunk", "dense", unwrap("insert_chunk"),
         k_cache=cache.k, v_cache=cache.v,
         k_slab=sds((L, 4, Hkv, Dh), cfg.dtype),
@@ -206,28 +188,20 @@ def run_matrix() -> dict:
         budgets=vec(2), stops=vec(2), temps=vec(2, jnp.float32),
         topks=vec(2), topps=vec(2, jnp.float32), adapters=vec(2))
 
-    for variant, B, steps, quant, lora in (
-        ("dense.b3n4", 3, 4, False, None),
-        ("dense.b2n2", 2, 2, False, None),
-        ("dense.lora", 3, 4, False, lora_tabs),
-        ("dense.q", 3, 4, True, None),
+    for variant, B, steps, lora in (
+        ("dense.b3n4", 3, 4, None),
+        ("dense.b2n2", 2, 2, None),
+        ("dense.lora", 3, 4, lora_tabs),
     ):
         add("decode_block", variant, unwrap("decode_block"),
-            cfg=cfg, params=params, cache=dense_cache(B, quant),
+            cfg=cfg, params=params, cache=dense_cache(B),
             state=state(B), active=vec(B, jnp.bool_), steps=steps,
             lora=lora)
     for variant, lora in (("paged", None), ("paged.lora", lora_tabs)):
-        kp, vp, _, _ = pools()
         add("decode_block_paged", variant, unwrap("decode_block_paged"),
             cfg=cfg, params=params, k_pool=kp, v_pool=vp, state=state(3),
             block_tables=sds((3, M), jnp.int32),
             active=vec(3, jnp.bool_), steps=4, lora=lora)
-    kp, vp, ksp, vsp = pools(quant=True)
-    add("decode_block_paged_q", "paged.q", unwrap("decode_block_paged_q"),
-        cfg=cfg, params=params, k_pool=kp, v_pool=vp, ks_pool=ksp,
-        vs_pool=vsp, state=state(3),
-        block_tables=sds((3, M), jnp.int32), active=vec(3, jnp.bool_),
-        steps=4, lora=None)
 
     for variant, B, chunk_c, steps, lora in (
         ("dense.b3n4", 3, 4, 4, None),
@@ -238,17 +212,8 @@ def run_matrix() -> dict:
             cfg=cfg, params=params, cache=dense_cache(B), state=state(B),
             chunk=sds((B, chunk_c), jnp.int32), chunk_start=vec(B),
             **ragged_tail(B, steps, lora))
-    kp, vp, _, _ = pools()
     add("ragged_step_paged", "paged", unwrap("ragged_step_paged"),
         cfg=cfg, params=params, k_pool=kp, v_pool=vp, state=state(3),
-        block_tables=sds((3, M), jnp.int32),
-        chunk=sds((3, 4), jnp.int32), chunk_start=vec(3),
-        chunk_active=vec(3, jnp.bool_), kv_capacity=vec(3),
-        **ragged_tail(3, 4, None))
-    kp, vp, ksp, vsp = pools(quant=True)
-    add("ragged_step_paged_q", "paged.q", unwrap("ragged_step_paged_q"),
-        cfg=cfg, params=params, k_pool=kp, v_pool=vp, ks_pool=ksp,
-        vs_pool=vsp, state=state(3),
         block_tables=sds((3, M), jnp.int32),
         chunk=sds((3, 4), jnp.int32), chunk_start=vec(3),
         chunk_active=vec(3, jnp.bool_), kv_capacity=vec(3),
@@ -257,18 +222,10 @@ def run_matrix() -> dict:
     add("verify_and_sample", "spec.dense", unwrap("verify_and_sample"),
         cfg=cfg, params=params, cache=dense_cache(3),
         chunk=sds((3, 3), jnp.int32), start_len=vec(3), **spec_tail(3))
-    kp, vp, _, _ = pools()
     add("verify_and_sample_paged", "spec.paged",
         unwrap("verify_and_sample_paged"),
         cfg=cfg, params=params, k_pool=kp, v_pool=vp,
         block_tables=sds((3, M), jnp.int32),
-        chunk=sds((3, 3), jnp.int32), start_len=vec(3),
-        active=vec(3, jnp.bool_), kv_capacity=vec(3), **spec_tail(3))
-    kp, vp, ksp, vsp = pools(quant=True)
-    add("verify_and_sample_paged_q", "spec.paged.q",
-        unwrap("verify_and_sample_paged_q"),
-        cfg=cfg, params=params, k_pool=kp, v_pool=vp, ks_pool=ksp,
-        vs_pool=vsp, block_tables=sds((3, M), jnp.int32),
         chunk=sds((3, 3), jnp.int32), start_len=vec(3),
         active=vec(3, jnp.bool_), kv_capacity=vec(3), **spec_tail(3))
 
@@ -278,7 +235,6 @@ def run_matrix() -> dict:
         b_row=sds((RANK, V), jnp.float32),
         token=sds((), jnp.int32), logits=sds((1, V), jnp.float32))
 
-    kp, vp, _, _ = pools()
     cases.append(_eval_case(
         kvc_mod._write_pages.__wrapped__, C["_write_pages"], "paged",
         {
@@ -288,23 +244,10 @@ def run_matrix() -> dict:
             "page_ids": vec(2),
         },
     ))
-    kp, vp, ksp, vsp = pools(quant=True)
-    cases.append(_eval_case(
-        kvc_mod._write_pages_q.__wrapped__, C["_write_pages_q"], "paged.q",
-        {
-            "k_pool": kp, "v_pool": vp, "ks_pool": ksp, "vs_pool": vsp,
-            "k_slab": sds((L, 2 * PAGE, Hkv, Dh), cfg.dtype),
-            "v_slab": sds((L, 2 * PAGE, Hkv, Dh), cfg.dtype),
-            "page_ids": vec(2),
-        },
-    ))
 
     # ops-level attention sees ONE layer's pool ([N+1, Hkv, page, Dh]) or,
     # given a layer index, the whole pools as the decode step passes them
     lp = sds((N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)
-    lp8 = sds((N_PAGES + 1, Hkv, PAGE, Dh), jnp.int8)
-    lps = sds((N_PAGES + 1, Hkv, PAGE, 1), jnp.float32)
-    kp, vp, _, _ = pools()
     scalar = sds((), jnp.int32)
     # the window is None (the kernel without the argument) or a scalar
     for variant, pool_k, pool_v, window, layer in (
@@ -332,16 +275,6 @@ def run_matrix() -> dict:
             "v_new": sds((3, Hkv, Dh), cfg.dtype),
             "layer": scalar, "pages": vec(3), "offsets": vec(3),
             "interpret": True,
-        },
-    ))
-    cases.append(_eval_case(
-        pa_mod.paged_decode_attention_q.__wrapped__,
-        C["paged_decode_attention_q"], "paged.q",
-        {
-            "q": sds((3, cfg.n_heads, Dh), cfg.dtype),
-            "k_pool": lp8, "v_pool": lp8, "k_scale": lps, "v_scale": lps,
-            "block_tables": sds((3, M), jnp.int32), "seq_lens": vec(3),
-            "scale": None, "interpret": True,
         },
     ))
     for variant, window in (("flash", None), ("flash.window", sds((), jnp.int32))):

@@ -221,7 +221,7 @@ BLOCKING_FETCH_CALLS = {"fetch_one", "fetch_chain", "prefill_compute"}
 # commits into rebuilt state that a retired thread must never perform
 COMMIT_CALLS = {
     "put", "write_span", "write_prefill", "insert_chunk",
-    "insert_slot", "insert_slot_quantized", "advance_slot",
+    "insert_slot", "advance_slot",
     "_commit_prefilled", "_commit_first_token",
 }
 RETIRE_GATE_CALLS = {"_check_retired"}

@@ -120,9 +120,9 @@ def step_stats_len(cfg: Cohere2MoeConfig) -> int:
 def unserved(engine_config: Any, lora: Any) -> str | None:
     """What an engine asks for that this model has no program for, in a
     sentence; None if it can be built."""
-    if engine_config.kv_layout != "paged" or engine_config.kv_dtype != "bf16":
-        return ("cohere2_moe is served from the paged bf16 KV layout only: its dense "
-                "and int8 decode programs wait for the one cache layout (ROADMAP D2)")
+    if engine_config.kv_layout != "paged":
+        return ("cohere2_moe is served from the paged KV layout only: its dense "
+                "decode programs wait for the one cache layout (ROADMAP D2)")
     if engine_config.spec_tokens > 0:
         return "cohere2_moe has no speculative verify program: set TPU_SPEC_TOKENS=0"
     if lora is not None:

@@ -33,7 +33,6 @@ from gofr_tpu.ops.flash_attention import flash_attention
 from gofr_tpu.ops.norms import rms_norm
 from gofr_tpu.ops.paged_attention import (
     paged_decode_attention,
-    paged_decode_attention_q,
     paged_kv_append,
 )
 from gofr_tpu.ops.rope import apply_rope, rope_table
@@ -209,72 +208,26 @@ def _mm(x: jnp.ndarray, w) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------- KV cache
-@jax.tree_util.register_pytree_node_class
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
-    """Dense KV cache: [L, B, S_max, Hkv, Dh] per k/v. The serving layer's
-    paged cache (serving/kv_cache.py) converts to/from this layout for the
-    model step functions.
-
-    Optional int8 quantization (``create(..., kv_dtype="int8")``): k/v are
-    stored int8 with per-(layer, row, position, head) absmax scales
-    (``ks``/``vs`` [L, B, S_max, Hkv] f32) and dequantized to the compute
-    dtype at the attention read. Decode is HBM-bound and the KV read grows
-    linearly with batch x length, so halving its width is a direct
-    throughput lever AND doubles resident KV capacity (SURVEY §5.7
-    lever (a) squared); compute stays bf16 — only storage narrows."""
+    """Dense KV cache: [L, B, S_max, Hkv, Dh] per k/v, in the model's
+    ``dtype``. The form ``prefill`` returns its slabs in; the serving
+    layer's paged cache (serving/kv_cache.py) converts to/from this layout
+    for the model step functions."""
 
     k: jnp.ndarray
     v: jnp.ndarray
-    ks: jnp.ndarray | None = None  # int8 mode: absmax scales
-    vs: jnp.ndarray | None = None
-
-    def tree_flatten(self):
-        if self.ks is None:
-            return (self.k, self.v), False
-        return (self.k, self.v, self.ks, self.vs), True
 
     @classmethod
-    def tree_unflatten(cls, quantized, children):
-        return cls(*children)
-
-    @classmethod
-    def create(
-        cls, cfg: LlamaConfig, batch: int, max_len: int | None = None,
-        kv_dtype: str | None = None,
-    ) -> "KVCache":
+    def create(cls, cfg: LlamaConfig, batch: int, max_len: int | None = None) -> "KVCache":
         S = max_len or cfg.max_seq_len
         shape = (cfg.n_layers, batch, S, cfg.n_kv_heads, cfg.head_dim)
-        if kv_dtype == "int8":
-            sshape = shape[:-1]
-            return cls(
-                jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                jnp.zeros(sshape, jnp.float32), jnp.zeros(sshape, jnp.float32),
-            )
         return cls(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
-
-    @property
-    def quantized(self) -> bool:
-        return self.ks is not None
 
     @property
     def max_len(self) -> int:
         return self.k.shape[2]
-
-
-def quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-vector (last-dim) absmax int8 quantization: [..., Dh] →
-    (int8 [..., Dh], f32 scale [...])."""
-    absmax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
-    scale = jnp.maximum(absmax / 127.0, 1e-8)
-    q = jnp.clip(
-        jnp.round(x.astype(jnp.float32) / scale[..., None]), -127, 127
-    ).astype(jnp.int8)
-    return q, scale
-
-
-def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype: Any) -> jnp.ndarray:
-    return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
 # ---------------------------------------------------------------- layer body
@@ -345,9 +298,7 @@ def _layer_cached(
     v_all: jnp.ndarray,
     cache_len: jnp.ndarray,  # [B] length AFTER writing current tokens
     mode: str,
-    ks_all: jnp.ndarray | None = None,  # int8 mode: [L, B, S_max, Hkv] scales
-    vs_all: jnp.ndarray | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray | None, jnp.ndarray | None]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Layer body for the cached modes, carrying the WHOLE stacked cache.
 
     Scanning the cache as xs/ys (the obvious formulation) makes XLA slice
@@ -355,28 +306,15 @@ def _layer_cached(
     step — profiled at ~15 ms of a 25 ms decode step at B=256. Keeping
     the stacked cache in the scan *carry* and doing per-layer indexed
     in-place updates leaves it resident in HBM: per step the only cache
-    traffic is the attention read plus a one-token scatter.
-
-    int8 KV (ks_all/vs_all present): k/v quantize on write; the attention
-    read dequantizes to the compute dtype — halving the dominant decode
-    HBM stream. Prefill attention always uses the fresh full-width k/v."""
+    traffic is the attention read plus a one-token scatter."""
     B, S, _ = x.shape
-    quantized = ks_all is not None
     _, q, k, v = _qkv(cfg, x, lp, sin, cos, positions)
 
     if mode == "prefill":
         # fill layer `layer`'s slab in place; attention runs on the fresh
         # k/v directly (no cache read-back needed during prefill)
-        if quantized:
-            kq, kscale = quantize_kv(k)
-            vq, vscale = quantize_kv(v)
-            k_all = jax.lax.dynamic_update_slice(k_all, kq[None], (layer, 0, 0, 0, 0))
-            v_all = jax.lax.dynamic_update_slice(v_all, vq[None], (layer, 0, 0, 0, 0))
-            ks_all = jax.lax.dynamic_update_slice(ks_all, kscale[None], (layer, 0, 0, 0))
-            vs_all = jax.lax.dynamic_update_slice(vs_all, vscale[None], (layer, 0, 0, 0))
-        else:
-            k_all = jax.lax.dynamic_update_slice(k_all, k[None], (layer, 0, 0, 0, 0))
-            v_all = jax.lax.dynamic_update_slice(v_all, v[None], (layer, 0, 0, 0, 0))
+        k_all = jax.lax.dynamic_update_slice(k_all, k[None], (layer, 0, 0, 0, 0))
+        v_all = jax.lax.dynamic_update_slice(v_all, v[None], (layer, 0, 0, 0, 0))
         if cfg.attn_impl == "flash" or (cfg.attn_impl == "auto" and S % 128 == 0):
             # compiled kernel on a TPU, ops.attention on the CPU
             # (ops/backend.py)
@@ -386,31 +324,13 @@ def _layer_cached(
     else:  # decode: S == 1, one-token scatter at (layer, row, position)
         idx = cache_len - 1  # position just written
         b_idx = jnp.arange(B)
-        if quantized:
-            kq, kscale = quantize_kv(k[:, 0])
-            vq, vscale = quantize_kv(v[:, 0])
-            k_all = k_all.at[layer, b_idx, idx].set(kq)
-            v_all = v_all.at[layer, b_idx, idx].set(vq)
-            ks_all = ks_all.at[layer, b_idx, idx].set(kscale)
-            vs_all = vs_all.at[layer, b_idx, idx].set(vscale)
-            kc = dequantize_kv(
-                jax.lax.dynamic_index_in_dim(k_all, layer, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(ks_all, layer, 0, keepdims=False),
-                cfg.dtype,
-            )
-            vc = dequantize_kv(
-                jax.lax.dynamic_index_in_dim(v_all, layer, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(vs_all, layer, 0, keepdims=False),
-                cfg.dtype,
-            )
-        else:
-            k_all = k_all.at[layer, b_idx, idx].set(k[:, 0])
-            v_all = v_all.at[layer, b_idx, idx].set(v[:, 0])
-            kc = jax.lax.dynamic_index_in_dim(k_all, layer, 0, keepdims=False)
-            vc = jax.lax.dynamic_index_in_dim(v_all, layer, 0, keepdims=False)
+        k_all = k_all.at[layer, b_idx, idx].set(k[:, 0])
+        v_all = v_all.at[layer, b_idx, idx].set(v[:, 0])
+        kc = jax.lax.dynamic_index_in_dim(k_all, layer, 0, keepdims=False)
+        vc = jax.lax.dynamic_index_in_dim(v_all, layer, 0, keepdims=False)
         attn = decode_attention(q, kc, vc, cache_len)
 
-    return _attn_mlp_epilogue(cfg, x, lp, attn), k_all, v_all, ks_all, vs_all
+    return _attn_mlp_epilogue(cfg, x, lp, attn), k_all, v_all
 
 
 def _run_layers(
@@ -442,27 +362,10 @@ def _run_layers(
 
     # cache modes: the stacked cache rides the CARRY (in-place per-layer
     # updates), never the xs/ys path — see _layer_cached's docstring
-    if cache.quantized:
-        def body(carry, xs):
-            h, k_all, v_all, ks_all, vs_all = carry
-            lp, layer = xs
-            h, k_all, v_all, ks_all, vs_all = _layer_cached(
-                cfg, h, lp, layer, sin, cos, positions, k_all, v_all,
-                cache_len, mode, ks_all, vs_all,
-            )
-            return (h, k_all, v_all, ks_all, vs_all), None
-
-        (x, new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
-            body,
-            (x, cache.k, cache.v, cache.ks, cache.vs),
-            (params["layers"], jnp.arange(cfg.n_layers)),
-        )
-        return x, KVCache(new_k, new_v, new_ks, new_vs)
-
     def body(carry, xs):
         h, k_all, v_all = carry
         lp, layer = xs
-        h, k_all, v_all, _, _ = _layer_cached(
+        h, k_all, v_all = _layer_cached(
             cfg, h, lp, layer, sin, cos, positions, k_all, v_all, cache_len, mode
         )
         return (h, k_all, v_all), None
@@ -626,68 +529,6 @@ def decode_step_paged(
     return logits, k_pool, v_pool
 
 
-@partial(jax.jit, static_argnums=0, donate_argnums=(3, 4, 5, 6))
-def decode_step_paged_q(
-    cfg: LlamaConfig,
-    params: dict,
-    tokens: jnp.ndarray,  # [B]
-    k_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, Dh] int8, donated
-    v_pool: jnp.ndarray,  # donated
-    ks_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, 1] f32, donated
-    vs_pool: jnp.ndarray,  # donated
-    block_tables: jnp.ndarray,  # [B, M] int32
-    seq_lens: jnp.ndarray,  # [B] length INCLUDING this token's position
-    active: jnp.ndarray,  # [B] bool
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """int8 twin of :func:`decode_step_paged`: this step's K/V quantize
-    (per-vector absmax) before the page scatter, and attention reads the
-    pools through the dequantizing kernel (ops/paged_attention.py) —
-    half the paged decode HBM stream."""
-    B = tokens.shape[0]
-    page = k_pool.shape[3]
-    trash_page = k_pool.shape[1] - 1
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    x = params["embedding"][tokens][:, None, :].astype(cfg.dtype)
-    pos = jnp.maximum(seq_lens - 1, 0)
-    positions = pos[:, None]
-    sin, cos = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
-    b_idx = jnp.arange(B)
-    pages = jnp.where(active, block_tables[b_idx, pos // page], trash_page)
-    offsets = jnp.where(active, pos % page, 0)
-
-    def body(h, xs):
-        lp, kc, vc, ksc, vsc = xs
-        hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(hn, lp["wq"]).reshape(B, 1, H, Dh)
-        k = _mm(hn, lp["wk"]).reshape(B, 1, Hkv, Dh)
-        v = _mm(hn, lp["wv"]).reshape(B, 1, Hkv, Dh)
-        q = apply_rope(q, positions, sin, cos)[:, 0]
-        k = apply_rope(k, positions, sin, cos)[:, 0]  # [B, Hkv, Dh]
-        v = v[:, 0]
-
-        kq, ks = quantize_kv(k)  # int8 [B,Hkv,Dh], f32 [B,Hkv]
-        vq, vs = quantize_kv(v)
-        kc = kc.at[pages, :, offsets].set(kq)
-        vc = vc.at[pages, :, offsets].set(vq)
-        ksc = ksc.at[pages, :, offsets, 0].set(ks)
-        vsc = vsc.at[pages, :, offsets, 0].set(vs)
-
-        attn = paged_decode_attention_q(
-            q, kc, vc, ksc, vsc, block_tables, seq_lens
-        )
-        h = h + _mm(attn.reshape(B, 1, H * Dh), lp["wo"])
-        hn = rms_norm(h, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(_mm(hn, lp["w_gate"]).astype(jnp.float32)).astype(hn.dtype)
-        h = h + _mm(gate * _mm(hn, lp["w_up"]), lp["w_down"])
-        return h, (kc, vc, ksc, vsc)
-
-    x, (k_pool, v_pool, ks_pool, vs_pool) = jax.lax.scan(
-        body, x, (params["layers"], k_pool, v_pool, ks_pool, vs_pool)
-    )
-    logits = _logits(cfg, params, x)[:, 0]
-    return logits, k_pool, v_pool, ks_pool, vs_pool
-
-
 @partial(jax.jit, static_argnums=0, donate_argnums=(3, 4))
 def decode_step_greedy(
     cfg: LlamaConfig,
@@ -760,40 +601,6 @@ def decode_chunk(
     sin, cos = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
     b_rows = jnp.arange(B)[:, None]
 
-    if cache.quantized:  # int8 storage (round-5: restriction lifted so the
-        # engine's speculative path covers the headline int8-KV config)
-        def body_q(carry, xs):
-            h, k_all, v_all, ks_all, vs_all = carry
-            lp, layer = xs
-            _, q, k, v = _qkv(cfg, h, lp, sin, cos, positions)
-            kq, kscale = quantize_kv(k)
-            vq, vscale = quantize_kv(v)
-            k_all = k_all.at[layer, b_rows, positions].set(kq)
-            v_all = v_all.at[layer, b_rows, positions].set(vq)
-            ks_all = ks_all.at[layer, b_rows, positions].set(kscale)
-            vs_all = vs_all.at[layer, b_rows, positions].set(vscale)
-            kc = dequantize_kv(
-                jax.lax.dynamic_index_in_dim(k_all, layer, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(ks_all, layer, 0, keepdims=False),
-                cfg.dtype,
-            )
-            vc = dequantize_kv(
-                jax.lax.dynamic_index_in_dim(v_all, layer, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(vs_all, layer, 0, keepdims=False),
-                cfg.dtype,
-            )
-            attn = attention(
-                q, kc, vc, causal=True, q_offset=start_len, kv_len=start_len + T
-            )
-            h = _attn_mlp_epilogue(cfg, h, lp, attn)
-            return (h, k_all, v_all, ks_all, vs_all), None
-
-        (x, new_k, new_v, new_ks, new_vs), _ = jax.lax.scan(
-            body_q, (x, cache.k, cache.v, cache.ks, cache.vs),
-            (params["layers"], jnp.arange(cfg.n_layers)),
-        )
-        return _logits(cfg, params, x), KVCache(new_k, new_v, new_ks, new_vs)
-
     def body(carry, xs):
         h, k_all, v_all = carry
         lp, layer = xs
@@ -840,16 +647,11 @@ def _paged_chunk_targets(
 def _paged_gather(
     pool: jnp.ndarray,  # [N+1, Hkv, page, Dh] one layer's pool
     block_tables: jnp.ndarray,  # [B, M]
-    scale: jnp.ndarray | None = None,  # [N+1, Hkv, page, 1]
-    dtype: Any = None,
 ) -> jnp.ndarray:
     """Gather a row's pages into contiguous [B, M*page, Hkv, Dh] for the
     chunk-verify attention (XLA-gather reference path: verify chunks are
     a small, latency-tolerant fraction of decode traffic)."""
     g = pool[block_tables]  # [B, M, Hkv, page, Dh]
-    if scale is not None:
-        s = scale[block_tables]  # [B, M, Hkv, page, 1]
-        g = (g.astype(jnp.float32) * s).astype(dtype)
     B, M, Hkv, page, Dh = g.shape
     return g.transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
 
@@ -893,52 +695,6 @@ def decode_chunk_paged(
 
     x, (k_pool, v_pool) = jax.lax.scan(body, x, (params["layers"], k_pool, v_pool))
     return _logits(cfg, params, x), k_pool, v_pool
-
-
-@partial(jax.jit, static_argnums=0, donate_argnums=(3, 4, 5, 6))
-def decode_chunk_paged_q(
-    cfg: LlamaConfig,
-    params: dict,
-    tokens: jnp.ndarray,  # [B, T]
-    k_pool: jnp.ndarray,  # int8, donated
-    v_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,  # f32 scales, donated
-    vs_pool: jnp.ndarray,
-    block_tables: jnp.ndarray,
-    start_len: jnp.ndarray,
-    active: jnp.ndarray,
-    kv_capacity: jnp.ndarray,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """int8 twin of :func:`decode_chunk_paged`."""
-    B, T = tokens.shape
-    positions = start_len[:, None] + jnp.arange(T)[None, :]
-    pages, offsets = _paged_chunk_targets(
-        k_pool, block_tables, positions, active, kv_capacity
-    )
-    x = params["embedding"][jnp.maximum(tokens, 0)].astype(cfg.dtype)
-    sin, cos = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
-
-    def body(h, xs):
-        lp, kc, vc, ksc, vsc = xs
-        _, q, k, v = _qkv(cfg, h, lp, sin, cos, positions)
-        kq, ks = quantize_kv(k)  # int8 [B,T,Hkv,Dh], f32 [B,T,Hkv]
-        vq, vs = quantize_kv(v)
-        kc = kc.at[pages, :, offsets].set(kq)
-        vc = vc.at[pages, :, offsets].set(vq)
-        ksc = ksc.at[pages, :, offsets, 0].set(ks)
-        vsc = vsc.at[pages, :, offsets, 0].set(vs)
-        kg = _paged_gather(kc, block_tables, scale=ksc, dtype=cfg.dtype)
-        vg = _paged_gather(vc, block_tables, scale=vsc, dtype=cfg.dtype)
-        attn = attention(
-            q, kg, vg, causal=True, q_offset=start_len, kv_len=start_len + T
-        )
-        h = _attn_mlp_epilogue(cfg, h, lp, attn)
-        return h, (kc, vc, ksc, vsc)
-
-    x, (k_pool, v_pool, ks_pool, vs_pool) = jax.lax.scan(
-        body, x, (params["layers"], k_pool, v_pool, ks_pool, vs_pool)
-    )
-    return _logits(cfg, params, x), k_pool, v_pool, ks_pool, vs_pool
 
 
 def _prompt_lookup_draft(context: list[int], ngram: int, draft_len: int) -> list[int]:

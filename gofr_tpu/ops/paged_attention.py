@@ -12,12 +12,12 @@ max_seq_len rows per slot.
 
 Reading, two implementations with one contract:
 - ``paged_decode_attention_ref`` — pure-XLA gather reference: the oracle
-  the kernels are tested against, and what both entries compute on the
+  the kernel is tested against, and what the entry computes on the
   CPU unless a test passes ``interpret=True`` (``ops/backend.py``);
-- ``paged_decode_attention`` / ``paged_decode_attention_q`` — one Pallas
-  kernel (bf16 or int8-with-scales pools) with one program a row and no
-  grid axis over pages. The pools stay in HBM; the kernel loops over the
-  blocks of pages the row owns (``cdiv(seq_len, pages_per_block * page)``
+- ``paged_decode_attention`` — one Pallas kernel with one program a row
+  and no grid axis over pages. The pools stay in HBM; the kernel loops
+  over the blocks of pages the row owns
+  (``cdiv(seq_len, pages_per_block * page)``
   trips, read from the scalar-prefetched lengths and block tables) and
   fetches each block itself, one DMA a page — a page of the pool is
   contiguous for all KV heads — into a double buffer in VMEM, so that
@@ -25,11 +25,10 @@ Reading, two implementations with one contract:
   flight while block *i* is computed. The online softmax runs in float32
   over every KV head of the block. A row of length 1 (an empty slot)
   costs one page. ``pages_per_block`` is worked out from the shapes the
-  call sees (:func:`_pages_per_block`). int8 pages arrive with their
-  per-vector absmax scales (two more copies a page) and dequantize in
-  VMEM. The kernel addresses ``pool[layer, page id]``: the bf16 entry
-  given a ``layer`` takes the WHOLE pools, as the decode step passes
-  them; without one (and in the int8 entry) the pools are one layer's.
+  call sees (:func:`_pages_per_block`). The kernel addresses
+  ``pool[layer, page id]``: given a ``layer`` the entry takes the WHOLE
+  pools, as the decode step passes them; without one the pools are one
+  layer's.
 
 Writing: ``paged_kv_append`` (reference ``paged_kv_append_ref``, the
 scatter it replaced) puts one decode step's K/V of every row into the
@@ -59,13 +58,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from gofr_tpu.ops.backend import COMPILED, INTERPRET, REFERENCE, kernel_mode
+from gofr_tpu.ops.backend import INTERPRET, REFERENCE, kernel_mode
 
 NEG_INF = -1e30
-
-# int8 arrays tile as (32, 128) on TPU; a smaller page would violate the
-# Mosaic block constraints for the quantized pools
-INT8_MIN_PAGE = 32
 
 
 def paged_decode_attention_ref(
@@ -76,17 +71,14 @@ def paged_decode_attention_ref(
     seq_lens: jnp.ndarray,  # [B] valid token count per sequence
     *,
     scale: float | None = None,
-    k_scale: jnp.ndarray | None = None,  # int8 pools: [N, Hkv, page, 1] f32
-    v_scale: jnp.ndarray | None = None,
     window: jnp.ndarray | int | None = None,
     layer: jnp.ndarray | int | None = None,  # pools are [L, N_pages, ...]
 ) -> jnp.ndarray:
     """Gather-based reference: materializes [B, M*page] K/V. Correctness
-    oracle + the CPU path. int8 pools carry per-vector absmax scales
-    and dequantize AFTER the gather — only the owned pages widen, never
-    the whole pool. With ``window`` the query (at position ``seq_len - 1``)
-    sees the last ``window`` positions only. With ``layer`` the pools are
-    whole ([L, N_pages, Hkv, page, Dh]) and that layer's pages are read."""
+    oracle + the CPU path. With ``window`` the query (at position
+    ``seq_len - 1``) sees the last ``window`` positions only. With
+    ``layer`` the pools are whole ([L, N_pages, Hkv, page, Dh]) and that
+    layer's pages are read."""
     B, H, Dh = q.shape
     Hkv, page = k_pool.shape[-3], k_pool.shape[-2]
     M = block_tables.shape[1]
@@ -96,11 +88,6 @@ def paged_decode_attention_ref(
     # [B, M, Hkv, page, Dh] -> [B, M*page, Hkv, Dh]
     k = k_pool[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
     v = v_pool[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, Dh)
-    if k_scale is not None:
-        ks = k_scale[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, 1)
-        vs = v_scale[owned].transpose(0, 1, 3, 2, 4).reshape(B, M * page, Hkv, 1)
-        k = k.astype(jnp.float32) * ks
-        v = v.astype(jnp.float32) * vs
     group = H // Hkv
     k = jnp.repeat(k, group, axis=2)  # [B, S, H, Dh]
     v = jnp.repeat(v, group, axis=2)
@@ -128,13 +115,12 @@ _BLOCK_TOKENS = 128
 _LANES = 128  # the running max and sum are kept a lane row wide
 
 
-def _pages_per_block(Hkv: int, page: int, Dh: int, itemsize: int,
-                     quantized: bool, M: int) -> int:
+def _pages_per_block(Hkv: int, page: int, Dh: int, itemsize: int, M: int) -> int:
     """Pages one DMA batch and one compute block hold, from the shapes the
     call sees: as many as fit :data:`_KV_VMEM_BUDGET` (two slots of K and
-    V, plus the float32 scales of an int8 pool, as wide as it), at most
-    :data:`_BLOCK_TOKENS` tokens, never more than the table is wide."""
-    page_bytes = Hkv * page * Dh * (itemsize + (4 if quantized else 0))
+    V), at most :data:`_BLOCK_TOKENS` tokens, never more than the table is
+    wide."""
+    page_bytes = Hkv * page * Dh * itemsize
     fit = _KV_VMEM_BUDGET // (4 * page_bytes)
     return max(1, min(fit, _BLOCK_TOKENS // page, M))
 
@@ -159,28 +145,27 @@ def _paged_body(
     q_ref,  # VMEM [1, Hkv, group, Dh]: this row's queries
     k_hbm,  # HBM [L, N, Hkv, page, Dh]: the whole pool, never copied or sliced
     v_hbm,
-    *rest,  # quantized: ks_hbm, vs_hbm first; then o_ref and the scratches
+    o_ref,  # VMEM [1, Hkv, group, Dh]
+    k_buf,  # VMEM [2, ppb, Hkv, page, Dh]: the double buffer
+    v_buf,
+    sem,  # DMA (2,): one a slot
+    slot_ref,  # SMEM [1]: the slot the next row starts in
+    m_scr,
+    l_scr,
+    acc_scr,
+    *,
     scale: float,
     ppb: int,
-    quantized: bool,
 ):
     """One program a row. The row's pages arrive ``ppb`` at a time by DMAs
     this kernel issues (one a page: a page is contiguous for all KV heads),
     into the slot of a double buffer that is not being computed on; the
     last block of a row starts the first block of the next row. The loop
     runs ``cdiv(seq_len, ppb * page)`` times: a row costs the pages it
-    owns. With ``quantized`` the pages arrive int8 with their per-vector
-    scales and dequantize in VMEM. With a ``window`` the loop starts at the
-    block that holds position ``seq_len - window`` and that block masks
-    below it: a row costs the pages its window covers."""
-    if quantized:
-        ks_hbm, vs_hbm, o_ref, *scratch = rest
-        k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref, m_scr, l_scr, acc_scr = scratch
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf), (vs_hbm, vs_buf))
-    else:
-        o_ref, *scratch = rest
-        k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr = scratch
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    owns. With a ``window`` the loop starts at the block that holds
+    position ``seq_len - window`` and that block masks below it: a row
+    costs the pages its window covers."""
+    streams = ((k_hbm, k_buf), (v_hbm, v_buf))
     Hkv, page, Dh = k_hbm.shape[2:]
     group = q_ref.shape[2]
     bk = ppb * page
@@ -251,8 +236,6 @@ def _paged_body(
         # zero weight times a stale NaN in V is NaN
         def clear(j, _):
             v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
-            if quantized:
-                vs_buf[slot, j] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
             return _
         jax.lax.fori_loop(n, ppb, clear, None)
 
@@ -262,9 +245,6 @@ def _paged_body(
             q = q_ref[0, h]  # [group, Dh]
             k = k_buf[slot, :, h].reshape(bk, Dh)
             v = v_buf[slot, :, h].reshape(bk, Dh)
-            if quantized:
-                k = k.astype(jnp.float32) * ks_buf[slot, :, h].reshape(bk, Dh)
-                v = v.astype(jnp.float32) * vs_buf[slot, :, h].reshape(bk, Dh)
             # products of two bf16 values are exact in the float32 the MXU
             # accumulates in, so bf16 pools go in as they are; anything
             # wider is computed in float32
@@ -318,31 +298,23 @@ def _paged_attention_call(
     seq_lens: jnp.ndarray,
     scale_v: float,
     interpret: bool,
-    k_scale: jnp.ndarray | None = None,
-    v_scale: jnp.ndarray | None = None,
     window: jnp.ndarray | None = None,
     layer: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """Shared pallas_call plumbing for both pool widths. ``window=None``
-    builds the kernel without the argument. The kernel sees whole pools
-    and a layer index: with ``layer=None`` the pools are one layer's, given
-    a leading axis of one here (a bitcast)."""
+    """The pallas_call and its plumbing. ``window=None`` builds the kernel
+    without the argument. The kernel sees whole pools and a layer index:
+    with ``layer=None`` the pools are one layer's, given a leading axis of
+    one here (a bitcast)."""
     B, H, Dh = q.shape
     Hkv, page = k_pool.shape[-3], k_pool.shape[-2]
     M = block_tables.shape[1]
     group = H // Hkv
-    quantized = k_scale is not None
-    ppb = _pages_per_block(Hkv, page, Dh, k_pool.dtype.itemsize, quantized, M)
+    ppb = _pages_per_block(Hkv, page, Dh, k_pool.dtype.itemsize, M)
 
     # [B, Hkv, group, Dh]: a program sees its row's queries by kv head
     q_t = q.reshape(B, Hkv, group, Dh)
     windowed = window is not None
     pools = [k_pool, v_pool]
-    if quantized:
-        # a scale a vector, spread over the vector's lanes: Mosaic cannot
-        # slice an HBM ref whose minor dim is narrower than a lane row, and
-        # XLA already pads a [..., 1] operand of a custom call to that size
-        pools += [jnp.broadcast_to(s, k_pool.shape) for s in (k_scale, v_scale)]
     if layer is None:
         pools, layer = [pool[None] for pool in pools], 0
     prefetch = [
@@ -351,10 +323,7 @@ def _paged_attention_call(
     ]
     if windowed:
         prefetch.append(jnp.asarray(window, jnp.int32).reshape(1))
-    kernel = functools.partial(
-        _paged_kernel, windowed=windowed, scale=scale_v, ppb=ppb,
-        quantized=quantized,
-    )
+    kernel = functools.partial(_paged_kernel, windowed=windowed, scale=scale_v, ppb=ppb)
     row_spec = pl.BlockSpec((1, Hkv, group, Dh), lambda b, *_: (b, 0, 0, 0))
     page_buffers = [
         pltpu.VMEM((2, ppb) + pool.shape[2:], pool.dtype) for pool in pools
@@ -419,47 +388,6 @@ def paged_decode_attention(
     return _paged_attention_call(
         q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET,
         window=window, layer=layer,
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode_attention_q(
-    q: jnp.ndarray,  # [B, H, Dh]
-    k_pool: jnp.ndarray,  # [N_pages, Hkv, page, Dh] int8
-    v_pool: jnp.ndarray,
-    k_scale: jnp.ndarray,  # [N_pages, Hkv, page, 1] f32
-    v_scale: jnp.ndarray,
-    block_tables: jnp.ndarray,  # [B, M] int32
-    seq_lens: jnp.ndarray,  # [B]
-    *,
-    scale: float | None = None,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Pallas paged decode attention over int8 pools (same kernel,
-    dequantizing in VMEM). A compiled call with pages below the int8
-    Mosaic tile (:data:`INT8_MIN_PAGE` sublanes) is an error — the
-    gather reference it used to drop to inverts the bandwidth win int8
-    exists for (ServingEngine validates the page size up front). The
-    pools are ONE layer's: XLA widens the ``[..., 1]`` scales to the
-    pool's shape before the call, which nobody wants done to a whole
-    pool a layer (R10)."""
-    Dh = q.shape[-1]
-    page = k_pool.shape[2]
-    scale_v = scale if scale is not None else 1.0 / math.sqrt(Dh)
-    mode = kernel_mode(interpret)
-    if mode == COMPILED and page < INT8_MIN_PAGE:
-        raise ValueError(
-            f"int8 paged attention needs page >= {INT8_MIN_PAGE} to compile "
-            f"(the int8 Mosaic tile); got page={page}"
-        )
-    if mode == REFERENCE:
-        return paged_decode_attention_ref(
-            q, k_pool, v_pool, block_tables, seq_lens,
-            scale=scale_v, k_scale=k_scale, v_scale=v_scale,
-        )
-    return _paged_attention_call(
-        q, k_pool, v_pool, block_tables, seq_lens, scale_v, mode == INTERPRET,
-        k_scale=k_scale, v_scale=v_scale,
     )
 
 

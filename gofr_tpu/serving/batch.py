@@ -60,7 +60,7 @@ def model_of(cfg: Any) -> Any:
     ``llama``'s arguments, ``step_stats_len(cfg)`` — how many int32
     counters its paged step returns after the pools (0: none) — and
     ``unserved(engine_config, lora)``, the sentence that refuses an engine
-    the model has no program for. The dense, int8 and speculative programs
+    the model has no program for. The dense and speculative programs
     call the functions ``llama`` has for them by the same names."""
     return sys.modules[type(cfg).__module__]
 
@@ -96,26 +96,6 @@ def insert_slot(
         v_cache, v_slab[:, None], (0, slot, 0, 0, 0)
     )
     return k_cache, v_cache
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def insert_slot_quantized(
-    cache: llama.KVCache,  # int8 cache (donated)
-    k_slab: jnp.ndarray,  # [L, S_bucket, Hkv, Dh] full-width prefill slab
-    v_slab: jnp.ndarray,
-    slot: jnp.ndarray,  # scalar int32
-) -> llama.KVCache:
-    """int8 twin of :func:`insert_slot`: quantize the full-width prefill
-    slabs (per-vector absmax) and scatter payload + scales into the slot
-    row of the quantized cache."""
-    kq, kscale = llama.quantize_kv(k_slab)
-    vq, vscale = llama.quantize_kv(v_slab)
-    return llama.KVCache(
-        jax.lax.dynamic_update_slice(cache.k, kq[:, None], (0, slot, 0, 0, 0)),
-        jax.lax.dynamic_update_slice(cache.v, vq[:, None], (0, slot, 0, 0, 0)),
-        jax.lax.dynamic_update_slice(cache.ks, kscale[:, None], (0, slot, 0, 0)),
-        jax.lax.dynamic_update_slice(cache.vs, vscale[:, None], (0, slot, 0, 0)),
-    )
 
 
 # ------------------------------------------------------- CPU-free hot loop
@@ -340,7 +320,7 @@ def _block_step(st: DecodeState, active, logits, params=None, lora=None):
 def decode_block(
     cfg: Any,  # a served model's config (model_of)
     params: dict,
-    cache: llama.KVCache,  # donated (bf16 or int8 dense)
+    cache: llama.KVCache,  # donated
     state: DecodeState,  # donated
     active: jnp.ndarray,  # [B] bool — rows the host dispatched this block
     steps: int,
@@ -424,41 +404,6 @@ def decode_block_paged(
     )
     packed = _append_stats(_pack_block(toks, state.done, active), stats)
     return packed, k_pool, v_pool, state
-
-
-@partial(jax.jit, static_argnums=(0, 9), donate_argnums=(2, 3, 4, 5, 6))
-def decode_block_paged_q(
-    cfg: Any,  # a served model's config (model_of)
-    params: dict,
-    k_pool: jnp.ndarray,  # int8, donated
-    v_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,  # f32 scales, donated
-    vs_pool: jnp.ndarray,
-    state: DecodeState,  # donated
-    block_tables: jnp.ndarray,
-    active: jnp.ndarray,
-    steps: int,
-    lora: tuple | None = None,  # (a_table, b_table) — heterogeneous LoRA
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
-           DecodeState]:
-    """int8 twin of :func:`decode_block_paged`."""
-
-    def step(carry, _):
-        kp, vp, ksp, vsp, st = carry
-        live = active & ~st.done
-        step_len = jnp.where(live, st.seq_len + 1, 1)
-        logits, kp, vp, ksp, vsp = model_of(cfg).decode_step_paged_q(
-            cfg, params, st.last_token, kp, vp, ksp, vsp, block_tables,
-            step_len, live,
-        )
-        st, out = _block_step(st, active, logits, params, lora)
-        return (kp, vp, ksp, vsp, st), out
-
-    (k_pool, v_pool, ks_pool, vs_pool, state), toks = jax.lax.scan(
-        step, (k_pool, v_pool, ks_pool, vs_pool, state), None, length=steps
-    )
-    packed = _pack_block(jnp.transpose(toks), state.done, active)
-    return packed, k_pool, v_pool, ks_pool, vs_pool, state
 
 
 # ------------------------------------------------- unified ragged dispatch
@@ -550,7 +495,7 @@ def _pack_ragged(toks: jnp.ndarray, done: jnp.ndarray, active: jnp.ndarray,
 def ragged_step(
     cfg: Any,  # a served model's config (model_of)
     params: dict,
-    cache: llama.KVCache,      # donated (bf16 or int8 dense)
+    cache: llama.KVCache,      # donated
     state: DecodeState,        # donated
     chunk: jnp.ndarray,        # [B, C] next prompt tokens (pad past len)
     chunk_start: jnp.ndarray,  # [B] resident length before the chunk;
@@ -654,67 +599,6 @@ def ragged_step_paged(
     return packed, last_logits, k_pool, v_pool, state
 
 
-@partial(jax.jit, static_argnums=(0, 22), donate_argnums=(2, 3, 4, 5, 6))
-def ragged_step_paged_q(
-    cfg: Any,  # a served model's config (model_of)
-    params: dict,
-    k_pool: jnp.ndarray,       # int8, donated
-    v_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,      # f32 scales, donated
-    vs_pool: jnp.ndarray,
-    state: DecodeState,        # donated
-    block_tables: jnp.ndarray,
-    chunk: jnp.ndarray,
-    chunk_start: jnp.ndarray,
-    chunk_active: jnp.ndarray,
-    kv_capacity: jnp.ndarray,
-    finish: jnp.ndarray,
-    new_len: jnp.ndarray,
-    budgets: jnp.ndarray,
-    stops: jnp.ndarray,
-    temps: jnp.ndarray,
-    topks: jnp.ndarray,
-    topps: jnp.ndarray,
-    rids: jnp.ndarray,
-    rng_root: jax.Array,
-    decode_active: jnp.ndarray,
-    steps: int,
-    adapters: jnp.ndarray | None = None,  # [B] LoRA slots for chunk rows
-    lora: tuple | None = None,  # (a_table, b_table) — never donated
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
-           jnp.ndarray, DecodeState]:
-    """int8 twin of :func:`ragged_step_paged`."""
-    logits_c, k_pool, v_pool, ks_pool, vs_pool = (
-        model_of(cfg).decode_chunk_paged_q.__wrapped__(
-            cfg, params, chunk, k_pool, v_pool, ks_pool, vs_pool,
-            block_tables, chunk_start, chunk_active, kv_capacity,
-        )
-    )
-    state, first, last_logits = _fold_finished_prefill(
-        state, logits_c, chunk, chunk_start, finish, new_len, budgets,
-        stops, temps, topks, topps, rids, rng_root, adapters, params, lora,
-    )
-
-    def step(carry, _):
-        kp, vp, ksp, vsp, st = carry
-        live = decode_active & ~st.done
-        step_len = jnp.where(live, st.seq_len + 1, 1)
-        logits, kp, vp, ksp, vsp = model_of(cfg).decode_step_paged_q(
-            cfg, params, st.last_token, kp, vp, ksp, vsp, block_tables,
-            step_len, live,
-        )
-        st, out = _block_step(st, decode_active, logits, params, lora)
-        return (kp, vp, ksp, vsp, st), out
-
-    (k_pool, v_pool, ks_pool, vs_pool, state), toks = jax.lax.scan(
-        step, (k_pool, v_pool, ks_pool, vs_pool, state), None, length=steps
-    )
-    packed = _pack_ragged(
-        jnp.transpose(toks), state.done, decode_active, first
-    )
-    return packed, last_logits, k_pool, v_pool, ks_pool, vs_pool, state
-
-
 @partial(jax.jit, donate_argnums=(0, 1))
 def insert_chunk(
     k_cache: jnp.ndarray,  # [L, B, S_max, Hkv, Dh] donated
@@ -786,7 +670,7 @@ def _accept_and_bonus(
 def verify_and_sample(
     cfg: Any,  # a served model's config (model_of)
     params: dict,
-    cache: llama.KVCache,  # donated (bf16 or int8 dense)
+    cache: llama.KVCache,  # donated
     chunk: jnp.ndarray,  # [B, T]
     start_len: jnp.ndarray,  # [B] committed length before the chunk
     temperature: jnp.ndarray,
@@ -837,41 +721,6 @@ def verify_and_sample_paged(
         [out.astype(jnp.int32), n_accept[:, None].astype(jnp.int32)], axis=1
     )
     return packed, k_pool, v_pool, rng
-
-
-@partial(jax.jit, static_argnums=0, donate_argnums=(2, 3, 4, 5))
-def verify_and_sample_paged_q(
-    cfg: Any,  # a served model's config (model_of)
-    params: dict,
-    k_pool: jnp.ndarray,  # int8, donated
-    v_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,  # f32 scales, donated
-    vs_pool: jnp.ndarray,
-    block_tables: jnp.ndarray,
-    chunk: jnp.ndarray,
-    start_len: jnp.ndarray,
-    active: jnp.ndarray,
-    kv_capacity: jnp.ndarray,
-    temperature: jnp.ndarray,
-    top_k: jnp.ndarray,
-    top_p: jnp.ndarray,
-    rng: jax.Array,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray,
-           jnp.ndarray, jax.Array]:
-    """int8-paged twin of :func:`verify_and_sample`."""
-    logits, k_pool, v_pool, ks_pool, vs_pool = (
-        model_of(cfg).decode_chunk_paged_q.__wrapped__(
-            cfg, params, chunk, k_pool, v_pool, ks_pool, vs_pool,
-            block_tables, start_len, active, kv_capacity,
-        )
-    )
-    out, n_accept, rng = _accept_and_bonus(
-        chunk, logits, temperature, top_k, top_p, rng
-    )
-    packed = jnp.concatenate(
-        [out.astype(jnp.int32), n_accept[:, None].astype(jnp.int32)], axis=1
-    )
-    return packed, k_pool, v_pool, ks_pool, vs_pool, rng
 
 
 def pad_bucket(length: int, buckets: tuple[int, ...]) -> int:

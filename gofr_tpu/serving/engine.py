@@ -40,12 +40,7 @@ from gofr_tpu.http.errors import (
 )
 from gofr_tpu.models import llama
 from gofr_tpu.native.runtime import QueueFull, Scheduler
-from gofr_tpu.ops.backend import (
-    COMPILED,
-    configure_compile_cache,
-    kernel_mode,
-    require_requested_backend,
-)
+from gofr_tpu.ops.backend import configure_compile_cache, require_requested_backend
 from gofr_tpu.serving import batch as batch_ops
 from gofr_tpu.serving.dedup import DedupEntry, DedupRegistry, ReplayGap, ReplayStream
 from gofr_tpu.serving.shed import QueueWaitEstimator
@@ -86,8 +81,9 @@ class EngineConfig:
     kv_layout: str = "dense"
     kv_page_size: int = 16
     kv_num_pages: int | None = None  # default: slots*max_seq worth of pages
-    # "int8" stores dense KV quantized (per-vector absmax; llama.KVCache):
-    # half the decode HBM stream, double the resident slots per GB
+    # the KV element type: "bf16" is the only value, checked at
+    # construction. The field exists because the benchmark's cell files
+    # name the key (ROADMAP D11)
     kv_dtype: str = "bf16"
     # decode tokens per device dispatch (dense AND paged layouts), i.e.
     # the N of the CPU-free N-step block: sampling + stop-condition
@@ -554,9 +550,12 @@ class ServingEngine:
                 "adapter gather (serve adapters from non-spec replicas)"
             )
 
-        if self.config.kv_dtype not in ("bf16", "int8"):
+        if self.config.kv_dtype != "bf16":
             raise ValueError(
-                f"TPU_KV_DTYPE={self.config.kv_dtype!r}: must be bf16 or int8"
+                f"TPU_KV_DTYPE={self.config.kv_dtype!r}: must be bf16 — the "
+                "KV cache has one element type, the model's; int8 KV left "
+                "in PR 31 (ROADMAP R10) and is refused here, not served "
+                "at twice the memory"
             )
         # the model's module (batch_ops.model_of) says what it has no
         # program for yet, in a sentence, before anything is built
@@ -607,10 +606,6 @@ class ServingEngine:
             step_token_budget=self.config.step_token_budget,
             max_admissions=self.config.admission_per_step,
         )
-        # chunk-prefix cache entries hold raw bf16 slabs; a quantized
-        # layout would re-quantize on every hit and drift — int8 engines
-        # keep only the whole-prompt (single-chunk) prefix cache
-        self._chunk_cache_enabled = self.config.kv_dtype != "int8"
         # the /requestz flight recorder: per-request lifecycle timelines,
         # stamped only with host-side data already materialized at the
         # existing sync points (docs/observability.md). Process-lifetime
@@ -2655,10 +2650,6 @@ class ServingEngine:
                     self._prefix_cache.put(cache_key, (last_logits, k_slab, v_slab))
                 if pc is not None:
                     pc.write_prefill(slot, k_slab, v_slab)
-                elif dense.quantized:
-                    self.cache = batch_ops.insert_slot_quantized(
-                        dense, k_slab, v_slab, jnp.int32(slot)
-                    )
                 else:
                     dense.k, dense.v = batch_ops.insert_slot(
                         dense.k, dense.v, k_slab, v_slab, jnp.int32(slot)
@@ -2788,7 +2779,7 @@ class ServingEngine:
             pos = 0
             cache_keys: dict[tuple[int, int], str] | None = None
             tiers: set[str] = set()
-            if self._prefix_cache is not None and self._chunk_cache_enabled:
+            if self._prefix_cache is not None:
                 boundaries = self._chunk_cache_keys(ids, req.adapter_id)
                 cache_keys = {(s, e): k for s, e, k in boundaries}
                 for start, end, key in boundaries:
@@ -3107,10 +3098,7 @@ class ServingEngine:
         # page out whole chunk-boundary spans below the resident length —
         # and strictly below the total, so the resume always computes at
         # least the final tail chunk (whose logits seed the next token).
-        # int8 layouts skip the page-out (read_span would dequantize) and
-        # simply recompute on resume — the chunk cache is off there anyway.
-        if (self._prefix_cache is not None and self._chunk_cache_enabled
-                and not req.prefill_only):
+        if self._prefix_cache is not None and not req.prefill_only:
             boundaries = self._chunk_cache_keys(ids, req.adapter_id)
             for start, end, key in boundaries:
                 if end > resident or end >= len(ids):
@@ -3220,8 +3208,8 @@ class ServingEngine:
         equality); sampled rows ride the same executable as plain steps.
         Unpipelined by design — drafting needs the newest consumed tokens,
         and the chunk already amortizes dispatch latency the way
-        multi_step does, multiplied by accepted drafts. Works on all four
-        cache layouts (dense/paged x bf16/int8); ref
+        multi_step does, multiplied by accepted drafts. Works on both
+        cache layouts (dense and paged); ref
         models/llama.py:speculative_generate for the library-level twin.
 
         Declared unpack site (kernel_contracts.UNPACK_SITES): the
@@ -3320,7 +3308,6 @@ class ServingEngine:
             t0 = time.perf_counter()
             with self._cold_dispatch(
                 "spec", "paged" if pc is not None else "dense",
-                pc.quantized if pc is not None else self.cache.quantized,
             ) as cold:
                 if pc is not None:
                     cap = np.zeros(B, np.int32)
@@ -3331,23 +3318,14 @@ class ServingEngine:
                     # restart never mutates): a retired thread's unpack must
                     # not clobber the replacement engine's state — self.*
                     # commits happen only after the retirement check below
-                    if pc.quantized:
-                        (packed, pc.k_pool, pc.v_pool, pc.ks_pool,
-                         pc.vs_pool, new_rng) = batch_ops.verify_and_sample_paged_q(
+                    (packed, pc.k_pool, pc.v_pool, new_rng) = (
+                        batch_ops.verify_and_sample_paged(
                             cfg, self.params, pc.k_pool, pc.v_pool,
-                            pc.ks_pool, pc.vs_pool, pc.tables_device(), chunk_d,
-                            start_d, self._mask_dev, cap_d,
+                            pc.tables_device(), chunk_d, start_d,
+                            self._mask_dev, cap_d,
                             temp_d, topk_d, topp_d, self.rng,
                         )
-                    else:
-                        (packed, pc.k_pool, pc.v_pool, new_rng) = (
-                            batch_ops.verify_and_sample_paged(
-                                cfg, self.params, pc.k_pool, pc.v_pool,
-                                pc.tables_device(), chunk_d, start_d,
-                                self._mask_dev, cap_d,
-                                temp_d, topk_d, topp_d, self.rng,
-                            )
-                        )
+                    )
                     new_cache = self.cache  # dense path untouched
                 else:
                     packed, new_cache, new_rng = batch_ops.verify_and_sample(
@@ -3623,26 +3601,17 @@ class ServingEngine:
                 cfg, pc, state, mask_d, chunk_rows, N)
         elif pc is not None:
             tables_d = pc.tables_device()
-            with self._cold_dispatch("decode", "paged", pc.quantized, N,
+            with self._cold_dispatch("decode", "paged", N,
                                      lora is not None) as cold:
-                if pc.quantized:
-                    (packed, pc.k_pool, pc.v_pool, pc.ks_pool, pc.vs_pool,
-                     new_state) = batch_ops.decode_block_paged_q(
-                        cfg, self.params, pc.k_pool, pc.v_pool,
-                        pc.ks_pool, pc.vs_pool, state, tables_d, mask_d, N,
-                        lora=lora,
+                (packed, pc.k_pool, pc.v_pool, new_state) = (
+                    batch_ops.decode_block_paged(
+                        cfg, self.params, pc.k_pool, pc.v_pool, state,
+                        tables_d, mask_d, N, lora=lora,
                     )
-                else:
-                    (packed, pc.k_pool, pc.v_pool, new_state) = (
-                        batch_ops.decode_block_paged(
-                            cfg, self.params, pc.k_pool, pc.v_pool, state,
-                            tables_d, mask_d, N, lora=lora,
-                        )
-                    )
+                )
             new_cache = self.cache  # dense path untouched
         else:
-            with self._cold_dispatch("decode", "dense",
-                                     self.cache.quantized, N,
+            with self._cold_dispatch("decode", "dense", N,
                                      lora is not None) as cold:
                 packed, new_cache, new_state = batch_ops.decode_block(
                     cfg, self.params, self.cache, state, mask_d, N,
@@ -3658,8 +3627,7 @@ class ServingEngine:
         # sync); otherwise drop the reference so the buffer can free
         keep_logits = (
             last_logits
-            if (prefill_rows and self._prefix_cache is not None
-                and self._chunk_cache_enabled) else None
+            if prefill_rows and self._prefix_cache is not None else None
         )
         self._blk_seq += 1
         chunk_tokens = sum(n for *_, n in chunk_rows)
@@ -3764,31 +3732,19 @@ class ServingEngine:
             tables_d = pc.tables_device()
             cactive_d = jnp.asarray(cactive)
             kvcap_d = jnp.asarray(kvcap)
-            with self._cold_dispatch("ragged", "paged", pc.quantized, N,
+            with self._cold_dispatch("ragged", "paged", N,
                                      lora is not None) as cold:
-                if pc.quantized:
-                    (packed, last_logits, pc.k_pool, pc.v_pool, pc.ks_pool,
-                     pc.vs_pool, new_state) = batch_ops.ragged_step_paged_q(
-                        cfg, self.params, pc.k_pool, pc.v_pool,
-                        pc.ks_pool, pc.vs_pool, state, tables_d, chunk_d,
-                        start_d, cactive_d, kvcap_d, finish_d, newlen_d,
-                        budgets_d, stops_d, temps_d, topks_d, topps_d,
-                        rids_d, self._rng_root, mask_d, N,
-                        adapters=adapters_d, lora=lora,
-                    )
-                else:
-                    (packed, last_logits, pc.k_pool, pc.v_pool,
-                     new_state) = batch_ops.ragged_step_paged(
-                        cfg, self.params, pc.k_pool, pc.v_pool, state,
-                        tables_d, chunk_d, start_d, cactive_d, kvcap_d,
-                        finish_d, newlen_d, budgets_d, stops_d, temps_d,
-                        topks_d, topps_d, rids_d, self._rng_root,
-                        mask_d, N, adapters=adapters_d, lora=lora,
-                    )
+                (packed, last_logits, pc.k_pool, pc.v_pool,
+                 new_state) = batch_ops.ragged_step_paged(
+                    cfg, self.params, pc.k_pool, pc.v_pool, state,
+                    tables_d, chunk_d, start_d, cactive_d, kvcap_d,
+                    finish_d, newlen_d, budgets_d, stops_d, temps_d,
+                    topks_d, topps_d, rids_d, self._rng_root,
+                    mask_d, N, adapters=adapters_d, lora=lora,
+                )
             new_cache = self.cache  # dense path untouched
         else:
-            with self._cold_dispatch("ragged", "dense",
-                                     self.cache.quantized, N,
+            with self._cold_dispatch("ragged", "dense", N,
                                      lora is not None) as cold:
                 (packed, last_logits, new_cache,
                  new_state) = batch_ops.ragged_step(
@@ -3917,7 +3873,7 @@ class ServingEngine:
                     cursor.cache_keys.get((start_pos, start_pos + n))
                     if cursor.cache_keys is not None else None
                 )
-                if (self._prefix_cache is not None and self._chunk_cache_enabled
+                if (self._prefix_cache is not None
                         and rec.last_logits is not None and put_key is not None):
                     # chunk-prefix cache PUT: the chunk's K/V just became
                     # resident — extract its slab (pure device reads, no sync;
@@ -4355,32 +4311,20 @@ class ServingEngine:
         return batch_ops.model_of(self.model_cfg).KVCache.create(
             self.model_cfg, self.config.max_slots,
             max_len=self.config.max_seq_len,
-            kv_dtype="int8" if self.config.kv_dtype == "int8" else None,
         )
 
     def _make_paged_cache(self):
         """The one paged pool constructor, shared by __init__ and the
         supervisor's warm restart so a rebuilt pool can never drift from
         the one the engine started with."""
-        from gofr_tpu.ops.paged_attention import INT8_MIN_PAGE
         from gofr_tpu.serving.kv_cache import PagedKVCache
 
         B, S = self.config.max_slots, self.config.max_seq_len
         page = self.config.kv_page_size
-        if (self.config.kv_dtype == "int8" and page < INT8_MIN_PAGE
-                and kernel_mode() == COMPILED):
-            # below the int8 Mosaic tile the kernel cannot compile (it
-            # raises); fail at construction, not at the first decode
-            raise ValueError(
-                f"TPU_KV_DTYPE=int8 with TPU_KV_LAYOUT=paged needs "
-                f"TPU_KV_PAGE_SIZE>={INT8_MIN_PAGE} on TPU (got "
-                f"{page}): smaller pages violate the int8 Mosaic tile"
-            )
         num_pages = self.config.kv_num_pages or (B * S + page - 1) // page
         return PagedKVCache(
             self.model_cfg, num_pages=num_pages, page_size=page,
             max_slots=B, max_seq_len=S,
-            kv_dtype="int8" if self.config.kv_dtype == "int8" else None,
         )
 
     def _init_runtime_state(self) -> None:

@@ -21,13 +21,13 @@ paged_decode_attention in the same module, given the whole pools and a
 layer index. Inside a decode program XLA neither slices nor writes a pool.
 
 shardcheck retrace/donation zone: the pool buffers are donated through
-every _write_pages*/decode dispatch and MUST be rebound in the same
+every _write_pages/decode dispatch and MUST be rebound in the same
 statement (``use-after-donation``, docs/static-analysis.md) — a stale
 ``self.k_pool`` read after a donating call is the round-4 on-TPU crash.
-The ``_write_pages*`` entries are declared in the kernel contract table
-(``gofr_tpu/analysis/kernel_contracts.KERNELS``) — pool/slab signatures
-and the donation sets are enforced by kernelcheck and replayed by the
-kerneltrace eval_shape matrix.
+``_write_pages`` is declared in the kernel contract table
+(``gofr_tpu/analysis/kernel_contracts.KERNELS``) — its pool/slab
+signature and donation set are enforced by kernelcheck and replayed by
+the kerneltrace eval_shape matrix.
 """
 
 from __future__ import annotations
@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from gofr_tpu import chaos
-from gofr_tpu.models.llama import quantize_kv
 from gofr_tpu.native.runtime import BlockAllocator, OutOfBlocks
 
 __all__ = ["PagedKVCache", "OutOfBlocks"]
@@ -66,35 +65,6 @@ def _write_pages(
     )
 
 
-@partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-def _write_pages_q(
-    k_pool: jnp.ndarray,  # [L, N, Hkv, page, Dh] int8, donated
-    v_pool: jnp.ndarray,
-    ks_pool: jnp.ndarray,  # [L, N, Hkv, page, 1] f32, donated
-    vs_pool: jnp.ndarray,
-    k_slab: jnp.ndarray,  # [L, S_pad, Hkv, Dh] full-width prefill slab
-    v_slab: jnp.ndarray,
-    page_ids: jnp.ndarray,  # [n_pages] int32
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """int8 twin of :func:`_write_pages`: per-vector absmax quantization
-    at the prefill scatter."""
-    L, S_pad, Hkv, Dh = k_slab.shape
-    n_pages = page_ids.shape[0]
-    page = S_pad // n_pages
-    kq, ks = quantize_kv(k_slab)  # int8 [L,S,Hkv,Dh], f32 [L,S,Hkv]
-    vq, vs = quantize_kv(v_slab)
-    k_pages = kq.reshape(L, n_pages, page, Hkv, Dh).transpose(0, 1, 3, 2, 4)
-    v_pages = vq.reshape(L, n_pages, page, Hkv, Dh).transpose(0, 1, 3, 2, 4)
-    ks_pages = ks.reshape(L, n_pages, page, Hkv, 1).transpose(0, 1, 3, 2, 4)
-    vs_pages = vs.reshape(L, n_pages, page, Hkv, 1).transpose(0, 1, 3, 2, 4)
-    return (
-        k_pool.at[:, page_ids].set(k_pages),
-        v_pool.at[:, page_ids].set(v_pages),
-        ks_pool.at[:, page_ids].set(ks_pages),
-        vs_pool.at[:, page_ids].set(vs_pages),
-    )
-
-
 class PagedKVCache:
     """Owns the device page pool + host page accounting for up to
     ``max_slots`` concurrent sequences."""
@@ -108,7 +78,6 @@ class PagedKVCache:
         max_slots: int = 8,
         max_seq_len: int = 1024,
         dtype: Any = None,
-        kv_dtype: str | None = None,
     ) -> None:
         self.cfg = cfg
         self.page_size = page_size
@@ -116,7 +85,6 @@ class PagedKVCache:
         self.max_slots = max_slots
         self.max_seq_len = max_seq_len
         self.max_pages_per_seq = (max_seq_len + page_size - 1) // page_size
-        self.quantized = kv_dtype == "int8"
         dtype = dtype or cfg.dtype
         self._pool_dtype = dtype
         self.reset_pools()
@@ -143,23 +111,14 @@ class PagedKVCache:
             cfg.n_layers, self.num_pages + 1, cfg.n_kv_heads,
             self.page_size, cfg.head_dim,
         )
-        # build every array BEFORE assigning any: a mid-rebuild failure
+        # build both arrays BEFORE assigning either: a mid-rebuild failure
         # (backend still down during recovery) must not leave a half-fresh
-        # pool set that the engine's health probe — it samples k_pool —
+        # pool pair that the engine's health probe — it samples k_pool —
         # would report healthy while v_pool is still deleted
-        if self.quantized:
-            sshape = shape[:-1] + (1,)
-            pools = (
-                jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                jnp.zeros(sshape, jnp.float32), jnp.zeros(sshape, jnp.float32),
-            )
-        else:
-            pools = (
-                jnp.zeros(shape, self._pool_dtype),
-                jnp.zeros(shape, self._pool_dtype),
-                None, None,
-            )
-        self.k_pool, self.v_pool, self.ks_pool, self.vs_pool = pools
+        self.k_pool, self.v_pool = (
+            jnp.zeros(shape, self._pool_dtype),
+            jnp.zeros(shape, self._pool_dtype),
+        )
 
     # ------------------------------------------------------------- accounting
     def alloc_slot(
@@ -307,15 +266,9 @@ class PagedKVCache:
             owned = self.allocator.block_table(seq_id)
             self.tables[slot, : len(owned)] = owned
         page_ids = jnp.asarray(owned[:n_pages], jnp.int32)
-        if self.quantized:
-            (self.k_pool, self.v_pool, self.ks_pool, self.vs_pool) = _write_pages_q(
-                self.k_pool, self.v_pool, self.ks_pool, self.vs_pool,
-                k_slab, v_slab, page_ids,
-            )
-        else:
-            self.k_pool, self.v_pool = _write_pages(
-                self.k_pool, self.v_pool, k_slab, v_slab, page_ids
-            )
+        self.k_pool, self.v_pool = _write_pages(
+            self.k_pool, self.v_pool, k_slab, v_slab, page_ids
+        )
 
     def write_span(
         self, slot: int, start: int, k_slab: jnp.ndarray, v_slab: jnp.ndarray
@@ -327,8 +280,6 @@ class PagedKVCache:
         coverage through ``alloc_slot``/``try_reserve_slot`` first. The
         slab is padded to whole pages (pad positions sit beyond
         ``seq_lens`` and are masked at read)."""
-        if self.quantized:
-            raise ValueError("write_span: int8 pools take no cached slabs")
         if start % self.page_size:
             raise ValueError(f"write_span start {start} not page-aligned")
         seq_id = self._slot_seq[slot]
@@ -358,12 +309,7 @@ class PagedKVCache:
         the chunk-prefix cache's extraction path (serving/engine.py).
         ``start`` must be page-aligned (chunk boundaries are); the gather
         is a pure device read (no sync, nothing donated) and the returned
-        slabs are fresh buffers safe to retain across later dispatches.
-        bf16 pools only: a quantized pool would have to dequantize here,
-        and re-quantizing on the next hit would drift — the engine keeps
-        chunk-prefix caching off for int8 layouts."""
-        if self.quantized:
-            raise ValueError("read_span: int8 pools are not extractable")
+        slabs are fresh buffers safe to retain across later dispatches."""
         if start % self.page_size:
             raise ValueError(f"read_span start {start} not page-aligned")
         p0 = start // self.page_size

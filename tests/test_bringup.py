@@ -21,7 +21,6 @@ import pytest
 
 from gofr_tpu.models import llama
 from gofr_tpu.ops import backend
-from gofr_tpu.ops.paged_attention import INT8_MIN_PAGE, paged_decode_attention_q
 from gofr_tpu.serving import ByteTokenizer, EngineConfig, ServingEngine
 from gofr_tpu.serving.lora import AdapterRegistry, make_adapter
 
@@ -55,22 +54,6 @@ def test_kernel_mode_raises_on_unknown_platform(monkeypatch):
         backend.kernel_mode()
     with pytest.raises(RuntimeError, match="gpu"):
         backend.kernel_mode(True)
-
-
-def test_int8_paged_kernel_raises_below_min_page():
-    page = INT8_MIN_PAGE // 2
-    q = jnp.zeros((1, 4, 128), jnp.bfloat16)
-    pool = jnp.zeros((3, 2, page, 128), jnp.int8)
-    scale = jnp.ones((3, 2, page, 1), jnp.float32)
-    tables = jnp.zeros((1, 2), jnp.int32)
-    lens = jnp.array([5], jnp.int32)
-    with pytest.raises(ValueError, match="page"):
-        paged_decode_attention_q(
-            q, pool, pool, scale, scale, tables, lens, interpret=False
-        )
-    # the reference (cpu default) and the interpreter take small pages
-    out = paged_decode_attention_q(q, pool, pool, scale, scale, tables, lens)
-    assert out.shape == q.shape
 
 
 # ------------------------------------------------------------- platform guard

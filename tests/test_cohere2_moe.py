@@ -278,11 +278,10 @@ def engine_settings(**kw):
 
 
 @pytest.mark.parametrize("settings, lora, sentence", [
-    (dict(kv_layout="dense"), None, "paged bf16 KV layout only"),
-    (dict(kv_dtype="int8", kv_page_size=32), None, "paged bf16 KV layout only"),
+    (dict(kv_layout="dense"), None, "paged KV layout only"),
     (dict(spec_tokens=2, multi_step=None), None, "no speculative verify program"),
     (dict(), object(), "serves no LoRA adapters"),
-], ids=["dense", "int8_kv", "speculative", "lora"])
+], ids=["dense", "speculative", "lora"])
 def test_engines_the_model_has_no_program_for_are_refused_at_construction(plain, settings, lora, sentence):
     with pytest.raises(ValueError, match=sentence):
         ServingEngine(CFG, plain, engine_settings(**settings), ByteTokenizer(300), lora=lora)

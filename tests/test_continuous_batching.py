@@ -414,26 +414,6 @@ def test_chunk_prefix_cache_skips_cached_chunks(engine_setup, kv_layout):
         engine.stop()
 
 
-def test_chunk_prefix_cache_stays_off_for_int8(engine_setup):
-    """int8 layouts would re-quantize cached slabs on every hit — the
-    chunk-prefix cache is gated off; chunked prefill itself still works."""
-    cfg, params = engine_setup
-    engine = make_engine(
-        cfg, params, prefix_cache_entries=64,
-        kv_layout="paged", kv_page_size=16, kv_dtype="int8",
-    )
-    engine.start()
-    try:
-        prompt = "int8 prefix " * 6
-        r1 = engine.submit(prompt, max_new_tokens=3, temperature=0.0).result(timeout=300)
-        r2 = engine.submit(prompt, max_new_tokens=3, temperature=0.0).result(timeout=300)
-        assert r1.token_ids == r2.token_ids
-        t2 = engine.timeline.get(r2.request_id)
-        assert all(not c["prefix_hit"] for c in t2.prefill_chunks)
-    finally:
-        engine.stop()
-
-
 # -- config knobs -------------------------------------------------------------
 
 def test_continuous_batching_knobs_from_config():

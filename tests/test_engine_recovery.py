@@ -62,24 +62,26 @@ def test_cpu_enforces_donation():
         _ = a[0]
 
 
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_decode_failure_after_donation_recovers(monkeypatch, kv_dtype):
-    """A decode dispatch that deletes its donated cache and then raises
-    (transport failure after donation committed) fails the in-flight
-    requests but leaves the engine servable: the recovery path detects the
-    deleted KV storage and rebuilds it."""
-    eng = make_engine(kv_dtype=kv_dtype, multi_step=2)
-    real_block = batch_ops.decode_block
+@pytest.mark.parametrize("kv_layout, entry, n_donated", [
+    ("dense", "decode_block", 1), ("paged", "decode_block_paged", 2),
+], ids=["dense", "paged"])
+def test_decode_failure_after_donation_recovers(monkeypatch, kv_layout, entry, n_donated):
+    """A decode dispatch that deletes its donated cache (dense) or its two
+    donated pools (paged) and then raises (transport failure after donation
+    committed) fails the in-flight requests but leaves the engine servable:
+    the recovery path detects the deleted KV storage and rebuilds it."""
+    eng = make_engine(kv_layout=kv_layout, kv_page_size=8, multi_step=2)
+    real_block = getattr(batch_ops, entry)
     boom = {"n": 0}
 
-    def wrapper(cfg, params, cache, *args, **kw):
+    def wrapper(cfg, params, *args, **kw):
         if boom["n"] == 0:
             boom["n"] += 1
-            _delete_leaves(cache)
+            _delete_leaves(args[:n_donated])
             raise RuntimeError("transient transport failure post-donation")
-        return real_block(cfg, params, cache, *args, **kw)
+        return real_block(cfg, params, *args, **kw)
 
-    monkeypatch.setattr(batch_ops, "decode_block", wrapper)
+    monkeypatch.setattr(batch_ops, entry, wrapper)
     eng.start()
     try:
         fut = eng.submit("hello world", max_new_tokens=8, temperature=0.0)
@@ -104,18 +106,18 @@ def test_prefill_failure_after_donation_recovers(monkeypatch):
     """The prefill insert donates the SHARED cache; when it dies post-
     donation the per-request error handling must escalate to full recovery
     (isolated cleanup would leave every later step raising)."""
-    eng = make_engine(kv_dtype="int8")
-    real = batch_ops.insert_slot_quantized
+    eng = make_engine()
+    real = batch_ops.insert_slot
     boom = {"n": 0}
 
-    def wrapper(cache, *args, **kw):
+    def wrapper(k_cache, v_cache, *args, **kw):
         if boom["n"] == 0:
             boom["n"] += 1
-            _delete_leaves(cache)
+            _delete_leaves((k_cache, v_cache))
             raise RuntimeError("transient transport failure post-donation")
-        return real(cache, *args, **kw)
+        return real(k_cache, v_cache, *args, **kw)
 
-    monkeypatch.setattr(batch_ops, "insert_slot_quantized", wrapper)
+    monkeypatch.setattr(batch_ops, "insert_slot", wrapper)
     eng.start()
     try:
         fut = eng.submit("doomed", max_new_tokens=4, temperature=0.0)
@@ -197,15 +199,15 @@ def test_block_output_survives_donated_carry_redispatch():
     assert np.asarray(packed_k1).shape == (2, 6)
 
 
-@pytest.mark.parametrize("kv_dtype,multi_step", [("bf16", 1), ("int8", 4)])
-def test_donation_discipline_under_churn(kv_dtype, multi_step):
+@pytest.mark.parametrize("multi_step", [1, 4])
+def test_donation_discipline_under_churn(multi_step):
     """Bench-shaped churn (mixed lengths, cancels, slot reuse) on the CPU
     backend, where donated buffers really are deleted: any use-after-donate
     in the dispatch/consume pipeline raises here."""
     import concurrent.futures as cf
 
     eng = make_engine(
-        kv_dtype=kv_dtype, multi_step=multi_step, max_slots=4,
+        multi_step=multi_step, max_slots=4,
         admission_per_step=4, max_queue=64,
     )
     eng.start()

@@ -139,26 +139,6 @@ def test_engine_spill_round_trip_serves_from_host_tier(engine_setup, kv_layout):
         engine.stop()
 
 
-def test_engine_spill_stays_off_for_int8(engine_setup):
-    """int8 pools keep the chunk cache (and so the spill of chunk slabs)
-    off — the tier composes with the existing gating, no new path."""
-    cfg, params = engine_setup
-    engine = make_engine(
-        cfg, params, prefix_cache_entries=4, kv_spill_bytes=1 << 24,
-        kv_layout="paged", kv_page_size=16, kv_dtype="int8",
-    )
-    engine.start()
-    try:
-        prompt = "int8 spill gate " * 4
-        r1 = engine.submit(prompt, max_new_tokens=3, temperature=0.0).result(timeout=300)
-        r2 = engine.submit(prompt, max_new_tokens=3, temperature=0.0).result(timeout=300)
-        assert r1.token_ids == r2.token_ids
-        t2 = engine.timeline.get(r2.request_id)
-        assert all(not c["prefix_hit"] for c in t2.prefill_chunks)
-    finally:
-        engine.stop()
-
-
 # -- distributed index: gossip idempotency -------------------------------------
 
 def test_index_observe_is_seq_idempotent_under_redelivery_and_reorder():

@@ -2,6 +2,8 @@
 Tiny configs on CPU (conftest forces JAX_PLATFORMS=cpu, 8 virtual devices).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -136,3 +138,25 @@ def test_decode_loop_matches_stepwise(tiny_llama):
     )
     got = jnp.concatenate([first[:, None], toks], axis=1)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(oracle))
+
+
+def test_kv_cache_is_a_two_leaf_pytree_that_survives_a_donated_jit():
+    """``KVCache`` is registered as a plain dataclass pytree: k and v, in
+    that order, nothing else — flatten/unflatten rebuild it, and a jit
+    that donates it returns the same class with shapes and dtype kept."""
+    cfg = llama.LlamaConfig.tiny()
+    cache = llama.KVCache.create(cfg, 2, max_len=16)
+    shape = (cfg.n_layers, 2, 16, cfg.n_kv_heads, cfg.head_dim)
+    leaves, treedef = jax.tree_util.tree_flatten(cache)
+    assert [f.name for f in dataclasses.fields(cache)] == ["k", "v"]
+    assert len(leaves) == 2 and leaves[0] is cache.k and leaves[1] is cache.v
+    back = jax.tree_util.tree_unflatten(treedef, leaves)
+    assert isinstance(back, llama.KVCache) and back.k is cache.k and back.v is cache.v
+    assert cache.max_len == 16
+
+    bump = jax.jit(lambda c: jax.tree.map(lambda x: x + 1, c), donate_argnums=0)
+    out = bump(cache)
+    assert isinstance(out, llama.KVCache)
+    assert out.k.shape == out.v.shape == shape and out.k.dtype == out.v.dtype == cfg.dtype
+    assert cache.k.is_deleted() and cache.v.is_deleted()  # donated, both leaves
+    assert float(out.k[0, 0, 0, 0, 0]) == 1.0 and float(out.v[-1, -1, -1, -1, -1]) == 1.0

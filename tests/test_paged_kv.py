@@ -76,15 +76,12 @@ class TestPagedAttentionOps:
                                    rtol=2e-5, atol=2e-5)
 
 
-def _paged_case(heads, pool, max_len, seed=0):
+def _paged_case(heads, max_len, seed=0):
     """Served types at a small width: bf16 queries over a bf16 pool of
-    16-token pages or an int8 pool (with scales) of 32-token pages, tables
-    ``max_len`` tokens wide. Returns (page, args for the entry up to the
-    tables, kwargs for the reference)."""
-    from gofr_tpu.models.llama import quantize_kv
-
+    16-token pages, tables ``max_len`` tokens wide. Returns (page, q,
+    one layer's k and v pools, tables)."""
     Hkv, group = heads
-    page = {"bf16": 16, "int8": 32}[pool]
+    page = 16
     Dh, B, M = 32, 8, max_len // page
     N = B * M + 1
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -94,32 +91,25 @@ def _paged_case(heads, pool, max_len, seed=0):
     tables = jnp.asarray(
         np.random.default_rng(seed).permutation(N - 1).reshape(B, M), jnp.int32
     )
-    if pool == "bf16":
-        return page, (q, k_pool, v_pool, tables), {}
-    k_q, ks = quantize_kv(k_pool)
-    v_q, vs = quantize_kv(v_pool)
-    ks, vs = ks[..., None], vs[..., None]
-    return page, (q, k_q, v_q, ks, vs, tables), {"k_scale": ks, "v_scale": vs}
+    return page, q, k_pool, v_pool, tables
 
 
 class TestPagedKernelShapes:
     """The loop kernel against the gather reference over both head classes
-    (grouped: Hkv 8 x 4 queries; full multi-head: Hkv 4 x 1) and both pool
-    kinds, each with rows that end everywhere a block can end."""
+    (grouped: Hkv 8 x 4 queries; full multi-head: Hkv 4 x 1) and both forms
+    of the call, each with rows that end everywhere a block can end."""
 
-    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    @pytest.mark.parametrize("form", ["one_layer", "whole_pools"])
     @pytest.mark.parametrize("heads", [(8, 4), (4, 1)], ids=["gqa8x4", "mha4x1"])
-    def test_kernel_matches_ref_ragged(self, heads, pool):
-        from gofr_tpu.ops.paged_attention import (
-            _pages_per_block,
-            paged_decode_attention_q,
-        )
+    def test_kernel_matches_ref_ragged(self, heads, form):
+        """``one_layer``: a layer's pool and no layer index. ``whole_pools``:
+        as the decode step calls it — the pools of two layers, the other
+        one NaN, and a traced layer index."""
+        from gofr_tpu.ops.paged_attention import _pages_per_block
 
-        page, args, ref_kw = _paged_case(heads, pool, max_len=320)
-        M = args[-1].shape[1]
-        block = page * _pages_per_block(
-            heads[0], page, 32, args[1].dtype.itemsize, pool == "int8", M
-        )
+        page, q, k_pool, v_pool, tables = _paged_case(heads, max_len=320)
+        M = tables.shape[1]
+        block = page * _pages_per_block(heads[0], page, 32, k_pool.dtype.itemsize, M)
         assert block == 128 and M * page > 2 * block  # several blocks a row
         # an empty slot, one page exactly, a page boundary - 1 and + 1, a
         # block boundary and + 1, a length no multiple of the block, the
@@ -127,9 +117,18 @@ class TestPagedKernelShapes:
         seq_lens = jnp.array(
             [1, page, page - 1, page + 1, block, block + 1, 200, M * page], jnp.int32
         )
-        ref = paged_decode_attention_ref(*args[:3], args[-1], seq_lens, **ref_kw)
-        entry = paged_decode_attention if pool == "bf16" else paged_decode_attention_q
-        out = entry(*args, seq_lens, interpret=True)
+        ref = paged_decode_attention_ref(q, k_pool, v_pool, tables, seq_lens)
+        if form == "one_layer":
+            out = paged_decode_attention(q, k_pool, v_pool, tables, seq_lens, interpret=True)
+        else:
+            kw = jnp.stack([jnp.full_like(k_pool, jnp.nan), k_pool])
+            vw = jnp.stack([jnp.full_like(v_pool, jnp.nan), v_pool])
+            out = jax.jit(
+                lambda layer: paged_decode_attention(
+                    q, kw, vw, tables, seq_lens, interpret=True, layer=layer)
+            )(jnp.int32(1))
+            by_ref = paged_decode_attention_ref(q, kw, vw, tables, seq_lens, layer=1)
+            assert jnp.array_equal(by_ref, ref)  # the reference reads that layer too
         assert out.dtype == ref.dtype == jnp.bfloat16
         # two roundings of a bf16 result (8 bits) of magnitude < 4
         np.testing.assert_allclose(
@@ -331,87 +330,34 @@ class TestPagedDecodeParity:
         cache.close()
 
 
-# ---------------------------------------------------------------- int8 pools
-def test_paged_attention_q_matches_ref_dequant():
-    """Kernel (interpret) vs reference on int8 pools with scales."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+# ------------------------------------------------------ the device pools
+def test_cache_holds_exactly_two_device_pools_and_leaves_none_behind():
+    """A ``PagedKVCache`` keeps two device arrays, ``k_pool`` and ``v_pool``
+    in the model's dtype, and nothing else on the device: ``reset_pools()``
+    replaces the pair (the old one is freed), and once the cache is closed
+    and dropped no pool-shaped array is live."""
+    import gc
 
-    from gofr_tpu.models.llama import quantize_kv
-    from gofr_tpu.ops.paged_attention import (
-        paged_decode_attention_q,
-        paged_decode_attention_ref,
-    )
+    cfg = llama.LlamaConfig.tiny()
+    # a shape no other test of this file makes, so live_arrays() can be read
+    shape = (cfg.n_layers, 13 + 1, cfg.n_kv_heads, 8, cfg.head_dim)
 
-    B, H, Hkv, Dh, page, N, M = 2, 4, 2, 16, 8, 6, 3
-    key = jax.random.PRNGKey(0)
-    kf = jax.random.normal(key, (N, Hkv, page, Dh), jnp.float32)
-    vf = jax.random.normal(jax.random.PRNGKey(1), (N, Hkv, page, Dh), jnp.float32)
-    kq, ks = quantize_kv(kf)
-    vq, vs = quantize_kv(vf)
-    ks = ks[..., None]
-    vs = vs[..., None]
-    q = jax.random.normal(jax.random.PRNGKey(2), (B, H, Dh), jnp.float32)
-    tables = jnp.array([[0, 2, 4], [1, 3, 5]], jnp.int32)
-    seq_lens = jnp.array([19, 8], jnp.int32)
+    def live_pools():
+        gc.collect()
+        return [a for a in jax.live_arrays() if a.shape == shape]
 
-    ref = paged_decode_attention_ref(
-        q, kq, vq, tables, seq_lens, k_scale=ks, v_scale=vs
-    )
-    out = paged_decode_attention_q(
-        q, kq, vq, ks, vs, tables, seq_lens, interpret=True
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_paged_int8_engine_matches_prefill_and_is_deterministic():
-    """Paged int8 engine: first (prefill-path) token matches the bf16
-    paged engine; generation fully deterministic."""
-    import jax
-
-    from gofr_tpu.models import llama
-    from gofr_tpu.serving import ByteTokenizer, EngineConfig, ServingEngine
-
-    cfg = llama.LlamaConfig.tiny(vocab_size=300)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-
-    def mk(kv_dtype):
-        return ServingEngine(
-            cfg, params,
-            EngineConfig(max_slots=4, max_seq_len=64, prefill_buckets=(16, 32),
-                         kv_layout="paged", kv_page_size=8, kv_dtype=kv_dtype),
-            ByteTokenizer(),
-        )
-
-    ref, q = mk("bf16"), mk("int8")
-    assert q.paged_cache.quantized and not ref.paged_cache.quantized
-    ref.start(), q.start()
-    try:
-        for prompt in ("paged int8", "zz"):
-            a = ref.submit(prompt, max_new_tokens=6, temperature=0.0).result(timeout=120)
-            b = q.submit(prompt, max_new_tokens=6, temperature=0.0).result(timeout=120)
-            assert b.token_ids[0] == a.token_ids[0]
-            b2 = q.submit(prompt, max_new_tokens=6, temperature=0.0).result(timeout=120)
-            assert b2.token_ids == b.token_ids
-    finally:
-        ref.stop(), q.stop()
-
-
-def test_paged_int8_pool_memory_halves():
-    import jax.numpy as jnp
-
-    from gofr_tpu.models import llama
-    from gofr_tpu.serving.kv_cache import PagedKVCache
-
-    cfg = llama.LlamaConfig.tiny(dtype=jnp.bfloat16)
-    full = PagedKVCache(cfg, num_pages=16, page_size=8, max_slots=4, max_seq_len=64)
-    quant = PagedKVCache(cfg, num_pages=16, page_size=8, max_slots=4,
-                         max_seq_len=64, kv_dtype="int8")
-    full_bytes = full.k_pool.nbytes + full.v_pool.nbytes
-    quant_bytes = (quant.k_pool.nbytes + quant.v_pool.nbytes
-                   + quant.ks_pool.nbytes + quant.vs_pool.nbytes)
-    ratio = (cfg.head_dim + 4) / (2 * cfg.head_dim)
-    assert quant_bytes <= ratio * full_bytes + 1
-    full.close()
-    quant.close()
+    assert live_pools() == []
+    cache = PagedKVCache(cfg, num_pages=13, page_size=8, max_slots=2, max_seq_len=64)
+    on_device = {n for n, v in vars(cache).items() if isinstance(v, jax.Array)}
+    assert on_device == {"k_pool", "v_pool"}
+    assert cache.k_pool.shape == cache.v_pool.shape == shape
+    assert cache.k_pool.dtype == cache.v_pool.dtype == cfg.dtype
+    assert len(live_pools()) == 2
+    old = (cache.k_pool, cache.v_pool)
+    cache.reset_pools()
+    assert cache.k_pool is not old[0] and cache.v_pool is not old[1]
+    del old
+    assert len(live_pools()) == 2  # the pair it replaced is gone
+    cache.close()
+    del cache
+    assert live_pools() == []

@@ -248,7 +248,7 @@ def test_engine_config_reads_every_knob():
         "TPU_KV_LAYOUT": "paged",
         "TPU_KV_PAGE_SIZE": "32",
         "TPU_KV_NUM_PAGES": "123",
-        "TPU_KV_DTYPE": "int8",
+        "TPU_KV_DTYPE": "bf16",
         "TPU_BATCH_MULTI_STEP": "4",
         "TPU_DECODE_SYNC_EVERY": "2",
     }, use_env=False))
@@ -265,7 +265,7 @@ def test_engine_config_reads_every_knob():
     assert cfg.kv_layout == "paged"
     assert cfg.kv_page_size == 32
     assert cfg.kv_num_pages == 123
-    assert cfg.kv_dtype == "int8"
+    assert cfg.kv_dtype == "bf16"
     assert cfg.multi_step == 4
     assert cfg.decode_sync_every == 2
     # unset → None → the engine resolves the CPU-free default block (4)
@@ -274,31 +274,44 @@ def test_engine_config_reads_every_knob():
     assert EngineConfig.from_config(_MC({}, use_env=False)).multi_step is None
 
 
-def test_engine_int8_kv_dense_matches_bf16(engine_setup):
-    """Dense int8-KV engine (TPU_KV_DTYPE=int8): the prefill-path first
-    token matches bf16 exactly and generation is fully deterministic.
-    (Decode-path int8 accuracy is pinned by the teacher-forced logit
-    bounds in test_llama_quant.py — free-running greedy comparison on a
-    random tiny model measures trajectory divergence, not KV error.)"""
+def _int8_from_field():
+    return EngineConfig(max_slots=2, max_seq_len=32, kv_dtype="int8")
+
+
+def _int8_from_env(**kw):
+    from gofr_tpu.config import MapConfig
+
+    settings = {"TPU_BATCH_MAX_SLOTS": "2", "TPU_BATCH_MAX_TOKENS": "32", "TPU_KV_DTYPE": "int8"}
+    if kw:
+        settings.update({"TPU_KV_LAYOUT": kw["kv_layout"], "TPU_KV_PAGE_SIZE": str(kw["kv_page_size"])})
+    return EngineConfig.from_config(MapConfig(settings, use_env=False))
+
+
+@pytest.mark.parametrize("make, settings", [
+    (_int8_from_field, {}),
+    (_int8_from_env, {}),
+    (_int8_from_env, dict(kv_layout="paged", kv_page_size=32)),
+], ids=["field", "env", "env_paged"])
+def test_int8_kv_is_refused_at_construction(engine_setup, monkeypatch, make, settings):
+    """The KV cache has one element type. ``kv_dtype`` / ``TPU_KV_DTYPE``
+    other than bf16 is an operator's input and is refused with a sentence
+    before any cache is built — never served, silently, at twice the
+    memory the operator sized for."""
+    from gofr_tpu.serving import kv_cache
+
+    def no_pool(*a, **kw):
+        raise AssertionError("a KV cache was allocated for a refused engine")
+
+    monkeypatch.setattr(llama.KVCache, "create", no_pool)
+    monkeypatch.setattr(kv_cache.PagedKVCache, "reset_pools", no_pool)
     cfg, params = engine_setup
-    ref = make_engine(cfg, params, kv_dtype="bf16")
-    q = make_engine(cfg, params, kv_dtype="int8")
-    assert q.cache.quantized and not ref.cache.quantized
-    ref.start(), q.start()
-    try:
-        for prompt in ("hello int8 kv", "b"):
-            a = ref.submit(prompt, max_new_tokens=6, temperature=0.0).result(timeout=120)
-            b = q.submit(prompt, max_new_tokens=6, temperature=0.0).result(timeout=120)
-            # the FIRST token comes from full-width prefill compute and
-            # must match exactly; later greedy tokens may flip at the
-            # near-ties of a random tiny model (the teacher-forced logit
-            # bound lives in test_llama_quant) — instead require the
-            # int8 engine to be fully deterministic
-            assert b.token_ids[0] == a.token_ids[0]
-            b2 = q.submit(prompt, max_new_tokens=6, temperature=0.0).result(timeout=120)
-            assert b2.token_ids == b.token_ids
-    finally:
-        ref.stop(), q.stop()
+    engine_config = make(**settings)
+    assert engine_config.kv_dtype == "int8"  # the field carries what was set
+    with pytest.raises(ValueError, match=r"TPU_KV_DTYPE='int8': must be bf16"):
+        ServingEngine(cfg, params, engine_config, ByteTokenizer())
+    monkeypatch.undo()
+    built = make_engine(cfg, params, kv_dtype="bf16")  # the one value builds as before
+    assert built.cache.k.dtype == cfg.dtype and built.paged_cache is None
 
 
 def test_engine_multi_step_matches_single(engine_setup):

@@ -186,22 +186,3 @@ def test_tp_engine_http_grpc_load(tp_setup, run_async):
     finally:
         app.stop()
         thread.join(timeout=15)
-
-
-def test_tp_engine_paged_int8(tp_setup):
-    """The full composition: tensor-parallel sharded weights × paged KV ×
-    int8 quantized pools on the 8-device mesh. First (prefill-path)
-    token matches the unsharded bf16 engine; generation deterministic."""
-    cfg, params, sharded, _ = tp_setup
-    ref = _make_engine(cfg, params)
-    tp_q = _make_engine(cfg, sharded, kv_layout="paged", kv_page_size=8,
-                        kv_dtype="int8")
-    ref.start(), tp_q.start()
-    try:
-        a = ref.submit("tp int8 paged", max_new_tokens=6, temperature=0.0).result(timeout=240)
-        b = tp_q.submit("tp int8 paged", max_new_tokens=6, temperature=0.0).result(timeout=240)
-        assert b.token_ids[0] == a.token_ids[0]
-        b2 = tp_q.submit("tp int8 paged", max_new_tokens=6, temperature=0.0).result(timeout=240)
-        assert b2.token_ids == b.token_ids
-    finally:
-        ref.stop(), tp_q.stop()
