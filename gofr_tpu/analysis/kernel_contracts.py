@@ -290,6 +290,13 @@ KERNELS: tuple[KernelContract, ...] = (
         params=("embedding", "a_row", "b_row", "token", "logits"),
         returns=(Ret("logits", like="logits"),),
     ),
+    # an admission's first token on the host path: ops/sampling.sample_logits
+    # as one program, the request's three sampling parameters as scalars
+    KernelContract(
+        "sample_first_token", _BATCH,
+        params=("logits", "key", "temperature", "top_k", "top_p"),
+        returns=(Ret("ids", shape="1", dtype="int32"),),
+    ),
     KernelContract(
         "_write_pages", _KVC,
         params=("k_pool", "v_pool", "k_slab", "v_slab", "page_ids"),

@@ -235,6 +235,11 @@ def run_matrix() -> dict:
         b_row=sds((RANK, V), jnp.float32),
         token=sds((), jnp.int32), logits=sds((1, V), jnp.float32))
 
+    add("sample_first_token", "first", unwrap("sample_first_token"),
+        logits=sds((1, V), jnp.float32), key=key,
+        temperature=sds((), jnp.float32), top_k=sds((), jnp.int32),
+        top_p=sds((), jnp.float32))
+
     cases.append(_eval_case(
         kvc_mod._write_pages.__wrapped__, C["_write_pages"], "paged",
         {

@@ -210,7 +210,7 @@ HOST_SYNC_METHODS = {"block_until_ready", "item"}
 # results are HOST values — materialization is the flagged sync itself,
 # so converting them afterwards is clean.
 DEVICE_PRODUCER_ROOTS = {"jnp", "jax", "batch_ops"}
-DEVICE_PRODUCER_NAMES = {"sample_logits", "prefill_compute"}
+DEVICE_PRODUCER_NAMES = {"sample_logits", "sample_first_token", "prefill_compute"}
 DEVICE_NAME_SUFFIXES = ("_dev", "_device")
 HOST_CONVERT_CALLS = {"int", "float", "bool"}
 

@@ -219,6 +219,12 @@ class Container:
             "kind=decode|prefill|padding)",
         )
         m.new_counter(
+            "app_sampler_steps_total",
+            "Sampling steps issued, by the path of ops.sampling.sample_logits "
+            "the rows' parameters select: a block's decode steps, and one "
+            "for an admission's first token (label path=greedy|sample|filter)",
+        )
+        m.new_counter(
             "app_moe_expert_rows_total",
             "Rows routed to each routed expert this replica holds, over "
             "decode steps and layers, read with each block's tokens (label "

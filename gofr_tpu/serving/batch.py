@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from gofr_tpu.models import llama
-from gofr_tpu.ops.sampling import sample_logits, stop_eval
+from gofr_tpu.ops.sampling import sample_logits, sampler_path, stop_eval
 
 
 def model_of(cfg: Any) -> Any:
@@ -289,6 +289,23 @@ def lora_adjust_logits(
     return logits + (h @ b_row.astype(jnp.float32))[None]
 
 
+@jax.jit
+def sample_first_token(
+    logits: jnp.ndarray,       # [1, V] last-position logits (LoRA-adjusted)
+    key: jax.Array,            # fold_in(rng_root, request id)
+    temperature: jnp.ndarray,  # scalar f32
+    top_k: jnp.ndarray,        # scalar i32
+    top_p: jnp.ndarray,        # scalar f32
+) -> jnp.ndarray:
+    """An admission's first token on the HOST-path sampling sites (bucketed
+    prefill, whole-prompt chunk-prefix-cache hits): ``sample_logits`` as ONE
+    program where the eager call queued some forty, which for a greedy
+    request runs the argmax branch alone. Returns ids [1]; no sync."""
+    return sample_logits(
+        logits, key, temperature=temperature, top_k=top_k, top_p=top_p
+    )
+
+
 def _block_step(st: DecodeState, active, logits, params=None, lora=None):
     """Shared per-step tail of every decode_block* scan body: apply the
     per-row LoRA delta (heterogeneous-adapter batching, serving/lora.py),
@@ -300,8 +317,11 @@ def _block_step(st: DecodeState, active, logits, params=None, lora=None):
     # same token's low-rank bypass, gathered by the row's adapter slot
     logits = _lora_logits(params, lora, st.last_token, st.adapter, logits)
     rng, key = jax.random.split(st.rng)
+    # only the live rows choose the sampler's path: a slot that never held
+    # a request carries temperature 1.0, a retired one its last request's
     nxt = sample_logits(
-        logits, key, temperature=st.temperature, top_k=st.top_k, top_p=st.top_p
+        logits, key, temperature=st.temperature, top_k=st.top_k,
+        top_p=st.top_p, rows=live,
     )
     nxt = jnp.where(live, nxt, st.last_token)
     done = st.done | (live & stop_eval(nxt, st.stop_tok, st.budget))
@@ -463,7 +483,14 @@ def _fold_finished_prefill(
             lg[None], key, temperature=t, top_k=tk, top_p=tp
         )[0]
 
-    sampled = jax.vmap(sample_one)(sample_from, keys, temps, topks, topps)
+    # under vmap the sampler's branches would all run (a cond of a batched
+    # predicate is a select), so the choice is made here, over the rows
+    # that finish: the argmax alone unless one of them samples
+    sampled = jax.lax.cond(
+        sampler_path(temps, topks, topps, finish) > 0,
+        lambda: jax.vmap(sample_one)(sample_from, keys, temps, topks, topps),
+        lambda: jnp.argmax(sample_from.astype(jnp.float32), axis=-1),
+    )
     done_f = (sampled == stops) | (budgets <= 0)
     st = DecodeState(
         jnp.where(finish, sampled, st.last_token),

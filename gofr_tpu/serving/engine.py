@@ -41,6 +41,7 @@ from gofr_tpu.http.errors import (
 from gofr_tpu.models import llama
 from gofr_tpu.native.runtime import QueueFull, Scheduler
 from gofr_tpu.ops.backend import configure_compile_cache, require_requested_backend
+from gofr_tpu.ops.sampling import SAMPLER_PATHS, sampler_path
 from gofr_tpu.serving import batch as batch_ops
 from gofr_tpu.serving.dedup import DedupEntry, DedupRegistry, ReplayGap, ReplayStream
 from gofr_tpu.serving.shed import QueueWaitEstimator
@@ -2455,6 +2456,22 @@ class ServingEngine:
             jnp.int32(last_token), last_logits,
         )
 
+    def _sample_first(self, phase: _StepPhase, req: _Request,
+                      last_logits: Any, last_token: int) -> Any:
+        """An admission's first token on the host path (bucketed prefill,
+        whole-prompt chunk-prefix hit): the request's own parameters, the
+        key folded from its id, one program (batch_ops.sample_first_token)
+        and no sync — the caller reads ids [1] under ``prefill_sync``.
+        ``phase``, the admission's gofr.step.prefill, gets the sampler's
+        path."""
+        key = jax.random.fold_in(self._rng_root, req.id)
+        sampling = (np.float32(req.temperature), np.int32(req.top_k),
+                    np.float32(req.top_p))
+        self._count_sampler(phase, *sampling, steps=1)
+        return batch_ops.sample_first_token(
+            self._lora_adjusted(req, last_logits, last_token), key, *sampling
+        )
+
     def _lora_release(self, req: _Request) -> None:
         """Unpin a row's adapter-table slot (no-op for base rows). Every
         path that takes a row out of the batch — retire, requeue,
@@ -2659,22 +2676,11 @@ class ServingEngine:
                 # The row's LoRA delta applies HERE, at the sampling site —
                 # cached entries stay base-model logits (adapter-scoped keys
                 # already make cross-adapter hits impossible).
-                key = jax.random.fold_in(self._rng_root, req.id)
-                from gofr_tpu.ops.sampling import sample_logits
-
-                first = sample_logits(
-                    self._lora_adjusted(req, last_logits, ids[-1]), key,
-                    temperature=jnp.float32(req.temperature),
-                    top_k=jnp.int32(req.top_k),
-                    top_p=jnp.float32(req.top_p),
-                )
+                first = self._sample_first(phase, req, last_logits, ids[-1])
                 # the engine thread's other read of the device, after
                 # the block's one sync: it returns when the block in
-                # flight, this prefill and the sampler's programs have
-                # run, and no next block is queued meanwhile. (The wait
-                # itself can come earlier in this prefill, where the eager
-                # sampler queues its programs behind the running block:
-                # PERF.md §5.)
+                # flight, this prefill and the sampler's one program have
+                # run, and no next block is queued meanwhile.
                 with self._phase("prefill_sync", rid=req.id):
                     first_id = int(first[0])
 
@@ -2903,15 +2909,7 @@ class ServingEngine:
                         )
                 with span:
                     last_logits = hits[-1][2][0]
-                    key = jax.random.fold_in(self._rng_root, req.id)
-                    from gofr_tpu.ops.sampling import sample_logits
-
-                    first = sample_logits(
-                        self._lora_adjusted(req, last_logits, ids[-1]), key,
-                        temperature=jnp.float32(req.temperature),
-                        top_k=jnp.int32(req.top_k),
-                        top_p=jnp.float32(req.top_p),
-                    )
+                    first = self._sample_first(phase, req, last_logits, ids[-1])
                     with self._phase("prefill_sync", rid=req.id):
                         first_id = int(first[0])
                 self._check_retired()
@@ -3640,6 +3638,8 @@ class ServingEngine:
         window = getattr(cfg, "sliding_window", None)
         if window:  # rows whose window layers no longer see their first key
             span.set(win_rows=int((self.cache_len[mask] > window).sum()))
+        self._count_sampler(span, self.temperature[mask], self.top_k[mask],
+                            self.top_p[mask], steps=N)
         self._count_step_tokens(
             len(rows) * N, chunk_tokens,
             self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),
@@ -3661,6 +3661,19 @@ class ServingEngine:
                 if n:
                     self._metrics.add_counter(
                         "app_moe_expert_rows_total", n, expert=str(first + e))
+
+    def _count_sampler(self, span: _StepPhase, temperature: Any, top_k: Any,
+                       top_p: Any, *, steps: int) -> None:
+        """Which path of ``ops.sampling.sample_logits`` the rows' sampling
+        parameters select, as ``sampler=greedy|sample|filter`` on ``span``
+        and ``steps`` more in app_sampler_steps_total{path}: the host
+        mirrors under the predicate the program branches on (a row that
+        stopped on the device inside a block not yet read back still
+        counts here, so the span can read dearer than what ran)."""
+        path = SAMPLER_PATHS[int(sampler_path(temperature, top_k, top_p))]
+        span.set(sampler=path)
+        if self._metrics:
+            self._metrics.add_counter("app_sampler_steps_total", steps, path=path)
 
     def _count_step_tokens(self, decode: int, prefill: int, issued: int) -> None:
         """app_step_tokens_total at the point of issue: of the positions a

@@ -17,6 +17,7 @@ import dataclasses
 import functools
 import re
 
+import hlo_text
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -311,3 +312,9 @@ def test_the_compiled_decode_block_leaves_the_pools_to_the_kernels(family, one_c
     assert appends and all("paged_kv_append" in line for line in appends)
     pool_bytes = 2 * int(np.prod(pool_shape))
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+    # the chip's compiler left the sampler's sorts where the program put
+    # them: inside a branch of the conditional that greedy rows do not
+    # take, not hoisted into the step (PERF.md §6, PR 32)
+    comps, entry = hlo_text.computations(text)
+    sorts = hlo_text.holds(comps, "sort", f"f32[{B},{cfg.vocab_size}]")  # not the router's top-k
+    assert sorts and not sorts & hlo_text.reached_outside_a_branch(comps, entry)

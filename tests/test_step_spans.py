@@ -150,8 +150,10 @@ def test_every_phase_is_a_span_nested_under_a_step(traced):
 def test_dispatch_kind_and_keywords(traced):
     blocks = [s for s in traced.spans if s.phase == "dispatch" and "blk" in s.kw]
     assert traced.kind in {s.kw["kind"] for s in blocks}
+    keywords = {"blk", "kind", "rows", "steps", "kv_tokens", "chunk_rows", "chunk_tokens", "cold"}
     for s in blocks:
-        assert set(s.kw) == {"blk", "kind", "rows", "steps", "kv_tokens", "chunk_rows", "chunk_tokens", "cold"}
+        # the sampler's path rides the blocks whose steps end in ops/sampling.sample_logits
+        assert set(s.kw) == keywords | ({"sampler"} if traced.kind != "spec" else set())
         assert s.kw["cold"] == 0  # the same traffic ran once before the trace
         assert s.kw["steps"] == (3 if traced.kind == "spec" else STEPS)
         assert (s.kw["chunk_rows"] > 0) == (s.kw["kind"] == "ragged")
@@ -190,6 +192,72 @@ def test_requestz_joins_a_request_to_its_blocks(traced):
         assert decode["first_blk"] <= decode["last_blk"]
         assert {decode["first_blk"], decode["last_blk"]} <= seen
         assert decode["last_blk"] - decode["first_blk"] + 1 >= decode["blocks"] >= 1
+
+
+# ------------------------------------------------------- the sampler's path
+def test_the_sampler_s_path_rides_the_spans_and_the_first_token_is_one_program(model, tmp_path, monkeypatch):
+    """A bucketed admission and a whole-prompt chunk-cache hit each take
+    their first token from ``batch.sample_first_token`` once and never call
+    ``sample_logits`` outside a trace; ``gofr.step.dispatch`` says
+    ``sampler=greedy`` until a live row sets top-p, then ``sampler=filter``;
+    ``app_sampler_steps_total{path}`` is the spans' steps, and one a first
+    token."""
+    from gofr_tpu.ops import sampling
+
+    container = Container(MapConfig({"LOG_LEVEL": "ERROR"}, use_env=False))
+    metrics = container.metrics_manager
+    engine = make_engine(model, metrics=metrics, prefill_chunk_tokens=16, prefix_cache_entries=16)
+    first_tokens, eager = [], []
+    entry, sampler = batch_ops.sample_first_token, sampling.sample_logits
+
+    def spy_entry(logits, *a):
+        first_tokens.append(logits.shape)
+        return entry(logits, *a)
+
+    def spy_sampler(logits, *a, **kw):
+        if not isinstance(logits, jax.core.Tracer):
+            eager.append(logits.shape)
+        return sampler(logits, *a, **kw)
+
+    monkeypatch.setattr(batch_ops, "sample_first_token", spy_entry)
+    monkeypatch.setattr(batch_ops, "sample_logits", spy_sampler)
+    monkeypatch.setattr(sampling, "sample_logits", spy_sampler)
+    long_prompt = "a prompt of three chunks and a bit"  # 34 tokens: chunked, cached at its boundaries
+    engine.start()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            engine.submit("open", max_new_tokens=6, temperature=0.0).result(timeout=120)
+            cold = engine.submit(long_prompt, max_new_tokens=5, temperature=0.0).result(timeout=120)
+            assert first_tokens == [(1, 300)]  # the bucketed one; the chunk path samples inside its dispatch
+            hit = engine.submit(long_prompt, max_new_tokens=5, temperature=0.0).result(timeout=120)
+            assert hit.token_ids == cold.token_ids and first_tokens == [(1, 300)] * 2
+            decoding = threading.Event()
+            greedy = engine.submit("greedy row", max_new_tokens=30, temperature=0.0,
+                                   stream_cb=lambda tid, piece, done: decoding.set())
+            assert decoding.wait(120)
+            sampled = engine.submit("sampled", max_new_tokens=6, temperature=0.8, top_p=0.9)
+            assert sampled.result(timeout=120).completion_tokens >= 1 and greedy.result(timeout=120)
+            assert settles_in(engine, "wait")
+            time.sleep(0.06)
+    finally:
+        engine.stop()
+    assert first_tokens == [(1, 300)] * 4 and eager == []
+    spans = read_spans(tmp_path)
+    prefills = [(s.kw["route"], s.kw.get("sampler")) for s in spans if s.phase == "prefill"]
+    assert prefills == [("bucketed", "greedy"), ("chunked", None), ("prefix_hit", "greedy"),
+                        ("bucketed", "greedy"), ("bucketed", "filter")]
+    blocks = [s.kw for s in spans if s.phase == "dispatch" and "blk" in s.kw]
+    paths = [kw["sampler"] for kw in blocks]
+    assert "sample" not in paths and paths.index("filter") > 0
+    # greedy until the row with a top-p decodes, greedy again once it has retired
+    assert [kw["sampler"] for kw in blocks if kw["rows"] == 2] == ["filter"] * paths.count("filter")
+    assert paths[-1] == "greedy"
+    counter = metrics.get("app_sampler_steps_total")
+    for path in ("greedy", "filter"):
+        steps = sum(kw["steps"] for kw in blocks if kw["sampler"] == path)
+        assert counter.value({"path": path}) == steps + sum(1 for _, p in prefills if p == path) > 0
+    assert counter.value({"path": "sample"}) == 0
+    container.close()
 
 
 # ------------------------------------------------------------ the account
