@@ -230,6 +230,13 @@ class Container:
             "decode steps and layers, read with each block's tokens (label "
             "expert=the expert's published index; sparse-expert models only)",
         )
+        m.new_counter(
+            "app_dsa_positions_total",
+            "Context positions a learned sparse attention's indexer scored "
+            "and its attention read, over decode steps, rows and layers, "
+            "read with each block's tokens (label kind=scored|selected; "
+            "models with an index_topk only)",
+        )
         m.new_gauge(
             "app_decode_block_size",
             "Decode steps fused per device dispatch (TPU_BATCH_MULTI_STEP)",

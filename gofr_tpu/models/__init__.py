@@ -5,6 +5,9 @@ The serving framework's model zoo (BASELINE.json configs):
 - cohere2_moe: parallel-block decoder with sparse and shared experts, window
   and full attention by layer type (served on the paged path, also as one
   chip's share of an expert-parallel deployment)
+- deepseek_v32: latent attention under a learned sparse selection, leading
+  dense layers, group-limited sparse experts beside a shared one (served on
+  the paged path, also as one chip's share)
 - bert: encoder embedder (/embed endpoint)
 - whisper: encoder-decoder ASR (async Pub/Sub path)
 
@@ -14,6 +17,6 @@ scanned (lax.scan) so compile time is flat in depth; weights are bf16 by
 default with f32 accumulation inside ops.
 """
 
-from gofr_tpu.models import bert, cohere2_moe, llama
+from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, llama
 
-__all__ = ["llama", "cohere2_moe", "bert"]
+__all__ = ["llama", "cohere2_moe", "deepseek_v32", "bert"]

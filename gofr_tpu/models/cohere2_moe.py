@@ -27,8 +27,8 @@ float32.
 
 The engine reaches this module through its config's class
 (``serving/batch.model_of``): ``KVCache``, ``prefill``,
-``decode_step_paged``, ``decode_chunk_paged``, ``step_stats_len`` and
-``unserved``. It serves the paged bf16 layout. Window layers keep all
+``decode_step_paged``, ``decode_chunk_paged``, ``step_stats_len``,
+``page_shapes`` and ``unserved``. It serves the paged bf16 layout. Window layers keep all
 their pages: releasing pages behind the window is the allocator's job
 (ROADMAP R3) and no program here depends on it.
 """
@@ -48,6 +48,7 @@ from gofr_tpu.models.llama import (
     _mm,
     _paged_chunk_targets,
     _paged_gather,
+    page_shapes,
     quantize_weight,
 )
 from gofr_tpu.ops.attention import attention
@@ -59,7 +60,7 @@ from gofr_tpu.ops.rope import apply_rope_interleaved, rope_angles
 
 __all__ = [
     "Cohere2MoeConfig", "KVCache", "init_params", "quantize_params", "prefill",
-    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "unserved",
+    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "page_shapes", "unserved",
 ]
 
 SLIDING, FULL = "sliding_attention", "full_attention"

@@ -145,6 +145,13 @@ def step_stats_len(cfg: LlamaConfig) -> int:
     return 0
 
 
+def page_shapes(cfg: Any, page_size: int) -> tuple[tuple, tuple]:
+    """What a page of each of the engine's two pools holds, [heads, page,
+    width]: K of every KV head in the first, V in the second."""
+    shape = (cfg.n_kv_heads, page_size, cfg.head_dim)
+    return shape, shape
+
+
 def unserved(engine_config: Any, lora: Any) -> str | None:
     """What an engine asks for that this model has no program for: nothing."""
     return None

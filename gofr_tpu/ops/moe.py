@@ -217,17 +217,45 @@ def sigmoid_topk_gates(
     h: jnp.ndarray,  # [T, D]
     w_router: jnp.ndarray,  # [D, E] float32: every published expert
     k: int,
+    *,
+    bias: jnp.ndarray | None = None,  # [E] added to the scores for the CHOICE only
+    n_group: int = 1,  # the experts in this many equal groups ...
+    topk_group: int = 1,  # ... of which a row keeps this many
+    scale: float = 1.0,  # the routed scaling factor
 ) -> jnp.ndarray:
     """The sigmoid rule with ``norm_topk_prob``: scores ``sigmoid(W_r h)``
     in float32 (the product at ``highest``: a bf16 pass can swap the 8th
     and 9th expert), the ``k`` largest kept and divided by their sum.
-    Returns the gates [T, E] float32, zero off the chosen experts."""
+    Returns the gates [T, E] float32, zero off the chosen experts.
+
+    The group-limited form (``noaux_tc``): the choice is made on ``scores +
+    bias``; with ``n_group > 1`` a group's score is the sum of its two
+    largest corrected scores, the ``topk_group`` best groups stay, and the
+    ``k`` experts are chosen among theirs. The gates are the UNcorrected
+    scores of the chosen experts over their sum, times ``scale``. The
+    neutral values (no bias, one group, scale 1) give the plain
+    rule bit for bit, by the plain rule's own operations: a program that
+    passes none of them compiles as it did before they existed."""
     scores = jax.nn.sigmoid(jnp.matmul(
         h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
-    top_s, top_i = jax.lax.top_k(scores, k)
+    if bias is None and n_group <= 1:
+        top_s, top_i = jax.lax.top_k(scores, k)  # the plain rule: the choice is made on the scores
+    else:
+        choice = scores if bias is None else scores + bias.astype(jnp.float32)
+        if n_group > 1:
+            T, E = scores.shape
+            grouped = choice.reshape(T, n_group, E // n_group)
+            group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, n_group]
+            _, kept = jax.lax.top_k(group_score, topk_group)
+            stays = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.float32), axis=1) > 0
+            choice = jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(T, E)
+        _, top_i = jax.lax.top_k(choice, k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
     top_s = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    if scale != 1.0:
+        top_s = top_s * scale
     onehot = jax.nn.one_hot(top_i, scores.shape[-1], dtype=jnp.float32)  # [T, k, E]
     return jnp.einsum("tke,tk->te", onehot, top_s)
 

@@ -440,7 +440,7 @@ def _append_kernel(
     shared slabs into owned pages (serving/kv_cache.py) — only the trash
     page is written by several rows, and its content is garbage by
     contract."""
-    R, Hkv, page, Dh = k_buf.shape
+    R = k_buf.shape[0]  # the two pools may hold pages of two shapes
     c = pl.program_id(0)
     rows = jnp.minimum(R, pages_ref.shape[0] - c * R)
     layer = layer_ref[0]
@@ -461,11 +461,14 @@ def _append_kernel(
             pltpu.make_async_copy(src.at[0, 0], buf.at[r], sem.at[0]).wait()
 
     def patch(r, row):
-        hit = jax.lax.broadcasted_iota(jnp.int32, (page, Dh), 0) == offsets_ref[row]
+        hits = {}  # one mask a page shape: pools of one shape share it, as before there were two
         for _, _, buf, new_ref in streams:
+            Hkv, page, Dh = buf.shape[1:]
+            if (page, Dh) not in hits:
+                hits[page, Dh] = jax.lax.broadcasted_iota(jnp.int32, (page, Dh), 0) == offsets_ref[row]
             for h in range(Hkv):
                 new = jnp.broadcast_to(new_ref[r, pl.ds(h, 1), :], (page, Dh))
-                buf[r, h] = jnp.where(hit, new, buf[r, h])
+                buf[r, h] = jnp.where(hits[page, Dh], new, buf[r, h])
 
     def write(r, row):
         for _, dst, buf, _ in streams:
@@ -484,7 +487,7 @@ def paged_kv_append(
     k_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, Dh]
     v_pool: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, Hkv, Dh]
-    v_new: jnp.ndarray,
+    v_new: jnp.ndarray,  # the V pool's pages may have a shape of their own
     layer: jnp.ndarray,  # scalar int32 (may be traced)
     pages: jnp.ndarray,  # [B] int32
     offsets: jnp.ndarray,  # [B] int32
@@ -504,19 +507,17 @@ def paged_kv_append(
     if mode == REFERENCE:
         return paged_kv_append_ref(k_pool, v_pool, k_new, v_new, layer, pages, offsets)
     B = k_new.shape[0]
-    Hkv, page, Dh = k_pool.shape[2:]
-    page_bytes = Hkv * page * Dh * k_pool.dtype.itemsize
-    R = max(1, min(B, _APPEND_VMEM_BUDGET // (2 * page_bytes)))
-    row_spec = pl.BlockSpec((R, Hkv, Dh), lambda c, *_: (c, 0, 0))
+    pools = (k_pool, v_pool)  # their pages [Hkv, page, Dh] may differ in shape
+    page_bytes = sum(math.prod(pool.shape[2:]) * pool.dtype.itemsize for pool in pools)
+    R = max(1, min(B, _APPEND_VMEM_BUDGET // page_bytes))
+    row_specs = [pl.BlockSpec((R, pool.shape[2], pool.shape[4]), lambda c, *_: (c, 0, 0)) for pool in pools]
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, pages, offsets
         grid=(pl.cdiv(B, R),),
-        in_specs=[row_spec, row_spec, hbm, hbm],
+        in_specs=row_specs + [hbm, hbm],
         out_specs=[hbm, hbm],
-        scratch_shapes=[
-            pltpu.VMEM((R, Hkv, page, Dh), k_pool.dtype),
-            pltpu.VMEM((R, Hkv, page, Dh), v_pool.dtype),
+        scratch_shapes=[pltpu.VMEM((R,) + pool.shape[2:], pool.dtype) for pool in pools] + [
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )

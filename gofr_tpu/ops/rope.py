@@ -7,6 +7,8 @@ position — decode steps index it with dynamic positions without recompute.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -58,3 +60,53 @@ def apply_rope_interleaved(
     x1, x2 = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.reshape(x.shape).astype(dtype)
+
+
+def apply_rope_halves(
+    x: jnp.ndarray,  # [..., seq, heads, head_dim]
+    sin: jnp.ndarray,  # [..., seq, head_dim//2]
+    cos: jnp.ndarray,
+) -> jnp.ndarray:
+    """:func:`apply_rope`'s layout (lane ``i`` pairs with ``i + half``)
+    from the angles themselves, as :func:`apply_rope_interleaved` takes
+    them."""
+    dtype = x.dtype
+    sin, cos = sin[..., :, None, :], cos[..., :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(dtype)
+
+
+def yarn_frequencies(
+    head_dim: int, theta: float, factor: float, original_max: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+) -> jnp.ndarray:
+    """YaRN's blend of the rotary frequencies [head_dim//2], float32: with
+    ``f_i = theta**(-2i/head_dim)``, pairs that turn more than ``beta_fast``
+    times in ``original_max`` positions keep ``f_i``, those that turn fewer
+    than ``beta_slow`` times get ``f_i / factor``, and a linear ramp over
+    the pair index blends the ones between."""
+    half = head_dim // 2
+    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+
+    def pair_turning(rotations: float) -> float:
+        return head_dim * math.log(original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return freqs / factor * (1.0 - keep) + freqs * keep
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: the softmax scale is multiplied by its
+    square."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def angles(positions: jnp.ndarray, freqs: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(sin, cos) [..., len(freqs)] of the positions under given
+    frequencies (:func:`rope_angles` with the frequencies handed in)."""
+    a = positions.astype(jnp.float32)[..., None] * freqs
+    return jnp.sin(a), jnp.cos(a)

@@ -53,12 +53,15 @@ from gofr_tpu.ops.sampling import sample_logits, sampler_path, stop_eval
 def model_of(cfg: Any) -> Any:
     """The module that serves ``cfg``: the one its class is defined in
     (``LlamaConfig`` → ``models/llama.py``, ``Cohere2MoeConfig`` →
-    ``models/cohere2_moe.py``). Every program here reaches its model
+    ``models/cohere2_moe.py``, ``DeepseekV32Config`` →
+    ``models/deepseek_v32.py``). Every program here reaches its model
     through this one lookup, at trace time. What a served module holds:
     ``KVCache`` (the dense cache, also a bucketed prefill's scratch),
     ``prefill``, ``decode_step_paged`` and ``decode_chunk_paged`` with
     ``llama``'s arguments, ``step_stats_len(cfg)`` — how many int32
-    counters its paged step returns after the pools (0: none) — and
+    counters its paged step returns after the pools (0: none) —
+    ``page_shapes(cfg, page_size)``, what a page of each of the two pools
+    holds (``serving/kv_cache.py``), and
     ``unserved(engine_config, lora)``, the sentence that refuses an engine
     the model has no program for. The dense and speculative programs
     call the functions ``llama`` has for them by the same names."""

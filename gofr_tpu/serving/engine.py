@@ -565,7 +565,8 @@ class ServingEngine:
         if refusal:
             raise ValueError(refusal)
         # int32 counters the model's paged step adds to a block's packed
-        # result (cohere2_moe: rows per held expert); 0 for most
+        # result (rows per held expert; deepseek_v32 also the positions its
+        # indexer scored and its attention read); 0 for most
         self._stats_len = model.step_stats_len(cfg)
         if self.config.spec_tokens < 0:
             raise ValueError("TPU_SPEC_TOKENS must be >= 0")
@@ -3638,6 +3639,9 @@ class ServingEngine:
         window = getattr(cfg, "sliding_window", None)
         if window:  # rows whose window layers no longer see their first key
             span.set(win_rows=int((self.cache_len[mask] > window).sum()))
+        topk = getattr(cfg, "index_topk", None)
+        if topk:  # rows whose sparse selection binds: attention reads index_topk of them
+            span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))
         self._count_sampler(span, self.temperature[mask], self.top_k[mask],
                             self.top_p[mask], steps=N)
         self._count_step_tokens(
@@ -3649,12 +3653,23 @@ class ServingEngine:
             prefill_rows=prefill_rows, last_logits=keep_logits,
         )
 
-    def _count_expert_rows(self, span: _StepPhase, rows: Any) -> None:
-        """A block's expert counters, read with its tokens: row-expert
+    def _count_step_stats(self, span: _StepPhase, stats: Any) -> None:
+        """A block's model counters, read with its tokens: row-expert
         pairs the held experts took over the block's decode steps and
         layers (``moe_rows``), the fullest expert's (``moe_max``), and
-        app_moe_expert_rows_total by the expert's published index."""
-        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()))
+        app_moe_expert_rows_total by the expert's published index. A model
+        with a sparse selection (``index_topk``) counts after them the
+        positions its indexer scored and the positions its attention read
+        (``dsa_scored``, ``dsa_selected``; app_dsa_positions_total)."""
+        rows, dsa = stats, {}
+        if getattr(self.model_cfg, "index_topk", None):
+            rows, (scored, selected) = stats[:-2], stats[-2:].tolist()
+            dsa = {"dsa_scored": scored, "dsa_selected": selected}
+            if self._metrics:
+                for kind, n in (("scored", scored), ("selected", selected)):
+                    if n:
+                        self._metrics.add_counter("app_dsa_positions_total", n, kind=kind)
+        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), **dsa)
         if self._metrics:
             first = self.model_cfg.first_expert
             for e, n in enumerate(rows.tolist()):
@@ -3911,7 +3926,7 @@ class ServingEngine:
 
             span.set(tokens=tokens, retired=self._retires - retires)
             if self._stats_len:
-                self._count_expert_rows(span, batch_ops.block_stats(
+                self._count_step_stats(span, batch_ops.block_stats(
                     packed, self.config.max_slots, self._stats_len))
 
         if self._metrics and n_active:
@@ -4338,6 +4353,7 @@ class ServingEngine:
         return PagedKVCache(
             self.model_cfg, num_pages=num_pages, page_size=page,
             max_slots=B, max_seq_len=S,
+            page_shapes=batch_ops.model_of(self.model_cfg).page_shapes(self.model_cfg, page),
         )
 
     def _init_runtime_state(self) -> None:

@@ -48,21 +48,32 @@ __all__ = ["PagedKVCache", "OutOfBlocks"]
 @partial(jax.jit, donate_argnums=(0, 1))
 def _write_pages(
     k_pool: jnp.ndarray,  # [L, N, Hkv, page, Dh] donated
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,  # its pages may have a shape of their own
     k_slab: jnp.ndarray,  # [L, S_pad, Hkv, Dh] (S_pad = n_pages*page)
     v_slab: jnp.ndarray,
     page_ids: jnp.ndarray,  # [n_pages] int32
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    L, S_pad, Hkv, Dh = k_slab.shape
     n_pages = page_ids.shape[0]
-    page = S_pad // n_pages
-    # [L, n_pages, Hkv, page, Dh] to match the pool's kernel-friendly layout
-    k_pages = k_slab.reshape(L, n_pages, page, Hkv, Dh).transpose(0, 1, 3, 2, 4)
-    v_pages = v_slab.reshape(L, n_pages, page, Hkv, Dh).transpose(0, 1, 3, 2, 4)
+
+    def paged(slab: jnp.ndarray) -> jnp.ndarray:
+        # [L, n_pages, Hkv, page, Dh] to match the pool's kernel-friendly layout
+        L, S_pad, Hkv, Dh = slab.shape
+        return slab.reshape(L, n_pages, S_pad // n_pages, Hkv, Dh).transpose(0, 1, 3, 2, 4)
+
     return (
-        k_pool.at[:, page_ids].set(k_pages),
-        v_pool.at[:, page_ids].set(v_pages),
+        k_pool.at[:, page_ids].set(paged(k_slab)),
+        v_pool.at[:, page_ids].set(paged(v_slab)),
     )
+
+
+def _pad_tokens(slab: jnp.ndarray, pad: int) -> jnp.ndarray:
+    return jnp.pad(slab, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else slab
+
+
+def _contiguous(pages: jnp.ndarray) -> jnp.ndarray:
+    """Gathered pages [L, n, Hkv, page, Dh] as a slab [L, n*page, Hkv, Dh]."""
+    L, n, Hkv, page, Dh = pages.shape
+    return pages.transpose(0, 1, 3, 2, 4).reshape(L, n * page, Hkv, Dh)
 
 
 class PagedKVCache:
@@ -71,16 +82,21 @@ class PagedKVCache:
 
     def __init__(
         self,
-        cfg: Any,  # LlamaConfig-shaped (n_layers, n_kv_heads, head_dim)
+        cfg: Any,  # a served model's config: n_layers, dtype
         *,
         num_pages: int,
         page_size: int = 16,
         max_slots: int = 8,
         max_seq_len: int = 1024,
         dtype: Any = None,
+        # what a page of each pool holds, [Hkv, page, Dh] twice: the model's
+        # ``page_shapes`` (the engine hands it over); None is K and V of
+        # every KV head
+        page_shapes: tuple[tuple, tuple] | None = None,
     ) -> None:
         self.cfg = cfg
         self.page_size = page_size
+        self._page_shapes = page_shapes or ((cfg.n_kv_heads, page_size, cfg.head_dim),) * 2
         self.num_pages = num_pages
         self.max_slots = max_slots
         self.max_seq_len = max_seq_len
@@ -102,22 +118,23 @@ class PagedKVCache:
 
         [L, N+1, Hkv, page, Dh]: trailing (page, Dh) are full dims in the
         pallas BlockSpecs (ops/paged_attention.py) — Mosaic tiling rule.
-        The extra LAST page is the trash page: inactive rows' decode
-        appends are redirected there (the model's decode_step_paged), so
-        the append never writes a live page for a row that does not own
-        it."""
-        cfg = self.cfg
-        shape = (
-            cfg.n_layers, self.num_pages + 1, cfg.n_kv_heads,
-            self.page_size, cfg.head_dim,
-        )
+        What a page holds, [Hkv, page, Dh] for each of the two pools, is
+        the model's to say (``page_shapes``, handed over at construction:
+        K and V of every KV head for ``llama`` and ``cohere2_moe``; one
+        latent row and one indexer key a token for ``deepseek_v32``) —
+        one block table and one allocator serve both. The extra LAST page is the
+        trash page: inactive rows' decode appends are redirected there
+        (the model's decode_step_paged), so the append never writes a
+        live page for a row that does not own it."""
+        k_page, v_page = self._page_shapes
+        lead = (self.cfg.n_layers, self.num_pages + 1)
         # build both arrays BEFORE assigning either: a mid-rebuild failure
         # (backend still down during recovery) must not leave a half-fresh
         # pool pair that the engine's health probe — it samples k_pool —
         # would report healthy while v_pool is still deleted
         self.k_pool, self.v_pool = (
-            jnp.zeros(shape, self._pool_dtype),
-            jnp.zeros(shape, self._pool_dtype),
+            jnp.zeros(lead + k_page, self._pool_dtype),
+            jnp.zeros(lead + v_page, self._pool_dtype),
         )
 
     # ------------------------------------------------------------- accounting
@@ -253,12 +270,10 @@ class PagedKVCache:
         bucket beyond the owned table are masked by seq_lens at read)."""
         seq_id = self._slot_seq[slot]
         assert seq_id is not None
-        L, S, Hkv, Dh = k_slab.shape
+        S = k_slab.shape[1]
         n_pages = self.pages_needed(S)
         pad = n_pages * self.page_size - S
-        if pad:
-            k_slab = jnp.pad(k_slab, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v_slab = jnp.pad(v_slab, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_slab, v_slab = _pad_tokens(k_slab, pad), _pad_tokens(v_slab, pad)
         owned = self.allocator.block_table(seq_id)
         if n_pages > len(owned):
             # bucket padding spilled past the reservation: grow it
@@ -284,13 +299,11 @@ class PagedKVCache:
             raise ValueError(f"write_span start {start} not page-aligned")
         seq_id = self._slot_seq[slot]
         assert seq_id is not None
-        L, C, Hkv, Dh = k_slab.shape
+        C = k_slab.shape[1]
         p0 = start // self.page_size
         p1 = self.pages_needed(start + C)
         pad = (p1 - p0) * self.page_size - C
-        if pad:
-            k_slab = jnp.pad(k_slab, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            v_slab = jnp.pad(v_slab, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_slab, v_slab = _pad_tokens(k_slab, pad), _pad_tokens(v_slab, pad)
         owned = self.allocator.block_table(seq_id)
         if p1 > len(owned):
             self.allocator.extend(seq_id, p1 * self.page_size)
@@ -315,11 +328,8 @@ class PagedKVCache:
         p0 = start // self.page_size
         p1 = self.pages_needed(end)
         page_ids = self.tables[slot, p0:p1]
-        k = self.k_pool[:, page_ids]  # [L, n, Hkv, page, Dh]
-        v = self.v_pool[:, page_ids]
-        L, n, Hkv, page, Dh = k.shape
-        k = k.transpose(0, 1, 3, 2, 4).reshape(L, n * page, Hkv, Dh)
-        v = v.transpose(0, 1, 3, 2, 4).reshape(L, n * page, Hkv, Dh)
+        k = _contiguous(self.k_pool[:, page_ids])  # [L, n, Hkv, page, Dh] gathered
+        v = _contiguous(self.v_pool[:, page_ids])
         off = start - p0 * self.page_size  # 0 by alignment, kept explicit
         return k[:, off : off + (end - start)], v[:, off : off + (end - start)]
 

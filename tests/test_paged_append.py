@@ -13,6 +13,7 @@ around the kernel back (PERF.md §6, PR 30).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import re
@@ -24,6 +25,7 @@ import numpy as np
 import pytest
 
 from gofr_tpu.models import cohere2_moe as cm
+from gofr_tpu.models import deepseek_v32 as ds
 from gofr_tpu.models import llama
 from gofr_tpu.ops import paged_attention as pa
 from gofr_tpu.serving import batch as batch_ops
@@ -63,6 +65,23 @@ def test_the_append_kernel_writes_what_the_scatter_wrote(rows, dtype, monkeypatc
         assert not (g[1] == before[1]).all()
 
 
+def test_the_append_kernel_takes_pools_of_two_page_shapes():
+    """A latent row and an indexer key a token (``deepseek_v32``): one
+    head, widths that differ, one set of pages and offsets for both."""
+    L, N, rows = 2, 9, 5
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    k_pool = jax.random.normal(keys[0], (L, N, 1, PAGE, 256), jnp.bfloat16)
+    v_pool = jax.random.normal(keys[1], (L, N, 1, PAGE, 128), jnp.bfloat16)
+    k_new = jax.random.normal(keys[2], (rows, 1, 256), jnp.bfloat16)
+    v_new = jax.random.normal(keys[3], (rows, 1, 128), jnp.bfloat16)
+    pages, offsets = jnp.asarray([4, 0, 7, 2, 5]), jnp.asarray([0, 3, 1, 2, 3])
+    args = (k_new, v_new, jnp.int32(1), pages, offsets)
+    got = pa.paged_kv_append(k_pool, v_pool, *args, interpret=True)
+    want = pa.paged_kv_append_ref(k_pool, v_pool, *args)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and bool(jnp.all(g == w))
+
+
 def test_on_the_cpu_the_append_is_the_scatter():
     pool = jnp.zeros((2, 3, 1, PAGE, 8), jnp.float32)
     new = jnp.ones((1, 1, 8), jnp.float32)
@@ -98,6 +117,8 @@ FAMILIES = {
     "llama": (llama, llama.LlamaConfig.tiny(vocab_size=300)),
     # two periods of three window layers (8 positions) and a full one
     "cohere2_moe": (cm, cm.Cohere2MoeConfig.tiny(vocab_size=300)),
+    # a dense layer and two expert layers; a selection of 8 positions, which binds from the ninth on
+    "deepseek_v32": (ds, ds.DeepseekV32Config.tiny(vocab_size=300)),
 }
 # (resident prompt length, active, budget) a row. Row 0 always starts with 6
 # resident positions, so its four steps write positions 6..9: slot PAGE - 1
@@ -137,11 +158,11 @@ def _run_block(family: str, rows: tuple, mode: str, monkeypatch):
                             functools.partial(pa.paged_decode_attention, interpret=True))
     B = len(rows)
     n_pages = B * SLOT_PAGES
-    shape = (cfg.n_layers, n_pages + 1, cfg.n_kv_heads, PAGE, cfg.head_dim)
+    k_page, v_page = model.page_shapes(cfg, PAGE)
     # pages nobody wrote hold noise, not zeros: a page fetched from the
     # wrong place, or written where it should not be, shows
-    k_pool = jax.random.normal(jax.random.PRNGKey(1), shape, cfg.dtype)
-    v_pool = jax.random.normal(jax.random.PRNGKey(2), shape, cfg.dtype)
+    k_pool = jax.random.normal(jax.random.PRNGKey(1), (cfg.n_layers, n_pages + 1) + k_page, cfg.dtype)
+    v_pool = jax.random.normal(jax.random.PRNGKey(2), (cfg.n_layers, n_pages + 1) + v_page, cfg.dtype)
     tables = np.random.default_rng(1).permutation(n_pages).reshape(B, SLOT_PAGES).astype(np.int32)
     first = []
     for b, (n, _, _) in enumerate(rows):
@@ -169,9 +190,13 @@ def scatter_runs():
     return {}
 
 
-@pytest.mark.parametrize("mode", MODES[1:])
+# deepseek_v32 reads its pools by gathers, not by the paged kernel: its one kernel is the append
+BLOCK_CASES = [(family, mode) for family in FAMILIES for mode in MODES[1:]
+               if (family, mode) != ("deepseek_v32", "both-kernels")]
+
+
 @pytest.mark.parametrize("rows", list(ROWS), ids=[r.replace(" ", "-") for r in ROWS])
-@pytest.mark.parametrize("family", list(FAMILIES))
+@pytest.mark.parametrize("family, mode", BLOCK_CASES, ids=[f"{f}-{m}" for f, m in BLOCK_CASES])
 def test_a_block_through_the_kernels_is_the_block_through_the_scatter(family, rows, mode, scatter_runs, monkeypatch):
     key = (family, rows)
     if key not in scatter_runs:
@@ -202,7 +227,8 @@ def test_a_block_s_tokens_are_the_dense_path_s(family, monkeypatch):
     ``prefill`` over prompt and tokens with a dense cache: greedy at every
     position (for cohere2_moe the last two lie past the window)."""
     model, cfg = FAMILIES[family]
-    tokens, _, _, _, first = _run_block(family, ROWS["all rows live"], "both-kernels", monkeypatch)
+    mode = "both-kernels" if (family, "both-kernels") in BLOCK_CASES else "append-kernel"
+    tokens, _, _, _, first = _run_block(family, ROWS["all rows live"], mode, monkeypatch)
     served = [first[0]] + [int(t) for t in tokens[0]]
     ids = np.concatenate([_prompt(0, 6), served[:-1]]).astype(np.int32)
     for n in range(6, len(ids) + 1):
@@ -256,6 +282,13 @@ CHIP_CONFIGS = {
         vocab_size=512, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=128,
         d_ff=256, layer_types=(cm.SLIDING, cm.FULL), sliding_window=32, max_seq_len=131,
         dtype=jnp.bfloat16),
+    # a latent row of 96 + 32 = one lane tile, an indexer key of 128; two dense and two expert
+    # layers (a scan of one layer is no loop: its layer index is a constant, and XLA slices the pool by it)
+    "deepseek_v32": ds.DeepseekV32Config.tiny(
+        vocab_size=512, d_model=256, n_layers=4, n_dense_layers=2, n_heads=4, q_lora_rank=64,
+        kv_lora_rank=96, qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=32, index_n_heads=4,
+        index_head_dim=128, index_topk=64, d_ff=256, d_ff_expert=128, n_experts=8, held_experts=8,
+        n_group=2, topk_group=1, top_k=2, max_seq_len=131, dtype=jnp.bfloat16),
 }
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
 # ops that hand a buffer on as it is
@@ -288,20 +321,24 @@ def test_the_compiled_decode_block_leaves_the_pools_to_the_kernels(family, one_c
     i32, f32 = jnp.int32, jnp.float32
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     params = on_chip(jax.eval_shape(lambda k: model.init_params(cfg, k), key))
-    pool_shape = (cfg.n_layers, N + 1, cfg.n_kv_heads, page, cfg.head_dim)
-    pool = jax.ShapeDtypeStruct(pool_shape, cfg.dtype, sharding=one_chip)
+    pool_shapes = [(cfg.n_layers, N + 1) + shape for shape in model.page_shapes(cfg, page)]
+    k_pool, v_pool = (jax.ShapeDtypeStruct(shape, cfg.dtype, sharding=one_chip) for shape in pool_shapes)
     state = batch_ops.DecodeState(vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
                                   vec(i32), vec(f32), on_chip(key), vec(i32))
     # conftest.py asks for float32 products everywhere; the served program
     # does not, and Mosaic takes bf16 operands at the default precision only
     with jax.default_matmul_precision("default"):
         compiled = batch_ops.decode_block_paged.lower(
-            cfg, params, pool, pool, state, vec(i32, B, M), vec(jnp.bool_), STEPS).compile()
+            cfg, params, k_pool, v_pool, state, vec(i32, B, M), vec(jnp.bool_), STEPS).compile()
 
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 2  # the append and the attention kernel
-    dims = ",".join(str(d) for d in pool_shape)
-    pool_like = {f"bf16[{dims}]", f"bf16[{dims.split(',', 1)[1]}]", f"bf16[1,{dims.split(',', 1)[1]}]"}
+    # the append and the attention kernel a layer body (deepseek_v32: two bodies, the append alone in each)
+    assert text.count("tpu_custom_call") >= 2
+    pool_like = set()
+    for pool_shape in pool_shapes:
+        dims = ",".join(str(d) for d in pool_shape)
+        pool_like |= {f"bf16[{dims}]", f"bf16[{dims.split(',', 1)[1]}]", f"bf16[1,{dims.split(',', 1)[1]}]"}
+    dims = ",".join(str(d) for d in pool_shapes[0])
     made = []
     for line in text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -310,7 +347,7 @@ def test_the_compiled_decode_block_leaves_the_pools_to_the_kernels(family, one_c
     assert not made, f"XLA ops that make, slice or update a pool: {made}"
     appends = [line for line in text.splitlines() if "custom-call(" in line and f"bf16[{dims}]" in line.split("custom-call(")[0]]
     assert appends and all("paged_kv_append" in line for line in appends)
-    pool_bytes = 2 * int(np.prod(pool_shape))
+    pool_bytes = 2 * int(np.prod(pool_shapes[0]))
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
     # the chip's compiler left the sampler's sorts where the program put
     # them: inside a branch of the conditional that greedy rows do not
@@ -318,3 +355,54 @@ def test_the_compiled_decode_block_leaves_the_pools_to_the_kernels(family, one_c
     comps, entry = hlo_text.computations(text)
     sorts = hlo_text.holds(comps, "sort", f"f32[{B},{cfg.vocab_size}]")  # not the router's top-k
     assert sorts and not sorts & hlo_text.reached_outside_a_branch(comps, entry)
+
+
+def test_the_compiled_ragged_step_writes_a_chunk_into_the_pools_in_place(one_chip, no_compile_cache, monkeypatch):
+    """``ragged_step_paged`` of ``deepseek_v32`` by the chip's compiler —
+    the program ``deepseekv32.long`` runs in every iteration. Its chunk
+    writes T rows a pool by an XLA scatter beside the decode steps'
+    Mosaic append (the append's kernel takes one token a row): the
+    hazard of PR 30, where an XLA write of a pool gave it another layout
+    than Mosaic's and a copy around every call. Held here: the only ops
+    whose result is a pool are those two scatters a stack of layers, each
+    over the pool it was handed (a bitcast of it), and nothing copies,
+    transposes, slices or pads one."""
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    cfg = CHIP_CONFIGS["deepseek_v32"]
+    model = batch_ops.model_of(cfg)
+    B, page, M, C = 32, 16, 64, 32
+    N = B * M
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = on_chip(jax.eval_shape(lambda k: model.init_params(cfg, k), key))
+    pool_shapes = [(cfg.n_layers, N + 1) + shape for shape in model.page_shapes(cfg, page)]
+    k_pool, v_pool = (jax.ShapeDtypeStruct(shape, cfg.dtype, sharding=one_chip) for shape in pool_shapes)
+    state = batch_ops.DecodeState(vec(i32), vec(i32), vec(flag), vec(i32), vec(i32), vec(f32),
+                                  vec(i32), vec(f32), on_chip(key), vec(i32))
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.ragged_step_paged.lower(
+            cfg, params, k_pool, v_pool, state, vec(i32, B, M), vec(i32, B, C), vec(i32), vec(flag), vec(i32),
+            vec(flag), vec(i32), vec(i32), vec(i32), vec(f32), vec(i32), vec(f32), vec(i32), on_chip(key),
+            vec(flag), STEPS).compile()
+
+    text = compiled.as_text()
+    sizes = {int(np.prod(shape)) for shape in pool_shapes}
+    made = collections.Counter()
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        shape = re.match(r"bf16\[([\d,]+)\]", m.group(2)) if m else None
+        if shape and m.group(3) not in _PASSES_ON and int(np.prod([int(d) for d in shape.group(1).split(",")])) in sizes:
+            made[m.group(3) if "/scatter" in line else f"{m.group(1)} = {m.group(3)}"] += 1
+    # the scatter itself and the fusion that wraps it, for each pool, in the dense stack's loop and in the expert stack's
+    assert set(made) <= {"scatter", "fusion"} and made["scatter"] == 4, made
+    appends = [line for line in text.splitlines() if "custom-call(" in line and "paged_kv_append" in line]
+    assert len(appends) == 2  # the decode steps' append, once a stack of layers
