@@ -231,6 +231,13 @@ class Container:
             "expert=the expert's published index; sparse-expert models only)",
         )
         m.new_counter(
+            "app_moe_experts_read_total",
+            "Held routed experts whose matrices a decode step read, over "
+            "decode steps and expert layers, read with each block's tokens: "
+            "over steps x expert layers x held experts it is the share of "
+            "the held experts a step and layer reads (sparse-expert models only)",
+        )
+        m.new_counter(
             "app_dsa_positions_total",
             "Context positions a learned sparse attention's indexer scored "
             "and its attention read, over decode steps, rows and layers, "

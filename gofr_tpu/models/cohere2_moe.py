@@ -114,8 +114,9 @@ class Cohere2MoeConfig:
 
 def step_stats_len(cfg: Cohere2MoeConfig) -> int:
     """int32 counters a paged decode step returns after the pools: rows
-    routed to each held expert, summed over the layers."""
-    return cfg.held_experts
+    routed to each held expert, then the held experts whose matrices were
+    read (``ops/moe.held_experts``), each summed over the layers."""
+    return cfg.held_experts + 1
 
 
 def unserved(engine_config: Any, lora: Any) -> str | None:
@@ -209,17 +210,18 @@ def _mix(
     lp: dict, stacks: dict, layer: jnp.ndarray, live: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The parallel block's sum: x + W_o attn + experts(h). ``live``
-    [B, S] marks the rows whose routing is counted. Returns the new
-    residual and the rows each held expert took [held] int32."""
+    [B, S] marks the rows whose routing counts: no other pulls an expert.
+    Returns the new residual and the layer's counters [held + 1] int32:
+    the rows each held expert took, then the held experts read."""
     B, S, D = x.shape
     a = _mm(attn.reshape(B, S, cfg.n_heads * cfg.head_dim), lp["wo"])
     flat = h.reshape(B * S, D)
     gates = sigmoid_topk_gates(flat, lp["w_router"], cfg.top_k)
-    y, g = held_experts(flat, gates, stacks["experts"], stacks["shared"], cfg.first_expert,
-                        _mm, layer)
+    y, g, read = held_experts(flat, gates, stacks["experts"], stacks["shared"], cfg.first_expert,
+                              _mm, layer, top_k=cfg.top_k, rows=live.reshape(B * S))
     rows = jnp.sum((g > 0) & live.reshape(B * S, 1), axis=0, dtype=jnp.int32)
     out = x.astype(jnp.float32) + a.astype(jnp.float32) + y.reshape(B, S, D)
-    return out.astype(x.dtype), rows
+    return out.astype(x.dtype), jnp.append(rows, read)
 
 
 def _logits(cfg: Cohere2MoeConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:

@@ -148,9 +148,10 @@ class DeepseekV32Config:
 
 def step_stats_len(cfg: DeepseekV32Config) -> int:
     """int32 counters a paged decode step returns after the pools: rows
-    routed to each held expert, then positions the indexer scored and
+    routed to each held expert, the held experts whose matrices were read
+    (``ops/moe.held_experts``), then positions the indexer scored and
     positions attention read, each summed over rows and layers."""
-    return cfg.held_experts + DSA_COUNTERS
+    return cfg.held_experts + 1 + DSA_COUNTERS
 
 
 def page_shapes(cfg: DeepseekV32Config, page_size: int) -> tuple[tuple, tuple]:
@@ -338,8 +339,8 @@ def _run_layers(cfg: DeepseekV32Config, params: dict, x: jnp.ndarray, carry: Any
     the expert ones. ``attend(lp, layer, h, carry)`` is the caller's
     attention: from the normed input to (heads' outputs [B, S, H*Dv], the
     carry — a cache or the pools — and its int32 counters [DSA_COUNTERS]).
-    ``live`` [B, S] marks the rows whose routing is counted. Returns x, the
-    carry and the counters of :func:`step_stats_len`."""
+    ``live`` [B, S] marks the rows whose routing counts: no other pulls an
+    expert. Returns x, the carry and the counters of :func:`step_stats_len`."""
     B, S, D = x.shape
     moe = dict(params["moe"])
     stacks = {"experts": moe.pop("experts"), "shared": moe.pop("shared")}
@@ -365,8 +366,10 @@ def _run_layers(cfg: DeepseekV32Config, params: dict, x: jnp.ndarray, carry: Any
             gates = sigmoid_topk_gates(
                 flat, lp["w_router"], cfg.top_k, bias=lp["router_bias"], n_group=cfg.n_group,
                 topk_group=cfg.topk_group, scale=cfg.routed_scaling)
-            y, g = held_experts(flat, gates, stacks["experts"], stacks["shared"], cfg.first_expert, _mm, i)
-            return y.reshape(B, S, D), jnp.sum((g > 0) & live.reshape(B * S, 1), axis=0, dtype=jnp.int32)
+            y, g, read = held_experts(flat, gates, stacks["experts"], stacks["shared"], cfg.first_expert,
+                                      _mm, i, top_k=cfg.top_k, rows=live.reshape(B * S))
+            took = jnp.sum((g > 0) & live.reshape(B * S, 1), axis=0, dtype=jnp.int32)
+            return y.reshape(B, S, D), jnp.append(took, read)
 
         x, carry, rows, counts = block(*c, lp, cfg.n_dense_layers + i, ffn)
         return (x, carry), (rows, counts)

@@ -260,6 +260,33 @@ def sigmoid_topk_gates(
     return jnp.einsum("tke,tk->te", onehot, top_s)
 
 
+# Rows of int8 weights a product needs before the chip's arithmetic, and
+# not its memory, bounds it: 2 FLOPs a byte and row against 197 TFLOP/s
+# over 819 GB/s = 240 FLOPs a byte, about 120 rows on a v5e; the prefill
+# bucket nearest above it.
+RIDGE_ROWS = 128
+
+# Places of one expert a step of the grouped product takes. On the chip 16,
+# 32 and 64 read within 3 % of each other at 32 rows and at a chunk of 256
+# (PERF.md §6, PR 34); 16 reads experts of 17-32 rows twice.
+TILE_ROWS = 32
+
+
+def groups_rows(T: int, n_experts: int, top_k: int | None) -> bool:
+    """Whether :func:`held_experts` multiplies each held expert by its own
+    rows alone (True) or by every row with the gate as the weight (False),
+    for ``T`` rows that each choose ``top_k`` of ``n_experts`` published
+    experts — every one of them where ``top_k`` is not told. The loop over
+    every row is right where both hold: the rows are under the ridge
+    (:data:`RIDGE_ROWS`: each matrix is read once and the products for zero
+    gates hide under that read) and a held expert expects two rows or more
+    (``T · top_k / n_experts``: every expert is reached, so every matrix
+    would be read anyway, and a sort and a gather can only add). Past the
+    ridge the products for zero gates are time the chip spends on nothing;
+    under two rows an expert, matrices are read that no row chose."""
+    return T > RIDGE_ROWS or T * (n_experts if top_k is None else top_k) < 2 * n_experts
+
+
 def held_experts(
     h: jnp.ndarray,  # [T, D]
     gates: jnp.ndarray,  # [T, E] over every published expert
@@ -268,42 +295,107 @@ def held_experts(
     first: Any,  # index of the first held expert among the published ones
     mm: Any = jnp.matmul,  # the product for the weights' storage (llama._mm for int8)
     layer: Any = None,  # the stacks are [L, held, ...] and this (traced) layer's is meant
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    *,
+    top_k: int | None = None,  # experts a row chooses (None: any number)
+    rows: jnp.ndarray | None = None,  # [T] bool: the rows whose output counts (None: all)
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One chip's part of a dropless expert layer: the sum over the HELD
     experts ``first .. first + held`` of ``g_e · FFN_e(h)``, plus the mean
-    of the shared experts, which every chip computes alike. Every held
-    expert runs over every row with its gate as the weight (zero where the
-    row chose another expert): exact, no capacity, no drop, and at serving
-    batch sizes an expert's three matrices are read once either way. With
-    ``first=0`` and every expert held this is the whole layer; the shares
-    of all chips, the shared part counted once, add up to it. Returns the
-    float32 sum [T, D] and the held experts' gates [T, held].
+    of the shared experts, which every chip computes alike: exact, no
+    capacity, no drop. With ``first=0`` and every expert held this is the
+    whole layer; the shares of all chips, the shared part counted once,
+    add up to it. Returns the float32 sum [T, D], the held experts' gates
+    [T, held], and how many held experts' matrices were read (int32
+    scalar).
+
+    Two ways to the same sum, chosen from the shapes alone
+    (:func:`groups_rows`, which has the rule and its two reasons): every
+    held expert over every row with its gate as the weight, zero where the
+    row chose another expert (:func:`_over_every_row`: all ``held`` are
+    read, whatever the routing); or each held expert over the rows that
+    chose it, in tiles (:func:`_over_own_rows`), where an expert that no
+    counted row chose reads nothing and a row that ``rows`` leaves out
+    pulls no expert (its output is nobody's to read).
 
     Inside a scan over layers, hand the stacks over whole with ``layer``:
     a matrix is then ONE dynamic slice of its stack with one product to
     read it, which XLA fuses; the scan's own slice of a layer's experts
     has as many readers as experts and is copied out first (268 MB a
     matrix kind at 16 experts of 4096 x 4096)."""
-
-    def ffn(w: dict, e: int) -> jnp.ndarray:
-        gate = jax.nn.silu(mm(h, _at(w["w_gate"], e, layer)).astype(jnp.float32)).astype(h.dtype)
-        return mm(gate * mm(h, _at(w["w_up"], e, layer)), _at(w["w_down"], e, layer)).astype(jnp.float32)
-
-    axis = 0 if layer is None else 1
-    held = jax.tree.leaves(experts["w_gate"])[0].shape[axis]
-    n_shared = jax.tree.leaves(shared["w_gate"])[0].shape[axis]
+    held = _experts_in(experts, layer)
     g = jax.lax.dynamic_slice_in_dim(gates, first, held, axis=1)  # [T, held]
-    y = jnp.zeros(h.shape, jnp.float32)
-    for e in range(held):
-        y = y + g[:, e:e + 1] * ffn(experts, e)
+    T, E = gates.shape
+    if held == 0 or not groups_rows(T, E, top_k):
+        return _over_every_row(h, g, experts, shared, mm, layer), g, jnp.int32(held)
+    y, read = _over_own_rows(h, g, experts, mm, layer, rows)
+    return _add_shared(y, h, shared, mm, layer), g, read
+
+
+def _ffn(x: jnp.ndarray, w: dict, e: Any, mm: Any, layer: Any) -> jnp.ndarray:
+    """Expert ``e``'s SwiGLU over x [n, D], float32."""
+    gate = jax.nn.silu(mm(x, _at(w["w_gate"], e, layer)).astype(jnp.float32)).astype(x.dtype)
+    return mm(gate * mm(x, _at(w["w_up"], e, layer)), _at(w["w_down"], e, layer)).astype(jnp.float32)
+
+
+def _add_shared(y: jnp.ndarray, h: jnp.ndarray, shared: dict, mm: Any, layer: Any) -> jnp.ndarray:
+    """y plus the mean of the shared experts over every row of h."""
+    n_shared = _experts_in(shared, layer)
     for e in range(n_shared):
-        y = y + ffn(shared, e) / n_shared
-    return y, g
+        y = y + _ffn(h, shared, e, mm, layer) / n_shared
+    return y
 
 
-def _at(w: Any, e: int, layer: Any) -> Any:
+def _over_every_row(h: jnp.ndarray, g: jnp.ndarray, experts: dict, shared: dict, mm: Any,
+                    layer: Any) -> jnp.ndarray:
+    """Every held expert over every row, the gate ``g`` [T, held] as the
+    weight; then the shared experts' mean. The reference of the other way."""
+    y = jnp.zeros(h.shape, jnp.float32)
+    for e in range(g.shape[1]):
+        y = y + g[:, e:e + 1] * _ffn(h, experts, e, mm, layer)
+    return _add_shared(y, h, shared, mm, layer)
+
+
+def _over_own_rows(h: jnp.ndarray, g: jnp.ndarray, experts: dict, mm: Any, layer: Any,
+                   rows: jnp.ndarray | None, tile: int = TILE_ROWS) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The grouped product of the held experts: a counting pass over
+    ``g > 0`` gives each row its place among its expert's rows; ONE loop
+    then runs over the tiles of ``tile`` places that hold a row, expert
+    after expert — an expert with no row has no tile and reads nothing —
+    and a tile's rows are taken out of ``h`` and its results added back to
+    them, gate as weight, by two products with the tile's 0/1 matrix
+    (exact: one term a sum). Returns the sum and how many held experts had
+    a tile."""
+    weight = (g if rows is None else jnp.where(rows[:, None], g, 0.0)).astype(jnp.float32)  # [T, held]
+    chosen = weight > 0
+    place = jnp.cumsum(chosen, axis=0, dtype=jnp.int32) - 1  # a row's place among its expert's rows
+    tiles = (jnp.sum(chosen, axis=0, dtype=jnp.int32) + tile - 1) // tile  # [held]
+    ends = jnp.cumsum(tiles)
+    slots = jnp.arange(tile, dtype=jnp.int32)[:, None]
+    exact = jax.lax.Precision.HIGHEST
+
+    def step(i, y):
+        e = jnp.sum(ends <= i, dtype=jnp.int32)  # the expert tile i belongs to
+        w_rows = jax.lax.dynamic_index_in_dim(weight, e, axis=1, keepdims=False)
+        place_rows = jax.lax.dynamic_index_in_dim(place, e, axis=1, keepdims=False)
+        first_place = (i - ends[e] + tiles[e]) * tile
+        take = (w_rows > 0)[None, :] & (place_rows[None, :] - first_place == slots)  # [tile, T]
+        x = jnp.matmul(take.astype(h.dtype), h, precision=exact, preferred_element_type=jnp.float32).astype(h.dtype)
+        back = jnp.where(take, w_rows[None, :], 0.0).T  # [T, tile]
+        return y + jnp.matmul(back, _ffn(x, experts, e, mm, layer), precision=exact)
+
+    y = jax.lax.fori_loop(0, ends[-1], step, jnp.zeros(h.shape, jnp.float32))
+    return y, jnp.sum(tiles > 0, dtype=jnp.int32)
+
+
+def _experts_in(stacks: dict, layer: Any) -> int:
+    """How many experts a tree of stacks [n, ...] — or [L, n, ...] with a ``layer`` — holds."""
+    return jax.tree.leaves(stacks["w_gate"])[0].shape[0 if layer is None else 1]
+
+
+def _at(w: Any, e: Any, layer: Any) -> Any:
     """Expert ``e``'s matrix of a stack [held, ...] — or of layer ``layer``
-    of a stack [L, held, ...] — plain or ``{"q", "s"}`` int8."""
+    of a stack [L, held, ...] — plain or ``{"q", "s"}`` int8; ``e`` may be
+    traced."""
     if layer is None:
         return jax.tree.map(lambda a: a[e], w)
     return jax.tree.map(

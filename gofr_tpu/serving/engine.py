@@ -565,8 +565,9 @@ class ServingEngine:
         if refusal:
             raise ValueError(refusal)
         # int32 counters the model's paged step adds to a block's packed
-        # result (rows per held expert; deepseek_v32 also the positions its
-        # indexer scored and its attention read); 0 for most
+        # result (rows per held expert and the held experts read;
+        # deepseek_v32 also the positions its indexer scored and its
+        # attention read); 0 for most
         self._stats_len = model.step_stats_len(cfg)
         if self.config.spec_tokens < 0:
             raise ValueError("TPU_SPEC_TOKENS must be >= 0")
@@ -3657,20 +3658,26 @@ class ServingEngine:
         """A block's model counters, read with its tokens: row-expert
         pairs the held experts took over the block's decode steps and
         layers (``moe_rows``), the fullest expert's (``moe_max``), and
-        app_moe_expert_rows_total by the expert's published index. A model
-        with a sparse selection (``index_topk``) counts after them the
-        positions its indexer scored and the positions its attention read
+        app_moe_expert_rows_total by the expert's published index; after
+        them the held experts whose matrices the steps read
+        (``moe_reached``, app_moe_experts_read_total:
+        ``ops/moe.held_experts`` counts them). A model with a sparse
+        selection (``index_topk``) counts after them the positions its
+        indexer scored and the positions its attention read
         (``dsa_scored``, ``dsa_selected``; app_dsa_positions_total)."""
-        rows, dsa = stats, {}
+        held = self.model_cfg.held_experts
+        rows, reached, dsa = stats[:held], int(stats[held]), {}
         if getattr(self.model_cfg, "index_topk", None):
-            rows, (scored, selected) = stats[:-2], stats[-2:].tolist()
+            scored, selected = stats[held + 1:].tolist()
             dsa = {"dsa_scored": scored, "dsa_selected": selected}
             if self._metrics:
                 for kind, n in (("scored", scored), ("selected", selected)):
                     if n:
                         self._metrics.add_counter("app_dsa_positions_total", n, kind=kind)
-        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), **dsa)
+        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), moe_reached=reached, **dsa)
         if self._metrics:
+            if reached:
+                self._metrics.add_counter("app_moe_experts_read_total", reached)
             first = self.model_cfg.first_expert
             for e, n in enumerate(rows.tolist()):
                 if n:

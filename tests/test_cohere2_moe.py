@@ -136,15 +136,19 @@ def test_prefill_then_decode_past_the_window_agrees_with_the_reference(weights, 
     assert got.shape == (29, 300) and np.abs(want).max() > 10
     assert np.abs(got - want[11:]).max() < TOL
     # every step, each of 8 layers routes the one live row to 4 of the 16 experts, all held
-    assert counted.shape == (28, 16) and (counted.sum(axis=1) == 8 * 4).all()
+    # (2 rows x 4 of 16 experts: the product groups the rows and counts, last, the experts it read — the live row's)
+    assert counted.shape == (28, 17) and (counted[:, :16].sum(axis=1) == 8 * 4).all() and (counted[:, 16] == 8 * 4).all()
 
 
+@pytest.mark.parametrize("chunk", [12, 3], ids=["chunks-of-12-the-loop-over-every-row", "chunks-of-3-the-grouped-product"])
 @pytest.mark.parametrize("weights", ["plain", "int8"])
-def test_chunked_prefill_agrees_with_the_reference(weights, request):
+def test_chunked_prefill_agrees_with_the_reference(weights, chunk, request):
     params = request.getfixturevalue(weights)
     ids = ids_of(40, seed=4)
     want = np.asarray(reference.logits(as_file(CFG), params, ids))
-    got = chunked(CFG, params, ids, chunk=12)  # chunks at 0, 12, 24, 36: the window crosses them
+    # two rows a dispatch: 24 tokens x 4 of 16 experts is 6 rows an expert, the loop; 6 tokens is 1.5, grouped
+    assert moe_ops.groups_rows(2 * chunk, CFG.n_experts, CFG.top_k) is (chunk == 3)
+    got = chunked(CFG, params, ids, chunk)  # chunks at 0, 12, 24, 36 (or every 3): the window crosses them
     assert np.abs(got - want).max() < TOL
 
 
@@ -176,14 +180,14 @@ def test_the_shares_add_up_to_the_uncut_layer(plain):
     gates = moe_ops.sigmoid_topk_gates(h, lp["w_router"], CFG.top_k)
     assert ((gates > 0).sum(axis=1) == CFG.top_k).all() and np.allclose(gates.sum(axis=1), 1.0, atol=1e-6)
     none_held = jax.tree.map(lambda a: a[:0], lp["experts"])
-    shared, _ = moe_ops.held_experts(h, gates, none_held, lp["shared"], 0)
+    shared, *_ = moe_ops.held_experts(h, gates, none_held, lp["shared"], 0)
     total, counted = jnp.zeros_like(shared), []
     for first in (0, 4, 8, 12):
         share = jax.tree.map(lambda a: a[first:first + 4], lp["experts"])
-        part, g = moe_ops.held_experts(h, gates, share, lp["shared"], first)
+        part, g, _ = moe_ops.held_experts(h, gates, share, lp["shared"], first)
         total += part - shared
         counted.append(int((g > 0).sum()))
-    whole, _ = moe_ops.held_experts(h, gates, lp["experts"], lp["shared"], 0)
+    whole, *_ = moe_ops.held_experts(h, gates, lp["experts"], lp["shared"], 0)
     assert sum(counted) == 24 * CFG.top_k
     assert np.abs(total + shared - whole).max() < 1e-5
     uncut = reference._ffn_sum(h, lp["experts"], gates.T, 8) + reference._ffn_sum(
@@ -202,7 +206,9 @@ def test_a_share_of_the_model_is_the_reference_given_the_same_share(plain):
     assert got.shape[1] == 200 and np.abs(got - want[11:]).max() < TOL
     whole = np.asarray(reference.logits(as_file(CFG), plain, ids))[11:, :200]
     assert np.abs(got - whole).max() > 10 * TOL
-    assert counted.shape[1] == 4 and 0 < counted.sum() < 12 * 8 * 4  # a quarter of the experts: some rows, not all
+    # a quarter of the experts: some rows, not all; after them the experts read (2 rows x 4 of 16: grouped)
+    assert counted.shape[1] == 4 + 1 and 0 < counted[:, :4].sum() < 12 * 8 * 4
+    assert ((counted[:, :4] > 0).sum(axis=1) <= counted[:, 4]).all() and (counted[:, 4] <= counted[:, :4].sum(axis=1)).all()
 
 
 # ------------------------------------------------ (d): the kernel's window
@@ -291,7 +297,8 @@ def test_the_seam_is_one_lookup_from_the_config_s_class():
     from gofr_tpu.models import llama
 
     assert batch_ops.model_of(CFG) is cm and batch_ops.model_of(llama.LlamaConfig.tiny()) is llama
-    assert (cm.step_stats_len(CFG), llama.step_stats_len(llama.LlamaConfig.tiny())) == (16, 0)
+    # 16 held experts' rows and the held experts read
+    assert (cm.step_stats_len(CFG), llama.step_stats_len(llama.LlamaConfig.tiny())) == (16 + 1, 0)
 
 
 def test_a_blocks_counters_ride_its_packed_result():
@@ -303,11 +310,13 @@ def test_a_blocks_counters_ride_its_packed_result():
     assert batch_ops._append_stats(packed, jnp.zeros(0, jnp.int32)) is packed
 
 
-def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counter(plain, monkeypatch):
+@pytest.mark.parametrize("slots", [3, 8], ids=["three-slots-the-grouped-product", "eight-slots-the-loop-over-every-row"])
+def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counter(plain, monkeypatch, slots):
     """POST /generate and the SSE route through a real App, a bucketed and
-    a chunked prompt: the tokens are the reference's greedy choice, the commit spans carry ``moe_rows`` and
-    ``moe_max``, the dispatch spans ``win_rows``, and /metrics counts rows
-    by expert."""
+    a chunked prompt: the tokens are the reference's greedy choice, the commit spans carry ``moe_rows``,
+    ``moe_max`` and ``moe_reached``, the dispatch spans ``win_rows``, and /metrics counts rows
+    by expert and the experts read. A decode step of 3 rows (4 of 16 experts each) groups the rows and
+    counts the experts it read; one of 8 rows runs every held expert over every row, and counts them all."""
     import gofr_tpu
     from gofr_tpu.config import MapConfig
     from gofr_tpu.serving import engine as engine_mod
@@ -318,8 +327,8 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counter(
     app = gofr_tpu.App(MapConfig({"HTTP_PORT": str(http_port), "METRICS_PORT": str(metrics_port),
                                   "APP_NAME": "cohere2-moe-test", "LOG_LEVEL": "WARN"}, use_env=False))
     tokenizer = ByteTokenizer(300)
-    engine = ServingEngine(CFG, plain, engine_settings(), tokenizer, metrics=app.container.metrics_manager,
-                           logger=app.container.logger)
+    engine = ServingEngine(CFG, plain, engine_settings(max_slots=slots), tokenizer,
+                           metrics=app.container.metrics_manager, logger=app.container.logger)
     seen = []
     real = engine_mod._StepPhase.set
     monkeypatch.setattr(engine_mod._StepPhase, "set", lambda self, **kw: (seen.append((self._phase, kw)), real(self, **kw))[1])
@@ -368,6 +377,14 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counter(
     assert any(kw["moe_rows"] for kw in commits) and all(0 <= kw["moe_max"] <= kw["moe_rows"] for kw in commits)
     # a live row's step routes it to top_k experts in each of 8 layers, all held here
     assert all(kw["moe_rows"] % (8 * CFG.top_k) == 0 for kw in commits)
+    if moe_ops.groups_rows(slots, CFG.n_experts, CFG.top_k):
+        # one request at a time: a live row's 4 experts a layer are the experts read, and an idle step reads none
+        assert all(kw["moe_reached"] == kw["moe_rows"] for kw in commits)
+    else:
+        assert all(kw["moe_reached"] == 4 * 8 * 16 for kw in commits)  # steps x layers x held, whatever the routing
+    read = [line for line in metrics.splitlines() if line.startswith("app_moe_experts_read_total")]
+    # /metrics was read while the engine still committed blocks: it holds the count of the commits up to one of them
+    assert sum(float(line.rsplit(" ", 1)[1]) for line in read) in np.cumsum([kw["moe_reached"] for kw in commits])[4:]
     wins = [kw["win_rows"] for phase, kw in seen if phase == "dispatch" and "win_rows" in kw]
     assert wins and max(wins) >= 1  # rows decode past the window of 8
     counted = [line for line in metrics.splitlines() if line.startswith("app_moe_expert_rows_total{")]

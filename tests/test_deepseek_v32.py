@@ -155,16 +155,20 @@ def test_prefill_then_decode_with_a_binding_selection_agrees_with_the_reference(
     assert np.abs(got - want[11:]).max() < TOL
     # every step, each of the 2 expert layers routes the one live row to 4 of the 16 experts, all held;
     # each of the 3 layers scores the row's whole context and reads 8 positions of it
-    assert counted.shape == (28, 18) and (counted[:, :16].sum(axis=1) == 2 * 4).all()
-    assert (counted[:, 16] == 3 * np.arange(13, 41)).all() and (counted[:, 17] == 3 * 8).all()
+    # (2 rows x 4 of 16 experts: the product groups the rows, and counts the experts it read — the live row's 4 a layer)
+    assert counted.shape == (28, 19) and (counted[:, :16].sum(axis=1) == 2 * 4).all() and (counted[:, 16] == 2 * 4).all()
+    assert (counted[:, 17] == 3 * np.arange(13, 41)).all() and (counted[:, 18] == 3 * 8).all()
 
 
+@pytest.mark.parametrize("chunk", [12, 6], ids=["chunks-of-12-the-loop-over-every-row", "chunks-of-6-the-grouped-product"])
 @pytest.mark.parametrize("weights", ["plain", "int8"])
-def test_chunked_prefill_agrees_with_the_reference(weights, request):
+def test_chunked_prefill_agrees_with_the_reference(weights, chunk, request):
     params = request.getfixturevalue(weights)
     ids = ids_of(40, seed=4)
     want = np.asarray(reference.logits(as_file(CFG), params, ids))
-    got = chunked(CFG, params, ids, chunk=12)  # chunks at 0, 12, 24, 36: selections reach across them
+    # 12 rows x 4 of 16 experts is 3 rows an expert: the loop; 6 rows is 1.5: each expert takes its own rows
+    assert moe_ops.groups_rows(chunk, CFG.n_experts, CFG.top_k) is (chunk == 6)
+    got = chunked(CFG, params, ids, chunk)  # chunks at 0, 12, 24, 36 (or every 6): selections reach across them
     assert np.abs(got - want).max() < TOL
 
 
@@ -184,7 +188,7 @@ def test_a_selection_wider_than_the_context_is_attention_without_an_indexer(plai
     binding, _ = serve_through_the_cache(CFG, plain, ids, 12, 16)
     got, counted = serve_through_the_cache(wide, plain, ids, 12, 16)
     assert np.abs(binding - got).max() > 10 * TOL
-    assert (counted[:, 17] == counted[:, 16]).all()  # every scored position is read
+    assert (counted[:, 18] == counted[:, 17]).all()  # every scored position is read
     blind = dict(plain)
     for group in ("dense", "moe"):
         blind[group] = dict(plain[group], idx_w=jnp.zeros_like(plain[group]["idx_w"]))
@@ -336,14 +340,14 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(plain):
     assert (chosen.sum(axis=1) == CFG.top_k).all() and np.allclose(gates.sum(axis=1), CFG.routed_scaling, atol=1e-5)
     assert (chosen.reshape(24, CFG.n_group, -1).any(axis=2).sum(axis=1) <= CFG.topk_group).all()
     none_held = jax.tree.map(lambda a: a[:0], lp["experts"])
-    shared, _ = moe_ops.held_experts(h, gates, none_held, lp["shared"], 0)
+    shared, *_ = moe_ops.held_experts(h, gates, none_held, lp["shared"], 0)
     total, counted = jnp.zeros_like(shared), []
     for first in range(0, 16, 2):
         share = jax.tree.map(lambda a: a[first:first + 2], lp["experts"])
-        part, g = moe_ops.held_experts(h, gates, share, lp["shared"], first)
+        part, g, _ = moe_ops.held_experts(h, gates, share, lp["shared"], first)
         total += part - shared
         counted.append(int((g > 0).sum()))
-    whole, _ = moe_ops.held_experts(h, gates, lp["experts"], lp["shared"], 0)
+    whole, *_ = moe_ops.held_experts(h, gates, lp["experts"], lp["shared"], 0)
     assert sum(counted) == 24 * CFG.top_k
     assert np.abs(total + shared - whole).max() < 1e-5
     sigma = jax.nn.sigmoid(jnp.matmul(h, lp["w_router"], precision=jax.lax.Precision.HIGHEST))
@@ -365,7 +369,9 @@ def test_a_share_of_the_model_is_the_reference_given_the_same_share(plain):
     assert got.shape[1] == 200 and np.abs(got - want[11:]).max() < TOL
     whole = np.asarray(reference.logits(as_file(CFG), plain, ids))[11:, :200]
     assert np.abs(got - whole).max() > 10 * TOL
-    assert counted.shape[1] == 4 + 2 and 0 < counted[:, :4].sum() < 12 * 2 * 4  # one group of four: some rows, not all
+    # one group of four: some rows, not all; then the experts the grouped product read, then the indexer's two
+    assert counted.shape[1] == 4 + 1 + 2 and 0 < counted[:, :4].sum() < 12 * 2 * 4
+    assert (counted[:, 4] == counted[:, :4].sum(axis=1)).all()  # one live row: each expert it chose, once a layer
 
 
 # ----------------------------------------------------------------- the pager
@@ -414,15 +420,16 @@ def test_engines_the_model_has_no_program_for_are_refused_at_construction(plain,
 
 
 def test_the_seam_finds_the_module_and_its_counters():
-    assert batch_ops.model_of(CFG) is ds and ds.step_stats_len(CFG) == 16 + 2
+    # 16 held experts' rows, the held experts read, the indexer's two
+    assert batch_ops.model_of(CFG) is ds and ds.step_stats_len(CFG) == 16 + 1 + 2
     assert CFG.n_kv_heads == 1 and CFG.head_dim == CFG.qk_rope_head_dim
 
 
 def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters(plain, monkeypatch):
     """POST /generate and the SSE route through a real App, a bucketed and
     a chunked prompt: the tokens are the reference's greedy choice, the
-    commit spans carry ``dsa_scored``, ``dsa_selected``, ``moe_rows`` and
-    ``moe_max``, the dispatch spans ``dsa_rows``, and /metrics counts the
+    commit spans carry ``dsa_scored``, ``dsa_selected``, ``moe_rows``,
+    ``moe_max`` and ``moe_reached``, the dispatch spans ``dsa_rows``, and /metrics counts the
     positions by kind and the rows by expert."""
     import gofr_tpu
     from gofr_tpu.config import MapConfig
@@ -485,14 +492,18 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters
     # a live row's step routes it to top_k experts in each of the 2 expert layers, all held here,
     # and reads at most index_topk positions in each of the 3 layers, of those it scored
     assert all(kw["moe_rows"] % (2 * CFG.top_k) == 0 for kw in commits)
+    # 3 slots x 4 of 16 experts: the product groups the rows and counts the experts it read on the device —
+    # one request at a time, so a live row's 4 experts a layer and no other
+    assert all(kw["moe_reached"] == kw["moe_rows"] for kw in commits)
     assert all(0 <= kw["dsa_selected"] <= kw["dsa_scored"] for kw in commits)
     assert any(0 < kw["dsa_selected"] < kw["dsa_scored"] for kw in commits)  # the selection binds
     assert all(kw["dsa_selected"] * 2 * CFG.top_k <= kw["moe_rows"] * 3 * CFG.index_topk for kw in commits)
     bound = [kw["dsa_rows"] for phase, kw in seen if phase == "dispatch" and "dsa_rows" in kw]
     assert bound and max(bound) >= 1  # rows decode past 8 positions
     for name, key, label in (("app_moe_expert_rows_total", "moe_rows", 'expert="'),
+                             ("app_moe_experts_read_total", "moe_reached", ""),
                              ("app_dsa_positions_total", None, 'kind="')):
-        counted = [line for line in metrics.splitlines() if line.startswith(name + "{")]
+        counted = [line for line in metrics.splitlines() if line.startswith((name + "{", name + " "))]
         assert counted and all(label in line for line in counted)
         total = sum(float(line.rsplit(" ", 1)[1]) for line in counted)
         assert total == sum(kw[key] if key else kw["dsa_scored"] + kw["dsa_selected"] for kw in commits)

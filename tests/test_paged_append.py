@@ -406,3 +406,110 @@ def test_the_compiled_ragged_step_writes_a_chunk_into_the_pools_in_place(one_chi
     assert set(made) <= {"scatter", "fusion"} and made["scatter"] == 4, made
     appends = [line for line in text.splitlines() if "custom-call(" in line and "paged_kv_append" in line]
     assert len(appends) == 2  # the decode steps' append, once a stack of layers
+
+
+# ------------------------------------- the expert product a program holds (PR 34)
+def _program_arguments(cfg, one_chip, B, M, page=16):
+    """Shapes, on the described chip, of what the two paged programs take
+    first: params, the two pools, the decode state, the block tables."""
+    model = batch_ops.model_of(cfg)
+    N = B * M
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    on_chip = functools.partial(jax.tree.map, lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip))
+    params = on_chip(jax.eval_shape(lambda k: model.init_params(cfg, k), key))
+    pools = [jax.ShapeDtypeStruct((cfg.n_layers, N + 1) + shape, cfg.dtype, sharding=one_chip)
+             for shape in model.page_shapes(cfg, page)]
+    state = batch_ops.DecodeState(vec(i32), vec(i32), vec(flag), vec(i32), vec(i32), vec(f32),
+                                  vec(i32), vec(f32), on_chip(key), vec(i32))
+    return vec, on_chip(key), (params, *pools, state, vec(i32, B, M))
+
+
+def test_wide_s_decode_block_lowers_to_the_loop_over_every_row_and_nothing_else(one_chip, monkeypatch):
+    """``cohere2_moe``'s ``decode_block_paged`` at the shapes that decide
+    (``commandaplus.wide``: 64 rows, 8 of 128 experts a row, 16 held),
+    lowered for a described v5e, is line for line the text of the program
+    whose expert layer is the parent's function — every held expert over
+    every row, no mask — with the constant count of its held experts
+    beside it: at four rows an expert under the ridge ``held_experts``
+    adds nothing else to it (PERF.md §6, PR 34)."""
+    from gofr_tpu.ops import moe
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    cfg = cm.Cohere2MoeConfig.tiny(
+        vocab_size=512, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=128, d_ff=256,
+        n_experts=128, top_k=8, held_experts=16, n_shared=4, layer_types=(cm.SLIDING, cm.FULL),
+        sliding_window=32, max_seq_len=131, dtype=jnp.bfloat16)
+
+    def lowered(B):
+        jax.clear_caches()  # the trace is cached by the arguments, and the function under it changes
+        vec, _, first = _program_arguments(cfg, one_chip, B, 64)
+        with jax.default_matmul_precision("default"):
+            text = batch_ops.decode_block_paged.lower(cfg, *first, vec(jnp.bool_), STEPS).as_text()
+        # a Mosaic call's payload is its kernel's serialized body, which carries where it was traced from
+        return re.sub(r'backend_config = "(?:[^"\\]|\\.)*"', "backend_config = <kernel>", text).splitlines()
+
+    def parents(h, gates, experts, shared, first, mm=jnp.matmul, layer=None, **told):
+        g = jax.lax.dynamic_slice_in_dim(gates, first, 16, axis=1)
+        return moe._over_every_row(h, g, experts, shared, mm, layer), g, jnp.int32(16)
+
+    ours = lowered(64)
+    monkeypatch.setattr(cm, "held_experts", parents)
+    theirs = lowered(64)
+    jax.clear_caches()
+    assert len(ours) > 500 and ours == theirs
+
+
+def test_the_compiled_ragged_step_multiplies_a_held_expert_by_tiles_of_its_own_rows(one_chip, no_compile_cache, monkeypatch):
+    """``ragged_step_paged`` of ``deepseek_v32`` by the chip's compiler at
+    the shapes that decide in ``deepseekv32.long`` (32 rows a decode step
+    and a chunk of 256, 8 of 256 experts a row, 32 held; narrow widths):
+    no product with a held expert's matrix takes a chunk's block of 256
+    rows — every one takes a tile, inside the ``while`` over the tiles
+    that hold a row — and the shared expert's product over the whole
+    chunk is there beside it."""
+    from gofr_tpu.ops import moe
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    D, F, B, C, held = 384, 640, 32, 256, 32  # widths no other matrix of the model has
+    cfg = ds.DeepseekV32Config.tiny(
+        vocab_size=512, d_model=D, n_layers=4, n_dense_layers=2, n_heads=4, q_lora_rank=64,
+        kv_lora_rank=96, qk_nope_head_dim=32, qk_rope_head_dim=32, v_head_dim=32, index_n_heads=4,
+        index_head_dim=128, index_topk=64, d_ff=256, d_ff_expert=F, n_experts=256, held_experts=held,
+        n_group=8, topk_group=4, top_k=8, max_seq_len=1024, dtype=jnp.bfloat16)
+    vec, key, first = _program_arguments(cfg, one_chip, B, 64)
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    with jax.default_matmul_precision("default"):
+        text = batch_ops.ragged_step_paged.lower(
+            cfg, *first, vec(i32, B, C), vec(i32), vec(flag), vec(i32), vec(flag), vec(i32), vec(i32), vec(i32),
+            vec(f32), vec(i32), vec(f32), vec(i32), key, vec(flag), STEPS).compile().as_text()
+
+    def products(experts):
+        """The fused computations that take a matrix of a stack of so many
+        experts a layer AND a block of activations, as (name, the rows of its blocks)."""
+        found = {}
+        for line in text.splitlines():
+            m = re.match(r"%(fused_computation[\w.\-]*) \((.*)\) -> ", line)
+            if not m:
+                continue
+            shapes = re.findall(r"\w+\[([\d,]*)\]", m.group(2))
+            dims = [tuple(int(d) for d in shape.split(",") if d) for shape in shapes]
+            stacks = [d for d in dims if d[-2:] in ((D, F), (F, D)) and int(np.prod(d[:-2])) in (experts, 2 * experts)]
+            blocks = {d[-2] for d in dims if len(d) >= 2 and d[-1] in (D, F) and d not in stacks and d[-2:] not in ((D, F), (F, D))}
+            if stacks and blocks:
+                found[m.group(1)] = blocks
+        return found
+
+    routed, shared = products(held), products(1)
+    assert routed and all(rows == {moe.TILE_ROWS} for rows in routed.values()), routed  # never the chunk's 256
+    assert {C} in shared.values()  # the shared expert is one product over every row of the chunk
+    comps, entry = hlo_text.computations(text)
+    calls = {name for name, lines in comps.items() if any(f"calls=%{r}" in line or f"calls={r}" in line
+                                                           for line in lines for r in routed)}
+    assert calls and entry not in calls  # inside the loop over the tiles that hold a row, not at the top
