@@ -303,6 +303,15 @@ KERNELS: tuple[KernelContract, ...] = (
         donated=("k_pool", "v_pool"),
         returns=(Ret("k_pool", like="k_pool"), Ret("v_pool", like="v_pool")),
     ),
+    # ``_write_pages`` for a cache of several pools and a per-slot state
+    # (a model's ``cache_spec``): the pools and slabs are dicts by pool
+    # name, ``k_pool["state"]`` the state arrays, written at ``slot``.
+    KernelContract(
+        "_write_slot", _KVC,
+        params=("k_pool", "v_pool", "k_slab", "v_slab", "page_ids", "slot"),
+        donated=("k_pool", "v_pool"),
+        returns=(Ret("k_pool", like="k_pool"), Ret("v_pool", like="v_pool")),
+    ),
     # The paged decode kernel: a program a row, the pools left in HBM,
     # pages fetched by the kernel's own DMAs in a loop whose trip count is
     # the row's length. Tile sizes are worked out inside from these shapes,
@@ -338,7 +347,9 @@ KERNELS: tuple[KernelContract, ...] = (
         "flash_attention", _FLASH,
         params=("q", "k", "v", "kv_len", "causal", "scale", "block_q",
                 "block_k", "interpret", "window"),
-        static=("causal", "block_q", "block_k", "interpret"),
+        # scale: None (1/sqrt(D)) or a Python float — a traced scalar would
+        # be a constant the kernel captures, which Mosaic refuses
+        static=("causal", "scale", "block_q", "block_k", "interpret"),
         returns=(Ret("out", like="q"),),
     ),
 )

@@ -250,6 +250,20 @@ def run_matrix() -> dict:
         },
     ))
 
+    # the same write into a cache of several pools and a per-slot state: a
+    # window pool of two layers, a full pool of one, a state array
+    pools = {"window": sds((2, N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype),
+             "full": sds((1, N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)}
+    slabs = {name: sds((pool.shape[0], 2 * PAGE, Hkv, Dh), cfg.dtype) for name, pool in pools.items()}
+    cases.append(_eval_case(
+        kvc_mod._write_slot.__wrapped__, C["_write_slot"], "pools",
+        {
+            "k_pool": {**pools, "state": {"ssm": sds((3, 3, 4, D), jnp.float32)}}, "v_pool": pools,
+            "k_slab": {**slabs, "state": {"ssm": sds((3, 4, D), jnp.float32)}}, "v_slab": slabs,
+            "page_ids": {"window": vec(2), "full": vec(2)}, "slot": sds((), jnp.int32),
+        },
+    ))
+
     # ops-level attention sees ONE layer's pool ([N+1, Hkv, page, Dh]) or,
     # given a layer index, the whole pools as the decode step passes them
     lp = sds((N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)

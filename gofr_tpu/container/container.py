@@ -245,6 +245,23 @@ class Container:
             "models with an index_topk only)",
         )
         m.new_gauge(
+            "app_kv_pool_pages",
+            "Pages of each pool of a cache that holds several (label pool="
+            "the pool's name, state=used|total), set with each consumed "
+            "block; models with a cache_spec only",
+        )
+        m.new_counter(
+            "app_prefill_positions_total",
+            "Prompt positions a model whose upper layers run on a prompt's "
+            "last position alone computed (label part=self: the layers up to "
+            "its shared cache, part=cross: the layers above)",
+        )
+        m.new_counter(
+            "app_ssm_state_resets_total",
+            "Slots whose recurrent state an admission started anew (a "
+            "bucketed prefill's write, a chunk at position 0)",
+        )
+        m.new_gauge(
             "app_decode_block_size",
             "Decode steps fused per device dispatch (TPU_BATCH_MULTI_STEP)",
         )

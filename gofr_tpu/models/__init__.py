@@ -8,6 +8,10 @@ The serving framework's model zoo (BASELINE.json configs):
 - deepseek_v32: latent attention under a learned sparse selection, leading
   dense layers, group-limited sparse experts beside a shared one (served on
   the paged path, also as one chip's share)
+- phi4flash: state-space and window-attention layers under one full-attention
+  layer whose K and V the whole upper half reads, gated memory units,
+  differential attention (served on the paged path: a recurrent state a slot
+  beside two pools)
 - bert: encoder embedder (/embed endpoint)
 - whisper: encoder-decoder ASR (async Pub/Sub path)
 
@@ -17,6 +21,6 @@ scanned (lax.scan) so compile time is flat in depth; weights are bf16 by
 default with f32 accumulation inside ops.
 """
 
-from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, llama
+from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, llama, phi4flash
 
-__all__ = ["llama", "cohere2_moe", "deepseek_v32", "bert"]
+__all__ = ["llama", "cohere2_moe", "deepseek_v32", "phi4flash", "bert"]

@@ -119,7 +119,7 @@ def step_stats_len(cfg: Cohere2MoeConfig) -> int:
     return cfg.held_experts + 1
 
 
-def unserved(engine_config: Any, lora: Any) -> str | None:
+def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     """What an engine asks for that this model has no program for, in a
     sentence; None if it can be built."""
     if engine_config.kv_layout != "paged":

@@ -160,7 +160,7 @@ def page_shapes(cfg: DeepseekV32Config, page_size: int) -> tuple[tuple, tuple]:
     return (1, page_size, cfg.row_width), (1, page_size, cfg.index_head_dim)
 
 
-def unserved(engine_config: Any, lora: Any) -> str | None:
+def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     """What an engine asks for that this model has no program for, in a
     sentence; None if it can be built."""
     if engine_config.kv_layout != "paged":

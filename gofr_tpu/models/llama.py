@@ -152,7 +152,7 @@ def page_shapes(cfg: Any, page_size: int) -> tuple[tuple, tuple]:
     return shape, shape
 
 
-def unserved(engine_config: Any, lora: Any) -> str | None:
+def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     """What an engine asks for that this model has no program for: nothing."""
     return None
 

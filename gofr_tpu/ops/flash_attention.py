@@ -133,7 +133,7 @@ def _flash_body(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("causal", "scale", "block_q", "block_k", "interpret")
 )
 def flash_attention(
     q: jnp.ndarray,  # [B, Sq, H, D]

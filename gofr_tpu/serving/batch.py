@@ -54,17 +54,23 @@ def model_of(cfg: Any) -> Any:
     """The module that serves ``cfg``: the one its class is defined in
     (``LlamaConfig`` → ``models/llama.py``, ``Cohere2MoeConfig`` →
     ``models/cohere2_moe.py``, ``DeepseekV32Config`` →
-    ``models/deepseek_v32.py``). Every program here reaches its model
+    ``models/deepseek_v32.py``, ``Phi4FlashConfig`` →
+    ``models/phi4flash.py``). Every program here reaches its model
     through this one lookup, at trace time. What a served module holds:
     ``KVCache`` (the dense cache, also a bucketed prefill's scratch),
     ``prefill``, ``decode_step_paged`` and ``decode_chunk_paged`` with
     ``llama``'s arguments, ``step_stats_len(cfg)`` — how many int32
     counters its paged step returns after the pools (0: none) —
     ``page_shapes(cfg, page_size)``, what a page of each of the two pools
-    holds (``serving/kv_cache.py``), and
-    ``unserved(engine_config, lora)``, the sentence that refuses an engine
-    the model has no program for. The dense and speculative programs
-    call the functions ``llama`` has for them by the same names."""
+    holds (``serving/kv_cache.py``) — or, for a model that stores several
+    kinds of thing, ``cache_spec(cfg, page_size)``: its pools by layer kind
+    and a per-slot state, in which case the programs' ``k_pool``,
+    ``v_pool`` and ``block_tables`` are the pager's dicts, its prefill
+    returns them through ``prefill_slabs`` and, if it sets
+    ``CHUNK_TAKES_FINISH``, its chunk program is told which rows finish —
+    and ``unserved(engine_config, lora, cfg)``, the sentence that refuses
+    an engine the model has no program for. The dense and speculative
+    programs call the functions ``llama`` has for them by the same names."""
     return sys.modules[type(cfg).__module__]
 
 
@@ -76,10 +82,15 @@ def prefill_compute(
     seq_len: jnp.ndarray,  # [1]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run prefill WITHOUT a persistent cache: returns (last_logits [1,V],
-    k_slab, v_slab [L, S_bucket, Hkv, Dh]) for scatter into a slot."""
+    k_slab, v_slab [L, S_bucket, Hkv, Dh]) for scatter into a slot (a
+    model with a ``cache_spec``: what its ``prefill_slabs`` makes of the
+    cache — slabs by pool name and the row's state)."""
     model = model_of(cfg)
     scratch = model.KVCache.create(cfg, 1, max_len=tokens.shape[1])
     last, cache = model.prefill(cfg, params, tokens, scratch, seq_len)
+    slabs = getattr(model, "prefill_slabs", None)
+    if slabs is not None:
+        return (last, *slabs(cache))
     return last, cache.k[:, 0], cache.v[:, 0]
 
 
@@ -611,9 +622,13 @@ def ragged_step_paged(
     """Paged twin of :func:`ragged_step`: chunk writes route through the
     block tables (inactive rows and beyond-capacity positions divert to
     the trash page), decode appends likewise."""
-    logits_c, k_pool, v_pool = model_of(cfg).decode_chunk_paged.__wrapped__(
+    model = model_of(cfg)
+    # a model whose upper layers run on a prompt's last position alone is
+    # told which rows finish, and returns logits [B, 1, V] at that position
+    told = {"finish": finish} if getattr(model, "CHUNK_TAKES_FINISH", False) else {}
+    logits_c, k_pool, v_pool = model.decode_chunk_paged.__wrapped__(
         cfg, params, chunk, k_pool, v_pool, block_tables, chunk_start,
-        chunk_active, kv_capacity,
+        chunk_active, kv_capacity, **told,
     )
     state, first, last_logits = _fold_finished_prefill(
         state, logits_c, chunk, chunk_start, finish, new_len, budgets,

@@ -561,14 +561,20 @@ class ServingEngine:
         # the model's module (batch_ops.model_of) says what it has no
         # program for yet, in a sentence, before anything is built
         model = batch_ops.model_of(cfg)
-        refusal = model.unserved(self.config, lora)
+        refusal = model.unserved(self.config, lora, cfg)
         if refusal:
             raise ValueError(refusal)
         # int32 counters the model's paged step adds to a block's packed
         # result (rows per held expert and the held experts read;
         # deepseek_v32 also the positions its indexer scored and its
-        # attention read); 0 for most
+        # attention read; a model that names its own, STEP_STATS, has them
+        # set on the commit span under those names); 0 for most
         self._stats_len = model.step_stats_len(cfg)
+        self._stats_names = getattr(model, "STEP_STATS", None)
+        # a model whose layers above its one shared cache run on a prompt's
+        # last position alone: its prefill spans say how many positions
+        # each half ran, and an admission resets a recurrent state
+        self._upper_on_last = bool(getattr(model, "CHUNK_TAKES_FINISH", False))
         if self.config.spec_tokens < 0:
             raise ValueError("TPU_SPEC_TOKENS must be >= 0")
         if (self.config.multi_step is not None and self.config.multi_step > 1
@@ -2630,6 +2636,8 @@ class ServingEngine:
                         self._prefix_cache.put(cache_key, cached)
                 self._record_prefix_tier(req, prefix_tier)
             phase.set(route="bucketed" if cached is None else "prefix_hit")
+            if self._upper_on_last:
+                self._count_prefill_positions(phase, S, 1, resets=1)
 
             tl = req.timeline
             if tl is not None:
@@ -3019,7 +3027,7 @@ class ServingEngine:
                 for r in waiting if r.priority == best
             )
             page_pressure = (
-                need > self.paged_cache.stats()["free_blocks"]
+                need > self.paged_cache.free_pages()
             )
         if not slot_pressure and not page_pressure:
             self._preempt_pending.clear()  # the pressure passed: resume
@@ -3640,6 +3648,15 @@ class ServingEngine:
         window = getattr(cfg, "sliding_window", None)
         if window:  # rows whose window layers no longer see their first key
             span.set(win_rows=int((self.cache_len[mask] > window).sum()))
+        for pool in (pc.ring_pools if pc is not None else ()):
+            # a window pool kept as a ring: pages the rows' tables address,
+            # and pages that fell behind the window since the last dispatch
+            held, freed = pc.window_turnover(pool, mask)
+            span.set(win_pages_held=held, win_pages_freed=freed)
+        if chunk_rows and self._upper_on_last:
+            self._count_prefill_positions(
+                span, chunk_tokens, sum(1 for row in prefill_rows if row[5]),
+                resets=sum(1 for _, _, _, start_pos, _ in chunk_rows if start_pos == 0))
         topk = getattr(cfg, "index_topk", None)
         if topk:  # rows whose sparse selection binds: attention reads index_topk of them
             span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))
@@ -3664,7 +3681,12 @@ class ServingEngine:
         ``ops/moe.held_experts`` counts them). A model with a sparse
         selection (``index_topk``) counts after them the positions its
         indexer scored and the positions its attention read
-        (``dsa_scored``, ``dsa_selected``; app_dsa_positions_total)."""
+        (``dsa_scored``, ``dsa_selected``; app_dsa_positions_total). A
+        model that names its counters (``STEP_STATS``) has them set under
+        those names."""
+        if self._stats_names is not None:
+            span.set(**dict(zip(self._stats_names, stats.tolist())))
+            return
         held = self.model_cfg.held_experts
         rows, reached, dsa = stats[:held], int(stats[held]), {}
         if getattr(self.model_cfg, "index_topk", None):
@@ -3696,6 +3718,23 @@ class ServingEngine:
         span.set(sampler=path)
         if self._metrics:
             self._metrics.add_counter("app_sampler_steps_total", steps, path=path)
+
+    def _count_prefill_positions(self, span: _StepPhase, self_tokens: int,
+                                 cross_tokens: int, *, resets: int) -> None:
+        """For a model whose upper layers run on a prompt's last position
+        alone: positions the layers up to its shared cache ran
+        (``self_tokens``) and positions the layers above ran
+        (``cross_tokens``: 1 a finished prompt, 0 a chunk that finishes
+        none) on ``span`` and in app_prefill_positions_total{part}; and
+        the slots whose recurrent state this dispatch starts anew, in
+        app_ssm_state_resets_total."""
+        span.set(self_tokens=self_tokens, cross_tokens=cross_tokens)
+        if self._metrics:
+            for part, n in (("self", self_tokens), ("cross", cross_tokens)):
+                if n:
+                    self._metrics.add_counter("app_prefill_positions_total", n, part=part)
+            if resets:
+                self._metrics.add_counter("app_ssm_state_resets_total", resets)
 
     def _count_step_tokens(self, decode: int, prefill: int, issued: int) -> None:
         """app_step_tokens_total at the point of issue: of the positions a
@@ -3952,6 +3991,10 @@ class ServingEngine:
                     "app_kv_cache_pages_used",
                     kv["total_blocks"] - kv["free_blocks"],
                 )
+                for pool, n in kv.get("pools", {}).items():
+                    for state in ("used", "total"):
+                        self._metrics.set_gauge(
+                            "app_kv_pool_pages", n[state], pool=pool, state=state)
             # the hot loop's success metric: host time per decode step —
             # the block's fold + dispatch + commit spans, not the sync
             # wait — must stay a small fraction of decode_step_ms
@@ -4310,8 +4353,7 @@ class ServingEngine:
                 self.cache.k.delete()
                 self.cache.v.delete()
             elif self.paged_cache is not None:
-                self.paged_cache.k_pool.delete()
-                self.paged_cache.v_pool.delete()
+                self.paged_cache.delete_pools()
         except Exception:
             pass  # already deleted / backend gone: the poison took either way
 
@@ -4328,7 +4370,7 @@ class ServingEngine:
         if self.cache is not None:
             arr = self.cache.k
         elif self.paged_cache is not None:
-            arr = self.paged_cache.k_pool
+            arr = self.paged_cache.probe()
         if arr is None:
             return False
         try:
@@ -4357,10 +4399,16 @@ class ServingEngine:
         B, S = self.config.max_slots, self.config.max_seq_len
         page = self.config.kv_page_size
         num_pages = self.config.kv_num_pages or (B * S + page - 1) // page
+        # what the model stores: pools by layer kind and a per-slot state
+        # (cache_spec), or what a page of the one pool pair holds
+        model = batch_ops.model_of(self.model_cfg)
+        if hasattr(model, "cache_spec"):
+            stored = {"spec": model.cache_spec(self.model_cfg, page)}
+        else:
+            stored = {"page_shapes": model.page_shapes(self.model_cfg, page)}
         return PagedKVCache(
             self.model_cfg, num_pages=num_pages, page_size=page,
-            max_slots=B, max_seq_len=S,
-            page_shapes=batch_ops.model_of(self.model_cfg).page_shapes(self.model_cfg, page),
+            max_slots=B, max_seq_len=S, **stored,
         )
 
     def _init_runtime_state(self) -> None:

@@ -508,7 +508,7 @@ def test_new_jitted_kernel_without_contract_flagged(tmp_path):
             "import functools\n"
             "import jax\n"
             "@functools.partial(jax.jit, static_argnames=('causal',"
-            " 'block_q', 'block_k', 'interpret'))\n"
+            " 'scale', 'block_q', 'block_k', 'interpret'))\n"
             "def flash_attention(q, k, v, kv_len=None, *, causal=True,\n"
             "                    scale=None, block_q=128, block_k=128,\n"
             "                    interpret=None, window=None):\n"
@@ -585,7 +585,7 @@ def test_matching_kernel_file_clean(tmp_path):
             "import functools\n"
             "import jax\n"
             "@functools.partial(jax.jit, static_argnames=('causal',"
-            " 'block_q', 'block_k', 'interpret'))\n"
+            " 'scale', 'block_q', 'block_k', 'interpret'))\n"
             "def flash_attention(q, k, v, kv_len=None, *, causal=True,\n"
             "                    scale=None, block_q=128, block_k=128,\n"
             "                    interpret=None, window=None):\n"

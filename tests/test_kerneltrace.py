@@ -100,7 +100,7 @@ def test_table_and_live_jitted_entries_are_the_same_set(matrix_payload):
     every one of them."""
     import importlib
 
-    assert len(kc.KERNELS) == len(kc.CONTRACTS) == 16
+    assert len(kc.KERNELS) == len(kc.CONTRACTS) == 17
     for rel in kc.KERNEL_FILES:
         mod = importlib.import_module(rel[:-3].replace("/", "."))
         live = {

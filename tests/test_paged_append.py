@@ -27,6 +27,7 @@ import pytest
 from gofr_tpu.models import cohere2_moe as cm
 from gofr_tpu.models import deepseek_v32 as ds
 from gofr_tpu.models import llama
+from gofr_tpu.models import phi4flash as phi
 from gofr_tpu.ops import paged_attention as pa
 from gofr_tpu.serving import batch as batch_ops
 
@@ -406,6 +407,121 @@ def test_the_compiled_ragged_step_writes_a_chunk_into_the_pools_in_place(one_chi
     assert set(made) <= {"scatter", "fusion"} and made["scatter"] == 4, made
     appends = [line for line in text.splitlines() if "custom-call(" in line and "paged_kv_append" in line]
     assert len(appends) == 2  # the decode steps' append, once a stack of layers
+
+
+# ------------------- several pools and a state a slot (phi4flash, PR 35)
+# tile-legal and narrow: a KV pair of 2 x 64 = one lane tile, a state of
+# 16 x 512; two window layers, the full layer, one cross pair. The window
+# is the published 512 and a slot 4,096 positions, so that the pools (8 and
+# 32 MiB; shapes only) are too large to be prefetched whole into fast memory
+PHI_CHIP = phi.Phi4FlashConfig(
+    vocab_size=512, d_model=256, n_layers=8, n_heads=4, n_kv_heads=2, head_dim=64, d_ff=512,
+    sliding_window=512, d_state=16, d_conv=4, expand=2, dt_rank=16, max_seq_len=4096, dtype=jnp.bfloat16)
+
+
+def _phi_arguments(one_chip, B, M, page=16):
+    """Shapes, on the described chip, of what the two paged programs take
+    first for ``PHI_CHIP``: params, the pools with the state, the decode
+    state, the tables — as ``PagedKVCache`` builds them from ``cache_spec``."""
+    cfg = PHI_CHIP
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = on_chip(jax.eval_shape(lambda k: phi.quantize_params(phi.init_params(cfg, k)), key))
+    pools, state = phi.cache_spec(cfg, page)
+    ring = -(-cfg.sliding_window // page) + 1
+    k_pool, v_pool = {}, {}
+    for name, layers, k_page, v_page, window in pools:
+        lead = (layers, (B * ring if window else B * M) + 1)
+        k_pool[name], v_pool[name] = vec(cfg.dtype, *lead, *k_page), vec(cfg.dtype, *lead, *v_page)
+    k_pool["state"] = {name: vec(dtype, layers, B, *shape) for name, (layers, shape, dtype) in state.items()}
+    dec = batch_ops.DecodeState(vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
+                                vec(i32), vec(f32), on_chip(key), vec(i32))
+    return cfg, params, k_pool, v_pool, dec, {"window": vec(i32, B, M), "full": vec(i32, B, M)}, vec, on_chip(key)
+
+
+def _pool_like(k_pool):
+    shapes = set()
+    for name in ("window", "full"):
+        dims = ",".join(str(d) for d in k_pool[name].shape)
+        shapes |= {f"bf16[{dims}]", f"bf16[{dims.split(',', 1)[1]}]", f"bf16[1,{dims.split(',', 1)[1]}]"}
+    return shapes
+
+
+def test_phi4flash_compiled_decode_block_leaves_its_pools_to_the_kernels(one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` of ``phi4flash`` by the chip's compiler: two
+    pool pairs (a window ring of two layers, ONE full layer read by its own
+    layer and by the cross-attention layer) and a state a slot. No copy,
+    fusion, slice, update or transpose has the shape of a pool or of a
+    layer's slice of one; the pools are written by the append's custom call
+    alone; the state arrays are XLA's, updated in place (their only writers
+    are dynamic-update-slices over the buffer they were handed) and the
+    float32 state is never rounded."""
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M = 32, 256
+    cfg, params, k_pool, v_pool, dec, tables, vec, _ = _phi_arguments(one_chip, B, M)
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.decode_block_paged.lower(
+            cfg, params, k_pool, v_pool, dec, tables, vec(jnp.bool_), STEPS).compile()
+    text = compiled.as_text()
+    # the window layers' append and attention, the full layer's, the cross layers' attention
+    assert text.count("tpu_custom_call") >= 5
+    made = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON and any(shape in m.group(2) for shape in _pool_like(k_pool)):
+            made.append(f"{m.group(1)} = {m.group(3)}")
+    assert not made, f"XLA ops that make, slice or update a pool: {made}"
+    appends = [line for line in text.splitlines() if "custom-call(" in line and "paged_kv_append" in line]
+    assert len(appends) == 2  # the window layers' (in their loop) and the full layer's
+    state_shape = "f32[{}]".format(",".join(str(d) for d in k_pool["state"]["ssm"].shape))
+    writers = set()
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON and m.group(2).startswith(state_shape):
+            writers.add(m.group(3) if m.group(3) != "fusion" else "fusion:" + ("dynamic-update-slice" if "dynamic-update-slice" in m.group(1) or "dynamic_update_slice" in m.group(1) else m.group(1)))
+    assert writers and all("dynamic-update-slice" in w for w in writers), writers
+    assert "bf16[{}]".format(state_shape[4:-1]) not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * int(np.prod(k_pool["full"].shape))
+
+
+def test_phi4flash_compiled_ragged_step_writes_a_chunk_into_its_pools_in_place(one_chip, no_compile_cache, monkeypatch):
+    """``ragged_step_paged`` of ``phi4flash`` by the chip's compiler. A
+    chunk's K and V go into pools of SEVERAL heads a page, where an indexed
+    write makes XLA swap the head and page axes and copy the pool around
+    the write (4.2 GB of temporaries at the cell's size before
+    ``_write_rows``, 105 MB after; my compile, PR 35). Held here: the
+    only ops whose result has a pool's size are scatters of rows over the
+    pool they were handed, one a pool a row's chunk."""
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M, C = 32, 256, 32
+    cfg, params, k_pool, v_pool, dec, tables, vec, key = _phi_arguments(one_chip, B, M)
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.ragged_step_paged.lower(
+            cfg, params, k_pool, v_pool, dec, tables, vec(i32, B, C), vec(i32), vec(flag), vec(i32),
+            vec(flag), vec(i32), vec(i32), vec(i32), vec(f32), vec(i32), vec(f32), vec(i32), key,
+            vec(flag), STEPS).compile()
+    text = compiled.as_text()
+    sizes = {int(np.prod(k_pool[name].shape)) for name in ("window", "full")}
+    made = collections.Counter()
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        shape = re.match(r"bf16\[([\d,]+)\]", m.group(2)) if m else None
+        if shape and m.group(3) not in _PASSES_ON and int(np.prod([int(d) for d in shape.group(1).split(",")])) in sizes:
+            made[m.group(3) if "/scatter" in line else f"{m.group(1)} = {m.group(3)}"] += 1
+    assert set(made) <= {"scatter", "fusion"} and made["scatter"] == 4, made  # K and V of the window pool and of the full pool
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * int(np.prod(k_pool["full"].shape))
 
 
 # ------------------------------------- the expert product a program holds (PR 34)
