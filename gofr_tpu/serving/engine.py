@@ -69,12 +69,15 @@ class EngineConfig:
     # continuous batching: prompts longer than this prefill in chunks of
     # this many tokens, interleaved with decode blocks in one ragged
     # dispatch — one long prefill can no longer head-of-line-block the
-    # decoding rows. Also the per-iteration prefill token budget when
-    # step_token_budget is 0 (auto).
+    # decoding rows. When step_token_budget is 0 (auto) an iteration's
+    # prefill budget is one such chunk for every cursor that has work,
+    # at most as many chunk rows as the block has decode steps
+    # (serving/stepplan.py).
     prefill_chunk_tokens: int = 256
     # explicit per-iteration token target: decode rows (rows*block_steps)
     # are reserved FIRST, prefill chunks fill the remainder. 0 = auto
-    # (decode implicitly reserved + one chunk budget of prefill).
+    # (decode implicitly reserved + one chunk a waiting cursor, bounded:
+    # a slot that holds a waiting cursor decodes nothing).
     step_token_budget: int = 0
     idle_sleep_s: float = 0.002
     # KV layout: "dense" reserves [slots, max_seq] rows; "paged" commits HBM
@@ -2267,6 +2270,7 @@ class ServingEngine:
                 free_slots=free_slots,
                 queue_depth=queue_depth,
             )
+            span.set(grants=len(plan.grants))
             if self._metrics:
                 # set on CHANGE (including the drop back to zero at idle —
                 # a frozen non-zero gauge would report phantom load

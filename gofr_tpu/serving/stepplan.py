@@ -22,17 +22,31 @@ one unified ragged dispatch against the KV pool — lives in
 Budget policy (docs/performance.md "Continuous batching"):
 
 - ``step_token_budget == 0`` (auto, the default) reserves the decode
-  block implicitly and grants exactly ``prefill_chunk_tokens`` of prefill
-  per iteration — neither side can starve the other.
+  block implicitly and grants ONE WHOLE CHUNK TO EVERY CURSOR THAT HAS
+  WORK (not blocked, tokens left to dispatch), oldest first, up to
+  ``block_steps`` cursors an iteration. A slot that holds a cursor
+  decodes nothing, so a cursor left waiting is a row of every decode
+  step bought and thrown away: with one chunk an iteration (the policy
+  until PR 36) a cell of long prompts kept 10-13 of 32 slots in waiting
+  cursors. The bound is derived, not configured: an iteration carries at
+  most as many chunk rows as its block has decode steps, so the longest
+  a decoding row waits between two blocks is bounded by what the engine
+  is built around, and the decode block still dispatches in EVERY
+  iteration. (Half that bound was measured beside it on the v5e and
+  served 8 % fewer tokens a second: a burst of arrivals drains at the
+  bound's pace. PERF.md section 6, PR 36.) With no cursor, or one, the
+  budget is one chunk, and a plan for traffic that never chunks is what
+  it always was, field for field.
 - An explicit ``step_token_budget`` is a hard per-iteration token target:
   decode rows (``rows * block_steps`` tokens) are subtracted first and
   prefill chunks fill whatever remains. Setting it at or below the decode
   reservation is an explicit decode-priority stance — prefill then only
   progresses in iterations with idle slots.
-- Chunk grants go to cursors OLDEST FIRST (FIFO over admission order), so
-  a long prompt drains steadily instead of interleaving fairly-but-
-  forever with every later arrival; admission of new requests is gated on
-  leftover budget so a saturated step admits nothing it cannot serve.
+- Chunk grants go to cursors OLDEST FIRST (FIFO over admission order
+  within a priority class), one grant a cursor an iteration, so a long
+  prompt drains steadily and, past the bound, the newest arrivals are
+  the ones that wait; admission of new requests is gated on leftover
+  budget so a saturated step admits nothing it cannot serve.
 
 This module is pure policy: no device work, no locks — the engine thread
 is the only caller. ``plan`` is a ``sched.plan`` chaos point (a fault
@@ -132,24 +146,32 @@ class StepPlanner:
         admission quota out of the leftover budget."""
         chaos.maybe_fail("sched.plan")
         decode_tokens = decode_rows * self.block_steps
-        if self.step_token_budget:
-            prefill_budget = max(0, self.step_token_budget - decode_tokens)
-        else:
-            # auto: decode is implicitly reserved (the block dispatches
-            # regardless); prefill gets one chunk budget per iteration
-            prefill_budget = self.chunk_tokens
-        budget = prefill_budget
-        grants: list[tuple[int, int]] = []
         # priority-aware grant order (multi-tenant plane, docs/serving.md
         # "Multi-tenancy"): higher classes (lower priority number) drain
         # first; FIFO within a class — the PR 10 starvation guarantee
         # (decode reserved first) is unchanged, only the PREFILL budget
-        # walk became class-aware
-        for cur in sorted(cursors, key=lambda c: (c.priority, c.seq)):
+        # walk became class-aware. A blocked or fully dispatched cursor
+        # asks for nothing.
+        waiting = [
+            cur for cur in sorted(cursors, key=lambda c: (c.priority, c.seq))
+            if not cur.blocked and cur.remaining > 0
+        ]
+        if self.step_token_budget:
+            prefill_budget = max(0, self.step_token_budget - decode_tokens)
+        else:
+            # auto: decode is implicitly reserved (the block dispatches
+            # regardless); every waiting cursor holds a slot that decodes
+            # nothing, so each gets a chunk, up to as many chunk rows as
+            # the block has decode steps (module docstring) — and with
+            # none waiting the budget is the one chunk admission is
+            # gated on
+            waiting = waiting[:self.block_steps]
+            prefill_budget = self.chunk_tokens * max(1, len(waiting))
+        budget = prefill_budget
+        grants: list[tuple[int, int]] = []
+        for cur in waiting:
             if budget <= 0:
                 break
-            if cur.blocked or cur.remaining <= 0:
-                continue
             # grants are WHOLE chunks (or the prompt's final ragged tail),
             # never budget-truncated partials: chunk boundaries double as
             # page-grid write boundaries and chunk-prefix cache keys, so a

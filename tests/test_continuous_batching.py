@@ -86,13 +86,81 @@ def test_planner_never_splits_a_chunk_across_the_budget():
     assert plan.grants == [(0, 32), (1, 6)]
 
 
-def test_planner_auto_budget_grants_one_chunk_per_iteration():
-    p = StepPlanner(chunk_tokens=32, block_steps=4)
-    plan = p.plan(decode_rows=6, cursors=[_cursor(0, 100, 1, dispatched=32)],
+def _parent_cursor_free_plan(prefill_budget, free_slots, queue_depth, max_admissions=4):
+    """What the one-chunk-an-iteration planner (the policy until PR 36)
+    gave a plan WITHOUT cursors — one chunk of budget in auto mode, what
+    decode left of an explicit one: the fields a change to the auto
+    budget must leave alone for traffic that never chunks."""
+    admit_cap = 0
+    if queue_depth > 0:
+        admit_cap = 1
+        if free_slots > 0 and prefill_budget > 0:
+            admit_cap = min(max_admissions, free_slots)
+    return dict(prefill_budget=prefill_budget, admit_cap=admit_cap,
+                budget_left=prefill_budget, grants=[])
+
+
+# block_steps=4 is the bound on chunk rows an iteration
+AUTO_CASES = {
+    # name: (budget, cursors as (slot, total, seq, dispatched, blocked),
+    #        expected grants, expected prefill_budget)
+    "no_cursor": (0, [], [], 16),
+    "one_cursor": (0, [(0, 100, 1, 32, False)], [(0, 16)], 16),
+    "k_cursors_under_the_bound_oldest_first": (
+        0, [(3, 64, 2, 0, False), (2, 64, 1, 16, False)],
+        [(2, 16), (3, 16)], 32),
+    # slot s admitted at seq 10 - s: the four OLDEST are the highest slots
+    "more_cursors_than_the_bound_the_oldest_get_it": (
+        0, [(s, 64, 10 - s, 0, False) for s in range(6)],
+        [(5, 16), (4, 16), (3, 16), (2, 16)], 64),
+    "blocked_and_finished_not_counted": (
+        0, [(0, 64, 1, 0, True), (1, 32, 2, 32, False), (2, 64, 3, 0, False),
+            (3, 64, 4, 16, False)],
+        [(2, 16), (3, 16)], 32),
+    "ragged_tail_beside_whole_chunks": (
+        0, [(0, 64, 1, 0, False), (1, 37, 2, 32, False)],
+        [(0, 16), (1, 5)], 32),
+    # 6 decode rows x 4 steps reserved first: 32 of the 56 are prefill's
+    "explicit_budget_as_today": (
+        56, [(3, 64, 2, 0, False), (2, 64, 1, 0, False), (4, 64, 3, 0, False)],
+        [(2, 16), (3, 16)], 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(AUTO_CASES))
+def test_planner_auto_budget_grants_a_chunk_to_every_waiting_cursor(case):
+    """Auto budget (ISSUE 36): one whole chunk (or the ragged tail) for
+    each cursor that has work, oldest first, at most ``block_steps`` an
+    iteration; a cursor-free plan is the one-chunk plan field for
+    field; an explicit budget plans as it always did."""
+    budget, cursors, grants, prefill_budget = AUTO_CASES[case]
+    p = StepPlanner(chunk_tokens=16, block_steps=4, step_token_budget=budget)
+    plan = p.plan(decode_rows=6, cursors=[_cursor(*c) for c in cursors],
                   free_slots=2, queue_depth=3)
-    assert plan.prefill_budget == 32
-    assert plan.grants == [(0, 32)]
-    assert plan.admit_cap >= 1
+    assert plan.grants == grants
+    assert plan.prefill_budget == prefill_budget
+    assert plan.budget_left == prefill_budget - sum(n for _, n in grants)
+    assert plan.decode_tokens == 24  # reserved first, whatever is granted
+    assert plan.admit_cap >= 1  # never zero while the queue holds work
+    # traffic that never chunks: the parent's plan, field for field
+    for free_slots, queue_depth in ((0, 0), (0, 5), (3, 0), (3, 5), (9, 2)):
+        bare = p.plan(decode_rows=6, cursors=[], free_slots=free_slots,
+                      queue_depth=queue_depth)
+        expect = _parent_cursor_free_plan(budget - 24 if budget else 16,
+                                          free_slots, queue_depth)
+        assert {k: getattr(bare, k) for k in expect} == expect
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4, 16])
+def test_planner_auto_bound_follows_the_block(steps):
+    """The bound is derived: as many chunk rows as the block has decode
+    steps (a one-step block carries one chunk, as before PR 36)."""
+    p = StepPlanner(chunk_tokens=16, block_steps=steps)
+    plan = p.plan(decode_rows=0,
+                  cursors=[_cursor(s, 64, s) for s in range(20)],
+                  free_slots=0, queue_depth=1)
+    assert plan.grants == [(s, 16) for s in range(steps)]
+    assert plan.budget_left == 0 and plan.admit_cap == 1
 
 
 def test_planner_grants_fifo_oldest_cursor_first():
@@ -101,9 +169,14 @@ def test_planner_grants_fifo_oldest_cursor_first():
     new = _cursor(3, 64, seq=2)
     plan = p.plan(decode_rows=0, cursors=[new, old], free_slots=0,
                   queue_depth=0)
-    # one chunk of budget -> it all goes to the OLDEST cursor
-    assert plan.grants == [(2, 16)]
-    # a wider explicit budget splits across cursors in admission order
+    # a chunk for each waiting cursor, the OLDEST first
+    assert plan.grants == [(2, 16), (3, 16)]
+    # past the bound it is the NEWEST that wait
+    more = [_cursor(10 + i, 64, seq=3 + i) for i in range(4)]
+    plan = p.plan(decode_rows=0, cursors=more[::-1] + [new, old],
+                  free_slots=0, queue_depth=0)
+    assert plan.grants == [(2, 16), (3, 16), (10, 16), (11, 16)]
+    # an explicit budget splits across cursors in admission order
     p2 = StepPlanner(chunk_tokens=16, block_steps=4, step_token_budget=32)
     plan = p2.plan(decode_rows=0, cursors=[new, old], free_slots=0,
                    queue_depth=0)
@@ -164,6 +237,71 @@ def test_chunked_prefill_matches_monolithic_greedy(engine_setup, kv_layout):
         assert sum(c["tokens"] for c in tl.prefill_chunks) == b.prompt_tokens
     finally:
         mono.stop(), chunked.stop()
+
+
+def test_waiting_prompts_share_an_iteration_up_to_the_bound(engine_setup, tmp_path):
+    """ISSUE 36: several long prompts submitted together to a paged
+    engine whose rows are decoding. Read off the engine's own spans
+    (a profiler trace on the CPU, the benchmark's reader): a ragged
+    dispatch carries MORE THAN ONE chunk row and never more than the
+    planner's bound, a plan never grants more than the bound however
+    many cursors ask, the decode block rides EVERY dispatch (the rows
+    that were decoding are in each one, for a whole block of steps),
+    every prompt's greedy tokens are the monolithic prefill's, and chunk
+    commits stay monotonic and cover each prompt."""
+    from benchmarks.harness import host_spans, trace_reduce
+
+    cfg, params = engine_setup
+    bound = 4  # the block's decode steps: chunk rows an iteration
+    kw = dict(kv_layout="paged", kv_page_size=8, max_slots=8, max_seq_len=192,
+              prefill_buckets=(128,), multi_step=bound)
+    prompts = [chr(ord("a") + i) * (97 + i) for i in range(6)]  # 7 chunks of 16 each
+    mono = make_engine(cfg, params, prefill_chunk_tokens=128, **kw)
+    mono.start()
+    try:
+        want = [mono.submit(p, max_new_tokens=4, temperature=0.0).result(timeout=300).token_ids
+                for p in prompts]
+    finally:
+        mono.stop()
+    engine = make_engine(cfg, params, prefill_chunk_tokens=16, **kw)
+    engine.start()
+    try:
+        # compile the programs off the trace
+        engine.submit("warm", max_new_tokens=4, temperature=0.0).result(timeout=300)
+        engine.submit("w" * 40, max_new_tokens=4, temperature=0.0).result(timeout=300)
+        with jax.profiler.trace(str(tmp_path)):
+            streaming = [threading.Event(), threading.Event()]
+            decoding = [
+                engine.submit(f"decode row {i}", max_new_tokens=150, temperature=0.0,
+                              stream_cb=lambda tid, piece, done, ev=ev: ev.set())
+                for i, ev in enumerate(streaming)
+            ]
+            assert all(ev.wait(120) for ev in streaming)
+            longs = [engine.submit(p, max_new_tokens=4, temperature=0.0) for p in prompts]
+            got = [f.result(timeout=300) for f in longs]
+            still_decoding = [not f.done() for f in decoding]
+            assert all(f.result(timeout=300).completion_tokens == 150 for f in decoding)
+            time.sleep(0.1)
+    finally:
+        engine.stop()
+    assert [r.token_ids for r in got] == want
+    for r in got:
+        tl = engine.timeline.get(r.request_id)
+        assert len(tl.prefill_chunks) == 7
+        _assert_chunks_cover(tl, r.prompt_tokens)
+    assert all(still_decoding)  # the two rows outlived every prompt's prefill
+    spans = [host_spans.parse(e) for e in
+             host_spans.load_host_events(trace_reduce.find_xplane(str(tmp_path)))]
+    ragged = [s.kw for s in spans if s.phase == "dispatch" and s.kw.get("kind") == "ragged"]
+    assert ragged and max(kw["chunk_rows"] for kw in ragged) > 1
+    assert all(1 <= kw["chunk_rows"] <= bound for kw in ragged)
+    # the decode block is in every one of them: both decoding rows, a whole block
+    assert all(kw["rows"] >= 2 and kw["steps"] == bound for kw in ragged)
+    plans = [s.kw for s in spans if s.phase == "plan"]
+    assert all(kw["grants"] <= min(bound, kw["cursors"]) for kw in plans)
+    # more cursors asked than the bound at some plan, and the bound held
+    assert any(kw["cursors"] > bound and kw["grants"] == bound for kw in plans)
+    assert sum(kw["chunk_rows"] for kw in ragged) == 6 * 7
 
 
 def test_prompt_longer_than_every_bucket_now_chunks_instead_of_truncating(
@@ -448,6 +586,23 @@ def test_deprecated_knobs_feed_the_planner(engine_setup):
     assert eng2._chunk_enabled is False  # spec mode keeps monolithic prefill
 
 
+def _assert_chunks_cover(tl, prompt_tokens):
+    """Within one slot tenancy, committed chunk spans are contiguous and
+    strictly increasing; a requeue restarts at 0; the final run covers
+    the whole prompt exactly once."""
+    runs = [[]]
+    for c in tl.prefill_chunks:
+        if c["start"] == 0 and runs[-1]:
+            runs.append([])
+        runs[-1].append(c)
+    for run in runs:
+        pos = 0
+        for c in run:
+            assert c["start"] == pos, tl.prefill_chunks
+            pos = c["start"] + c["tokens"]
+    assert sum(c["tokens"] for c in runs[-1]) == prompt_tokens
+
+
 def test_chunk_commits_are_monotonic_and_cover_the_prompt(engine_setup):
     """The double-prefill guard: within one slot tenancy, committed chunk
     spans are contiguous and strictly increasing; a requeue restarts at
@@ -457,17 +612,6 @@ def test_chunk_commits_are_monotonic_and_cover_the_prompt(engine_setup):
     engine.start()
     try:
         r = engine.submit("m" * 70, max_new_tokens=3, temperature=0.0).result(timeout=300)
-        tl = engine.timeline.get(r.request_id)
-        runs = [[]]
-        for c in tl.prefill_chunks:
-            if c["start"] == 0 and runs[-1]:
-                runs.append([])
-            runs[-1].append(c)
-        for run in runs:
-            pos = 0
-            for c in run:
-                assert c["start"] == pos, tl.prefill_chunks
-                pos = c["start"] + c["tokens"]
-        assert sum(c["tokens"] for c in runs[-1]) == r.prompt_tokens
+        _assert_chunks_cover(engine.timeline.get(r.request_id), r.prompt_tokens)
     finally:
         engine.stop()

@@ -125,9 +125,16 @@ def test_planner_grants_walk_priority_then_fifo():
     gold_cur = ChunkCursor(req=None, slot=1, total=32, seq=1, priority=0)
     plan = planner.plan(decode_rows=0, cursors=[batch_cur, gold_cur],
                         free_slots=2, queue_depth=0)
-    # the later-admitted high class drains FIRST; budget (one chunk in
-    # auto mode) covers exactly one grant
-    assert plan.grants == [(1, 8)]
+    # the later-admitted high class drains FIRST; the lower class no
+    # longer waits a whole iteration for it (auto mode: a chunk a
+    # waiting cursor, ISSUE 36) — it follows in the SAME plan
+    assert plan.grants == [(1, 8), (0, 8)]
+    # past the bound the lower class is what waits, FIFO within each
+    golds = [ChunkCursor(req=None, slot=2 + i, total=32, seq=2 + i, priority=0)
+             for i in range(4)]
+    plan = planner.plan(decode_rows=0, cursors=[batch_cur, *golds[::-1], gold_cur],
+                        free_slots=2, queue_depth=0)
+    assert plan.grants == [(1, 8), (2, 8), (3, 8), (4, 8)]  # block_steps of them
 
 
 # -- adapter registry ----------------------------------------------------------

@@ -8,6 +8,11 @@ every end-to-end metric with their ratio.
     git archive <parent> | tar -x -C .bench_checkout/parent      # a directory .gitignore lists
     python3 tools/pairs.py <workload> <seconds> .bench_checkout/parent <seed> [<seed> ...]
 
+A further candidate rides the same seeds as ``<name>=<checkout>`` after
+the parent's (``.bench_checkout/parent,bound2=.bench_checkout/bound2``):
+the order then rotates by one side a seed, and every side is printed
+against the parent.
+
 This process never imports JAX: each run is a child that holds the chip.
 """
 
@@ -32,16 +37,19 @@ def run(checkout: str, command: list[str], workload: str, seed: str, seconds: st
 
 
 def main(argv: list[str]) -> int:
-    workload, seconds, parent = argv[0], argv[1], os.path.abspath(argv[2])
+    workload, seconds = argv[0], argv[1]
+    parent, *others = argv[2].split(",")
     seeds = argv[3:]
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
         command = json.load(fh)["command"]
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     out = os.path.join(ROOT, "chiprun_out", f"pairs.{workload}.jsonl")
-    sides = {"parent": parent, "change": ROOT}
+    sides = {"parent": os.path.abspath(parent), "change": ROOT}
+    sides.update((name, os.path.abspath(path)) for name, path in (o.split("=", 1) for o in others))
+    order = list(sides)
     lines: dict[tuple[str, str], dict] = {}
     for i, seed in enumerate(seeds):
-        for side in (("parent", "change"), ("change", "parent"))[i % 2]:
+        for side in order[i % len(order):] + order[:i % len(order)]:
             line = run(sides[side], command, workload, seed, seconds)
             if line is None:
                 continue
@@ -53,12 +61,13 @@ def main(argv: list[str]) -> int:
                   + " ".join(f"{k}={v['value']!r}" for k, v in line["metrics"].items())
                   + f" peak={line['device'].get('memory_peak_bytes')}", flush=True)
     for seed in seeds:
-        if ("parent", seed) in lines and ("change", seed) in lines:
-            a, b = lines["parent", seed]["metrics"], lines["change", seed]["metrics"]
-            print(f"seed {seed}: " + ", ".join(
-                f"{k} {a[k]['value']!r} -> {b[k]['value']!r} (x {b[k]['value'] / a[k]['value']:.4f})"
-                for k in a if k in b and a[k]["value"]), flush=True)
-    return 0 if len(lines) == 2 * len(seeds) and all(v["correct"] for v in lines.values()) else 1
+        for side in order[1:]:
+            if ("parent", seed) in lines and (side, seed) in lines:
+                a, b = lines["parent", seed]["metrics"], lines[side, seed]["metrics"]
+                print(f"seed {seed} parent -> {side}: " + ", ".join(
+                    f"{k} {a[k]['value']!r} -> {b[k]['value']!r} (x {b[k]['value'] / a[k]['value']:.4f})"
+                    for k in a if k in b and a[k]["value"]), flush=True)
+    return 0 if len(lines) == len(sides) * len(seeds) and all(v["correct"] for v in lines.values()) else 1
 
 
 if __name__ == "__main__":
