@@ -152,7 +152,8 @@ ROUTER_RETRIABLE_NAMES = {
 HOT_SYNC_ZONES: dict[str, set[str] | str] = {
     "gofr_tpu/serving/engine.py": {
         "_loop", "_loop_body", "_decode_step", "_spec_step",
-        "_dispatch_decode", "_dispatch_ragged", "_consume_block",
+        "_dispatch_decode", "_dispatch_rows", "_dispatch_ragged",
+        "_launch_idle", "_count_launch", "_consume_block",
         "_commit_token", "_commit_first_token", "_emit_token",
         "_emit_async", "_block_sync", "_slot_in_flight",
         "_make_device_state", "_retire", "_plan_step", "_cursor_health",

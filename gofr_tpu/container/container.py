@@ -195,23 +195,33 @@ class Container:
             "app_spec_accept_rate",
             "Speculative-decode draft acceptance rate over drafted tokens",
         )
-        # CPU-free decode hot loop (docs/performance.md): the host-overhead
-        # win must be observable — host ms per decode step should stay a
-        # small fraction of the device step time
-        m.new_gauge(
-            "app_decode_host_ms_per_step",
-            "Host-side time per decode step (the block's fold, dispatch and "
-            "commit spans, not its sync wait), milliseconds",
-        )
         # the step loop's own account (serving/engine.py _phase, docs/
         # observability.md "Engine step spans"): where the loop thread's
-        # time goes, and what each dispatch issues
+        # time goes — on the wall and on its own CPU clock, so the host's
+        # cost per block and per phase is a rate of these two —, whether
+        # a block found the device waiting, and what each dispatch issues
         m.new_counter(
             "app_engine_phase_seconds_total",
             "Seconds the engine loop thread spent in each phase of the "
             "step loop, each instant charged to the innermost phase open "
             "(label phase=step|preempt|plan|admit|prefill|prefill_sync|"
-            "fold|dispatch|sync|commit|wait)",
+            "fold|dispatch|dispatch.rows|dispatch.launch|dispatch.count|"
+            "sync|commit|commit.rows|commit.chunks|commit.stats|wait)",
+        )
+        m.new_counter(
+            "app_engine_phase_cpu_seconds_total",
+            "CPU seconds of the engine loop thread (its own thread clock) "
+            "in each phase of the step loop: a phase's seconds less these "
+            "are what the thread spent off the CPU — the GIL, a lock, a "
+            "call blocked in the runtime (label phase= as "
+            "app_engine_phase_seconds_total)",
+        )
+        m.new_counter(
+            "app_engine_blocks_total",
+            "Blocks launched while the newest block in flight had its "
+            "result ready — the device had run dry and waited for the "
+            "host (launch=idle) —, while it still ran (launch=queued), or "
+            "with none in flight, after a wait for work (launch=none)",
         )
         m.new_counter(
             "app_step_tokens_total",

@@ -380,7 +380,7 @@ class _Inflight:
     carry (the round-4 use-after-donate shape)."""
 
     __slots__ = ("packed", "rows", "dispatched_at", "steps", "blk",
-                 "dispatch_s", "prefill_rows", "last_logits")
+                 "prefill_rows", "last_logits")
 
     def __init__(self, packed: Any, rows: list, dispatched_at: float,
                  steps: int = 1, blk: int = 0,
@@ -393,9 +393,6 @@ class _Inflight:
         # the block's sequence number: what joins its gofr.step.dispatch,
         # .sync and .commit spans, and a request's first_blk/last_blk
         self.blk = blk
-        # seconds of the block's dispatch span (the fold inside it
-        # included), set when that span closes
-        self.dispatch_s = 0.0
         # ragged dispatches only: the prefill-chunk rows this block ran —
         # (slot, req, cursor, start, n_tokens, final, chunk_index) — plus
         # the device-resident last-position logits (retained ONLY for the
@@ -414,13 +411,19 @@ def _block_sync(value: Any) -> np.ndarray:
 
 
 # the step loop's phases (docs/observability.md "Engine step spans"):
-# "step" is one loop iteration, the others nest inside it. Each is a
+# "step" is one loop iteration, the others nest inside it, and a dotted
+# name is a part of the phase before the dot, nested inside it. Each is a
 # gofr.step[.<phase>] span on the profiler's clock and a key of
-# ServingEngine._phase_s
+# ServingEngine._phase_s and ._phase_cpu_s
 STEP_PHASES = ("step", "preempt", "plan", "admit", "prefill", "prefill_sync",
-               "fold", "dispatch", "sync", "commit", "wait")
+               "fold", "dispatch", "dispatch.rows", "dispatch.launch",
+               "dispatch.count", "sync", "commit", "commit.rows",
+               "commit.chunks", "commit.stats", "wait")
 _SPAN_NAMES = {p: "gofr.step" if p == "step" else f"gofr.step.{p}"
                for p in STEP_PHASES}
+# a dispatch span's dev_idle (ServingEngine._launch_idle) as the launch
+# label of app_engine_blocks_total
+LAUNCHES = ("queued", "idle", "none")
 
 
 class _StepPhase:
@@ -429,20 +432,23 @@ class _StepPhase:
     any profiler session, on the device trace's clock, and an inactive
     TraceMe otherwise — and a segment of the engine's phase accumulator.
     Keyword values are integers (or short constant strings) the caller
-    already holds: a span never reads the device."""
+    already holds: a span never reads the device. A span that closes
+    carries ``cpu_us``, the CPU time of its thread between its two ends
+    (its children's included): what its duration holds beyond that, the
+    thread spent off the CPU — waiting for the GIL, a lock or a call
+    blocked in the runtime."""
 
-    __slots__ = ("_engine", "_phase", "_outer", "_span", "_t0", "seconds")
+    __slots__ = ("_engine", "_phase", "_outer", "_span", "_cpu0")
 
     def __init__(self, engine: "ServingEngine", phase: str, kw: dict) -> None:
         self._engine = engine
         self._phase = phase
         self._span = jax.profiler.TraceAnnotation(_SPAN_NAMES[phase], **kw)
-        self.seconds = 0.0  # the span's whole duration, once it has closed
 
     def __enter__(self) -> "_StepPhase":
         self._span.__enter__()
         self._outer = self._engine._phase_state[0]
-        self._t0 = self._engine._phase_switch(self._phase)
+        self._cpu0 = self._engine._phase_switch(self._phase)
         return self
 
     def set(self, **kw: Any) -> None:
@@ -450,7 +456,8 @@ class _StepPhase:
         self._span.set_metadata(**kw)
 
     def __exit__(self, *exc: Any) -> None:
-        self.seconds = self._engine._phase_switch(self._outer) - self._t0
+        cpu_ns = self._engine._phase_switch(self._outer) - self._cpu0
+        self._span.set_metadata(cpu_us=cpu_ns // 1000)
         self._span.__exit__(*exc)
 
 
@@ -633,9 +640,26 @@ class ServingEngine:
         self._phase_s = dict.fromkeys(STEP_PHASES, 0.0)
         self._phase_state: tuple[str | None, float, float] = (
             None, time.monotonic(), 0.0)
-        self._phase_counted = dict(self._phase_s)  # what the counter has
+        # the same account in the loop thread's own CPU time
+        # (time.thread_time_ns at every switch): a phase's wall seconds
+        # less these are the seconds its thread was off the CPU
+        self._phase_cpu_s = dict.fromkeys(STEP_PHASES, 0.0)
+        self._phase_cpu_ns = 0  # the thread's CPU clock at the last switch
+        # what the two counters have (app_engine_phase[_cpu]_seconds_total)
+        self._phase_counted = (dict(self._phase_s), dict(self._phase_cpu_s))
         self._step_iter = 0  # loop iterations: gofr.step's iter=
         self._blk_seq = 0    # dispatched blocks: blk= on their spans
+        # of those, the blocks launched while the newest block in flight
+        # had its result ready (the device had run dry and idled until the
+        # launch landed), while it had not, and with none in flight
+        # (_launch_idle)
+        self._launched = dict.fromkeys(LAUNCHES, 0)
+        if self._metrics:  # every series from the start, at zero
+            for phase in STEP_PHASES:
+                self._metrics.add_counter("app_engine_phase_seconds_total", 0.0, phase=phase)
+                self._metrics.add_counter("app_engine_phase_cpu_seconds_total", 0.0, phase=phase)
+            for launch in LAUNCHES:
+                self._metrics.add_counter("app_engine_blocks_total", 0.0, launch=launch)
         self._retires = 0    # rows retired: a commit span's retired=
         # optional DeviceTelemetry poller backref: health_check embeds its
         # last sample, the membership announcer reads HBM headroom off it
@@ -1415,32 +1439,76 @@ class ServingEngine:
         ``_phase_s``. Engine thread only; closes on any unwind."""
         return _StepPhase(self, phase, kw)
 
-    def _phase_switch(self, phase: str | None) -> float:
-        """Charge the time since the last switch to the phase that was
-        open and make ``phase`` the open one. Only the loop's owner keeps
-        the account: a retired thread unwinding through its spans must
-        not write into its replacement's (with no loop thread at all —
-        direct calls, tests — the caller owns it)."""
+    def _phase_switch(self, phase: str | None) -> int:
+        """Charge the time since the last switch — on the wall and on the
+        calling thread's CPU clock — to the phase that was open and make
+        ``phase`` the open one; returns that CPU clock's reading. Only the
+        loop's owner keeps the account: a retired thread unwinding through
+        its spans must not write into its replacement's (with no loop
+        thread at all — direct calls, tests — the caller owns it)."""
         now = time.monotonic()
+        cpu_ns = time.thread_time_ns()
         if self._thread is None or threading.current_thread() is self._thread:
             was, since, busy = self._phase_state
             if was is not None:
                 self._phase_s[was] += now - since
+                self._phase_cpu_s[was] += (cpu_ns - self._phase_cpu_ns) * 1e-9
                 if was != "wait":
                     busy += now - since
+            self._phase_cpu_ns = cpu_ns
             self._phase_state = (phase, now, busy)
-        return now
+        return cpu_ns
 
     def _count_phases(self) -> None:
-        """Carry the account into app_engine_phase_seconds_total, once a
-        loop iteration rather than at every span."""
-        for phase, total in self._phase_s.items():
-            delta = total - self._phase_counted[phase]
-            if delta > 0.0:
-                self._phase_counted[phase] = total
-                self._metrics.add_counter(
-                    "app_engine_phase_seconds_total", delta, phase=phase
-                )
+        """Carry the account into app_engine_phase_seconds_total and
+        app_engine_phase_cpu_seconds_total, once a loop iteration rather
+        than at every span."""
+        for name, account, counted in zip(
+                ("app_engine_phase_seconds_total",
+                 "app_engine_phase_cpu_seconds_total"),
+                (self._phase_s, self._phase_cpu_s), self._phase_counted):
+            for phase, total in account.items():
+                delta = total - counted[phase]
+                if delta > 0.0:
+                    counted[phase] = total
+                    self._metrics.add_counter(name, delta, phase=phase)
+
+    def loop_account(self) -> dict[str, Any]:
+        """The step loop's whole account in one read: blocks dispatched,
+        how many of them were launched onto an idle device and how many
+        behind a block still running (_launch_idle), the seconds and the
+        CPU seconds of the loop thread by phase as of its last phase
+        switch, and the clock. Any thread may take it: every value only
+        grows, and a read torn across two phases is off by one segment."""
+        return {"t": time.monotonic(), "blocks": self._blk_seq,
+                "launched_idle": self._launched["idle"],
+                "launched_queued": self._launched["queued"],
+                "phase_s": dict(self._phase_s),
+                "cpu_s": dict(self._phase_cpu_s)}
+
+    def _launch_idle(self) -> int:
+        """Asked once a dispatch, before anything of it is launched: is
+        the device waiting for this launch? 1 — the newest block in flight
+        has its result ready, so everything this engine queued has run
+        (one stream a device: a bucketed prefill queued after that block
+        is behind it) and the device idles until the launch lands; 0 — it
+        is still running, the launch queues behind it; 2 — no block is in
+        flight (the first after a wait or a restart, and every speculative
+        chunk, which is read before the next is built): the device idled
+        for want of work, not of host. ``is_ready`` asks the runtime for
+        the buffer's event and neither waits nor transfers."""
+        if not self._inflight_q:
+            return 2
+        return 1 if self._inflight_q[-1].packed.is_ready() else 0
+
+    def _count_launch(self, span: _StepPhase, dev_idle: int) -> None:
+        """A launched block's ``dev_idle`` on its dispatch span, in the
+        loop's account and in app_engine_blocks_total{launch}."""
+        span.set(dev_idle=dev_idle)
+        launch = LAUNCHES[dev_idle]
+        self._launched[launch] += 1
+        if self._metrics:
+            self._metrics.add_counter("app_engine_blocks_total", 1, launch=launch)
 
     @property
     def in_cold_dispatch(self) -> bool:
@@ -2357,6 +2425,7 @@ class ServingEngine:
             if tl is not None and "admitted" not in tl.phases:
                 now = time.perf_counter()
                 tl.stamp("admitted")
+                tl.loop_admit = self.loop_account()
                 queue_wait = now - req.created
                 qspan = tl.spans.get("queue")
                 if qspan is not None:
@@ -3201,7 +3270,6 @@ class ServingEngine:
         with self._phase("dispatch") as span:
             inflight = self._dispatch_decode(plan, span)
         if inflight is not None:
-            inflight.dispatch_s = span.seconds
             self._inflight_q.append(inflight)
         did = inflight is not None
         if self._inflight_q and (
@@ -3239,66 +3307,67 @@ class ServingEngine:
         self._pending_admit.clear()  # host state is authoritative in spec mode
 
         with self._phase("dispatch") as span:
-            rows: list[tuple[int, _Request]] = []
-            now = time.perf_counter()
-            for slot, req in enumerate(self.slots):
-                if req is None:
-                    continue
-                if req.canceled:
-                    self._retire(slot, "cancel")
-                    continue
-                if req.expired(now):
-                    # abandon mid-stream: free the slot for live requests and
-                    # resolve with the tokens produced so far
-                    self._retire(slot, "deadline_exceeded")
-                    continue
-                if (len(req.tokens) >= req.max_new_tokens
-                        or len(req.prompt_ids) + len(req.tokens) >= max_seq):
-                    continue  # retires at the next consume's limit checks
-                rows.append((slot, req))
-            if not rows:
-                return False
+            with self._phase("dispatch.rows"):
+                rows: list[tuple[int, _Request]] = []
+                now = time.perf_counter()
+                for slot, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    if req.canceled:
+                        self._retire(slot, "cancel")
+                        continue
+                    if req.expired(now):
+                        # abandon mid-stream: free the slot for live requests and
+                        # resolve with the tokens produced so far
+                        self._retire(slot, "deadline_exceeded")
+                        continue
+                    if (len(req.tokens) >= req.max_new_tokens
+                            or len(req.prompt_ids) + len(req.tokens) >= max_seq):
+                        continue  # retires at the next consume's limit checks
+                    rows.append((slot, req))
+                if not rows:
+                    return False
 
-            B = self.config.max_slots
-            chunk = np.full((B, T), -1, np.int32)
-            for slot, req in rows:
-                chunk[slot, 0] = self.last_token[slot]
-                room = min(
-                    req.max_new_tokens - len(req.tokens),
-                    max_seq - 1 - (len(req.prompt_ids) + len(req.tokens)),
-                )
-                if req.temperature == 0 and room > 1 and K > 0:
-                    draft = llama._prompt_lookup_draft(
-                        req.prompt_ids + req.tokens, self.config.spec_ngram,
-                        min(K, room - 1),
+                B = self.config.max_slots
+                chunk = np.full((B, T), -1, np.int32)
+                for slot, req in rows:
+                    chunk[slot, 0] = self.last_token[slot]
+                    room = min(
+                        req.max_new_tokens - len(req.tokens),
+                        max_seq - 1 - (len(req.prompt_ids) + len(req.tokens)),
                     )
-                    chunk[slot, 1 : 1 + len(draft)] = draft
+                    if req.temperature == 0 and room > 1 and K > 0:
+                        draft = llama._prompt_lookup_draft(
+                            req.prompt_ids + req.tokens, self.config.spec_ngram,
+                            min(K, room - 1),
+                        )
+                        chunk[slot, 1 : 1 + len(draft)] = draft
 
-            pc = self.paged_cache
-            if pc is not None:
-                slot_ids = [s for s, _ in rows]
-                if not pc.try_reserve_chunk(slot_ids, T):
-                    # pool pressure: fall back to single-position coverage per
-                    # row (chunk tails spill to the trash page; zero drafts
-                    # still verify position 0 = a plain decode step). A row
-                    # that can't even cover one more token retires with what
-                    # it has, like the non-spec path.
-                    kept = []
-                    for slot, req in rows:
-                        if pc.try_reserve_chunk([slot], 1):
-                            chunk[slot, 1:] = -1
-                            kept.append((slot, req))
-                        else:
-                            if self._logger:
-                                self._logger.warn(
-                                    f"KV pool exhausted; retiring request "
-                                    f"{req.id} early"
-                                )
-                            req.kv_exhausted = True
-                            self._retire(slot, "kv_exhausted")
-                    rows = kept
-                    if not rows:
-                        return True
+                pc = self.paged_cache
+                if pc is not None:
+                    slot_ids = [s for s, _ in rows]
+                    if not pc.try_reserve_chunk(slot_ids, T):
+                        # pool pressure: fall back to single-position coverage per
+                        # row (chunk tails spill to the trash page; zero drafts
+                        # still verify position 0 = a plain decode step). A row
+                        # that can't even cover one more token retires with what
+                        # it has, like the non-spec path.
+                        kept = []
+                        for slot, req in rows:
+                            if pc.try_reserve_chunk([slot], 1):
+                                chunk[slot, 1:] = -1
+                                kept.append((slot, req))
+                            else:
+                                if self._logger:
+                                    self._logger.warn(
+                                        f"KV pool exhausted; retiring request "
+                                        f"{req.id} early"
+                                    )
+                                req.kv_exhausted = True
+                                self._retire(slot, "kv_exhausted")
+                        rows = kept
+                        if not rows:
+                            return True
 
             mask = np.zeros(B, bool)
             for slot, _ in rows:
@@ -3317,40 +3386,43 @@ class ServingEngine:
             chunk_d = jnp.asarray(chunk)
             start_d = jnp.asarray(np.maximum(self.cache_len, 1))
 
-            t0 = time.perf_counter()
-            with self._cold_dispatch(
-                "spec", "paged" if pc is not None else "dense",
-            ) as cold:
-                if pc is not None:
-                    cap = np.zeros(B, np.int32)
-                    for slot, _ in rows:
-                        cap[slot] = pc.owned_capacity(slot)
-                    cap_d = jnp.asarray(cap)
-                    # unpack into LOCALS (and the pre-bound pc, which a
-                    # restart never mutates): a retired thread's unpack must
-                    # not clobber the replacement engine's state — self.*
-                    # commits happen only after the retirement check below
-                    (packed, pc.k_pool, pc.v_pool, new_rng) = (
-                        batch_ops.verify_and_sample_paged(
-                            cfg, self.params, pc.k_pool, pc.v_pool,
-                            pc.tables_device(), chunk_d, start_d,
-                            self._mask_dev, cap_d,
+            with self._phase("dispatch.launch"):
+                t0 = time.perf_counter()
+                with self._cold_dispatch(
+                    "spec", "paged" if pc is not None else "dense",
+                ) as cold:
+                    if pc is not None:
+                        cap = np.zeros(B, np.int32)
+                        for slot, _ in rows:
+                            cap[slot] = pc.owned_capacity(slot)
+                        cap_d = jnp.asarray(cap)
+                        # unpack into LOCALS (and the pre-bound pc, which a
+                        # restart never mutates): a retired thread's unpack must
+                        # not clobber the replacement engine's state — self.*
+                        # commits happen only after the retirement check below
+                        (packed, pc.k_pool, pc.v_pool, new_rng) = (
+                            batch_ops.verify_and_sample_paged(
+                                cfg, self.params, pc.k_pool, pc.v_pool,
+                                pc.tables_device(), chunk_d, start_d,
+                                self._mask_dev, cap_d,
+                                temp_d, topk_d, topp_d, self.rng,
+                            )
+                        )
+                        new_cache = self.cache  # dense path untouched
+                    else:
+                        packed, new_cache, new_rng = batch_ops.verify_and_sample(
+                            cfg, self.params, self.cache, chunk_d, start_d,
                             temp_d, topk_d, topp_d, self.rng,
                         )
-                    )
-                    new_cache = self.cache  # dense path untouched
-                else:
-                    packed, new_cache, new_rng = batch_ops.verify_and_sample(
-                        cfg, self.params, self.cache, chunk_d, start_d,
-                        temp_d, topk_d, topp_d, self.rng,
-                    )
 
-            self._blk_seq += 1
-            blk = self._blk_seq
-            span.set(blk=blk, kind="spec", rows=len(rows), steps=T,
-                     kv_tokens=int(self.cache_len[mask].sum()),
-                     chunk_rows=0, chunk_tokens=0, cold=int(cold))
-            self._count_step_tokens(len(rows) * T, 0, B * T)
+            with self._phase("dispatch.count"):
+                self._blk_seq += 1
+                blk = self._blk_seq
+                span.set(blk=blk, kind="spec", rows=len(rows), steps=T,
+                         kv_tokens=int(self.cache_len[mask].sum()),
+                         chunk_rows=0, chunk_tokens=0, cold=int(cold))
+                self._count_launch(span, self._launch_idle())
+                self._count_step_tokens(len(rows) * T, 0, B * T)
         # accepted tokens + per-row accept count come back as ONE packed
         # [B, T+1] array: one sync per chunk, like the plain path's one
         # sync per block
@@ -3392,26 +3464,25 @@ class ServingEngine:
                     if pc is not None:
                         pc.advance_slot(slot, committed)
             commit.set(tokens=emitted_total, retired=self._retires - retires)
-
-        self.spec_stats["dispatches"] += 1
-        self.spec_stats["accepted"] += accepted_total
-        self.spec_stats["emitted"] += emitted_total
-        if self._metrics and n_active:
-            self._metrics.record_histogram(
-                "app_tpot_seconds", step_time / max(emitted_total / n_active, 1)
-            )
-            self._metrics.record_histogram(
-                "app_decode_block_seconds", step_time
-            )
-            self._metrics.set_gauge(
-                "app_batch_occupancy", n_active / self.config.max_slots
-            )
-            if drafted_total:
-                # rate over tokens actually DRAFTED — sampled rows and
-                # draft-less lookups must not dilute the tuning signal
-                self._metrics.set_gauge(
-                    "app_spec_accept_rate", accepted_total / drafted_total
+            self.spec_stats["dispatches"] += 1
+            self.spec_stats["accepted"] += accepted_total
+            self.spec_stats["emitted"] += emitted_total
+            if self._metrics and n_active:
+                self._metrics.record_histogram(
+                    "app_tpot_seconds", step_time / max(emitted_total / n_active, 1)
                 )
+                self._metrics.record_histogram(
+                    "app_decode_block_seconds", step_time
+                )
+                self._metrics.set_gauge(
+                    "app_batch_occupancy", n_active / self.config.max_slots
+                )
+                if drafted_total:
+                    # rate over tokens actually DRAFTED — sampled rows and
+                    # draft-less lookups must not dilute the tuning signal
+                    self._metrics.set_gauge(
+                        "app_spec_accept_rate", accepted_total / drafted_total
+                    )
         return True
 
     def _slot_in_flight(self, slot: int, req: _Request) -> bool:
@@ -3462,6 +3533,138 @@ class ServingEngine:
         # ownership BEFORE reading slots/pools that may since be rebuilt
         self._check_retired()
 
+        with self._phase("dispatch.rows"):
+            rows, chunk_rows = self._dispatch_rows(plan)
+        if not rows and not chunk_rows:
+            return None
+        N = self._block_steps
+        pc = self.paged_cache
+
+        mask = np.zeros(self.config.max_slots, bool)
+        for slot, _ in rows:
+            mask[slot] = True
+
+        dev_idle = self._launch_idle()
+        # the device-side carry: build cold, or fold admissions in with ONE
+        # donated scatter — steady state uploads nothing per block
+        state = self._dec_state
+        if state is None:
+            with self._phase("fold", n=len(rows)):
+                state = self._make_device_state()
+        elif self._pending_admit:
+            items = sorted(self._pending_admit.items())
+            self._pending_admit.clear()
+            with self._phase("fold", n=len(items)):
+                idx = np.fromiter((s for s, _ in items), np.int32, len(items))
+                state = batch_ops.admit_decode_state(
+                    state, jnp.asarray(idx),
+                    jnp.asarray(np.fromiter((v[0] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(np.fromiter((v[1] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(np.fromiter((v[2] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(np.fromiter((v[3] for _, v in items),
+                                            np.int32, len(items))),
+                    jnp.asarray(self.temperature[idx]),
+                    jnp.asarray(self.top_k[idx]),
+                    jnp.asarray(self.top_p[idx]),
+                    jnp.asarray(np.fromiter((v[4] for _, v in items),
+                                            np.int32, len(items))),
+                )
+        # NOTE: self._dec_state is NOT updated here — the scatter donated
+        # the old buffers, and the commit happens in one place after the
+        # block dispatch (a failed dispatch resets it via _fail_all)
+
+        if self._mask_host is None or not np.array_equal(mask, self._mask_host):
+            self._mask_dev = jnp.asarray(mask)
+            self._mask_host = mask
+        mask_d = self._mask_dev
+
+        with self._phase("dispatch.launch"):
+            t0 = time.perf_counter()
+            # unpack into LOCALS (and the pre-bound pc, which a restart never
+            # mutates): a retired thread returning from a hung dispatch must
+            # not clobber the replacement engine's state at assignment time —
+            # self.* commits happen only after the retirement check
+            prefill_rows: list = []
+            last_logits = None
+            lora = self._lora.tables() if self._lora is not None else None
+            if chunk_rows:
+                (packed, last_logits, new_cache, new_state, prefill_rows,
+                 cold) = self._dispatch_ragged(
+                    cfg, pc, state, mask_d, chunk_rows, N)
+            elif pc is not None:
+                tables_d = pc.tables_device()
+                with self._cold_dispatch("decode", "paged", N,
+                                         lora is not None) as cold:
+                    (packed, pc.k_pool, pc.v_pool, new_state) = (
+                        batch_ops.decode_block_paged(
+                            cfg, self.params, pc.k_pool, pc.v_pool, state,
+                            tables_d, mask_d, N, lora=lora,
+                        )
+                    )
+                new_cache = self.cache  # dense path untouched
+            else:
+                with self._cold_dispatch("decode", "dense", N,
+                                         lora is not None) as cold:
+                    packed, new_cache, new_state = batch_ops.decode_block(
+                        cfg, self.params, self.cache, state, mask_d, N,
+                        lora=lora,
+                    )
+        with self._phase("dispatch.count"):
+            self._check_retired()  # commit to self only as the loop's owner
+            self.cache = new_cache
+            self._dec_state = new_state
+            for _, req in rows:
+                req.dispatched += N
+            # the last-position chunk logits are retained ONLY when the
+            # chunk-prefix cache will store them at consume (device ref, no
+            # sync); otherwise drop the reference so the buffer can free
+            keep_logits = (
+                last_logits
+                if prefill_rows and self._prefix_cache is not None else None
+            )
+            self._blk_seq += 1
+            chunk_tokens = sum(n for *_, n in chunk_rows)
+            # cache_len is the committed mirror: rows with a block in flight
+            # are resident N positions further on the device
+            span.set(blk=self._blk_seq, kind="ragged" if chunk_rows else "decode",
+                     rows=len(rows), steps=N, kv_tokens=int(self.cache_len[mask].sum()),
+                     chunk_rows=len(chunk_rows), chunk_tokens=chunk_tokens,
+                     cold=int(cold))
+            self._count_launch(span, dev_idle)
+            window = getattr(cfg, "sliding_window", None)
+            if window:  # rows whose window layers no longer see their first key
+                span.set(win_rows=int((self.cache_len[mask] > window).sum()))
+            for pool in (pc.ring_pools if pc is not None else ()):
+                # a window pool kept as a ring: pages the rows' tables address,
+                # and pages that fell behind the window since the last dispatch
+                held, freed = pc.window_turnover(pool, mask)
+                span.set(win_pages_held=held, win_pages_freed=freed)
+            if chunk_rows and self._upper_on_last:
+                self._count_prefill_positions(
+                    span, chunk_tokens, sum(1 for row in prefill_rows if row[5]),
+                    resets=sum(1 for _, _, _, start_pos, _ in chunk_rows if start_pos == 0))
+            topk = getattr(cfg, "index_topk", None)
+            if topk:  # rows whose sparse selection binds: attention reads index_topk of them
+                span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))
+            self._count_sampler(span, self.temperature[mask], self.top_k[mask],
+                                self.top_p[mask], steps=N)
+            self._count_step_tokens(
+                len(rows) * N, chunk_tokens,
+                self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),
+            )
+        return _Inflight(
+            packed, rows, t0, steps=N, blk=self._blk_seq,
+            prefill_rows=prefill_rows, last_logits=keep_logits,
+        )
+
+    def _dispatch_rows(self, plan: StepPlan | None) -> tuple[list, list]:
+        """The next block's rows (gofr.step.dispatch.rows): the slots that
+        decode, each with page coverage for the whole block reserved, and
+        the step plan's granted chunk rows with theirs. Rows whose exit is
+        due — canceled, expired, out of pages — leave here."""
         rows: list[tuple[int, _Request]] = []
         now = time.perf_counter()
         for slot, req in enumerate(self.slots):
@@ -3556,124 +3759,7 @@ class ServingEngine:
                         continue
                 chunk_rows.append((slot, cursor, req, cursor.dispatched, n))
 
-        if not rows and not chunk_rows:
-            return None
-
-        mask = np.zeros(self.config.max_slots, bool)
-        for slot, _ in rows:
-            mask[slot] = True
-
-        # the device-side carry: build cold, or fold admissions in with ONE
-        # donated scatter — steady state uploads nothing per block
-        state = self._dec_state
-        if state is None:
-            with self._phase("fold", n=len(rows)):
-                state = self._make_device_state()
-        elif self._pending_admit:
-            items = sorted(self._pending_admit.items())
-            self._pending_admit.clear()
-            with self._phase("fold", n=len(items)):
-                idx = np.fromiter((s for s, _ in items), np.int32, len(items))
-                state = batch_ops.admit_decode_state(
-                    state, jnp.asarray(idx),
-                    jnp.asarray(np.fromiter((v[0] for _, v in items),
-                                            np.int32, len(items))),
-                    jnp.asarray(np.fromiter((v[1] for _, v in items),
-                                            np.int32, len(items))),
-                    jnp.asarray(np.fromiter((v[2] for _, v in items),
-                                            np.int32, len(items))),
-                    jnp.asarray(np.fromiter((v[3] for _, v in items),
-                                            np.int32, len(items))),
-                    jnp.asarray(self.temperature[idx]),
-                    jnp.asarray(self.top_k[idx]),
-                    jnp.asarray(self.top_p[idx]),
-                    jnp.asarray(np.fromiter((v[4] for _, v in items),
-                                            np.int32, len(items))),
-                )
-        # NOTE: self._dec_state is NOT updated here — the scatter donated
-        # the old buffers, and the commit happens in one place after the
-        # block dispatch (a failed dispatch resets it via _fail_all)
-
-        if self._mask_host is None or not np.array_equal(mask, self._mask_host):
-            self._mask_dev = jnp.asarray(mask)
-            self._mask_host = mask
-        mask_d = self._mask_dev
-
-        t0 = time.perf_counter()
-        # unpack into LOCALS (and the pre-bound pc, which a restart never
-        # mutates): a retired thread returning from a hung dispatch must
-        # not clobber the replacement engine's state at assignment time —
-        # self.* commits happen only after the retirement check
-        prefill_rows: list = []
-        last_logits = None
-        lora = self._lora.tables() if self._lora is not None else None
-        if chunk_rows:
-            (packed, last_logits, new_cache, new_state, prefill_rows,
-             cold) = self._dispatch_ragged(
-                cfg, pc, state, mask_d, chunk_rows, N)
-        elif pc is not None:
-            tables_d = pc.tables_device()
-            with self._cold_dispatch("decode", "paged", N,
-                                     lora is not None) as cold:
-                (packed, pc.k_pool, pc.v_pool, new_state) = (
-                    batch_ops.decode_block_paged(
-                        cfg, self.params, pc.k_pool, pc.v_pool, state,
-                        tables_d, mask_d, N, lora=lora,
-                    )
-                )
-            new_cache = self.cache  # dense path untouched
-        else:
-            with self._cold_dispatch("decode", "dense", N,
-                                     lora is not None) as cold:
-                packed, new_cache, new_state = batch_ops.decode_block(
-                    cfg, self.params, self.cache, state, mask_d, N,
-                    lora=lora,
-                )
-        self._check_retired()  # commit to self only as the loop's owner
-        self.cache = new_cache
-        self._dec_state = new_state
-        for _, req in rows:
-            req.dispatched += N
-        # the last-position chunk logits are retained ONLY when the
-        # chunk-prefix cache will store them at consume (device ref, no
-        # sync); otherwise drop the reference so the buffer can free
-        keep_logits = (
-            last_logits
-            if prefill_rows and self._prefix_cache is not None else None
-        )
-        self._blk_seq += 1
-        chunk_tokens = sum(n for *_, n in chunk_rows)
-        # cache_len is the committed mirror: rows with a block in flight
-        # are resident N positions further on the device
-        span.set(blk=self._blk_seq, kind="ragged" if chunk_rows else "decode",
-                 rows=len(rows), steps=N, kv_tokens=int(self.cache_len[mask].sum()),
-                 chunk_rows=len(chunk_rows), chunk_tokens=chunk_tokens,
-                 cold=int(cold))
-        window = getattr(cfg, "sliding_window", None)
-        if window:  # rows whose window layers no longer see their first key
-            span.set(win_rows=int((self.cache_len[mask] > window).sum()))
-        for pool in (pc.ring_pools if pc is not None else ()):
-            # a window pool kept as a ring: pages the rows' tables address,
-            # and pages that fell behind the window since the last dispatch
-            held, freed = pc.window_turnover(pool, mask)
-            span.set(win_pages_held=held, win_pages_freed=freed)
-        if chunk_rows and self._upper_on_last:
-            self._count_prefill_positions(
-                span, chunk_tokens, sum(1 for row in prefill_rows if row[5]),
-                resets=sum(1 for _, _, _, start_pos, _ in chunk_rows if start_pos == 0))
-        topk = getattr(cfg, "index_topk", None)
-        if topk:  # rows whose sparse selection binds: attention reads index_topk of them
-            span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))
-        self._count_sampler(span, self.temperature[mask], self.top_k[mask],
-                            self.top_p[mask], steps=N)
-        self._count_step_tokens(
-            len(rows) * N, chunk_tokens,
-            self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),
-        )
-        return _Inflight(
-            packed, rows, t0, steps=N, blk=self._blk_seq,
-            prefill_rows=prefill_rows, last_logits=keep_logits,
-        )
+        return rows, chunk_rows
 
     def _count_step_stats(self, span: _StepPhase, stats: Any) -> None:
         """A block's model counters, read with its tokens: row-expert
@@ -3875,141 +3961,137 @@ class ServingEngine:
 
             n_active = tokens = 0
             retires = self._retires
-            for slot, req in rec.rows:
-                if self.slots[slot] is not req:
-                    continue  # retired (and possibly re-admitted) since dispatch
-                n_active += 1
-                n_valid = int(packed[slot, rec.steps + 1])
-                device_done = bool(packed[slot, rec.steps])
-                committed = 0
-                for i in range(n_valid):
-                    self._commit_token(slot, req, int(packed[slot, i]))
-                    committed += 1
+            with self._phase("commit.rows"):
+                for slot, req in rec.rows:
                     if self.slots[slot] is not req:
-                        break  # retired mid-block: discard the tail tokens
-                if req.timeline is not None:
-                    # flight-recorder stamp at the block's ONE host sync:
-                    # COMMITTED tokens only (a mid-block retire discards the
-                    # tail — the spec path's `committed` twin), no extra
-                    # device read, and no timestamp passed (`now` is
-                    # perf_counter; the timeline's clock is monotonic)
-                    req.timeline.block(committed, blk=rec.blk)
-                tokens += committed
-                if self.slots[slot] is not req:
-                    continue
-                # committed residency advances by what the device actually
-                # emitted (the device carry already did)
-                self.cache_len[slot] += n_valid
-                if self.paged_cache is not None:
-                    self.paged_cache.advance_slot(slot, n_valid)
-                if req.kv_exhausted:
-                    # clamped at dispatch time: retire with the pool-pressure
-                    # reason, but only once NO younger in-flight block still
-                    # carries tokens for this row (decode_sync_every >= 2 can
-                    # have several) — retiring earlier would discard tokens
-                    # the client paid for via the consume identity check
-                    if not self._slot_in_flight(slot, req):
-                        self._retire(slot, "kv_exhausted")
-                elif device_done:
-                    # defensive: _commit_token's own stop/limit chain normally
-                    # retired the row on its last committed token already —
-                    # this catches a host/device divergence rather than
-                    # leaving a device-frozen row parked in a slot forever
-                    self._retire(
-                        slot,
-                        "stop" if req.tokens and req.tokens[-1] in req.stop_ids
-                        else "length",
-                    )
+                        continue  # retired (and possibly re-admitted) since dispatch
+                    n_active += 1
+                    n_valid = int(packed[slot, rec.steps + 1])
+                    device_done = bool(packed[slot, rec.steps])
+                    committed = 0
+                    for i in range(n_valid):
+                        self._commit_token(slot, req, int(packed[slot, i]))
+                        committed += 1
+                        if self.slots[slot] is not req:
+                            break  # retired mid-block: discard the tail tokens
+                    if req.timeline is not None:
+                        # flight-recorder stamp at the block's ONE host sync:
+                        # COMMITTED tokens only (a mid-block retire discards the
+                        # tail — the spec path's `committed` twin), no extra
+                        # device read, and no timestamp passed (`now` is
+                        # perf_counter; the timeline's clock is monotonic)
+                        req.timeline.block(committed, blk=rec.blk)
+                    tokens += committed
+                    if self.slots[slot] is not req:
+                        continue
+                    # committed residency advances by what the device actually
+                    # emitted (the device carry already did)
+                    self.cache_len[slot] += n_valid
+                    if self.paged_cache is not None:
+                        self.paged_cache.advance_slot(slot, n_valid)
+                    if req.kv_exhausted:
+                        # clamped at dispatch time: retire with the pool-pressure
+                        # reason, but only once NO younger in-flight block still
+                        # carries tokens for this row (decode_sync_every >= 2 can
+                        # have several) — retiring earlier would discard tokens
+                        # the client paid for via the consume identity check
+                        if not self._slot_in_flight(slot, req):
+                            self._retire(slot, "kv_exhausted")
+                    elif device_done:
+                        # defensive: _commit_token's own stop/limit chain normally
+                        # retired the row on its last committed token already —
+                        # this catches a host/device divergence rather than
+                        # leaving a device-frozen row parked in a slot forever
+                        self._retire(
+                            slot,
+                            "stop" if req.tokens and req.tokens[-1] in req.stop_ids
+                            else "length",
+                        )
 
             # -- prefill-chunk rows (ragged dispatches only): commit each
             # chunk's residency, feed the chunk-prefix cache, and admit rows
             # whose prompt just finished — their device-sampled first token
             # rides the same packed sync in the trailing column
-            for slot, req, cursor, start_pos, n, fin, idx in rec.prefill_rows:
-                if (self.slots[slot] is not req
-                        or self._cursors.get(slot) is not cursor):
-                    continue  # retired/requeued since dispatch: stale chunk
-                n_active += 1
-                cursor.committed = start_pos + n
-                self.cache_len[slot] = cursor.committed
-                if self.paged_cache is not None:
-                    self.paged_cache.advance_slot(slot, n)
-                tl = req.timeline
-                if tl is not None:
-                    tl.chunk(idx, n, prefix_hit=False, start=start_pos)
-                    tl.end_span(f"prefill_chunk:{idx}")
-                if self._metrics:
-                    self._metrics.record_histogram(
-                        "app_prefill_chunk_tokens", n, kind="compute",
-                    )
-                # only whole-chunk-aligned spans have a precomputed key: the
-                # lookup walk probes exactly (k*C, k*C+C|total), and the paged
-                # extraction needs a page-aligned start — the planner
-                # guarantees this shape; a missing key (future policy drift)
-                # skips the put instead of failing the engine loop
-                put_key = (
-                    cursor.cache_keys.get((start_pos, start_pos + n))
-                    if cursor.cache_keys is not None else None
-                )
-                if (self._prefix_cache is not None
-                        and rec.last_logits is not None and put_key is not None):
-                    # chunk-prefix cache PUT: the chunk's K/V just became
-                    # resident — extract its slab (pure device reads, no sync;
-                    # the slices/gathers are fresh buffers safe to retain) and
-                    # store it with the prefix's last-position logits, so a
-                    # later prompt sharing this prefix skips the chunk
-                    if self.paged_cache is not None:
-                        k_slab, v_slab = self.paged_cache.read_span(
-                            slot, start_pos, start_pos + n
+            if rec.prefill_rows:
+                with self._phase("commit.chunks"):
+                    for slot, req, cursor, start_pos, n, fin, idx in rec.prefill_rows:
+                        if (self.slots[slot] is not req
+                                or self._cursors.get(slot) is not cursor):
+                            continue  # retired/requeued since dispatch: stale chunk
+                        n_active += 1
+                        cursor.committed = start_pos + n
+                        self.cache_len[slot] = cursor.committed
+                        if self.paged_cache is not None:
+                            self.paged_cache.advance_slot(slot, n)
+                        tl = req.timeline
+                        if tl is not None:
+                            tl.chunk(idx, n, prefix_hit=False, start=start_pos)
+                            tl.end_span(f"prefill_chunk:{idx}")
+                        if self._metrics:
+                            self._metrics.record_histogram(
+                                "app_prefill_chunk_tokens", n, kind="compute",
+                            )
+                        # only whole-chunk-aligned spans have a precomputed key: the
+                        # lookup walk probes exactly (k*C, k*C+C|total), and the paged
+                        # extraction needs a page-aligned start — the planner
+                        # guarantees this shape; a missing key (future policy drift)
+                        # skips the put instead of failing the engine loop
+                        put_key = (
+                            cursor.cache_keys.get((start_pos, start_pos + n))
+                            if cursor.cache_keys is not None else None
                         )
-                    else:
-                        k_slab = self.cache.k[:, slot, start_pos : start_pos + n]
-                        v_slab = self.cache.v[:, slot, start_pos : start_pos + n]
-                    self._prefix_cache.put(
-                        put_key,
-                        (rec.last_logits[slot : slot + 1], k_slab, v_slab),
+                        if (self._prefix_cache is not None
+                                and rec.last_logits is not None and put_key is not None):
+                            # chunk-prefix cache PUT: the chunk's K/V just became
+                            # resident — extract its slab (pure device reads, no sync;
+                            # the slices/gathers are fresh buffers safe to retain) and
+                            # store it with the prefix's last-position logits, so a
+                            # later prompt sharing this prefix skips the chunk
+                            if self.paged_cache is not None:
+                                k_slab, v_slab = self.paged_cache.read_span(
+                                    slot, start_pos, start_pos + n
+                                )
+                            else:
+                                k_slab = self.cache.k[:, slot, start_pos : start_pos + n]
+                                v_slab = self.cache.v[:, slot, start_pos : start_pos + n]
+                            self._prefix_cache.put(
+                                put_key,
+                                (rec.last_logits[slot : slot + 1], k_slab, v_slab),
+                            )
+                        if fin:
+                            self._cursors.pop(slot, None)
+                            first_id = int(packed[slot, rec.steps + 2])
+                            self._commit_first_token(slot, req, first_id)
+
+            with self._phase("commit.stats"):
+                span.set(tokens=tokens, retired=self._retires - retires)
+                if self._stats_len:
+                    self._count_step_stats(span, batch_ops.block_stats(
+                        packed, self.config.max_slots, self._stats_len))
+                if self._metrics and n_active:
+                    self._metrics.record_histogram(
+                        "app_tpot_seconds", step_time / rec.steps
                     )
-                if fin:
-                    self._cursors.pop(slot, None)
-                    first_id = int(packed[slot, rec.steps + 2])
-                    self._commit_first_token(slot, req, first_id)
-
-            span.set(tokens=tokens, retired=self._retires - retires)
-            if self._stats_len:
-                self._count_step_stats(span, batch_ops.block_stats(
-                    packed, self.config.max_slots, self._stats_len))
-
-        if self._metrics and n_active:
-            self._metrics.record_histogram(
-                "app_tpot_seconds", step_time / rec.steps
-            )
-            self._metrics.record_histogram(
-                "app_decode_block_seconds", step_time
-            )
-            self._metrics.set_gauge(
-                "app_batch_occupancy", n_active / self.config.max_slots
-            )
-            if self.paged_cache is not None:
-                kv = self.paged_cache.stats()
-                self._metrics.set_gauge(
-                    "app_kv_cache_pages_used",
-                    kv["total_blocks"] - kv["free_blocks"],
-                )
-                for pool, n in kv.get("pools", {}).items():
-                    for state in ("used", "total"):
+                    self._metrics.record_histogram(
+                        "app_decode_block_seconds", step_time
+                    )
+                    self._metrics.set_gauge(
+                        "app_batch_occupancy", n_active / self.config.max_slots
+                    )
+                    if self.paged_cache is not None:
+                        kv = self.paged_cache.stats()
                         self._metrics.set_gauge(
-                            "app_kv_pool_pages", n[state], pool=pool, state=state)
-            # the hot loop's success metric: host time per decode step —
-            # the block's fold + dispatch + commit spans, not the sync
-            # wait — must stay a small fraction of decode_step_ms
-            self._metrics.set_gauge(
-                "app_decode_host_ms_per_step",
-                (rec.dispatch_s + span.seconds) * 1e3 / rec.steps,
-            )
-            self._metrics.set_gauge("app_decode_block_size", rec.steps)
-            with self._detok_mu:
-                depth = self._detok_depth
-            self._metrics.set_gauge("app_detok_queue_depth", depth)
+                            "app_kv_cache_pages_used",
+                            kv["total_blocks"] - kv["free_blocks"],
+                        )
+                        for pool, n in kv.get("pools", {}).items():
+                            for state in ("used", "total"):
+                                self._metrics.set_gauge(
+                                    "app_kv_pool_pages", n[state], pool=pool, state=state)
+                    self._metrics.set_gauge("app_decode_block_size", rec.steps)
+                    with self._detok_mu:
+                        depth = self._detok_depth
+                    self._metrics.set_gauge("app_detok_queue_depth", depth)
 
     def _commit_first_token(self, slot: int, req: _Request,
                             first_id: int) -> None:
@@ -4143,6 +4225,9 @@ class ServingEngine:
     def _retire(self, slot: int, reason: str) -> None:
         req = self.slots[slot]
         if req is not None and req.timeline is not None:
+            # the loop's account as the row leaves: with the one taken at
+            # admission, what the loop did while this request was served
+            req.timeline.loop_end = self.loop_account()
             # final residency facts for the decode span, read from the
             # host mirrors BEFORE the slot is reclaimed (zero device reads)
             dspan = req.timeline.spans.get("decode")
@@ -4230,6 +4315,11 @@ class ServingEngine:
         tl = req.timeline
         if tl is None:
             return
+        if tl.loop_end is None:
+            # settled without the loop thread retiring it (a sweep or a
+            # cancel that won, a deadline in the queue): the account as
+            # this thread can read it
+            tl.loop_end = self.loop_account()
         dspan = tl.spans.get("decode")
         if dspan is not None:
             dspan.set_attribute("tokens.out", len(req.tokens))

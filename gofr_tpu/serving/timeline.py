@@ -36,6 +36,11 @@ from typing import Any
 # canonical phase names, in lifecycle order (decode-block syncs are
 # aggregated as counters, not individual stamps — a 1024-token request
 # would otherwise grow 256 entries)
+# the step-loop phases in which the engine thread waits (on the device,
+# or for work): what a request's ``loop.during`` leaves out of the loop's
+# host time a block
+LOOP_WAITS = ("sync", "prefill_sync", "wait")
+
 PHASES = (
     "submitted",
     "admitted",
@@ -53,7 +58,7 @@ class RequestTimeline:
     __slots__ = (
         "request_id", "trace_id", "created_unix", "prompt_tokens",
         "phases", "decode_blocks", "decode_tokens", "last_block_at",
-        "first_blk", "last_blk",
+        "first_blk", "last_blk", "loop_admit", "loop_end",
         "prefill_chunks", "prefix_tier", "finish_reason", "terminal_at",
         "terminal_marks", "spans", "tenant", "_t0",
     )
@@ -74,6 +79,11 @@ class RequestTimeline:
         # carried this row: the join from a request to a device trace
         self.first_blk: int | None = None
         self.last_blk: int | None = None
+        # the step loop's account (ServingEngine.loop_account) as the
+        # engine thread admitted this request and as it retired it: their
+        # difference is what the loop did while the request was served
+        self.loop_admit: dict[str, Any] | None = None
+        self.loop_end: dict[str, Any] | None = None
         # chunked-prefill record (continuous batching): one entry per
         # committed prefill chunk — {index, tokens, prefix_hit, ms}. A
         # monolithic (single-bucket) prefill leaves this empty; the
@@ -204,6 +214,36 @@ class RequestTimeline:
     def _ms(self, t: float) -> float:
         return round((t - self._t0) * 1e3, 3)
 
+    def _loop_view(self) -> dict[str, Any] | None:
+        """``loop`` of the JSON view: the two snapshots of the step loop's
+        account in milliseconds, and what lies between them."""
+        out: dict[str, Any] = {}
+        for key, snap in (("at_admit", self.loop_admit), ("at_end", self.loop_end)):
+            if snap is not None:
+                out[key] = {
+                    "ms": self._ms(snap["t"]),
+                    "blocks": snap["blocks"],
+                    "launched_idle": snap["launched_idle"],
+                    "launched_queued": snap["launched_queued"],
+                    "phase_ms": {p: round(v * 1e3, 3) for p, v in snap["phase_s"].items()},
+                    "cpu_ms": {p: round(v * 1e3, 3) for p, v in snap["cpu_s"].items()},
+                }
+        if len(out) == 2:
+            a, b = self.loop_admit, self.loop_end
+            blocks = b["blocks"] - a["blocks"]
+            launched = (b["launched_idle"] - a["launched_idle"]
+                        + b["launched_queued"] - a["launched_queued"])
+            host_s = sum(v - a["phase_s"][p] for p, v in b["phase_s"].items()
+                         if p not in LOOP_WAITS)
+            out["during"] = {
+                "blocks": blocks,
+                "host_ms_per_block": round(host_s * 1e3 / blocks, 3) if blocks else None,
+                "launched_idle_share": (
+                    round((b["launched_idle"] - a["launched_idle"]) / launched, 4)
+                    if launched else None),
+            }
+        return out or None
+
     def to_dict(self) -> dict[str, Any]:
         # snapshot first: an in-flight timeline is being stamped by the
         # engine thread while /requestz serializes it — iterating the
@@ -233,6 +273,9 @@ class RequestTimeline:
         if self.first_blk is not None:
             out["decode"]["first_blk"] = self.first_blk
             out["decode"]["last_blk"] = self.last_blk
+        loop = self._loop_view()
+        if loop is not None:
+            out["loop"] = loop
         if self.prefill_chunks:
             # snapshot (list() of the live list): the engine thread may
             # append a chunk while /requestz serializes an in-flight row

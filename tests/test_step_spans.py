@@ -24,14 +24,17 @@ from gofr_tpu.serving import engine as engine_mod
 KINDS = {
     # dispatch kind -> engine settings, the open request's prompt, the
     # phases that kind of engine can show
-    "decode": (dict(), "open", set(engine_mod.STEP_PHASES)),
+    "decode": (dict(), "open", set(engine_mod.STEP_PHASES) - {"commit.chunks"}),
     # a prompt longer than a chunk prefills through ragged dispatches
     "ragged": (dict(prefill_chunk_tokens=16), "a long open prompt of three chunks",
                set(engine_mod.STEP_PHASES)),
-    # speculative decoding keeps its decode state on the host: no fold
-    "spec": (dict(spec_tokens=2, multi_step=None), "open", set(engine_mod.STEP_PHASES) - {"fold"}),
+    # speculative decoding keeps its decode state on the host (no fold) and
+    # commits a chunk in one piece
+    "spec": (dict(spec_tokens=2, multi_step=None), "open",
+             set(engine_mod.STEP_PHASES) - {"fold", "commit.rows", "commit.chunks", "commit.stats"}),
 }
 STEPS = 4
+PARTS = {"dispatch.rows", "dispatch.launch", "dispatch.count", "commit.rows", "commit.chunks", "commit.stats"}
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +81,20 @@ def read_spans(trace_dir):
     return [s for s in spans if s.end_ns <= whole]
 
 
+def run_over(spans, fold_parts=False):
+    """The benchmark's view of a list of spans: a RunData whose traced
+    sub-window holds them all, with the parts of dispatch and commit
+    left in or taken out (their time then falls to their parents)."""
+    from benchmarks.harness.runner import RunData
+
+    events = [trace_reduce.Event(*s.thread.rsplit("/", 1), "gofr.step" + ("" if s.phase == "step" else "." + s.phase)
+                                 + "#" + ",".join(f"{k}={v}" for k, v in s.kw.items()) + "#", s.start_ns, s.dur_ns)
+              for s in spans if not (fold_parts and s.phase in PARTS)]
+    a, b = min(s.start_ns for s in spans), max(s.end_ns for s in spans)
+    return RunData({"name": "x"}, {}, {"engine": {"max_slots": 3}}, [], (0.0, 1.0), (a / 1e9, b / 1e9),
+                   events, 0, {}, [], "cpu")
+
+
 def depths(spans):
     """Nesting depth of every span, thread by thread; raises if two spans
     of a thread overlap without one holding the other."""
@@ -117,6 +134,7 @@ def traced(request, model, tmp_path_factory):
         while (engine._inflight_q or engine._phase_state[0] != "wait") and time.monotonic() < deadline:
             time.sleep(0.02)  # the pipeline drains its last block: a span cut by the trace's start has no parent
         del finished[:], syncs[:]
+        launched = dict(engine._launched)
         trace_dir = tmp_path_factory.mktemp(f"trace-{kind}")
         with jax.profiler.trace(str(trace_dir)):
             time.sleep(0.12)  # an idle engine waits
@@ -127,7 +145,8 @@ def traced(request, model, tmp_path_factory):
         engine.stop()
         patch.undo()
     return SimpleNamespace(kind=kind, phases=phases, spans=read_spans(trace_dir), results=results,
-                           views=views, requests=list(finished), syncs=len(syncs))
+                           views=views, requests=list(finished), syncs=len(syncs),
+                           launched={k: v - launched[k] for k, v in engine._launched.items()})
 
 
 def test_every_phase_is_a_span_nested_under_a_step(traced):
@@ -142,6 +161,9 @@ def test_every_phase_is_a_span_nested_under_a_step(traced):
     assert ("prefill_sync", "prefill") in parents and ("prefill", "admit") in parents
     if "fold" in traced.phases:
         assert ("fold", "dispatch") in parents
+    # a part of dispatch or of commit lies inside a span of that name, and nowhere else
+    assert {(p, parent) for p, parent in parents if p in PARTS} == {
+        (p, p.split(".")[0]) for p in PARTS & traced.phases}
     # the iteration's number and the host's clock ride every gofr.step
     iters = [s.kw["iter"] for s in traced.spans if s.phase == "step"]
     assert iters == sorted(set(iters))
@@ -150,7 +172,7 @@ def test_every_phase_is_a_span_nested_under_a_step(traced):
 def test_dispatch_kind_and_keywords(traced):
     blocks = [s for s in traced.spans if s.phase == "dispatch" and "blk" in s.kw]
     assert traced.kind in {s.kw["kind"] for s in blocks}
-    keywords = {"blk", "kind", "rows", "steps", "kv_tokens", "chunk_rows", "chunk_tokens", "cold"}
+    keywords = {"blk", "kind", "rows", "steps", "kv_tokens", "chunk_rows", "chunk_tokens", "cold", "dev_idle", "cpu_us"}
     for s in blocks:
         # the sampler's path rides the blocks whose steps end in ops/sampling.sample_logits
         assert set(s.kw) == keywords | ({"sampler"} if traced.kind != "spec" else set())
@@ -160,6 +182,40 @@ def test_dispatch_kind_and_keywords(traced):
     assert any(s.kw["rows"] == 2 and s.kw["kv_tokens"] > 0 for s in blocks)  # both rows in one block
     routes = {s.kw.get("route") for s in traced.spans if s.phase == "prefill"}
     assert routes == ({"chunked", "bucketed"} if traced.kind == "ragged" else {"bucketed"})
+
+
+def test_self_time_adds_up_to_the_iterations_and_the_parts_change_no_sum(traced):
+    """Every instant of an iteration belongs to one phase, and what the
+    parts of dispatch and commit hold is what their parents held before:
+    the benchmark's sums by prefix read the same with the parts folded
+    back into them."""
+    run, folded = run_over(traced.spans), run_over(traced.spans, fold_parts=True)
+    by_phase, before = host_spans.self_seconds_by_phase(run), host_spans.self_seconds_by_phase(folded)
+    iterations = sum(s.dur_ns for s in traced.spans if s.phase == "step") / 1e9
+    assert sum(by_phase.values()) == pytest.approx(iterations, abs=1e-9)
+    for parent in ("dispatch", "commit"):
+        parts = sum(v for p, v in by_phase.items() if p.split(".")[0] == parent)
+        assert parts == pytest.approx(before[parent], abs=1e-9)
+    assert PARTS & set(by_phase) and not PARTS & set(before)
+    assert [s.kw["blk"] for s in host_spans.blocks(run)] == [s.kw["blk"] for s in host_spans.blocks(folded)]
+    assert host_spans.host_ms_per_block(run) == pytest.approx(host_spans.host_ms_per_block(folded), abs=1e-6)
+    assert host_spans.slot_use_pct(run) == host_spans.slot_use_pct(folded)
+
+
+def test_every_span_carries_its_cpu_time_and_a_block_says_whether_the_device_waited(traced):
+    for s in traced.spans:
+        # its own thread's clock: never more than its duration, but for a tick of that clock (10 ms on some hosts)
+        assert 0 <= s.kw["cpu_us"] <= s.dur_ns / 1e3 + 11_000, s
+    blocks = [s for s in traced.spans if s.phase == "dispatch" and "blk" in s.kw]
+    # the loop's count of launches is the spans'
+    assert traced.launched == {launch: sum(s.kw["dev_idle"] == said for s in blocks)
+                               for said, launch in enumerate(engine_mod.LAUNCHES)}
+    if traced.kind == "spec":
+        assert {s.kw["dev_idle"] for s in blocks} == {2}  # a chunk is read before the next is built
+    else:
+        # a block with none in flight follows a wait or an iteration that dispatched nothing
+        assert blocks[0].kw["dev_idle"] == 2 and {s.kw["dev_idle"] for s in blocks} <= {0, 1, 2}
+        assert traced.launched["none"] <= len(blocks) // 2
 
 
 def test_a_blocks_dispatch_sync_and_commit_carry_one_number(traced):
@@ -192,6 +248,22 @@ def test_requestz_joins_a_request_to_its_blocks(traced):
         assert decode["first_blk"] <= decode["last_blk"]
         assert {decode["first_blk"], decode["last_blk"]} <= seen
         assert decode["last_blk"] - decode["first_blk"] + 1 >= decode["blocks"] >= 1
+
+
+def test_requestz_carries_the_loops_account_at_admission_and_at_the_end(traced):
+    for view in traced.views:
+        admit, end, during = (view["loop"][k] for k in ("at_admit", "at_end", "during"))
+        assert admit["ms"] == view["phases_ms"]["admitted"] or admit["ms"] >= view["phases_ms"]["admitted"]
+        for key in ("ms", "blocks", "launched_idle", "launched_queued"):
+            assert admit[key] <= end[key], key
+        for key in ("phase_ms", "cpu_ms"):
+            assert set(admit[key]) == set(end[key]) == set(engine_mod.STEP_PHASES)
+            assert all(admit[key][p] <= end[key][p] for p in admit[key]), key
+        assert during["blocks"] == end["blocks"] - admit["blocks"] >= view["decode"]["blocks"] >= 1
+        assert during["host_ms_per_block"] > 0
+        launched = (end["launched_idle"] - admit["launched_idle"]) + (end["launched_queued"] - admit["launched_queued"])
+        assert (during["launched_idle_share"] is None) == (launched == 0)
+        assert during["launched_idle_share"] is None or 0.0 <= during["launched_idle_share"] <= 1.0
 
 
 # ------------------------------------------------------- the sampler's path
@@ -269,7 +341,7 @@ def test_phase_account_covers_the_loop_and_busy_leaves_wait_out(model):
     engine.start()
     try:
         drive(engine, "open")
-        time.sleep(0.3)  # and an idle stretch
+        time.sleep(0.8)  # and an idle stretch (long beside what start() and stop() take outside the account)
     finally:
         engine.stop()
     wall = time.monotonic() - t0
@@ -288,8 +360,62 @@ def test_phase_account_covers_the_loop_and_busy_leaves_wait_out(model):
     decode = tokens.value({"kind": "decode"})
     assert decode >= 22 + 7 - 2 and decode % STEPS == 0
     assert tokens.value({"kind": "padding"}) > 0 and tokens.value({"kind": "prefill"}) == 0
-    # the host's share of a block: its fold, dispatch and commit spans
-    assert 0 < metrics.get("app_decode_host_ms_per_step").value() < 1e3
+    # the host's share of a block is a rate of the two counters, by phase:
+    # the CPU one never passes the wall one, and every phase has its series
+    cpu = metrics.get("app_engine_phase_cpu_seconds_total")
+    for phase in engine_mod.STEP_PHASES:
+        assert engine._phase_cpu_s[phase] <= account[phase] + 0.011 + 0.01 * account[phase], phase  # a tick of room
+        assert cpu.value({"phase": phase}) == pytest.approx(engine._phase_cpu_s[phase], rel=0.05, abs=1e-6), phase
+        assert f'app_engine_phase_cpu_seconds_total{{phase="{phase}"}}' in metrics.expose_prometheus()
+    host = sum(account[p] for p in ("fold", "dispatch", "commit") + tuple(PARTS))
+    assert 0 < host / engine._blk_seq < 1.0 and cpu.value({"phase": "wait"}) < 0.1 * account["wait"]
+    blocks = metrics.get("app_engine_blocks_total")
+    assert {k: blocks.value({"launch": k}) for k in engine_mod.LAUNCHES} == engine._launched
+    assert sum(engine._launched.values()) == engine._blk_seq  # every block, once
+    assert 0 < engine._launched["none"] < engine._blk_seq  # the first block found none in flight
+    assert metrics.get("app_decode_host_ms_per_step") is None  # gone: the rate of the counters replaces it
+    container.close()
+
+
+def test_a_busy_phase_reads_cpu_as_wall_and_a_sleeping_one_reads_none(model):
+    engine = make_engine(model)  # never started: the caller owns the account
+    with engine._phase("plan"):
+        until = time.monotonic() + 0.2
+        while time.monotonic() < until:
+            pass
+    with engine._phase("wait"):
+        time.sleep(0.2)
+    wall, cpu = engine._phase_s, engine._phase_cpu_s
+    assert wall["plan"] >= 0.2 and wall["wait"] >= 0.2
+    # a thread's CPU clock may tick in steps of 10 ms: a tick of room on either side
+    assert 0.5 * wall["plan"] <= cpu["plan"] <= wall["plan"] + 0.011
+    assert cpu["wait"] <= 0.011 + 0.05 * wall["wait"]
+    account = engine.loop_account()
+    assert account["phase_s"] == wall and account["cpu_s"] == cpu and account["phase_s"] is not wall
+    assert account["blocks"] == 0 and account["t"] <= time.monotonic()
+
+
+@pytest.mark.parametrize("newest, said, counted", [("ready", 1, "idle"), ("running", 0, "queued"), (None, 2, "none")])
+def test_a_launch_is_onto_an_idle_device_when_the_newest_block_in_flight_is_ready(model, newest, said, counted):
+    container = Container(MapConfig({"LOG_LEVEL": "ERROR"}, use_env=False))
+    metrics = container.metrics_manager
+    engine = make_engine(model, metrics=metrics)
+    asked = []
+    if newest is not None:
+        # an older block still running does not matter: the newest is what was queued last
+        older = SimpleNamespace(is_ready=lambda: asked.append("older") or False)
+        packed = SimpleNamespace(is_ready=lambda: asked.append("newest") or newest == "ready")
+        engine._inflight_q.extend(engine_mod._Inflight(p, [], 0.0) for p in (older, packed))
+    with engine._phase("dispatch") as span:
+        dev_idle = engine._launch_idle()
+        engine._count_launch(span, dev_idle)
+    assert dev_idle == said and asked == ([] if newest is None else ["newest"])
+    assert engine._launched == {k: int(k == counted) for k in engine_mod.LAUNCHES}
+    blocks = metrics.get("app_engine_blocks_total")
+    assert {k: blocks.value({"launch": k}) for k in engine_mod.LAUNCHES} == engine._launched
+    account = engine.loop_account()
+    assert (account["launched_idle"], account["launched_queued"]) == (engine._launched["idle"], engine._launched["queued"])
+    engine._inflight_q.clear()
     container.close()
 
 
@@ -388,13 +514,15 @@ def test_a_retired_thread_unwinds_without_touching_the_account(model, monkeypatc
             doomed.result(timeout=30)
         assert engine.submit("fresh", max_new_tokens=3).result(timeout=120).completion_tokens >= 1
         assert settles_in(engine, "wait")  # the replacement idles in its own wait
-        before = dict(engine._phase_s)
+        before = dict(engine._phase_s), dict(engine._phase_cpu_s)
         hold.set()
         old.join(timeout=60)
         assert not old.is_alive()
-        after = dict(engine._phase_s)
-        # the old thread left dispatch and step behind it: neither was charged by its unwind
-        assert after["dispatch"] == before["dispatch"] and after["step"] == before["step"]
+        after = dict(engine._phase_s), dict(engine._phase_cpu_s)
+        # the old thread left dispatch.launch, dispatch and step behind it: its unwind charged
+        # none of them, in neither account
+        for phase in ("dispatch.launch", "dispatch", "step"):
+            assert after[0][phase] == before[0][phase] and after[1][phase] == before[1][phase], phase
         assert settles_in(engine, "wait")
         assert engine.submit("again", max_new_tokens=3).result(timeout=120).completion_tokens >= 1
     finally:
