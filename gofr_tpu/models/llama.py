@@ -238,6 +238,32 @@ class KVCache:
 
 
 # ---------------------------------------------------------------- layer body
+def _qkv_products(
+    h: jnp.ndarray,  # [..., D] normed hidden state
+    lp: dict,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The three projections as plain ``[rows, D] x [D, N]`` products —
+    ``[..., H*Dh]``, ``[..., Hkv*Dh]``, ``[..., Hkv*Dh]`` — handed on
+    together through an optimization barrier; the caller reshapes to heads
+    and applies RoPE after it.
+
+    Without the barrier XLA folds the reshape to ``[B, S, heads, Dh]`` INTO
+    the product: the dot's weight operand becomes a view ``s8[heads, Dh,
+    D]``, which needs the stack with the contraction axis minor
+    (``{1,2,0}``). A program that scans the layers then copies each whole
+    ``wq``/``wk``/``wv`` stack to that layout once a dispatch (``copy.30``,
+    ``copy.44``: 0.75 and 1.4 GiB of temporaries at the 7B cells' sizes) and
+    in every layer first writes the layer's slice out
+    (``constant_dynamic-slice_fusion.*``) and only then multiplies it — two
+    passes where ``wo``, of ``wq``'s size, streams from HBM into its
+    ``convolution`` once through a bitcast of the slice. Behind the barrier
+    q, k and v take ``wo``'s form (PERF.md §6, PR 38;
+    ``tests/test_paged_append.py`` holds the compiled text)."""
+    return jax.lax.optimization_barrier(
+        (_mm(h, lp["wq"]), _mm(h, lp["wk"]), _mm(h, lp["wv"]))
+    )
+
+
 def _qkv(
     cfg: LlamaConfig,
     x: jnp.ndarray,  # [B, S, D]
@@ -251,11 +277,10 @@ def _qkv(
     B, S, _ = x.shape
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = _mm(h, lp["wq"]).reshape(B, S, H, Dh)
-    k = _mm(h, lp["wk"]).reshape(B, S, Hkv, Dh)
-    v = _mm(h, lp["wv"]).reshape(B, S, Hkv, Dh)
-    q = apply_rope(q, positions, sin, cos)
-    k = apply_rope(k, positions, sin, cos)
+    q, k, v = _qkv_products(h, lp)
+    q = apply_rope(q.reshape(B, S, H, Dh), positions, sin, cos)
+    k = apply_rope(k.reshape(B, S, Hkv, Dh), positions, sin, cos)
+    v = v.reshape(B, S, Hkv, Dh)
     return h, q, k, v
 
 
@@ -494,7 +519,16 @@ def decode_step_paged(
     scatters into or copies a pool. Any XLA op that writes one token into
     a pool makes layout assignment swap the pool's KV-head and page axes,
     and the pool is then transposed on entry, around every kernel call and
-    on exit — more than half of a decode step (PERF.md §6, PR 30)."""
+    on exit — more than half of a decode step (PERF.md §6, PR 30).
+
+    The q, k and v products come through ``_qkv_products`` and are reshaped
+    to heads after its barrier. Written ``_mm(hn, lp["wq"]).reshape(B, 1, H,
+    Dh)`` the reshape is folded into the product, and the block then copies
+    the three weight stacks to another layout on entry (``copy.*`` of
+    ``s8[L, D, N]{1,2,0}``) and reads every layer's slice twice
+    (``constant_dynamic-slice_fusion.*``, then the product): a fifth of a
+    step at 32 query and 32 KV heads, a ninth at 32 over 8 (PERF.md §6,
+    PR 38)."""
     B = tokens.shape[0]
     page = k_pool.shape[3]
     trash_page = k_pool.shape[1] - 1  # reserved by PagedKVCache
@@ -512,12 +546,10 @@ def decode_step_paged(
         h, kp, vp = carry  # kp/vp: the whole pools
         lp, layer = xs
         hn = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
-        q = _mm(hn, lp["wq"]).reshape(B, 1, H, Dh)
-        k = _mm(hn, lp["wk"]).reshape(B, 1, Hkv, Dh)
-        v = _mm(hn, lp["wv"]).reshape(B, 1, Hkv, Dh)
-        q = apply_rope(q, positions, sin, cos)[:, 0]  # [B, H, Dh]
-        k = apply_rope(k, positions, sin, cos)[:, 0]  # [B, Hkv, Dh]
-        v = v[:, 0]
+        q, k, v = _qkv_products(hn, lp)  # [B, 1, heads * Dh], not yet by heads
+        q = apply_rope(q.reshape(B, 1, H, Dh), positions, sin, cos)[:, 0]  # [B, H, Dh]
+        k = apply_rope(k.reshape(B, 1, Hkv, Dh), positions, sin, cos)[:, 0]  # [B, Hkv, Dh]
+        v = v.reshape(B, Hkv, Dh)
 
         # Mosaic kernels on a TPU, scatter and gather references on the CPU
         kp, vp = paged_kv_append(kp, vp, k, v, layer, pages, offsets)

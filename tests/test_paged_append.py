@@ -525,9 +525,10 @@ def test_phi4flash_compiled_ragged_step_writes_a_chunk_into_its_pools_in_place(o
 
 
 # ------------------------------------- the expert product a program holds (PR 34)
-def _program_arguments(cfg, one_chip, B, M, page=16):
+def _program_arguments(cfg, one_chip, B, M, page=16, init_params=None):
     """Shapes, on the described chip, of what the two paged programs take
-    first: params, the two pools, the decode state, the block tables."""
+    first: params (the model's ``init_params``, or the one given), the two
+    pools, the decode state, the block tables."""
     model = batch_ops.model_of(cfg)
     N = B * M
 
@@ -537,7 +538,7 @@ def _program_arguments(cfg, one_chip, B, M, page=16):
     i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     on_chip = functools.partial(jax.tree.map, lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip))
-    params = on_chip(jax.eval_shape(lambda k: model.init_params(cfg, k), key))
+    params = on_chip(jax.eval_shape(lambda k: (init_params or model.init_params)(cfg, k), key))
     pools = [jax.ShapeDtypeStruct((cfg.n_layers, N + 1) + shape, cfg.dtype, sharding=one_chip)
              for shape in model.page_shapes(cfg, page)]
     state = batch_ops.DecodeState(vec(i32), vec(i32), vec(flag), vec(i32), vec(i32), vec(f32),
@@ -629,3 +630,163 @@ def test_the_compiled_ragged_step_multiplies_a_held_expert_by_tiles_of_its_own_r
     calls = {name for name, lines in comps.items() if any(f"calls=%{r}" in line or f"calls={r}" in line
                                                            for line in lines for r in routed)}
     assert calls and entry not in calls  # inside the loop over the tiles that hold a row, not at the top
+
+
+# ------------------------ the q/k/v products stay two-dimensional (PR 38)
+def _folded_products(h, lp):
+    """The parent's expression: the three products with nothing between
+    them and the caller's reshape to heads, which XLA folds into them."""
+    return llama._mm(h, lp["wq"]), llama._mm(h, lp["wk"]), llama._mm(h, lp["wv"])
+
+
+def _logits_of_three_programs(cfg, params):
+    """``forward`` over two prompts, ``prefill`` of both (6 and 8 tokens)
+    into a dense cache, and one ``decode_step_paged`` over pools that hold
+    what the prefill wrote: the three programs' logits."""
+    B, S = 2, 8
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 3, cfg.vocab_size)
+    lens = np.asarray([6, 8], np.int32)
+    whole = llama.forward(cfg, params, tokens)
+    last, cache = llama.prefill(cfg, params, tokens, llama.KVCache.create(cfg, B, max_len=S), jnp.asarray(lens))
+    k_page, v_page = llama.page_shapes(cfg, PAGE)
+    n_pages = B * SLOT_PAGES
+    k_pool = jax.random.normal(jax.random.PRNGKey(2), (cfg.n_layers, n_pages + 1) + k_page, cfg.dtype)
+    v_pool = jax.random.normal(jax.random.PRNGKey(3), (cfg.n_layers, n_pages + 1) + v_page, cfg.dtype)
+    tables = np.random.default_rng(1).permutation(n_pages).reshape(B, SLOT_PAGES).astype(np.int32)
+    for b in range(B):
+        for t in range(int(lens[b])):
+            k_pool = k_pool.at[:, tables[b, t // PAGE], :, t % PAGE].set(cache.k[:, b, t])
+            v_pool = v_pool.at[:, tables[b, t // PAGE], :, t % PAGE].set(cache.v[:, b, t])
+    step, _, _ = llama.decode_step_paged(
+        cfg, params, jnp.argmax(last, axis=-1).astype(jnp.int32), k_pool, v_pool, jnp.asarray(tables),
+        jnp.asarray(lens + 1), jnp.ones(B, bool))
+    return {"forward": whole, "prefill": last, "decode_step_paged": step}
+
+
+@pytest.fixture(scope="module")
+def qkv_runs():
+    return {}
+
+
+@pytest.mark.parametrize("program", ["forward", "prefill", "decode_step_paged"])
+@pytest.mark.parametrize("weights", ["bf16", "int8"])
+def test_the_barrier_after_the_q_k_v_products_changes_no_value(weights, program, qkv_runs, monkeypatch):
+    """``_qkv_products`` against the expression it replaced
+    (``_mm(...).reshape`` with no barrier between), for weights in bf16 and
+    in int8: the same logits from all three programs that reach it."""
+    cfg = llama.LlamaConfig.tiny(vocab_size=300, dtype=jnp.bfloat16)
+    if weights not in qkv_runs:
+        params = llama.init_params(cfg, jax.random.PRNGKey(7), quantize=weights == "int8")
+        ours = _logits_of_three_programs(cfg, params)
+        # a config of its own (a static argument of every jitted program), so no trace of ours is reused
+        monkeypatch.setattr(llama, "_qkv_products", _folded_products)
+        theirs = _logits_of_three_programs(dataclasses.replace(cfg, max_seq_len=cfg.max_seq_len + 1), params)
+        qkv_runs[weights] = ours, theirs
+    ours, theirs = (np.asarray(run[program], np.float32) for run in qkv_runs[weights])
+    assert np.isfinite(ours).all() and ours.std() > 0
+    np.testing.assert_allclose(ours, theirs, rtol=2 ** -7, atol=2 ** -7 * float(np.abs(theirs).max()))
+
+
+def test_a_gradient_passes_the_barrier_after_the_q_k_v_products(monkeypatch):
+    """``jax.grad`` of a loss through ``forward``: every leaf finite, the
+    three projections' among them and not zero, and the gradient the
+    parent's expression gives."""
+    cfg = llama.LlamaConfig.tiny(vocab_size=300, dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.PRNGKey(7))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 3, cfg.vocab_size)
+
+    def grads(cfg):
+        def loss(p):
+            logp = jax.nn.log_softmax(llama.forward(cfg, p, tokens[:, :-1]))
+            return -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1).mean()
+        return jax.grad(loss)(params)
+
+    ours = grads(cfg)
+    monkeypatch.setattr(llama, "_qkv_products", _folded_products)
+    theirs = grads(dataclasses.replace(cfg, max_seq_len=cfg.max_seq_len + 1))
+    for name in ("wq", "wk", "wv"):
+        assert float(jnp.abs(ours["layers"][name]).max()) > 0
+    for got, want in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6)
+
+
+# tile-legal and narrow, int8 as the cells serve it: heads of 128, 8 query
+# heads over 2 KV heads (mistral7b.chat's grouping) and over 8
+# (deepseek7b.gen's), eight layers so that the smallest stack (K of the
+# grouped layout, 2 MiB) is more than the sampler and the logits take.
+# The cells' own shapes beside them, 20 s a compile: (layers, hidden,
+# query heads, KV heads, ff, vocabulary, rows, pages a row)
+QKV_SHAPES = {
+    "grouped-8-over-2": (8, 1024, 8, 2, 2048, 512, 32, 16),
+    "one-kv-head-a-query-head": (8, 1024, 8, 8, 2048, 512, 32, 16),
+    "mistral7b.chat": (32, 4096, 32, 8, 14336, 32768, 32, 48),
+    "deepseek7b.gen": (30, 4096, 32, 32, 11008, 102400, 6, 64),
+}
+QKV_CASES = [pytest.param(name, marks=pytest.mark.slow) if "." in name else name for name in QKV_SHAPES]
+# what yields an int8 array and moves no byte; a dynamic-slice is judged by the fusion that holds it
+_NO_BYTES = _PASSES_ON - {"custom-call"} | {"dynamic-slice"}
+
+
+def _materialized_weights(text: str, sizes: set[int]) -> list[str]:
+    """The instructions of a compiled program that write an int8 array of
+    one of ``sizes`` elements: everything but a fusion that only slices its
+    stack by the layer's index and bitcasts the slice, INSIDE the fusion
+    whose ``convolution`` takes it — the form in which a weight goes from
+    HBM into its product once."""
+    comps, _ = hlo_text.computations(text)
+    slices_only = {name for name, lines in comps.items()
+                   if {m.group(3) for m in map(_INSTRUCTION.match, lines) if m}
+                   <= {"parameter", "constant", "dynamic-slice", "bitcast"}}
+    made = []
+    for lines in comps.values():
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            shape = re.match(r"s8\[([\d,]+)\]", m.group(2)) if m else None
+            if not shape or m.group(3) in _NO_BYTES or int(np.prod([int(d) for d in shape.group(1).split(",")])) not in sizes:
+                continue
+            name, _, op = m.groups()
+            callee = re.search(r"calls=%?([\w.\-]+)", line)
+            feeds_a_product = any("convolution(" in other and f"%{name}" in other.split("convolution(")[1]
+                                  for other in lines)
+            if not (op == "fusion" and callee and callee.group(1) in slices_only and feeds_a_product):
+                made.append(f"{name} = {shape.group(0)} {op}")
+    return made
+
+
+@pytest.mark.parametrize("program", ["decode_block_paged", "prefill_compute"])
+@pytest.mark.parametrize("shapes", QKV_CASES)
+def test_the_compiled_llama_step_streams_q_k_v_weights_into_their_products_once(shapes, program, one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` and ``prefill_compute`` of a Llama model with
+    int8 weights by the chip's compiler: no ``copy`` and no fusion of its
+    own yields an int8 array the size of a ``wq``/``wk``/``wv`` stack or of
+    one layer's slice of one. With the reshape to heads folded into the
+    product (the parent) XLA wants each stack with the contraction axis
+    minor: it copies the whole stack to ``{1,2,0}`` once a program
+    (``copy.36``-``.38`` here, ``copy.30`` / ``copy.44`` in the cells'
+    traces), and in every layer a ``constant_dynamic-slice_fusion`` first
+    writes the slice out and the product then reads it — two passes where
+    ``wo`` takes one (PERF.md §6, PR 38). ``wo`` has ``wq``'s size, so its
+    path is held with them."""
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    L, D, H, Hkv, F, V, B, M = QKV_SHAPES[shapes]
+    cfg = llama.LlamaConfig(vocab_size=V, d_model=D, n_layers=L, n_heads=H, n_kv_heads=Hkv, d_ff=F,
+                            max_seq_len=16 * M, dtype=jnp.bfloat16)
+    vec, _, first = _program_arguments(cfg, one_chip, B, M, init_params=functools.partial(llama.init_params, quantize=True))
+    assert first[0]["layers"]["wq"]["q"].dtype == jnp.int8
+    with jax.default_matmul_precision("default"):
+        if program == "decode_block_paged":
+            compiled = batch_ops.decode_block_paged.lower(cfg, *first, vec(jnp.bool_), STEPS).compile()
+        else:
+            compiled = batch_ops.prefill_compute.lower(cfg, first[0], vec(jnp.int32, 1, 256), vec(jnp.int32, 1)).compile()
+
+    text = compiled.as_text()
+    slices = {D * H * cfg.head_dim, D * Hkv * cfg.head_dim}
+    made = _materialized_weights(text, slices | {L * n for n in slices})
+    assert not made, f"XLA ops that write a q/k/v weight out again: {made}"
+    # and the products are there, each taking its weight as [D, N]: q, k, v (and wo, of wq's shape)
+    straight = [line for line in text.splitlines() if "convolution(" in line and "dim_labels=bf_io->bf" in line]
+    assert len(straight) >= 7  # the seven matrices of a layer
+    assert compiled.memory_analysis().temp_size_in_bytes < L * min(slices)
