@@ -12,6 +12,9 @@ The serving framework's model zoo (BASELINE.json configs):
   layer whose K and V the whole upper half reads, gated memory units,
   differential attention (served on the paged path: a recurrent state a slot
   beside two pools)
+- lfm2_moe: gated short convolutions and per-head QK-normed attention in an
+  irregular order, two dense layers then sigmoid experts with an expert bias
+  (served on the paged path: conv tails a slot beside one pool)
 - bert: encoder embedder (/embed endpoint)
 - whisper: encoder-decoder ASR (async Pub/Sub path)
 
@@ -21,6 +24,6 @@ scanned (lax.scan) so compile time is flat in depth; weights are bf16 by
 default with f32 accumulation inside ops.
 """
 
-from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, llama, phi4flash
+from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, lfm2_moe, llama, phi4flash
 
-__all__ = ["llama", "cohere2_moe", "deepseek_v32", "phi4flash", "bert"]
+__all__ = ["llama", "cohere2_moe", "deepseek_v32", "phi4flash", "lfm2_moe", "bert"]

@@ -37,16 +37,19 @@ def causal_conv(
     u: jnp.ndarray,     # [B, T, Din] the positions to convolve
     tail: jnp.ndarray,  # [B, K-1, Din] the K-1 inputs before them (zeros at a sequence's start)
     w: jnp.ndarray,     # [K, Din] float32, w[K-1] weighs the position itself
-    b: jnp.ndarray,     # [Din]
+    b: jnp.ndarray | None = None,  # [Din]; None: no bias
+    silu: bool = True,  # False: the conv as it is (LFM2's gated short convolution)
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(SiLU(conv) [B, T, Din] float32, the inputs seen [B, K-1+T, Din]:
-    ``conv_tail`` takes the next call's ``tail`` out of them)."""
+    """(SiLU(conv) [B, T, Din] float32 — the conv itself with ``silu``
+    False — and the inputs seen [B, K-1+T, Din]: ``conv_tail`` takes the
+    next call's ``tail`` out of them)."""
     K, T = w.shape[0], u.shape[1]
     seen = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
-    acc = b.astype(jnp.float32)
+    acc = None if b is None else b.astype(jnp.float32)
     for k in range(K):
-        acc = acc + w[k].astype(jnp.float32) * seen[:, k:k + T].astype(jnp.float32)
-    return jax.nn.silu(acc), seen
+        term = w[k].astype(jnp.float32) * seen[:, k:k + T].astype(jnp.float32)
+        acc = term if acc is None else acc + term
+    return (jax.nn.silu(acc) if silu else acc), seen
 
 
 def conv_tail(seen: jnp.ndarray, n: jnp.ndarray, width: int) -> jnp.ndarray:

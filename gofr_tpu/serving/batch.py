@@ -55,7 +55,8 @@ def model_of(cfg: Any) -> Any:
     (``LlamaConfig`` → ``models/llama.py``, ``Cohere2MoeConfig`` →
     ``models/cohere2_moe.py``, ``DeepseekV32Config`` →
     ``models/deepseek_v32.py``, ``Phi4FlashConfig`` →
-    ``models/phi4flash.py``). Every program here reaches its model
+    ``models/phi4flash.py``, ``Lfm2MoeConfig`` → ``models/lfm2_moe.py``).
+    Every program here reaches its model
     through this one lookup, at trace time. What a served module holds:
     ``KVCache`` (the dense cache, also a bucketed prefill's scratch),
     ``prefill``, ``decode_step_paged`` and ``decode_chunk_paged`` with
@@ -67,8 +68,13 @@ def model_of(cfg: Any) -> Any:
     and a per-slot state, in which case the programs' ``k_pool``,
     ``v_pool`` and ``block_tables`` are the pager's dicts, its prefill
     returns them through ``prefill_slabs`` and, if it sets
-    ``CHUNK_TAKES_FINISH``, its chunk program is told which rows finish —
-    and ``unserved(engine_config, lora, cfg)``, the sentence that refuses
+    ``CHUNK_TAKES_FINISH``, its chunk program is told which rows finish
+    (``lfm2_moe``'s is not told: it returns logits [B, 1, V] at every
+    row's last chunk position, which this module's fold reads as it reads
+    any) — ``STEP_STATS``, the names of counters its step returns (after
+    the experts' rows and reads for a config with ``held_experts``: the
+    engine sets them on the commit span), and ``unserved(engine_config,
+    lora, cfg)``, the sentence that refuses
     an engine the model has no program for. The dense and speculative
     programs call the functions ``llama`` has for them by the same names."""
     return sys.modules[type(cfg).__module__]

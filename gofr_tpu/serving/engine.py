@@ -3773,11 +3773,16 @@ class ServingEngine:
         indexer scored and the positions its attention read
         (``dsa_scored``, ``dsa_selected``; app_dsa_positions_total). A
         model that names its counters (``STEP_STATS``) has them set under
-        those names."""
+        those names: all of them, or — for a sparse-expert model
+        (``models/lfm2_moe.py``) — those after the experts'."""
+        held = getattr(self.model_cfg, "held_experts", 0)
+        named = {}
         if self._stats_names is not None:
-            span.set(**dict(zip(self._stats_names, stats.tolist())))
-            return
-        held = self.model_cfg.held_experts
+            # after the experts' rows and reads, where the model has experts
+            named = dict(zip(self._stats_names, (stats[held + 1:] if held else stats).tolist()))
+            if not held:
+                span.set(**named)
+                return
         rows, reached, dsa = stats[:held], int(stats[held]), {}
         if getattr(self.model_cfg, "index_topk", None):
             scored, selected = stats[held + 1:].tolist()
@@ -3786,7 +3791,7 @@ class ServingEngine:
                 for kind, n in (("scored", scored), ("selected", selected)):
                     if n:
                         self._metrics.add_counter("app_dsa_positions_total", n, kind=kind)
-        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), moe_reached=reached, **dsa)
+        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), moe_reached=reached, **dsa, **named)
         if self._metrics:
             if reached:
                 self._metrics.add_counter("app_moe_experts_read_total", reached)

@@ -26,6 +26,7 @@ import pytest
 
 from gofr_tpu.models import cohere2_moe as cm
 from gofr_tpu.models import deepseek_v32 as ds
+from gofr_tpu.models import lfm2_moe as lm
 from gofr_tpu.models import llama
 from gofr_tpu.models import phi4flash as phi
 from gofr_tpu.ops import paged_attention as pa
@@ -790,3 +791,115 @@ def test_the_compiled_llama_step_streams_q_k_v_weights_into_their_products_once(
     straight = [line for line in text.splitlines() if "convolution(" in line and "dim_labels=bf_io->bf" in line]
     assert len(straight) >= 7  # the seven matrices of a layer
     assert compiled.memory_analysis().temp_size_in_bytes < L * min(slices)
+
+
+# --------------------- conv tails a slot beside one pool (lfm2_moe, PR 39)
+# tile-legal and narrow: two KV heads of 64 = one cached head of 128, the
+# published structure (two dense conv layers, a period of four, a last of
+# three), 8 experts of 128; slots of 4,096 positions, so that the pool is
+# too large to be prefetched whole into fast memory
+LFM2_CHIP = lm.Lfm2MoeConfig(
+    vocab_size=512, d_model=256, n_layers=9, n_heads=4, n_kv_heads=2, head_dim=64, d_ff=512, d_ff_expert=128,
+    n_experts=8, top_k=4, layer_types=lm.Lfm2MoeConfig.tiny().layer_types, max_seq_len=4096, dtype=jnp.bfloat16)
+
+
+def _lfm2_arguments(one_chip, B, M, page=16):
+    """Shapes, on the described chip, of what the two paged programs take
+    first for ``LFM2_CHIP``: params (int8), the pool with the conv tails,
+    the decode state, the table — as ``PagedKVCache`` builds them from
+    ``cache_spec``."""
+    cfg = LFM2_CHIP
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    i32, f32 = jnp.int32, jnp.float32
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    params = on_chip(jax.eval_shape(lambda k: lm.quantize_params(lm.init_params(cfg, k)), key))
+    ((name, layers, k_page, v_page, _),), state = lm.cache_spec(cfg, page)
+    k_pool = {name: vec(cfg.dtype, layers, B * M + 1, *k_page),
+              "state": {key_: vec(dtype, n, B, *shape) for key_, (n, shape, dtype) in state.items()}}
+    v_pool = {name: vec(cfg.dtype, layers, B * M + 1, *v_page)}
+    dec = batch_ops.DecodeState(vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
+                                vec(i32), vec(f32), on_chip(key), vec(i32))
+    return cfg, params, k_pool, v_pool, dec, {name: vec(i32, B, M)}, vec, on_chip(key)
+
+
+def test_lfm2_compiled_decode_block_leaves_its_pool_to_the_kernels(one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` of ``lfm2_moe`` by the chip's compiler: the
+    pool is written by the append's custom call alone (one a block of
+    layers: the attention layer of each block), no XLA op makes, slices or
+    updates it; the conv tails are XLA's, updated in place (their only
+    writers are dynamic-update-slices) and never rounded. (At these widths
+    the compiler prefetches whole weight stacks into fast memory; at the
+    cell's, ``decode_block_paged`` and ``ragged_step_paged`` write no
+    weight out before its product — ``_materialized_weights`` finds none:
+    my compile, PR 39.)"""
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M = 32, 256
+    cfg, params, k_pool, v_pool, dec, tables, vec, _ = _lfm2_arguments(one_chip, B, M)
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.decode_block_paged.lower(
+            cfg, params, k_pool, v_pool, dec, tables, vec(jnp.bool_), STEPS).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    dims = ",".join(str(d) for d in k_pool["full"].shape)
+    pool_like = {f"bf16[{dims}]", f"bf16[{dims.split(',', 1)[1]}]", f"bf16[1,{dims.split(',', 1)[1]}]"}
+    made = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON and any(shape in m.group(2) for shape in pool_like):
+            made.append(f"{m.group(1)} = {m.group(3)}")
+    assert not made, f"XLA ops that make, slice or update the pool: {made}"
+    appends = [line for line in text.splitlines() if "custom-call(" in line and "paged_kv_append" in line]
+    assert len(appends) == 1  # the attention layer of the block loop
+    tails = "f32[{}]".format(",".join(str(d) for d in k_pool["state"]["conv"].shape))
+    comps, _ = hlo_text.computations(text)
+    roots = {name: next((l for l in lines if l.lstrip().startswith("ROOT")), "") for name, lines in comps.items()}
+    writers = collections.Counter()
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON and m.group(2).startswith(tails):
+            callee = re.search(r"calls=%?([\w.\-]+)", line)
+            fused_update = m.group(3) == "fusion" and callee and " dynamic-update-slice(" in roots.get(callee.group(1), "")
+            writers["dynamic-update-slice" if fused_update else m.group(3)] += 1
+    # a conv layer's tail written in place; the layout of the whole array changed on entry and back on exit
+    # (19 MB twice a block at the cell's size, about 0.1 ms of a 4-step block)
+    assert writers["dynamic-update-slice"] >= 2 and set(writers) <= {"dynamic-update-slice", "copy"}, writers
+    assert writers["copy"] <= 2, writers
+    assert "bf16[{}]".format(tails[4:-1]) not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * int(np.prod(k_pool["full"].shape))
+
+
+def test_lfm2_compiled_ragged_step_writes_a_chunk_into_its_pool_in_place(one_chip, no_compile_cache, monkeypatch):
+    """``ragged_step_paged`` of ``lfm2_moe`` by the chip's compiler: a
+    chunk's K and V go into the pool through ``phi4flash._write_rows``'s
+    row scatter — the only ops whose result has the pool's size are those
+    scatters (K and V, in the attention layer of the chunk's block loop)
+    and the fusions that wrap them."""
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M, C = 32, 256, 32
+    cfg, params, k_pool, v_pool, dec, tables, vec, key = _lfm2_arguments(one_chip, B, M)
+    i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.ragged_step_paged.lower(
+            cfg, params, k_pool, v_pool, dec, tables, vec(i32, B, C), vec(i32), vec(flag), vec(i32),
+            vec(flag), vec(i32), vec(i32), vec(i32), vec(f32), vec(i32), vec(f32), vec(i32), key,
+            vec(flag), STEPS).compile()
+    text = compiled.as_text()
+    size = int(np.prod(k_pool["full"].shape))
+    made = collections.Counter()
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        shape = re.match(r"bf16\[([\d,]+)\]", m.group(2)) if m else None
+        if shape and m.group(3) not in _PASSES_ON and int(np.prod([int(d) for d in shape.group(1).split(",")])) == size:
+            made[m.group(3) if "/scatter" in line else f"{m.group(1)} = {m.group(3)}"] += 1
+    assert set(made) <= {"scatter", "fusion"} and made["scatter"] == 2, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * size
