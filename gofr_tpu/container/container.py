@@ -235,6 +235,12 @@ class Container:
             "for an admission's first token (label path=greedy|sample|filter)",
         )
         m.new_counter(
+            "app_moe_path_blocks_total",
+            "Blocks dispatched, by the branch of ops.moe.held_experts their "
+            "decode rows take (label path=kernel|loop|grouped; sparse-expert "
+            "models only)",
+        )
+        m.new_counter(
             "app_moe_expert_rows_total",
             "Rows routed to each routed expert this replica holds, over "
             "decode steps and layers, read with each block's tokens (label "

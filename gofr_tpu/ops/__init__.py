@@ -4,10 +4,11 @@ All ops are pure jax (traced once under jit, static shapes, fused by XLA);
 the hot attention paths have Pallas TPU kernels in ops/flash_attention.py
 (prefill: a grid over query and key blocks) and ops/paged_attention.py
 (decode from the paged pool: one program a row, which loops over the pages
-the row owns and fetches them itself). ops/backend.py decides, at trace
-time and in one place, what each runs: the Mosaic-compiled kernel on a TPU, the XLA
-reference on the CPU (the Pallas interpreter when a test asks), an error
-anywhere else.
+the row owns and fetches them itself), and ops/expert_rows.py computes the
+every-row expert sum of ops/moe.py as one call a layer. ops/backend.py
+decides, at trace time and in one place, what each runs: the
+Mosaic-compiled kernel on a TPU, the XLA reference on the CPU (the Pallas
+interpreter when a test asks), an error anywhere else.
 """
 
 from gofr_tpu.ops.norms import layer_norm, rms_norm

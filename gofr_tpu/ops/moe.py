@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from gofr_tpu.ops import expert_rows
 from gofr_tpu.parallel.mesh import require_axis
 
 
@@ -287,6 +288,18 @@ def groups_rows(T: int, n_experts: int, top_k: int | None) -> bool:
     return T > RIDGE_ROWS or T * (n_experts if top_k is None else top_k) < 2 * n_experts
 
 
+def path(T: int, n_experts: int, top_k: int | None, experts: dict) -> str:
+    """The branch :func:`held_experts` takes for ``T`` rows over the
+    stacks ``experts``, by the tests it makes itself: ``grouped`` where
+    :func:`groups_rows`, else the every-row sum as one Mosaic call a layer
+    (``kernel``: ``expert_rows.serves`` the stacks) or as the loop of XLA
+    products (``loop``: on the CPU, or stacks that are not int8 of whole
+    lane tiles). The engine's host mirror."""
+    if groups_rows(T, n_experts, top_k):
+        return "grouped"
+    return "kernel" if expert_rows.serves(experts) else "loop"
+
+
 def held_experts(
     h: jnp.ndarray,  # [T, D]
     gates: jnp.ndarray,  # [T, E] over every published expert
@@ -312,10 +325,12 @@ def held_experts(
     (:func:`groups_rows`, which has the rule and its two reasons): every
     held expert over every row with its gate as the weight, zero where the
     row chose another expert (:func:`_over_every_row`: all ``held`` are
-    read, whatever the routing); or each held expert over the rows that
-    chose it, in tiles (:func:`_over_own_rows`), where an expert that no
-    counted row chose reads nothing and a row that ``rows`` leaves out
-    pulls no expert (its output is nobody's to read).
+    read, whatever the routing; one Mosaic call a tree of int8 stacks on
+    the chip, ``ops/expert_rows.py``); or each held expert over the rows
+    that chose it, in tiles (:func:`_over_own_rows`), where an expert that
+    no counted row chose reads nothing and a row that ``rows`` leaves out
+    pulls no expert (its output is nobody's to read). :func:`path` names
+    the branch.
 
     Inside a scan over layers, hand the stacks over whole with ``layer``:
     a matrix is then ONE dynamic slice of its stack with one product to
@@ -348,7 +363,27 @@ def _add_shared(y: jnp.ndarray, h: jnp.ndarray, shared: dict, mm: Any, layer: An
 def _over_every_row(h: jnp.ndarray, g: jnp.ndarray, experts: dict, shared: dict, mm: Any,
                     layer: Any) -> jnp.ndarray:
     """Every held expert over every row, the gate ``g`` [T, held] as the
-    weight; then the shared experts' mean. The reference of the other way."""
+    weight; then the shared experts' mean, the same sum with 1/n_shared
+    over every row. Where ``expert_rows.serves`` the stacks, each tree
+    (routed, shared) is one Mosaic call — the stacks whole, the layer a
+    scalar the call reads; both trees are stored alike (the families'
+    ``quantize_params``) — else :func:`_loop_over_every_row`, the
+    reference."""
+    if not expert_rows.serves(experts):
+        return _loop_over_every_row(h, g, experts, shared, mm, layer)
+    y = expert_rows.expert_rows(h, g, experts, layer)
+    n_shared = _experts_in(shared, layer)
+    if not n_shared:
+        return y
+    mean = jnp.full((h.shape[0], n_shared), 1.0 / n_shared, jnp.float32)
+    return y + expert_rows.expert_rows(h, mean, shared, layer)
+
+
+def _loop_over_every_row(h: jnp.ndarray, g: jnp.ndarray, experts: dict, shared: dict, mm: Any,
+                         layer: Any) -> jnp.ndarray:
+    """:func:`_over_every_row` as a loop of XLA products, an expert at a
+    time: the CPU's path, the reference of the kernel and of the grouped
+    product."""
     y = jnp.zeros(h.shape, jnp.float32)
     for e in range(g.shape[1]):
         y = y + g[:, e:e + 1] * _ffn(h, experts, e, mm, layer)

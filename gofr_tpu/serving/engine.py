@@ -40,6 +40,7 @@ from gofr_tpu.http.errors import (
 )
 from gofr_tpu.models import llama
 from gofr_tpu.native.runtime import QueueFull, Scheduler
+from gofr_tpu.ops import moe as moe_ops
 from gofr_tpu.ops.backend import configure_compile_cache, require_requested_backend
 from gofr_tpu.ops.sampling import SAMPLER_PATHS, sampler_path
 from gofr_tpu.serving import batch as batch_ops
@@ -410,6 +411,18 @@ def _block_sync(value: Any) -> np.ndarray:
     return np.asarray(value)  # gofrlint: disable=host-sync -- the one sanctioned block-sync point
 
 
+def _moe_path(cfg: Any, params: dict, rows: int) -> str | None:
+    """The branch ``ops/moe.held_experts`` takes at a block's ``rows``
+    decode rows (``moe.path``: the program's own test of the rows and of
+    the stacks' storage), or None for a model without sparse experts. The
+    routed stacks are the ``experts`` of the params' group that holds them
+    (``moe`` or ``layers``)."""
+    if not getattr(cfg, "held_experts", 0):
+        return None
+    experts = next(group["experts"] for group in params.values() if isinstance(group, dict) and "experts" in group)
+    return moe_ops.path(rows, cfg.n_experts, cfg.top_k, experts)
+
+
 # the step loop's phases (docs/observability.md "Engine step spans"):
 # "step" is one loop iteration, the others nest inside it, and a dotted
 # name is a part of the phase before the dot, nested inside it. Each is a
@@ -581,6 +594,9 @@ class ServingEngine:
         # set on the commit span under those names); 0 for most
         self._stats_len = model.step_stats_len(cfg)
         self._stats_names = getattr(model, "STEP_STATS", None)
+        # a sparse-expert model's branch of held_experts at a block's
+        # decode rows, set as moe_path on every block's dispatch span
+        self._moe_path = _moe_path(cfg, self.params, self.config.max_slots)
         # a model whose layers above its one shared cache run on a prompt's
         # last position alone: its prefill spans say how many positions
         # each half ran, and an admission resets a recurrent state
@@ -3651,6 +3667,10 @@ class ServingEngine:
                 span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))
             self._count_sampler(span, self.temperature[mask], self.top_k[mask],
                                 self.top_p[mask], steps=N)
+            if self._moe_path is not None:
+                span.set(moe_path=self._moe_path)
+                if self._metrics:
+                    self._metrics.add_counter("app_moe_path_blocks_total", 1, path=self._moe_path)
             self._count_step_tokens(
                 len(rows) * N, chunk_tokens,
                 self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),

@@ -327,8 +327,11 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters
     a chunked prompt, 40 tokens each: the tokens are the reference's greedy
     choice; the commit spans carry ``conv_rows`` (the conv layers times the
     live row-steps: no layer ran the other kind's mixer), ``attn_kv`` and
-    the experts' ``moe_rows``, ``moe_max`` and ``moe_reached``; /metrics
-    counts the experts' rows and reads."""
+    the experts' ``moe_rows``, ``moe_max`` and ``moe_reached``; every
+    block's dispatch span names the branch of ``held_experts`` its rows
+    take (``moe_path``: at three slots and 8 experts a row chooses 4 of,
+    the grouped product); /metrics counts the experts' rows and reads and
+    the blocks by that branch."""
     import gofr_tpu
     from gofr_tpu.config import MapConfig
     from gofr_tpu.serving import engine as engine_mod
@@ -394,6 +397,12 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters
     rows = sum(int(float(l.rsplit(" ", 1)[1])) for l in metrics.splitlines() if l.startswith("app_moe_expert_rows_total{"))
     assert rows == sum(kw["moe_rows"] for kw in commits) > 0
     assert any(l.startswith("app_moe_experts_read_total ") for l in metrics.splitlines())
+    paths = [kw["moe_path"] for phase, kw in seen if phase == "dispatch" and "moe_path" in kw]
+    blocks = [kw for phase, kw in seen if phase == "dispatch" and "blk" in kw]
+    assert paths == ["grouped"] * len(blocks) and moe.path(3, CFG.n_experts, CFG.top_k, plain["moe"]["experts"]) == "grouped"
+    counted = {l.split("{", 1)[1].split("}")[0]: int(float(l.rsplit(" ", 1)[1]))
+               for l in metrics.splitlines() if l.startswith("app_moe_path_blocks_total{")}
+    assert counted == {'path="grouped"': len(blocks)} and blocks
     pages = health["data"]["details"]["serving"]["details"]["kv_pages"]
     assert pages["pools"] == {"full": {"used": 0, "total": 3 * 96 // PAGE}}
 

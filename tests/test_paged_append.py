@@ -547,18 +547,21 @@ def _program_arguments(cfg, one_chip, B, M, page=16, init_params=None):
     return vec, on_chip(key), (params, *pools, state, vec(i32, B, M))
 
 
-def test_wide_s_decode_block_lowers_to_the_loop_over_every_row_and_nothing_else(one_chip, monkeypatch):
+def test_wide_s_decode_block_lowers_to_the_sum_over_every_row_and_nothing_else(one_chip, monkeypatch):
     """``cohere2_moe``'s ``decode_block_paged`` at the shapes that decide
     (``commandaplus.wide``: 64 rows, 8 of 128 experts a row, 16 held),
     lowered for a described v5e, is line for line the text of the program
-    whose expert layer is the parent's function — every held expert over
-    every row, no mask — with the constant count of its held experts
-    beside it: at four rows an expert under the ridge ``held_experts``
-    adds nothing else to it (PERF.md §6, PR 34)."""
-    from gofr_tpu.ops import moe
+    whose expert layer is the every-row sum — every held expert over every
+    row, no mask — with the constant count of its held experts beside it:
+    at four rows an expert under the ridge ``held_experts`` adds nothing
+    else to it (PERF.md §6, PR 34). With int8 stacks, as served, that sum
+    is two ``expert_rows`` calls a layer on the chip, the routed experts'
+    and the shared ones' (PR 40)."""
+    from gofr_tpu.ops import expert_rows, moe
     from gofr_tpu.ops.backend import COMPILED
 
     monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    monkeypatch.setattr(expert_rows, "kernel_mode", lambda interpret=None: COMPILED)
     cfg = cm.Cohere2MoeConfig.tiny(
         vocab_size=512, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=128, d_ff=256,
         n_experts=128, top_k=8, held_experts=16, n_shared=4, layer_types=(cm.SLIDING, cm.FULL),
@@ -566,7 +569,8 @@ def test_wide_s_decode_block_lowers_to_the_loop_over_every_row_and_nothing_else(
 
     def lowered(B):
         jax.clear_caches()  # the trace is cached by the arguments, and the function under it changes
-        vec, _, first = _program_arguments(cfg, one_chip, B, 64)
+        vec, _, first = _program_arguments(cfg, one_chip, B, 64,
+                                           init_params=lambda c, k: cm.quantize_params(cm.init_params(c, k)))
         with jax.default_matmul_precision("default"):
             text = batch_ops.decode_block_paged.lower(cfg, *first, vec(jnp.bool_), STEPS).as_text()
         # a Mosaic call's payload is its kernel's serialized body, which carries where it was traced from
@@ -581,6 +585,7 @@ def test_wide_s_decode_block_lowers_to_the_loop_over_every_row_and_nothing_else(
     theirs = lowered(64)
     jax.clear_caches()
     assert len(ours) > 500 and ours == theirs
+    assert sum('kernel_name = "expert_rows"' in line for line in ours) == 2  # one layer body: routed, shared
 
 
 def test_the_compiled_ragged_step_multiplies_a_held_expert_by_tiles_of_its_own_rows(one_chip, no_compile_cache, monkeypatch):
@@ -591,10 +596,11 @@ def test_the_compiled_ragged_step_multiplies_a_held_expert_by_tiles_of_its_own_r
     rows — every one takes a tile, inside the ``while`` over the tiles
     that hold a row — and the shared expert's product over the whole
     chunk is there beside it."""
-    from gofr_tpu.ops import moe
+    from gofr_tpu.ops import expert_rows, moe
     from gofr_tpu.ops.backend import COMPILED
 
     monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    monkeypatch.setattr(expert_rows, "kernel_mode", lambda interpret=None: COMPILED)
     D, F, B, C, held = 384, 640, 32, 256, 32  # widths no other matrix of the model has
     cfg = ds.DeepseekV32Config.tiny(
         vocab_size=512, d_model=D, n_layers=4, n_dense_layers=2, n_heads=4, q_lora_rank=64,
@@ -603,6 +609,7 @@ def test_the_compiled_ragged_step_multiplies_a_held_expert_by_tiles_of_its_own_r
         n_group=8, topk_group=4, top_k=8, max_seq_len=1024, dtype=jnp.bfloat16)
     vec, key, first = _program_arguments(cfg, one_chip, B, 64)
     i32, f32, flag = jnp.int32, jnp.float32, jnp.bool_
+    jax.clear_caches()  # traced afresh with the every-row kernel on
     with jax.default_matmul_precision("default"):
         text = batch_ops.ragged_step_paged.lower(
             cfg, *first, vec(i32, B, C), vec(i32), vec(flag), vec(i32), vec(flag), vec(i32), vec(i32), vec(i32),
@@ -631,6 +638,12 @@ def test_the_compiled_ragged_step_multiplies_a_held_expert_by_tiles_of_its_own_r
     calls = {name for name, lines in comps.items() if any(f"calls=%{r}" in line or f"calls={r}" in line
                                                            for line in lines for r in routed)}
     assert calls and entry not in calls  # inside the loop over the tiles that hold a row, not at the top
+    # the grouped branch everywhere: neither the chunk nor the decode steps call the every-row kernel
+    # (PR 40), in this program or in the decode block
+    with jax.default_matmul_precision("default"):
+        block = batch_ops.decode_block_paged.lower(cfg, *first, vec(flag), STEPS).as_text()
+    jax.clear_caches()
+    assert "expert_rows" not in text and "expert_rows" not in block and "tpu_custom_call" in block
 
 
 # ------------------------ the q/k/v products stay two-dimensional (PR 38)
@@ -874,6 +887,44 @@ def test_lfm2_compiled_decode_block_leaves_its_pool_to_the_kernels(one_chip, no_
     assert writers["copy"] <= 2, writers
     assert "bf16[{}]".format(tails[4:-1]) not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * int(np.prod(k_pool["full"].shape))
+
+
+def test_lfm2_compiled_decode_block_reads_the_experts_through_the_kernel_alone(one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` of ``lfm2_moe`` by the chip's compiler at
+    rows under the ridge with two or more an expert (32 rows, 4 of 8
+    experts a row): each expert layer is one ``expert_rows`` call over the
+    stacks whole (two sites: the first expert layer of a block and the
+    loop over the rest), and no other op carries a stack's shape or its
+    scales' — the calls are all ``moe.experts_roofline.tools`` times, and
+    no slice of a stack is left beside them (PR 40)."""
+    from gofr_tpu.ops import expert_rows
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    monkeypatch.setattr(expert_rows, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M = 32, 256
+    cfg, params, k_pool, v_pool, dec, tables, vec, _ = _lfm2_arguments(one_chip, B, M)
+    assert moe_path_of(cfg, B, params) == "kernel"
+    jax.clear_caches()  # the trace is cached by the arguments, and the branch under it changes
+    with jax.default_matmul_precision("default"):
+        text = batch_ops.decode_block_paged.lower(
+            cfg, params, k_pool, v_pool, dec, tables, vec(jnp.bool_), STEPS).compile().as_text()
+    jax.clear_caches()
+    Lm, E, D, F = cfg.n_layers - cfg.n_dense_layers, cfg.n_experts, cfg.d_model, cfg.d_ff_expert
+    marks = [f"s8[{a},{b},{c}]" for a in (f"{Lm},{E}", f"{Lm * E}") for b, c in ((D, F), (F, D))]
+    marks += [f"f32[{Lm * E},{F}]", f"f32[{Lm * E},{D}]"]
+    carriers = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON - {"custom-call"} and any(mark in line for mark in marks):
+            carriers.append((m.group(1).split(".")[0], m.group(3)))
+    assert carriers == [("expert_rows", "custom-call")] * 2, carriers
+
+
+def moe_path_of(cfg, rows, params):
+    from gofr_tpu.ops import moe
+
+    return moe.path(rows, cfg.n_experts, cfg.top_k, params["moe"]["experts"])
 
 
 def test_lfm2_compiled_ragged_step_writes_a_chunk_into_its_pool_in_place(one_chip, no_compile_cache, monkeypatch):
