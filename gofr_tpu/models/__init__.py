@@ -7,7 +7,8 @@ The serving framework's model zoo (BASELINE.json configs):
   chip's share of an expert-parallel deployment)
 - deepseek_v32: latent attention under a learned sparse selection, leading
   dense layers, group-limited sparse experts beside a shared one (served on
-  the paged path, also as one chip's share)
+  the paged path, also as one chip's share); without the selection, the
+  DeepSeek-V3 layer JoyAI-LLM-Flash publishes (one latent pool)
 - phi4flash: state-space and window-attention layers under one full-attention
   layer whose K and V the whole upper half reads, gated memory units,
   differential attention (served on the paged path: a recurrent state a slot

@@ -1,8 +1,10 @@
 """DeepSeek-V3.2 decoder (``model_type`` ``deepseek_v32``): latent
 attention (MLA) under a learned sparse selection (the lightning indexer),
 group-limited sigmoid routing over sparse experts beside a shared one,
-leading dense layers, an untied head. Pre-norm RMSNorm; for a token ``t``
-with hidden ``x``:
+leading dense layers, an untied head — and, with no indexer
+(``index_topk`` 0) and plain rotary frequencies, the DeepSeek-V3 layer
+that ``joyai_llm_flash`` (JoyAI-LLM-Flash) publishes: latent attention
+over EVERY position. Pre-norm RMSNorm; for a token ``t`` with hidden ``x``:
 
     c_q  = RMSNorm(x W_qa);  q = c_q W_qb -> heads x (nope | rope), RoPE on rope
     [c_kv | k_r] = x W_kva;  c_kv = RMSNorm(c_kv);  k_r = RoPE(k_r)   (CACHED, one for all heads)
@@ -19,8 +21,9 @@ with hidden ``x``:
            (``ops/moe.sigmoid_topk_gates`` with bias, groups and scaling)
 
 ``scale = (nope + rope)^-1/2 · m^2`` with YaRN's ``m`` and YaRN's blended
-rotary frequencies (``ops/rope.yarn_frequencies``); MLA's RoPE is on
-interleaved pairs, the indexer's on the two halves.
+rotary frequencies (``ops/rope.yarn_frequencies``); with ``rope_factor`` 1
+(no ``rope_scaling``) the frequencies are plain and ``m`` is 1. MLA's RoPE
+is on interleaved pairs, the indexer's on the two halves.
 
 A chip may hold a SHARE (``held_experts`` of ``n_experts`` from
 ``first_expert`` on, ``vocab_size`` rows of embedding and head), as
@@ -37,7 +40,18 @@ selected rows with ``ops/mla.sparse_decode_attention``. A chunk of a
 prompt runs one row at a time under a ``cond``: rows without a chunk cost
 nothing, and a row's scores ([heads, chunk, context]) fit.
 
-Not served, each stated in the benchmark configuration's ``assumed``: the
+Without an indexer ``S_t`` is every position ``u <= t``, a token caches
+the latent row alone and the engine keeps ONE pool (:func:`page_shapes`
+answers None for the second; ``v_pool`` is None throughout). A decode
+step appends with ``paged_kv_append`` and reads every cached row of the
+row through ``ops/latent_attention.paged_latent_attention``, one Mosaic
+call a layer; it counts the positions read and the live rows (``mla_kv``,
+``mla_rows``: :func:`step_stats`). A chunk runs one row at a time
+as above, its attention over the pages up to the chunk's end only: the
+context is bucketed (the chunk's length doubling up to the slot, one
+branch of a ``switch`` each), and its head runs at the row's last token.
+
+Not served, each stated in the benchmark configurations' ``assumed``: the
 indexer's Hadamard rotation (orthogonal on both sides of a dot product)
 and its FP8 (bf16 here); the multi-token-prediction module.
 """
@@ -54,19 +68,18 @@ import jax.numpy as jnp
 
 from gofr_tpu.models.llama import _mm, _paged_chunk_targets, quantize_weight
 from gofr_tpu.ops import mla
+from gofr_tpu.ops.latent_attention import paged_latent_attention
 from gofr_tpu.ops.moe import held_experts, sigmoid_topk_gates
 from gofr_tpu.ops.norms import layer_norm, rms_norm
 from gofr_tpu.ops.paged_attention import paged_kv_append
 from gofr_tpu.ops.rope import (
-    angles, apply_rope_halves, apply_rope_interleaved, yarn_frequencies, yarn_mscale,
+    angles, apply_rope_halves, apply_rope_interleaved, rope_angles, yarn_frequencies, yarn_mscale,
 )
 
 __all__ = [
     "DeepseekV32Config", "KVCache", "init_params", "quantize_params", "prefill",
-    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "page_shapes", "unserved",
+    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "step_stats", "page_shapes", "unserved", "prefill_slabs",
 ]
-
-DSA_COUNTERS = 2  # positions the indexer scored, positions attention read
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,7 +94,7 @@ class DeepseekV32Config:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
-    index_n_heads: int = 64
+    index_n_heads: int = 64  # the indexer's: all three 0 for a model without one
     index_head_dim: int = 128
     index_topk: int = 2048
     d_ff: int = 18432  # a dense layer's width
@@ -96,7 +109,7 @@ class DeepseekV32Config:
     first_expert: int = 0  # ... from this one on
     max_seq_len: int = 163840
     rope_theta: float = 10000.0
-    rope_factor: float = 40.0
+    rope_factor: float = 40.0  # YaRN's; 1 for plain frequencies (no rope_scaling)
     rope_original_max: int = 4096
     beta_fast: float = 32.0
     beta_slow: float = 1.0
@@ -109,7 +122,7 @@ class DeepseekV32Config:
             raise ValueError("the leading dense layers are not among the layers")
         if not 0 <= self.first_expert <= self.n_experts - self.held_experts:
             raise ValueError("the held experts are not among the published ones")
-        if self.n_experts % self.n_group or self.index_head_dim < self.qk_rope_head_dim:
+        if self.n_experts % self.n_group or (self.index_topk and self.index_head_dim < self.qk_rope_head_dim):
             raise ValueError("the experts do not divide into n_group groups, or the indexer's "
                              "head is narrower than the rotary part")
 
@@ -146,18 +159,26 @@ class DeepseekV32Config:
         return cls(**defaults)
 
 
+def step_stats(cfg: DeepseekV32Config) -> tuple[str, ...]:
+    """Names of the two counters a paged step returns after the experts',
+    each summed over rows and layers: the positions the indexer scored and
+    the positions attention read; without an indexer the positions
+    attention read and the live rows it read them for."""
+    return ("dsa_scored", "dsa_selected") if cfg.index_topk else ("mla_kv", "mla_rows")
+
+
 def step_stats_len(cfg: DeepseekV32Config) -> int:
     """int32 counters a paged decode step returns after the pools: rows
     routed to each held expert, the held experts whose matrices were read
-    (``ops/moe.held_experts``), then positions the indexer scored and
-    positions attention read, each summed over rows and layers."""
-    return cfg.held_experts + 1 + DSA_COUNTERS
+    (``ops/moe.held_experts``), then :func:`step_stats`."""
+    return cfg.held_experts + 1 + len(step_stats(cfg))
 
 
-def page_shapes(cfg: DeepseekV32Config, page_size: int) -> tuple[tuple, tuple]:
+def page_shapes(cfg: DeepseekV32Config, page_size: int) -> tuple[tuple, tuple | None]:
     """What a page of each of the engine's two pools holds, [heads, page,
-    width]: latent rows in the first, the indexer's keys in the second."""
-    return (1, page_size, cfg.row_width), (1, page_size, cfg.index_head_dim)
+    width]: latent rows in the first, the indexer's keys in the second —
+    None, no second pool, without an indexer."""
+    return (1, page_size, cfg.row_width), ((1, page_size, cfg.index_head_dim) if cfg.index_topk else None)
 
 
 def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
@@ -171,6 +192,15 @@ def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
                 "module is not served): set TPU_SPEC_TOKENS=0")
     if lora is not None:
         return "deepseek_v32 serves no LoRA adapters: its head is a slice of the vocabulary"
+    if cfg is not None and not cfg.index_topk:
+        # one pool: a cached prefix is (logits, latent slab, None), which the
+        # host tier and the HTTP handoff carry as arrays alone
+        if engine_config.kv_spill_bytes > 0:
+            return ("deepseek_v32 without an indexer keeps one pool, and the host spill tier moves K and V "
+                    "slab pairs: set TPU_KV_SPILL_BYTES=0")
+        if engine_config.role != "unified":
+            return ("deepseek_v32 without an indexer keeps one pool, and a prefill replica hands a decode "
+                    "replica K and V slab pairs: serve it from unified replicas")
     return None
 
 
@@ -179,20 +209,27 @@ def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
 class KVCache:
     """The dense form ``prefill`` fills and a bucketed prefill scatters
     into pages: latent rows [L, B, S, 1, W] in ``k``, the indexer's keys
-    [L, B, S, 1, Di] in ``v``."""
+    [L, B, S, 1, Di] in ``v`` (None without an indexer)."""
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: jnp.ndarray | None
 
     @classmethod
     def create(cls, cfg: DeepseekV32Config, batch: int, max_len: int | None = None) -> "KVCache":
         S = max_len or cfg.max_seq_len
         return cls(jnp.zeros((cfg.n_layers, batch, S, 1, cfg.row_width), cfg.dtype),
-                   jnp.zeros((cfg.n_layers, batch, S, 1, cfg.index_head_dim), cfg.dtype))
+                   jnp.zeros((cfg.n_layers, batch, S, 1, cfg.index_head_dim), cfg.dtype) if cfg.index_topk else None)
 
     @property
     def max_len(self) -> int:
         return self.k.shape[2]
+
+
+def prefill_slabs(cache: KVCache) -> tuple[jnp.ndarray, jnp.ndarray | None]:
+    """Row 0 of a prefill's cache, as ``batch.prefill_compute`` returns it
+    and the pager's ``write_prefill`` takes it (a V slab of None without
+    an indexer)."""
+    return jax.tree.map(lambda a: a[:, 0], (cache.k, cache.v))
 
 
 _ATTN_MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo", "idx_wq", "idx_wk")
@@ -212,17 +249,21 @@ def init_params(cfg: DeepseekV32Config, key: jax.Array) -> dict:
         return jax.random.normal(next(ks), shape, dtype or cfg.dtype) / math.sqrt(fan_in)
 
     def attention(L: int) -> dict:
-        return {
+        out = {
             "attn_norm": jnp.ones((L, D), jnp.float32), "mlp_norm": jnp.ones((L, D), jnp.float32),
             "wq_a": w((L, D, Rq), D), "q_norm": jnp.ones((L, Rq), jnp.float32),
             "wq_b": w((L, Rq, H * (Dn + Dr)), Rq),
             "wkv_a": w((L, D, Rkv + Dr), D), "kv_norm": jnp.ones((L, Rkv), jnp.float32),
             "wkv_b": w((L, Rkv, H * (Dn + Dv)), Rkv), "wo": w((L, H * Dv, D), H * Dv),
-            "idx_wq": w((L, Rq, Hi * Di), Rq), "idx_wk": w((L, D, Di), D),
-            "idx_norm_w": jnp.ones((L, Di), jnp.float32),
-            "idx_norm_b": 0.1 * jax.random.normal(next(ks), (L, Di), jnp.float32),
-            "idx_w": w((L, D, Hi), D, jnp.float32),
         }
+        if cfg.index_topk:
+            out.update({
+                "idx_wq": w((L, Rq, Hi * Di), Rq), "idx_wk": w((L, D, Di), D),
+                "idx_norm_w": jnp.ones((L, Di), jnp.float32),
+                "idx_norm_b": 0.1 * jax.random.normal(next(ks), (L, Di), jnp.float32),
+                "idx_w": w((L, D, Hi), D, jnp.float32),
+            })
+        return out
 
     def ffn(lead: tuple, F: int) -> dict:
         return {"w_gate": w(lead + (D, F), D), "w_up": w(lead + (D, F), D), "w_down": w(lead + (F, D), F)}
@@ -252,7 +293,8 @@ def quantize_params(params: dict) -> dict:
     for group, ffn in (("dense", _FFN_MATRICES), ("moe", ())):
         lp = dict(params[group])
         for k in _ATTN_MATRICES + ffn:
-            lp[k] = quantize_weight(lp[k], axis=-2)
+            if k in lp:  # a model without an indexer has no idx_* matrices
+                lp[k] = quantize_weight(lp[k], axis=-2)
         out[group] = lp
     for stack in ("experts", "shared"):
         out["moe"][stack] = {k: quantize_weight(params["moe"][stack][k], axis=-2) for k in _FFN_MATRICES}
@@ -261,6 +303,8 @@ def quantize_params(params: dict) -> dict:
 
 # ------------------------------------------------------------- one layer
 def _angles(cfg: DeepseekV32Config, positions: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    if cfg.rope_factor <= 1.0:  # no rope_scaling: theta^(-2i/d)
+        return rope_angles(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
     return angles(positions, yarn_frequencies(
         cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max,
         cfg.beta_fast, cfg.beta_slow))
@@ -270,18 +314,29 @@ def _project(cfg: DeepseekV32Config, h: jnp.ndarray, lp: dict, sin: jnp.ndarray,
     """The normed input h [B, S, D] to what attention and the indexer take:
     q_nope [B,S,H,Dn], q_rope [B,S,H,Dr], the latent row to cache [B,S,W],
     the indexer's queries [B,S,Hi,Di], its key to cache [B,S,Di] and its
-    head weights [B,S,Hi] float32."""
+    head weights [B,S,Hi] float32 (the last three None without an
+    indexer)."""
     B, S, _ = h.shape
     H, Dn, Dr, Rkv = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     Hi, Di = cfg.index_n_heads, cfg.index_head_dim
     c_q = rms_norm(_mm(h, lp["wq_a"]), lp["q_norm"], cfg.norm_eps)
-    q = _mm(c_q, lp["wq_b"]).reshape(B, S, H, Dn + Dr)
+    q = _mm(c_q, lp["wq_b"])
+    if not cfg.index_topk:
+        # the product handed on whole, as llama._qkv_products hands q, k
+        # and v on: folded into it, the reshape to heads makes the dot's
+        # weight a view [heads, Dn+Dr, Rq] and the stack is copied to that
+        # layout once a dispatch. Keyed on the indexer only so that V3.2's
+        # lowered programs keep their digests (ROADMAP S16 applies it there)
+        q = jax.lax.optimization_barrier(q)
+    q = q.reshape(B, S, H, Dn + Dr)
     q_nope, q_rope = q[..., :Dn], apply_rope_interleaved(q[..., Dn:], sin, cos)
     kv = _mm(h, lp["wkv_a"])
     c_kv = rms_norm(kv[..., :Rkv], lp["kv_norm"], cfg.norm_eps)
     k_r = apply_rope_interleaved(kv[..., None, Rkv:], sin, cos)[..., 0, :]
     row = jnp.concatenate(
         [c_kv, k_r, jnp.zeros((B, S, cfg.row_width - Rkv - Dr), c_kv.dtype)], axis=-1)
+    if not cfg.index_topk:
+        return q_nope, q_rope, row, None, None, None
 
     def index_rope(x: jnp.ndarray) -> jnp.ndarray:  # [B, S, heads, Di]: the first Dr dims turn
         return jnp.concatenate([apply_rope_halves(x[..., :Dr], sin, cos), x[..., Dr:]], axis=-1)
@@ -338,7 +393,7 @@ def _run_layers(cfg: DeepseekV32Config, params: dict, x: jnp.ndarray, carry: Any
     """Both stacks of layers over x [B, S, D] float32: the leading dense ones, then
     the expert ones. ``attend(lp, layer, h, carry)`` is the caller's
     attention: from the normed input to (heads' outputs [B, S, H*Dv], the
-    carry — a cache or the pools — and its int32 counters [DSA_COUNTERS]).
+    carry — a cache or the pools — and its two int32 counters, :func:`step_stats`).
     ``live`` [B, S] marks the rows whose routing counts: no other pulls an
     expert. Returns x, the carry and the counters of :func:`step_stats_len`."""
     B, S, D = x.shape
@@ -413,8 +468,11 @@ def prefill(
     def attend(lp, layer, h, cache):
         q_nope, q_rope, row, qi, ki, wi = _project(cfg, h, lp, sin, cos)
         k_all = jax.lax.dynamic_update_slice(cache.k, row[None, :, :, None], (layer, 0, 0, 0, 0))
-        v_all = jax.lax.dynamic_update_slice(cache.v, ki[None, :, :, None], (layer, 0, 0, 0, 0))
-        keep = mla.selection_mask(mla.index_scores(qi, ki, wi), seen, cfg.index_topk)
+        if cfg.index_topk:
+            v_all = jax.lax.dynamic_update_slice(cache.v, ki[None, :, :, None], (layer, 0, 0, 0, 0))
+            keep = mla.selection_mask(mla.index_scores(qi, ki, wi), seen, cfg.index_topk)
+        else:
+            v_all, keep = None, seen
         kvb = _mm(row[..., :cfg.kv_lora_rank], lp["wkv_b"]).reshape(B, S, H, -1)
         o = mla.expanded_attention(
             q_nope, q_rope, kvb[..., :Dn], row[..., cfg.kv_lora_rank:cfg.kv_lora_rank + cfg.qk_rope_head_dim],
@@ -441,7 +499,9 @@ def decode_step_paged(
     """One decode step over the paged pools, with ``llama.decode_step_paged``'s
     arguments: the pools ride the layers whole, written by the append's
     kernel alone and read by two gathers — every page of a row's indexer
-    keys, and of the latent rows only the selected ones. After the pools,
+    keys, and of the latent rows only the selected ones. Without an
+    indexer ``v_pool`` is None and every cached latent row of a row is read
+    by ``paged_latent_attention``, a Mosaic call a layer. After the pools,
     the step's counters (:func:`step_stats_len`)."""
     B = tokens.shape[0]
     page = k_pool.shape[3]
@@ -456,6 +516,14 @@ def decode_step_paged(
     def attend(lp, layer, h, pools):
         kp, vp = pools
         q_nope, q_rope, row, qi, ki, wi = _project(cfg, h, lp, sin, cos)
+        if not cfg.index_topk:
+            kp, _ = paged_kv_append(kp, None, row[:, 0, None], None, layer, pages, offsets)
+            q = _absorb_query(cfg, q_nope[:, 0], q_rope[:, 0], lp["wkv_b"])
+            o_lat = paged_latent_attention(q, kp, block_tables, seq_lens, layer, scale=cfg.softmax_scale,
+                                           kv_lora_rank=cfg.kv_lora_rank)
+            read = jnp.stack([jnp.sum(jnp.where(active, seq_lens, 0), dtype=jnp.int32),
+                              jnp.sum(active, dtype=jnp.int32)])
+            return _absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[:, None], (kp, vp), read
         kp, vp = paged_kv_append(kp, vp, row[:, 0, None], ki[:, 0, None], layer, pages, offsets)
         scores, seen = mla.paged_index_scores(qi[:, 0], wi[:, 0], vp, block_tables, seq_lens, layer)
         rows, valid = mla.select_topk(
@@ -475,7 +543,8 @@ def _chunk_row(cfg: DeepseekV32Config, params: dict, tokens: jnp.ndarray, positi
                k_pool: jnp.ndarray, v_pool: jnp.ndarray) -> tuple:
     """One row's chunk of T tokens in the absorbed form: its rows and keys
     written through its table, then every chunk position against the
-    row's gathered pages under the selection's mask."""
+    row's gathered pages under the selection's mask — without an indexer,
+    against the pages up to the chunk's end (:func:`_bounded_attention`)."""
     T = tokens.shape[0]
     page = k_pool.shape[3]
     x = params["embedding"][jnp.maximum(tokens, 0)][None].astype(jnp.float32)  # [1, T, D]
@@ -487,6 +556,10 @@ def _chunk_row(cfg: DeepseekV32Config, params: dict, tokens: jnp.ndarray, positi
         kp, vp = pools
         q_nope, q_rope, row, qi, ki, wi = _project(cfg, h, lp, sin, cos)
         kp = kp.at[layer, pages, 0, offsets].set(row[0])
+        if not cfg.index_topk:
+            q = _absorb_query(cfg, q_nope[0], q_rope[0], lp["wkv_b"])
+            o_lat = _bounded_attention(cfg, q, kp, table, layer, positions, start + T)
+            return _absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[None], (kp, vp), jnp.zeros(2, jnp.int32)
         vp = vp.at[layer, pages, 0, offsets].set(ki[0])
         rows, keys = mla.row_pages(kp, table[None], layer)[0], mla.row_pages(vp, table[None], layer)[0]
         keep = mla.selection_mask(mla.index_scores(qi[0], keys, wi[0]), seen, cfg.index_topk)
@@ -497,7 +570,39 @@ def _chunk_row(cfg: DeepseekV32Config, params: dict, tokens: jnp.ndarray, positi
                 _count(seen & live, keep & live))
 
     x, (k_pool, v_pool), _ = _run_layers(cfg, params, x, (k_pool, v_pool), attend, (tokens >= 0)[None])
+    # the head at the row's last token alone: [1, V]. Keyed on the indexer
+    # only so that V3.2's lowered programs keep their digests (ROADMAP S16)
+    if not cfg.index_topk:
+        last = jnp.maximum(jnp.sum(tokens >= 0) - 1, 0)
+        return _logits(cfg, params, jax.lax.dynamic_index_in_dim(x[0], last, 0)), k_pool, v_pool
     return _logits(cfg, params, x)[0], k_pool, v_pool
+
+
+def _bounded_attention(cfg: DeepseekV32Config, q: jnp.ndarray, k_pool: jnp.ndarray, table: jnp.ndarray,
+                       layer: jnp.ndarray, positions: jnp.ndarray, end: jnp.ndarray) -> jnp.ndarray:
+    """A chunk's queries q [T, H, W] against the row's latent rows up to
+    ``end``, the chunk's end, under the causal mask: the context read is
+    the smallest of T, 2T, 4T ... (whole pages; the last the slot) that
+    holds ``end``, one branch of a ``switch`` each, so a chunk near the
+    start of a long slot reads and scores its own few pages and not the
+    slot. Returns o_lat [T, H, kv_lora_rank] float32."""
+    page = k_pool.shape[3]
+    slot = table.shape[0] * page
+    bounds, n = [], -(-q.shape[0] // page) * page
+    while n < slot:
+        bounds.append(n)
+        n *= 2
+    bounds.append(slot)
+
+    def over(n: int) -> Any:
+        def attend() -> jnp.ndarray:
+            rows = mla.row_pages(k_pool, table[None, :n // page], layer)[0]  # [n, W]
+            keep = jnp.arange(n)[None, :] <= positions[:, None]
+            return mla.latent_attention(q, rows, keep, cfg.softmax_scale, cfg.kv_lora_rank)
+        return attend
+
+    which = jnp.sum(jnp.asarray(bounds[:-1], jnp.int32) < end, dtype=jnp.int32)
+    return jax.lax.switch(which, [over(n) for n in bounds])
 
 
 @partial(jax.jit, static_argnums=0, donate_argnums=(3, 4))
@@ -517,7 +622,9 @@ def decode_chunk_paged(
     ONE ROW AT A TIME under a ``cond``: a row without a chunk runs nothing
     and returns zeros, so a dispatch costs its live rows, and one row's
     scores over its context are all that is held at once. Returns (logits
-    [B, T, V], k_pool, v_pool)."""
+    [B, T, V] — without an indexer [B, 1, V] at each row's last chunk
+    position, where the head runs alone, as ``lfm2_moe``'s — k_pool,
+    v_pool)."""
     B, T = tokens.shape
     positions = start_len[:, None] + jnp.arange(T)[None, :]
     pages, offsets = _paged_chunk_targets(k_pool, block_tables, positions, active, kv_capacity)
@@ -529,7 +636,7 @@ def decode_chunk_paged(
             return _chunk_row(cfg, params, toks, pos, pg, off, table, start, kp, vp)
 
         def skip(kp, vp):
-            return jnp.zeros((T, cfg.vocab_size), jnp.float32), kp, vp
+            return jnp.zeros((T if cfg.index_topk else 1, cfg.vocab_size), jnp.float32), kp, vp
 
         logits, kp, vp = jax.lax.cond(act, run, skip, *pools)
         return (kp, vp), logits

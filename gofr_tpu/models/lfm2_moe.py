@@ -64,7 +64,7 @@ from gofr_tpu.ops.rope import apply_rope_halves, rope_angles
 
 __all__ = [
     "Lfm2MoeConfig", "KVCache", "init_params", "quantize_params", "prefill", "prefill_slabs",
-    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "cache_spec", "unserved", "STEP_STATS",
+    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "step_stats", "cache_spec", "unserved", "STEP_STATS",
 ]
 
 CONV, ATTN = "conv", "full_attention"
@@ -156,6 +156,11 @@ def step_stats_len(cfg: Lfm2MoeConfig) -> int:
     each expert took and the experts read (``ops/moe.held_experts``), each
     summed over the expert layers, then :data:`STEP_STATS`."""
     return cfg.n_experts + 1 + len(STEP_STATS)
+
+
+def step_stats(cfg: Lfm2MoeConfig) -> tuple[str, ...]:
+    """Names of the counters after the experts' (:data:`STEP_STATS`)."""
+    return STEP_STATS
 
 
 def cache_spec(cfg: Lfm2MoeConfig, page_size: int) -> tuple[tuple, dict]:

@@ -67,7 +67,7 @@ from gofr_tpu.ops.paged_attention import paged_decode_attention, paged_kv_append
 
 __all__ = [
     "Phi4FlashConfig", "KVCache", "init_params", "quantize_params", "prefill", "prefill_slabs",
-    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "cache_spec", "unserved",
+    "decode_step_paged", "decode_chunk_paged", "step_stats_len", "step_stats", "cache_spec", "unserved",
     "layer_kinds", "lambda_init", "STEP_STATS", "CHUNK_TAKES_FINISH",
 ]
 
@@ -155,6 +155,11 @@ def step_stats_len(cfg: Phi4FlashConfig) -> int:
     (:data:`STEP_STATS`): cache positions the full layer and its readers
     read, positions the window layers read, rows whose state advanced."""
     return len(STEP_STATS)
+
+
+def step_stats(cfg: Phi4FlashConfig) -> tuple[str, ...]:
+    """Names of the step's counters (:data:`STEP_STATS`)."""
+    return STEP_STATS
 
 
 def cache_spec(cfg: Phi4FlashConfig, page_size: int) -> tuple[tuple, dict]:

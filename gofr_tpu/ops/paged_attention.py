@@ -409,10 +409,11 @@ def paged_kv_append_ref(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The append as an XLA scatter: the oracle, and the CPU path.
     ``pool.at[layer, pages, :, offsets]`` (advanced indices split by a
-    slice) addresses [B, Hkv, Dh]."""
+    slice) addresses [B, Hkv, Dh]. A ``v_pool`` of None (a model that
+    stores one thing a token) stays None."""
     return (
         k_pool.at[layer, pages, :, offsets].set(k_new.astype(k_pool.dtype)),
-        v_pool.at[layer, pages, :, offsets].set(v_new.astype(v_pool.dtype)),
+        None if v_pool is None else v_pool.at[layer, pages, :, offsets].set(v_new.astype(v_pool.dtype)),
     )
 
 
@@ -420,15 +421,9 @@ def _append_kernel(
     layer_ref,  # SMEM [1] (scalar prefetch)
     pages_ref,  # SMEM [B] (scalar prefetch)
     offsets_ref,  # SMEM [B] (scalar prefetch)
-    k_new_ref,  # VMEM [R, Hkv, Dh]: this chunk's rows
-    v_new_ref,
-    k_in,  # HBM [L, N, Hkv, page, Dh]: the pools, aliased to the outputs
-    v_in,
-    k_out,  # the same two buffers
-    v_out,
-    k_buf,  # VMEM [R, Hkv, page, Dh]
-    v_buf,
-    sem,  # DMA (2,): reads, writes
+    *refs,  # for each of the n pools (K, V; or one): its new rows, then
+    # its HBM input and output (one buffer, aliased), then its VMEM page
+    # buffer; last the DMA semaphores (2,): reads, writes
 ):
     """One program a chunk of ``R`` rows. A DMA cannot write one token's
     row into a page (a slice of 1 along the tiled page axis), so a row's
@@ -440,11 +435,14 @@ def _append_kernel(
     shared slabs into owned pages (serving/kv_cache.py) — only the trash
     page is written by several rows, and its content is garbage by
     contract."""
-    R = k_buf.shape[0]  # the two pools may hold pages of two shapes
+    n = (len(refs) - 1) // 4
+    new_refs, ins, outs, bufs = (refs[i * n:(i + 1) * n] for i in range(4))
+    sem = refs[-1]
+    R = bufs[0].shape[0]  # the two pools may hold pages of two shapes
     c = pl.program_id(0)
     rows = jnp.minimum(R, pages_ref.shape[0] - c * R)
     layer = layer_ref[0]
-    streams = ((k_in, k_out, k_buf, k_new_ref), (v_in, v_out, v_buf, v_new_ref))
+    streams = tuple(zip(ins, outs, bufs, new_refs))
 
     def each_row(fn):
         def one(r, _):
@@ -485,18 +483,19 @@ def _append_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_kv_append(
     k_pool: jnp.ndarray,  # [L, N_pages, Hkv, page, Dh]
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray | None,  # None: the model stores one thing a token
     k_new: jnp.ndarray,  # [B, Hkv, Dh]
-    v_new: jnp.ndarray,  # the V pool's pages may have a shape of their own
+    v_new: jnp.ndarray | None,  # the V pool's pages may have a shape of their own
     layer: jnp.ndarray,  # scalar int32 (may be traced)
     pages: jnp.ndarray,  # [B] int32
     offsets: jnp.ndarray,  # [B] int32
     *,
     interpret: bool | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     """One decode step's K/V of every row into ``[layer, pages[b], :,
     offsets[b]]`` of the pools, by a Pallas call aliased over both whole
-    pools; contract identical to :func:`paged_kv_append_ref`, given that
+    pools (over the one, with ``v_pool`` None: a latent row a token);
+    contract identical to :func:`paged_kv_append_ref`, given that
     rows that share a page (the trash page) leave garbage in it. Inside a
     program that donates the pools this writes in place, and — the reason
     it is a kernel — leaves XLA no op that writes into a pool: an XLA
@@ -507,7 +506,9 @@ def paged_kv_append(
     if mode == REFERENCE:
         return paged_kv_append_ref(k_pool, v_pool, k_new, v_new, layer, pages, offsets)
     B = k_new.shape[0]
-    pools = (k_pool, v_pool)  # their pages [Hkv, page, Dh] may differ in shape
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)  # their pages [Hkv, page, Dh] may differ in shape
+    news = (k_new,) if v_pool is None else (k_new, v_new)
+    n = len(pools)
     page_bytes = sum(math.prod(pool.shape[2:]) * pool.dtype.itemsize for pool in pools)
     R = max(1, min(B, _APPEND_VMEM_BUDGET // page_bytes))
     row_specs = [pl.BlockSpec((R, pool.shape[2], pool.shape[4]), lambda c, *_: (c, 0, 0)) for pool in pools]
@@ -515,28 +516,24 @@ def paged_kv_append(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, pages, offsets
         grid=(pl.cdiv(B, R),),
-        in_specs=row_specs + [hbm, hbm],
-        out_specs=[hbm, hbm],
+        in_specs=row_specs + [hbm] * n,
+        out_specs=[hbm] * n,
         scratch_shapes=[pltpu.VMEM((R,) + pool.shape[2:], pool.dtype) for pool in pools] + [
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    k_pool, v_pool = pl.pallas_call(
+    out = pl.pallas_call(
         _append_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-        ],
-        # operands count the scalar prefetches: 3 scalars, 2 new rows, pools
-        input_output_aliases={5: 0, 6: 1},
+        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype) for pool in pools],
+        # operands count the scalar prefetches: 3 scalars, n new rows, pools
+        input_output_aliases={3 + n + i: i for i in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=mode == INTERPRET,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1), pages.astype(jnp.int32),
-        offsets.astype(jnp.int32), k_new.astype(k_pool.dtype),
-        v_new.astype(v_pool.dtype), k_pool, v_pool,
+        offsets.astype(jnp.int32), *(new.astype(pool.dtype) for new, pool in zip(news, pools)), *pools,
     )
-    return k_pool, v_pool
+    return out[0], (out[1] if n == 2 else None)

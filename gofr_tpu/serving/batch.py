@@ -63,7 +63,8 @@ def model_of(cfg: Any) -> Any:
     ``llama``'s arguments, ``step_stats_len(cfg)`` — how many int32
     counters its paged step returns after the pools (0: none) —
     ``page_shapes(cfg, page_size)``, what a page of each of the two pools
-    holds (``serving/kv_cache.py``) — or, for a model that stores several
+    holds (``serving/kv_cache.py``; None for the second: one pool, and
+    ``v_pool`` is None) — or, for a model that stores several
     kinds of thing, ``cache_spec(cfg, page_size)``: its pools by layer kind
     and a per-slot state, in which case the programs' ``k_pool``,
     ``v_pool`` and ``block_tables`` are the pager's dicts, its prefill
@@ -71,9 +72,9 @@ def model_of(cfg: Any) -> Any:
     ``CHUNK_TAKES_FINISH``, its chunk program is told which rows finish
     (``lfm2_moe``'s is not told: it returns logits [B, 1, V] at every
     row's last chunk position, which this module's fold reads as it reads
-    any) — ``STEP_STATS``, the names of counters its step returns (after
-    the experts' rows and reads for a config with ``held_experts``: the
-    engine sets them on the commit span), and ``unserved(engine_config,
+    any) — ``step_stats(cfg)``, the names of counters its step returns
+    (after the experts' rows and reads for a config with ``held_experts``:
+    the engine sets them on the commit span), and ``unserved(engine_config,
     lora, cfg)``, the sentence that refuses
     an engine the model has no program for. The dense and speculative
     programs call the functions ``llama`` has for them by the same names."""

@@ -588,12 +588,12 @@ class ServingEngine:
         if refusal:
             raise ValueError(refusal)
         # int32 counters the model's paged step adds to a block's packed
-        # result (rows per held expert and the held experts read;
-        # deepseek_v32 also the positions its indexer scored and its
-        # attention read; a model that names its own, STEP_STATS, has them
-        # set on the commit span under those names); 0 for most
+        # result (rows per held expert and the held experts read; a model
+        # that names its own, step_stats(cfg), has them set on the commit
+        # span under those names); 0 for most
         self._stats_len = model.step_stats_len(cfg)
-        self._stats_names = getattr(model, "STEP_STATS", None)
+        names = getattr(model, "step_stats", None)
+        self._stats_names = names(cfg) if names else None
         # a sparse-expert model's branch of held_experts at a block's
         # decode rows, set as moe_path on every block's dispatch span
         self._moe_path = _moe_path(cfg, self.params, self.config.max_slots)
@@ -3788,13 +3788,12 @@ class ServingEngine:
         app_moe_expert_rows_total by the expert's published index; after
         them the held experts whose matrices the steps read
         (``moe_reached``, app_moe_experts_read_total:
-        ``ops/moe.held_experts`` counts them). A model with a sparse
-        selection (``index_topk``) counts after them the positions its
-        indexer scored and the positions its attention read
-        (``dsa_scored``, ``dsa_selected``; app_dsa_positions_total). A
-        model that names its counters (``STEP_STATS``) has them set under
-        those names: all of them, or — for a sparse-expert model
-        (``models/lfm2_moe.py``) — those after the experts'."""
+        ``ops/moe.held_experts`` counts them). A model that names its
+        counters (``step_stats(cfg)``) has them set under those names: all
+        of them, or — for a sparse-expert model — those after the
+        experts'. A sparse selection's two (``deepseek_v32``'s
+        ``dsa_scored``, ``dsa_selected``: the positions its indexer scored
+        and its attention read) also count in app_dsa_positions_total."""
         held = getattr(self.model_cfg, "held_experts", 0)
         named = {}
         if self._stats_names is not None:
@@ -3803,15 +3802,12 @@ class ServingEngine:
             if not held:
                 span.set(**named)
                 return
-        rows, reached, dsa = stats[:held], int(stats[held]), {}
-        if getattr(self.model_cfg, "index_topk", None):
-            scored, selected = stats[held + 1:].tolist()
-            dsa = {"dsa_scored": scored, "dsa_selected": selected}
-            if self._metrics:
-                for kind, n in (("scored", scored), ("selected", selected)):
-                    if n:
-                        self._metrics.add_counter("app_dsa_positions_total", n, kind=kind)
-        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), moe_reached=reached, **dsa, **named)
+        rows, reached = stats[:held], int(stats[held])
+        if self._metrics:
+            for kind in ("scored", "selected"):
+                if named.get(f"dsa_{kind}"):
+                    self._metrics.add_counter("app_dsa_positions_total", named[f"dsa_{kind}"], kind=kind)
+        span.set(moe_rows=int(rows.sum()), moe_max=int(rows.max()), moe_reached=reached, **named)
         if self._metrics:
             if reached:
                 self._metrics.add_counter("app_moe_experts_read_total", reached)

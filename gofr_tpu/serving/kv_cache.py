@@ -61,16 +61,14 @@ __all__ = ["PagedKVCache", "OutOfBlocks"]
 @partial(jax.jit, donate_argnums=(0, 1))
 def _write_pages(
     k_pool: jnp.ndarray,  # [L, N, Hkv, page, Dh] donated
-    v_pool: jnp.ndarray,  # its pages may have a shape of their own
+    v_pool: jnp.ndarray | None,  # its pages may have a shape of their own; None: one pool
     k_slab: jnp.ndarray,  # [L, S_pad, Hkv, Dh] (S_pad = n_pages*page)
-    v_slab: jnp.ndarray,
+    v_slab: jnp.ndarray | None,
     page_ids: jnp.ndarray,  # [n_pages] int32
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     n_pages = page_ids.shape[0]
-    return (
-        k_pool.at[:, page_ids].set(_paged(k_slab, n_pages)),
-        v_pool.at[:, page_ids].set(_paged(v_slab, n_pages)),
-    )
+    return jax.tree.map(lambda pool, slab: pool.at[:, page_ids].set(_paged(slab, n_pages)),
+                        (k_pool, v_pool), (k_slab, v_slab))
 
 
 def _paged(slab: jnp.ndarray, n_pages: int) -> jnp.ndarray:
@@ -101,8 +99,8 @@ def _write_slot(
     return k_out, v_out
 
 
-def _pad_tokens(slab: jnp.ndarray, pad: int) -> jnp.ndarray:
-    return jnp.pad(slab, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else slab
+def _pad_tokens(slab: jnp.ndarray | None, pad: int) -> jnp.ndarray | None:
+    return jnp.pad(slab, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad and slab is not None else slab
 
 
 def _contiguous(pages: jnp.ndarray) -> jnp.ndarray:
@@ -126,7 +124,8 @@ class PagedKVCache:
         dtype: Any = None,
         # what a page of each pool holds, [Hkv, page, Dh] twice: the model's
         # ``page_shapes`` (the engine hands it over); None is K and V of
-        # every KV head
+        # every KV head. A second shape of None: no second pool (v_pool is
+        # None, and so is every V slab)
         page_shapes: tuple[tuple, tuple] | None = None,
         # a model that stores several kinds of thing: its ``cache_spec``,
         # (pools by layer kind, per-slot state). None is one pool pair of
@@ -174,7 +173,8 @@ class PagedKVCache:
         What a page holds, [Hkv, page, Dh] for each of the two pools, is
         the model's to say (``page_shapes``, handed over at construction:
         K and V of every KV head for ``llama`` and ``cohere2_moe``; one
-        latent row and one indexer key a token for ``deepseek_v32``) —
+        latent row and one indexer key a token for ``deepseek_v32``, the
+        latent row alone where it has no indexer, and no second pool) —
         one block table and one allocator serve both. The extra LAST page is the
         trash page: inactive rows' decode appends are redirected there
         (the model's decode_step_paged), so the append never writes a
@@ -190,7 +190,7 @@ class PagedKVCache:
             lead = (self.cfg.n_layers, self.num_pages + 1)
             self.k_pool, self.v_pool = (
                 jnp.zeros(lead + k_page, self._pool_dtype),
-                jnp.zeros(lead + v_page, self._pool_dtype),
+                None if v_page is None else jnp.zeros(lead + v_page, self._pool_dtype),
             )
             return
         pools, state = self._spec
@@ -502,10 +502,10 @@ class PagedKVCache:
         p0 = start // self.page_size
         p1 = self.pages_needed(end)
         page_ids = self.tables[slot, p0:p1]
-        k = _contiguous(self.k_pool[:, page_ids])  # [L, n, Hkv, page, Dh] gathered
-        v = _contiguous(self.v_pool[:, page_ids])
         off = start - p0 * self.page_size  # 0 by alignment, kept explicit
-        return k[:, off : off + (end - start)], v[:, off : off + (end - start)]
+        # [L, n, Hkv, page, Dh] gathered; a V pool of None gives a V slab of None
+        return jax.tree.map(lambda pool: _contiguous(pool[:, page_ids])[:, off : off + (end - start)],
+                            (self.k_pool, self.v_pool))
 
     def _one_pool(self, what: str) -> None:
         if self._spec is not None:

@@ -24,9 +24,12 @@ PROMPTS = [6, 40]  # one bucketed prompt, one that chunks
 
 
 def families() -> dict:
-    """name -> (config, lowering, engine settings), the five families the
-    benchmark serves (the two dense cells share ``llama``: GQA and MHA)."""
-    from benchmarks.harness import cohere2_moe_family, deepseek_v32_family, llama_family, phi4flash_family
+    """name -> (config, lowering, engine settings), the families the
+    benchmark serves (the two dense cells share ``llama``: GQA and MHA;
+    ``joyai_llm_flash`` is ``deepseek_v32`` without an indexer)."""
+    from benchmarks.harness import (
+        cohere2_moe_family, deepseek_v32_family, joyai_flash_family, llama_family, phi4flash_family,
+    )
     from gofr_tpu.models import cohere2_moe, deepseek_v32, llama, phi4flash
 
     paged = dict(kv_layout="paged", kv_page_size=4, max_slots=3, max_seq_len=64, prefill_buckets=(16, 32))
@@ -40,6 +43,9 @@ def families() -> dict:
                          dict(paged, prefill_chunk_tokens=16)),
         "phi4flash": (phi4flash.Phi4FlashConfig.tiny(), phi4flash_family.lowered_programs,
                       dict(paged, prefill_chunk_tokens=8)),
+        "joyai_llm_flash": (deepseek_v32.DeepseekV32Config.tiny(
+            index_n_heads=0, index_head_dim=0, index_topk=0, n_group=1, topk_group=1, rope_factor=1.0,
+            rope_theta=3.2e7), joyai_flash_family.lowered_programs, dict(paged, prefill_chunk_tokens=16)),
     }
 
 

@@ -67,21 +67,27 @@ def test_the_append_kernel_writes_what_the_scatter_wrote(rows, dtype, monkeypatc
         assert not (g[1] == before[1]).all()
 
 
-def test_the_append_kernel_takes_pools_of_two_page_shapes():
+@pytest.mark.parametrize("second", [128, None], ids=["an-indexer-key", "no-second-pool"])
+def test_the_append_kernel_takes_pools_of_two_page_shapes_or_one_pool(second):
     """A latent row and an indexer key a token (``deepseek_v32``): one
-    head, widths that differ, one set of pages and offsets for both."""
+    head, widths that differ, one set of pages and offsets for both; or
+    the latent row alone (no indexer: the second pool is None, and stays
+    None)."""
     L, N, rows = 2, 9, 5
     keys = jax.random.split(jax.random.PRNGKey(3), 4)
     k_pool = jax.random.normal(keys[0], (L, N, 1, PAGE, 256), jnp.bfloat16)
-    v_pool = jax.random.normal(keys[1], (L, N, 1, PAGE, 128), jnp.bfloat16)
     k_new = jax.random.normal(keys[2], (rows, 1, 256), jnp.bfloat16)
-    v_new = jax.random.normal(keys[3], (rows, 1, 128), jnp.bfloat16)
+    v_pool = v_new = None
+    if second:
+        v_pool = jax.random.normal(keys[1], (L, N, 1, PAGE, second), jnp.bfloat16)
+        v_new = jax.random.normal(keys[3], (rows, 1, second), jnp.bfloat16)
     pages, offsets = jnp.asarray([4, 0, 7, 2, 5]), jnp.asarray([0, 3, 1, 2, 3])
     args = (k_new, v_new, jnp.int32(1), pages, offsets)
     got = pa.paged_kv_append(k_pool, v_pool, *args, interpret=True)
     want = pa.paged_kv_append_ref(k_pool, v_pool, *args)
+    assert (got[1] is None) is (second is None) and not bool(jnp.all(got[0] == k_pool))
     for g, w in zip(got, want):
-        assert g.shape == w.shape and bool(jnp.all(g == w))
+        assert (g is None and w is None) or (g.shape == w.shape and bool(jnp.all(g == w)))
 
 
 def test_on_the_cpu_the_append_is_the_scatter():
@@ -954,3 +960,95 @@ def test_lfm2_compiled_ragged_step_writes_a_chunk_into_its_pool_in_place(one_chi
             made[m.group(3) if "/scatter" in line else f"{m.group(1)} = {m.group(3)}"] += 1
     assert set(made) <= {"scatter", "fusion"} and made["scatter"] == 2, made
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * size
+
+
+# ------------------------------------------ JoyAI-LLM-Flash, compiled for the chip
+def _joyai_arguments(one_chip, n_layers, B, M, page=16):
+    """The cell's configuration at ``n_layers`` (the first dense), its
+    weights' and pool's shapes on the described chip: nothing is made."""
+    from benchmarks.harness import joyai_flash_family
+    from benchmarks.harness.manifest import Manifest
+
+    config = dict(Manifest(joyai_flash_family.__file__.rsplit("/benchmarks/", 1)[0]).config("joyai-llm-flash-ep8-int8"),
+                  num_hidden_layers=n_layers)
+    cfg = joyai_flash_family.program_config(config)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(lambda: joyai_flash_family.make_weights(config, 0)))
+    page_shape, second = ds.page_shapes(cfg, page)
+    assert second is None  # one latent pool
+    pool = jax.ShapeDtypeStruct((cfg.n_layers, B * M + 1) + page_shape, cfg.dtype, sharding=one_chip)
+    i32, f32 = jnp.int32, jnp.float32
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    dec = batch_ops.DecodeState(vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
+                                vec(i32), vec(f32), key, vec(i32))
+    return cfg, params, pool, dec, vec
+
+
+def test_joyai_latent_kernel_compiles_at_the_cell_s_widths(one_chip, no_compile_cache, monkeypatch):
+    """``paged_latent_attention`` by the chip's compiler at the cell's
+    shapes: 64 rows of 32 heads over 640-wide latent rows, 512 pages of 16
+    a row, the whole pool of 13 layers in HBM — one Mosaic call and no
+    other op, nothing copied."""
+    from gofr_tpu.ops import latent_attention as la
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(la, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M, page = 64, 512, 16
+    pool = jax.ShapeDtypeStruct((13, B * M + 1, 1, page, 640), jnp.bfloat16, sharding=one_chip)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(lambda q, p, t, n, layer: la.paged_latent_attention(
+            q, p, t, n, layer, scale=192 ** -0.5, kv_lora_rank=512)).lower(
+            vec(jnp.bfloat16, B, 32, 640), pool, vec(jnp.int32, B, M), vec(jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "paged_latent_attention" in text
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    assert not any("bf16[" in line.split(" copy(")[0] for line in copies), copies  # the pool and queries go in as they are
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_joyai_compiled_decode_block_leaves_its_pool_to_the_kernels(one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` of the cell's configuration at 3 layers (one
+    dense, two expert) by the chip's compiler: the latent pool is written
+    by the append's custom call and read by the latent kernel's alone, no
+    XLA op makes, slices or updates it; and of the weight stacks only
+    W_kvb's is copied once a dispatch (both absorptions read it, over two
+    different axes: the one layout change a block can hold) — W_qb's
+    product takes the stack as stored (the barrier after it)."""
+    from gofr_tpu.ops import expert_rows
+    from gofr_tpu.ops import latent_attention as la
+    from gofr_tpu.ops.backend import COMPILED
+
+    for module in (pa, la, expert_rows):
+        monkeypatch.setattr(module, "kernel_mode", lambda interpret=None: COMPILED)
+    B, M = 64, 512
+    cfg, params, pool, dec, vec = _joyai_arguments(one_chip, 3, B, M)
+    with jax.default_matmul_precision("default"):
+        compiled = batch_ops.decode_block_paged.lower(
+            cfg, params, pool, None, dec, vec(jnp.int32, B, M), vec(jnp.bool_), STEPS).compile()
+    text = compiled.as_text()
+    calls = collections.Counter(m.group(1) for m in re.finditer(r"%(paged_latent_attention|paged_kv_append|expert_rows)[\w.\-]* = ", text))
+    # a layer body of each stack: the append and the attention; the expert layers' two expert_rows calls
+    assert calls["paged_latent_attention"] == 2 and calls["paged_kv_append"] == 2 and calls["expert_rows"] == 2
+    dims = ",".join(str(d) for d in pool.shape)
+    pool_like = {f"bf16[{dims}]", f"bf16[{dims.split(',', 1)[1]}]", f"bf16[1,{dims.split(',', 1)[1]}]"}
+    made = []
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(3) not in _PASSES_ON and any(shape in m.group(2) for shape in pool_like):
+            made.append(f"{m.group(1)} = {m.group(3)}")
+    assert not made, f"XLA ops that make, slice or update the pool: {made}"
+    copied = set(re.findall(r"= (s8\[\d+,\d+,\d+\])\{[^}]*\} copy\(", text))
+    assert copied <= {"s8[2,512,8192]", "s8[1,512,8192]", "s8[2,2048,576]", "s8[1,2048,576]"}, copied
+    assert "s8[2,1536,6144]" not in " ".join(copied)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
