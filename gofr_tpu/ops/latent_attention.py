@@ -17,7 +17,9 @@ loops over the blocks of pages the row owns up to ``seq_len``
 (``cdiv(seq_len, ppb * page)`` trips, read from the scalar-prefetched
 lengths and block tables), fetches each page by a DMA of its own into a
 double buffer in VMEM — block *i + 1*, at a row's end the next row's
-first block, is in flight while block *i* is computed — and keeps an
+first block, is in flight while block *i* is computed; the starts are
+unrolled over the block's static pages and a full block lands with ONE
+wait (:func:`_start_pages`, :func:`_land_pages`) — and keeps an
 online softmax in float32 for all heads: ``[H, W] x [W, block]`` to score,
 ``[H, block] x [block, kv_lora_rank]`` to sum, one float32 product. It
 returns o_lat ``[B, H,
@@ -46,8 +48,8 @@ from gofr_tpu.ops.backend import INTERPRET, REFERENCE, kernel_mode
 NEG_INF = -1e30
 # positions one compute block and one DMA batch hold (640 KiB a slot at 640
 # bf16 lanes). The last block of a row is computed whole and masked, yet on
-# the v5e at 64 rows of 32 heads and 188k positions 512 read 1.44 ms against
-# 1.63 at 256 and 1.92 at 128: the per-block cost outweighs the tail's
+# the v5e at 64 rows of 32 heads and 188k positions 512 read faster than 256
+# and 128: the per-block cost outweighs the tail's
 _BLOCK_TOKENS = 512
 _LANES = 128  # the running max and sum are kept a lane row wide
 
@@ -68,6 +70,56 @@ def paged_latent_attention_ref(
     rows = mla.row_pages(latent_pool, block_tables, layer)  # [B, M*page, W]
     keep = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
     return mla.latent_attention(q[:, None], rows, keep[:, None], scale, kv_lora_rank)[:, 0]
+
+
+def _start_pages(pool_hbm, tables_ref, buf, sem, layer, row, first, n, slot):
+    """Starts the DMAs of the ``n`` pages from entry ``first`` of ``row``'s
+    block table into slot ``slot`` of the double buffer, a DMA a page,
+    unrolled over the slot's static pages: a page costs its descriptor and
+    no loop trip. A full block — every block of a row but its last —
+    starts them under no predicate; a partial one puts each under its own."""
+    ppb = buf.shape[1]
+
+    def start(j):
+        pid = tables_ref[row, first + j]
+        pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
+
+    @pl.when(n == ppb)
+    def _whole():
+        for j in range(ppb):
+            start(j)
+
+    @pl.when(n < ppb)
+    def _partial():
+        for j in range(ppb):
+            pl.when(j < n)(functools.partial(start, j))
+
+
+def _land_pages(pool_hbm, buf, sem, n, slot):
+    """Waits for the ``n`` pages :func:`_start_pages` started into slot
+    ``slot`` and zeroes the slot's pages past them. The DMAs count the
+    bytes they land on the slot's one semaphore, so a full slot — every
+    block of a row but its last — is ONE wait, described as the whole
+    slot (its source a run of pages of the pool, for its shape alone); a
+    partial block waits page by page."""
+    ppb = buf.shape[1]
+
+    @pl.when(n == ppb)
+    def _whole():
+        pltpu.make_async_copy(pool_hbm.at[0, pl.ds(0, ppb)], buf.at[slot], sem.at[slot]).wait()
+
+    @pl.when(n < ppb)
+    def _partial():
+        for j in range(ppb):
+            @pl.when(j < n)
+            def _wait():
+                pltpu.make_async_copy(pool_hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
+
+            # a page past the row's last holds what the slot held before: its
+            # scores are masked, but a zero weight times a stale NaN is NaN
+            @pl.when(j >= n)
+            def _clear():
+                buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
 
 
 def _kernel(
@@ -107,17 +159,7 @@ def _kernel(
         return jnp.minimum(row_pages(row) - blk * ppb, ppb)
 
     def start_block(row, blk, slot):
-        def one(j, _):
-            pid = tables_ref[row, blk * ppb + j]
-            pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
-            return _
-        jax.lax.fori_loop(0, block_pages(row, blk), one, None)
-
-    def wait_block(n, slot):
-        def one(j, _):
-            pltpu.make_async_copy(pool_hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
-            return _
-        jax.lax.fori_loop(0, n, one, None)
+        _start_pages(pool_hbm, tables_ref, buf, sem, layer, row, blk * ppb, block_pages(row, blk), slot)
 
     @pl.when(b == 0)
     def _prime():
@@ -141,16 +183,7 @@ def _kernel(
             row = jnp.where(last, jnp.minimum(b + 1, B - 1), b)
             start_block(row, jnp.where(last, 0, i + 1), 1 - slot)
 
-        n = block_pages(b, i)
-        wait_block(n, slot)
-
-        # pages of the block past the row's last hold what the slot held
-        # before: their scores are masked, but a zero weight times a stale
-        # NaN is NaN
-        def clear(j, _):
-            buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
-            return _
-        jax.lax.fori_loop(n, ppb, clear, None)
+        _land_pages(pool_hbm, buf, sem, block_pages(b, i), slot)
 
         rows = buf[slot, :, 0].reshape(bk, W)  # [bk, W]
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
@@ -181,7 +214,8 @@ def _call(q, latent_pool, block_tables, seq_lens, layer, scale, kv_lora_rank, in
     B, H, W = q.shape
     page = latent_pool.shape[3]
     M = block_tables.shape[1]
-    ppb = max(1, min(_BLOCK_TOKENS // page, M))
+    # a slot's pages never outnumber the pool's: the whole slot's wait is described by a run of them
+    ppb = max(1, min(_BLOCK_TOKENS // page, M, latent_pool.shape[1]))
     kernel = functools.partial(_kernel, scale=scale, ppb=ppb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # seq_lens, block_tables, layer

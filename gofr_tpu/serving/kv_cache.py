@@ -304,9 +304,12 @@ class PagedKVCache:
         seq_id = self._slot_seq[slot]
         assert seq_id is not None
         target = min(int(self.seq_lens[slot]) + tokens, self.max_seq_len)
-        owned = len(self.allocator.block_table(seq_id))
+        # the pages the slot owns, as the last _sync_table counted them: no
+        # copy of the allocator's table, and no ask for its free pages
+        # unless a page is wanted (a row wants one every page_size tokens)
+        owned = int(self._cover[slot]) // self.page_size
         needed = max(0, self.pages_needed(target) - owned)
-        if needed > self.allocator.stats()["free_blocks"]:
+        if needed and needed > self.allocator.stats()["free_blocks"]:
             return False
         if target > self.allocator.seq_length(seq_id):
             try:
@@ -316,7 +319,8 @@ class PagedKVCache:
                 # free_blocks raced another consumer (or the chaos point
                 # fired): same contract as the capacity check above
                 return False
-            self._sync_table(slot, seq_id)
+            if needed:  # else the reservation grew inside the pages owned: the table is as it was
+                self._sync_table(slot, seq_id)
         return True
 
     def advance_slot(self, slot: int, n_tokens: int) -> None:

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 import lowered_digests
 from benchmarks.harness import joyai_flash_reference as reference
@@ -124,22 +125,69 @@ def chunked(cfg, params, ids, chunk):
 
 
 # ------------------------------------------------------------- the kernel
-def kernel_case(dtype=jnp.bfloat16):
+# lengths a case of six rows holds: 8 positions a block at two pages, and the
+# entry's block here is the whole table (6 pages, 24 positions)
+LENGTHS = {
+    # an empty slot, one position, a partial last page, a block's end, across a block boundary, the whole table
+    "ragged": [0, 1, 9, 16, 17, 24],
+    "ends-at-a-block": [8, 16, 24, 24, 16, 8],  # full blocks alone at two pages a block
+    "one-into-a-block": [9, 17, 1, 9, 17, 1],
+    "whole-tables": [24] * 6,  # every block full at both sizes
+    "empty-and-one": [0, 1, 0, 1, 1, 0],
+}
+# the TPU interpreter that lands a DMA only when a wait on its semaphore
+# needs its bytes, into buffers that start as NaN, and prints a semaphore
+# left with a count at the kernel's exit
+ON_WAIT = pltpu.InterpretParams(dma_execution_mode="on_wait", uninitialized_memory="nan")
+
+
+def kernel_case(dtype=jnp.bfloat16, lengths="ragged"):
     L, B, H, W, R, M = 2, 6, 4, 128, 96, 6
     n = B * M
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     pool = jax.random.normal(ks[0], (L, n + 1, 1, PAGE, W), jnp.float32).astype(dtype)
     q = jax.random.normal(ks[1], (B, H, W), jnp.float32).astype(dtype)
     tables = jnp.asarray(np.random.default_rng(3).permutation(n).reshape(B, M), jnp.int32)
-    # an empty slot, one position, a partial last page, a block's end (8 positions a block
-    # below), across a block boundary, the whole table
-    lens = jnp.asarray([0, 1, 9, 16, 17, 24])
-    return pool, q, tables, lens, R
+    return pool, q, tables, jnp.asarray(LENGTHS[lengths]), R
 
 
-def interpreted(q, pool, tables, lens, layer, R):
+def interpreted(q, pool, tables, lens, layer, R, interpret=True):
     """The kernel's call, un-jitted, in the Pallas interpreter."""
-    return la._call(q, pool, tables, lens, layer, 0.3, R, True)
+    return la._call(q, pool, tables, lens, layer, 0.3, R, interpret)
+
+
+def poisoned(pool, tables, lens):
+    """The pool with NaN in every page no row holds below its length, and
+    in the other layer."""
+    out = np.full(pool.shape, np.nan, np.asarray(pool).dtype)
+    for b, n in enumerate(np.asarray(lens)):
+        for j in range(max(1, -(-int(n) // PAGE))):  # a row of length 0 is read as one of length 1
+            page = int(tables[b, j])
+            out[1, page] = np.asarray(pool)[1, page]
+    return jnp.asarray(out)
+
+
+def committed_start(pool_hbm, tables_ref, buf, sem, layer, row, first, n, slot):
+    """The fetch the kernel had before one wait a block, kept as the
+    oracle of the fetch: a loop of dynamic length starts a DMA a page ..."""
+    def one(j, _):
+        pid = tables_ref[row, first + j]
+        pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
+        return _
+    jax.lax.fori_loop(0, n, one, None)
+
+
+def committed_land(pool_hbm, buf, sem, n, slot):
+    """... another waits for them page by page, a third zeroes the rest."""
+    def wait(j, _):
+        pltpu.make_async_copy(pool_hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
+        return _
+    jax.lax.fori_loop(0, n, wait, None)
+
+    def clear(j, _):
+        buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
+        return _
+    jax.lax.fori_loop(n, buf.shape[1], clear, None)
 
 
 @pytest.fixture
@@ -149,25 +197,60 @@ def blocks_of_two_pages(monkeypatch):
     monkeypatch.setattr(la, "_BLOCK_TOKENS", 2 * PAGE)
 
 
-@pytest.mark.parametrize("blocks", ["blocks-of-two-pages", "the-entry-s-block"])
-def test_the_kernel_is_latent_attention_over_the_row_s_pages_at_ragged_lengths(blocks, request):
+@pytest.mark.parametrize("blocks,lengths", [
+    pytest.param(blocks, lengths, id=blocks if lengths == "ragged" else f"{blocks}-{lengths}")
+    for lengths in LENGTHS for blocks in ("blocks-of-two-pages", "the-entry-s-block")])
+def test_the_kernel_is_latent_attention_over_the_row_s_pages_at_ragged_lengths(blocks, lengths, request,
+                                                                               monkeypatch, capsys):
     """In the interpreter: every length against the oracle computed in
     float32 (the kernel's P·V is a float32 product; the oracle's bf16
-    weights are not)."""
+    weights are not); and in ``ON_WAIT`` bit for bit the committed
+    fetch's output (``committed_start``, ``committed_land``), over the
+    pool as it is and over the pool with every page the row does not own
+    up to its length poisoned. ``ON_WAIT`` honours a wait described as a
+    whole slot after a DMA a page: it lands the DMAs queued on the slot's
+    semaphore until the wait's bytes are counted, so a wait that covered
+    fewer pages than were started would leave a page NaN, or another
+    block's rows, when the block is computed; and it counts no semaphore
+    left over at the kernel's exit."""
     if blocks == "blocks-of-two-pages":
         request.getfixturevalue("blocks_of_two_pages")
-    pool, q, tables, lens, R = kernel_case()
+    pool, q, tables, lens, R = kernel_case(lengths=lengths)
     want = la.paged_latent_attention_ref(q.astype(jnp.float32), pool.astype(jnp.float32), tables, lens, jnp.int32(1),
                                          scale=0.3, kv_lora_rank=R)
     got = interpreted(q, pool, tables, lens, jnp.int32(1), R)
     assert got.shape == (6, 4, R) and got.dtype == jnp.float32
     assert np.abs(np.asarray(got - want)).max() < 2e-5 and np.abs(np.asarray(want)).max() > 0.5
-    assert not np.asarray(got[0]).any()  # a row of length 0 sums nothing
+    assert not np.asarray(got)[np.asarray(lens) == 0].any()  # a row of length 0 sums nothing
     # the other layer's pages are other numbers
     assert np.abs(np.asarray(interpreted(q, pool, tables, lens, jnp.int32(0), R) - got)).max() > 0.1
     if blocks == "the-entry-s-block":  # the jitted entry in the interpreter is the same call
         entry = la.paged_latent_attention(q, pool, tables, lens, jnp.int32(1), scale=0.3, kv_lora_rank=R, interpret=True)
         assert np.abs(np.asarray(entry - want)).max() < 2e-5
+
+    with monkeypatch.context() as m:
+        m.setattr(la, "_start_pages", committed_start)
+        m.setattr(la, "_land_pages", committed_land)
+        committed = np.asarray(interpreted(q, pool, tables, lens, jnp.int32(1), R, ON_WAIT))
+    assert np.array_equal(np.asarray(interpreted(q, pool, tables, lens, jnp.int32(1), R, ON_WAIT)), committed)
+    unowned = np.asarray(interpreted(q, poisoned(pool, tables, lens), tables, lens, jnp.int32(1), R, ON_WAIT))
+    assert np.array_equal(unowned, committed)
+    assert "non-zero count" not in capsys.readouterr().out
+
+
+def test_a_pool_of_fewer_pages_than_a_table_holds_is_read_in_blocks_of_the_pool_s_pages():
+    """A pool of 4 pages and the trash page under tables of 6 entries
+    (rows share pages), at the entry's block: a block holds no more pages
+    than the pool (5 here), since a run of the pool's pages describes the
+    whole slot's wait — a bound that neither the interpreter nor the
+    chip's compiler checks for a wait."""
+    pool, q, tables, lens, R = kernel_case()
+    pool = pool[:, :5]
+    tables = jnp.asarray(np.random.default_rng(5).integers(0, 4, tables.shape), jnp.int32)
+    want = la.paged_latent_attention_ref(q.astype(jnp.float32), pool.astype(jnp.float32), tables, lens, jnp.int32(1),
+                                         scale=0.3, kv_lora_rank=R)
+    got = interpreted(q, pool, tables, lens, jnp.int32(1), R, ON_WAIT)
+    assert np.abs(np.asarray(got - want)).max() < 2e-5 and np.abs(np.asarray(want)).max() > 0.5
 
 
 def test_the_kernel_reads_the_pages_a_row_owns_up_to_its_length_alone(blocks_of_two_pages):
@@ -176,12 +259,7 @@ def test_the_kernel_reads_the_pages_a_row_owns_up_to_its_length_alone(blocks_of_
     NaN in a page past the row's last would poison the sum even under a
     zero weight). The CPU entry is the oracle."""
     pool, q, tables, lens, R = kernel_case(jnp.float32)
-    poisoned = np.full(pool.shape, np.nan, np.float32)
-    for b, n in enumerate(np.asarray(lens)):
-        for j in range(max(1, -(-int(n) // PAGE))):  # a row of length 0 is read as one of length 1
-            page = int(tables[b, j])
-            poisoned[1, page] = np.asarray(pool)[1, page]
-    got = interpreted(q, jnp.asarray(poisoned), tables, lens, jnp.int32(1), R)
+    got = interpreted(q, poisoned(pool, tables, lens), tables, lens, jnp.int32(1), R)
     want = la.paged_latent_attention(q, pool, tables, lens, jnp.int32(1), scale=0.3, kv_lora_rank=R)
     assert np.isfinite(np.asarray(got)).all() and np.abs(np.asarray(got - want)).max() < 2e-5
     # on the CPU the entry is mla.latent_attention over row_pages under the length's mask

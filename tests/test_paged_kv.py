@@ -231,6 +231,26 @@ class TestPagedKVCache:
         assert cache.stats()["free_blocks"] == 11
         cache.close()
 
+    def test_a_block_s_reservation_copies_the_table_only_when_it_takes_a_page(self, monkeypatch):
+        """The decode loop reserves a row's block ahead every block: a
+        reservation inside the pages the row owns leaves the host table as
+        it was and reads no table from the allocator; one that takes a
+        page copies the table once, and it is the allocator's."""
+        cfg = llama.LlamaConfig.tiny()
+        cache = PagedKVCache(cfg, num_pages=16, page_size=8, max_slots=2, max_seq_len=64)
+        cache.alloc_slot(1, seq_id=7, prompt_len=3)  # 1 page
+        reads = []
+        real = cache.allocator.block_table
+        monkeypatch.setattr(cache.allocator, "block_table", lambda seq: reads.append(seq) or real(seq))
+        for _ in range(6):  # 4 positions a block, reserved 2 blocks ahead
+            assert cache.try_reserve_slot(1, 8)
+            owned = real(7)
+            assert list(cache.tables[1, :len(owned)]) == owned and cache.owned_capacity(1) == len(owned) * 8
+            assert len(reads) == len(owned) - 1  # one read a page taken, none between
+            cache.advance_slot(1, 4)
+        assert cache.stats()["free_blocks"] == 12  # the last reservation reached position 31: 4 pages
+        cache.close()
+
 
 class TestPagedDecodeParity:
     def test_paged_decode_matches_dense_path(self):
