@@ -38,7 +38,10 @@ step appends with ``ops/paged_attention.paged_kv_append``, scores the
 whole context with ``ops/mla.paged_index_scores`` and reads only the
 selected rows with ``ops/mla.sparse_decode_attention``. A chunk of a
 prompt runs one row at a time under a ``cond``: rows without a chunk cost
-nothing, and a row's scores ([heads, chunk, context]) fit.
+nothing, and a row's scores ([heads, chunk, context]) fit. Its reads,
+index scores, selection and attention stop at the pages up to the
+chunk's end: the context is bucketed (the chunk's length doubling up to
+the slot, :func:`chunk_contexts`, one branch of a ``switch`` each).
 
 Without an indexer ``S_t`` is every position ``u <= t``, a token caches
 the latent row alone and the engine keeps ONE pool (:func:`page_shapes`
@@ -47,9 +50,8 @@ step appends with ``paged_kv_append`` and reads every cached row of the
 row through ``ops/latent_attention.paged_latent_attention``, one Mosaic
 call a layer; it counts the positions read and the live rows (``mla_kv``,
 ``mla_rows``: :func:`step_stats`). A chunk runs one row at a time
-as above, its attention over the pages up to the chunk's end only: the
-context is bucketed (the chunk's length doubling up to the slot, one
-branch of a ``switch`` each), and its head runs at the row's last token.
+over its bucket of the context as above, and its head runs at the row's
+last token.
 
 Not served, each stated in the benchmark configurations' ``assumed``: the
 indexer's Hadamard rotation (orthogonal on both sides of a dot product)
@@ -79,6 +81,7 @@ from gofr_tpu.ops.rope import (
 __all__ = [
     "DeepseekV32Config", "KVCache", "init_params", "quantize_params", "prefill",
     "decode_step_paged", "decode_chunk_paged", "step_stats_len", "step_stats", "page_shapes", "unserved", "prefill_slabs",
+    "chunk_contexts",
 ]
 
 
@@ -543,61 +546,84 @@ def _chunk_row(cfg: DeepseekV32Config, params: dict, tokens: jnp.ndarray, positi
                k_pool: jnp.ndarray, v_pool: jnp.ndarray) -> tuple:
     """One row's chunk of T tokens in the absorbed form: its rows and keys
     written through its table, then every chunk position against the
-    row's gathered pages under the selection's mask — without an indexer,
-    against the pages up to the chunk's end (:func:`_bounded_attention`)."""
+    row's pages up to the chunk's end (:func:`_bounded_attention`), under
+    the selection's mask where the model has an indexer."""
     T = tokens.shape[0]
-    page = k_pool.shape[3]
     x = params["embedding"][jnp.maximum(tokens, 0)][None].astype(jnp.float32)  # [1, T, D]
     sin, cos = _angles(cfg, positions[None])
-    ctx = jnp.arange(table.shape[0] * page)
-    seen = (ctx[None, :] <= positions[:, None]) & (ctx[None, :] < start + T)  # [T, S]
 
     def attend(lp, layer, h, pools):
         kp, vp = pools
         q_nope, q_rope, row, qi, ki, wi = _project(cfg, h, lp, sin, cos)
         kp = kp.at[layer, pages, 0, offsets].set(row[0])
-        if not cfg.index_topk:
-            q = _absorb_query(cfg, q_nope[0], q_rope[0], lp["wkv_b"])
-            o_lat = _bounded_attention(cfg, q, kp, table, layer, positions, start + T)
-            return _absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[None], (kp, vp), jnp.zeros(2, jnp.int32)
-        vp = vp.at[layer, pages, 0, offsets].set(ki[0])
-        rows, keys = mla.row_pages(kp, table[None], layer)[0], mla.row_pages(vp, table[None], layer)[0]
-        keep = mla.selection_mask(mla.index_scores(qi[0], keys, wi[0]), seen, cfg.index_topk)
+        index = None
+        if cfg.index_topk:
+            vp = vp.at[layer, pages, 0, offsets].set(ki[0])
+            index = (vp, qi[0], wi[0])
         q = _absorb_query(cfg, q_nope[0], q_rope[0], lp["wkv_b"])
-        o_lat = mla.latent_attention(q, rows, keep, cfg.softmax_scale, cfg.kv_lora_rank)
-        live = (tokens >= 0)[:, None]
-        return (_absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[None], (kp, vp),
-                _count(seen & live, keep & live))
+        o_lat = _bounded_attention(cfg, q, kp, table, layer, positions, start + T, index)
+        return _absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[None], (kp, vp), jnp.zeros(2, jnp.int32)
 
     x, (k_pool, v_pool), _ = _run_layers(cfg, params, x, (k_pool, v_pool), attend, (tokens >= 0)[None])
-    # the head at the row's last token alone: [1, V]. Keyed on the indexer
-    # only so that V3.2's lowered programs keep their digests (ROADMAP S16)
+    # without an indexer the head runs at the row's last token alone: [1, V];
+    # V3.2's chunk still runs it at every position (ROADMAP S16)
     if not cfg.index_topk:
         last = jnp.maximum(jnp.sum(tokens >= 0) - 1, 0)
         return _logits(cfg, params, jax.lax.dynamic_index_in_dim(x[0], last, 0)), k_pool, v_pool
     return _logits(cfg, params, x)[0], k_pool, v_pool
 
 
-def _bounded_attention(cfg: DeepseekV32Config, q: jnp.ndarray, k_pool: jnp.ndarray, table: jnp.ndarray,
-                       layer: jnp.ndarray, positions: jnp.ndarray, end: jnp.ndarray) -> jnp.ndarray:
-    """A chunk's queries q [T, H, W] against the row's latent rows up to
-    ``end``, the chunk's end, under the causal mask: the context read is
-    the smallest of T, 2T, 4T ... (whole pages; the last the slot) that
-    holds ``end``, one branch of a ``switch`` each, so a chunk near the
-    start of a long slot reads and scores its own few pages and not the
-    slot. Returns o_lat [T, H, kv_lora_rank] float32."""
-    page = k_pool.shape[3]
-    slot = table.shape[0] * page
-    bounds, n = [], -(-q.shape[0] // page) * page
+def chunk_contexts(T: int, page: int, slot: int) -> tuple[int, ...]:
+    """The contexts a chunk of T tokens may read in a slot of ``slot``
+    positions: T in whole pages, doubling, the last the slot. The chunk
+    reads the first that holds its end (``_bounded_attention``'s
+    branches); the engine mirrors that choice as ``chunk_ctx`` on the
+    ragged dispatch's span."""
+    bounds, n = [], -(-T // page) * page
     while n < slot:
         bounds.append(n)
         n *= 2
-    bounds.append(slot)
+    return (*bounds, slot)
+
+
+def _chunk_keep(cfg: DeepseekV32Config, positions: jnp.ndarray, end: jnp.ndarray, n: int,
+                scores: jnp.ndarray | None = None) -> jnp.ndarray:
+    """Which of the first n positions each chunk query attends [T, n]:
+    those at or before it — under an indexer, of those before the chunk's
+    ``end`` the ``index_topk`` best by ``scores`` [T, n]. Positions past
+    ``end`` are never seen, so this is the selection over the whole slot
+    cut to its first n positions, bit for bit."""
+    keep = jnp.arange(n)[None, :] <= positions[:, None]
+    if not cfg.index_topk:
+        return keep
+    seen = keep & (jnp.arange(n)[None, :] < end)
+    return mla.selection_mask(scores, seen, cfg.index_topk)
+
+
+def _bounded_attention(cfg: DeepseekV32Config, q: jnp.ndarray, k_pool: jnp.ndarray, table: jnp.ndarray,
+                       layer: jnp.ndarray, positions: jnp.ndarray, end: jnp.ndarray,
+                       index: tuple | None = None) -> jnp.ndarray:
+    """A chunk's queries q [T, H, W] against the row's latent rows up to
+    ``end``, the chunk's end, under the causal mask: the context read is
+    the first of :func:`chunk_contexts` that holds ``end``, one branch of
+    a ``switch`` each, so a chunk near the start of a long slot reads and
+    scores its own few pages and not the slot. With ``index`` (the key
+    pool and the chunk's indexer queries [T, Hi, Di] and head weights
+    [T, Hi]) the branch also reads that many positions of indexer keys,
+    scores them and attends to the selection alone. Returns o_lat [T, H,
+    kv_lora_rank] float32."""
+    page = k_pool.shape[3]
+    bounds = chunk_contexts(q.shape[0], page, table.shape[0] * page)
 
     def over(n: int) -> Any:
         def attend() -> jnp.ndarray:
             rows = mla.row_pages(k_pool, table[None, :n // page], layer)[0]  # [n, W]
-            keep = jnp.arange(n)[None, :] <= positions[:, None]
+            scores = None
+            if index is not None:
+                v_pool, qi, wi = index
+                keys = mla.row_pages(v_pool, table[None, :n // page], layer)[0]  # [n, Di]
+                scores = mla.index_scores(qi, keys, wi)
+            keep = _chunk_keep(cfg, positions, end, n, scores)
             return mla.latent_attention(q, rows, keep, cfg.softmax_scale, cfg.kv_lora_rank)
         return attend
 

@@ -601,6 +601,9 @@ class ServingEngine:
         # last position alone: its prefill spans say how many positions
         # each half ran, and an admission resets a recurrent state
         self._upper_on_last = bool(getattr(model, "CHUNK_TAKES_FINISH", False))
+        # a model whose chunk reads only the context that holds its end:
+        # the contexts it may read, mirrored as chunk_ctx on ragged spans
+        self._chunk_contexts = getattr(model, "chunk_contexts", None)
         if self.config.spec_tokens < 0:
             raise ValueError("TPU_SPEC_TOKENS must be >= 0")
         if (self.config.multi_step is not None and self.config.multi_step > 1
@@ -3662,6 +3665,8 @@ class ServingEngine:
                 self._count_prefill_positions(
                     span, chunk_tokens, sum(1 for row in prefill_rows if row[5]),
                     resets=sum(1 for _, _, _, start_pos, _ in chunk_rows if start_pos == 0))
+            if chunk_rows and pc is not None and self._chunk_contexts is not None:
+                span.set(chunk_ctx=self._chunk_ctx(pc, chunk_rows))
             topk = getattr(cfg, "index_topk", None)
             if topk:  # rows whose sparse selection binds: attention reads index_topk of them
                 span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))
@@ -3679,6 +3684,15 @@ class ServingEngine:
             packed, rows, t0, steps=N, blk=self._blk_seq,
             prefill_rows=prefill_rows, last_logits=keep_logits,
         )
+
+    def _chunk_ctx(self, pc: Any, chunk_rows: list) -> int:
+        """The positions the dispatch's chunk rows read, summed: each the
+        first of the model's ``chunk_contexts`` that holds the row's chunk
+        end, by the program's own test of ``start + T``."""
+        C = self._chunk_tokens
+        contexts = self._chunk_contexts(C, pc.page_size, pc.max_pages_per_seq * pc.page_size)
+        return sum(contexts[sum(n < start_pos + C for n in contexts[:-1])]
+                   for _, _, _, start_pos, _ in chunk_rows)
 
     def _dispatch_rows(self, plan: StepPlan | None) -> tuple[list, list]:
         """The next block's rows (gofr.step.dispatch.rows): the slots that

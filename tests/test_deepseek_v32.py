@@ -160,16 +160,88 @@ def test_prefill_then_decode_with_a_binding_selection_agrees_with_the_reference(
     assert (counted[:, 17] == 3 * np.arange(13, 41)).all() and (counted[:, 18] == 3 * 8).all()
 
 
-@pytest.mark.parametrize("chunk", [12, 6], ids=["chunks-of-12-the-loop-over-every-row", "chunks-of-6-the-grouped-product"])
+@pytest.mark.parametrize("chunk, n", [
+    pytest.param(12, 40, id="chunks-of-12-the-loop-over-every-row"),
+    pytest.param(6, 40, id="chunks-of-6-the-grouped-product"),
+    pytest.param(12, 60, id="chunks-of-12-to-the-slot"),
+    pytest.param(8, 60, id="chunks-of-8-to-the-slot"),
+])
 @pytest.mark.parametrize("weights", ["plain", "int8"])
-def test_chunked_prefill_agrees_with_the_reference(weights, chunk, request):
+def test_chunked_prefill_agrees_with_the_reference(weights, chunk, n, request):
+    """A chunk reads the first of its contexts (``ds.chunk_contexts``:
+    12, 24, 48, 64 for chunks of 12; 8, 16, 32, 64 for 6 and 8) that holds
+    its end. Every one is taken by some case, the 8-position one no wider
+    than ``index_topk`` (the selection keeps every seen position), the
+    others with a selection that binds."""
     params = request.getfixturevalue(weights)
-    ids = ids_of(40, seed=4)
+    ids = ids_of(n, seed=4)
     want = np.asarray(reference.logits(as_file(CFG), params, ids))
     # 12 rows x 4 of 16 experts is 3 rows an expert: the loop; 6 rows is 1.5: each expert takes its own rows
     assert moe_ops.groups_rows(chunk, CFG.n_experts, CFG.top_k) is (chunk == 6)
+    contexts = ds.chunk_contexts(chunk, PAGE, 64)
+    read = {next(c for c in contexts if c >= start + chunk) for start in range(0, n, chunk)}
+    assert n < 60 or read == set(contexts)  # 60 tokens reach the slot: every context is read
     got = chunked(CFG, params, ids, chunk)  # chunks at 0, 12, 24, 36 (or every 6): selections reach across them
     assert np.abs(got - want).max() < TOL
+
+
+def test_the_selection_over_a_chunks_context_is_the_one_over_the_slot():
+    """For a chunk at every start, the selection over the context it reads
+    is the selection over the whole slot (the form a chunk had before it
+    read a context, kept here as the oracle) cut to that context, bit for
+    bit: nothing past the chunk's end is seen, and ties (scores drawn from
+    seven values, zero among them) break alike."""
+    T, slot = 12, 64
+    contexts = ds.chunk_contexts(T, PAGE, slot)
+    scores = jnp.asarray(np.random.default_rng(5).integers(-3, 4, (T, slot)).astype(np.float32))
+    ctx = jnp.arange(slot)
+    for start in range(0, slot, T):
+        positions, end = start + jnp.arange(T), jnp.asarray(start + T)
+        seen = (ctx[None, :] <= positions[:, None]) & (ctx[None, :] < end)
+        oracle = np.asarray(mla.selection_mask(scores, seen, CFG.index_topk))
+        for n in (c for c in contexts if c >= min(start + T, slot)):
+            got = np.asarray(ds._chunk_keep(CFG, positions, end, n, scores[:, :n]))
+            assert (got == oracle[:, :n]).all() and not oracle[:, n:].any(), (start, n)
+        assert oracle.sum(axis=1).max() == min(CFG.index_topk, start + T)
+
+
+def poisoned(pool, table, first, last=None):
+    """The pool with a row's pages from position ``first`` (to ``last``) NaN."""
+    return pool.at[:, np.asarray(table)[first // PAGE:None if last is None else last // PAGE]].set(jnp.nan)
+
+
+def one_chunk(params, ids, start, kp, vp, tables):
+    """``ids[start:start+12]`` as row 1's chunk over the given pools."""
+    piece = np.full((3, 12), -1, np.int32)
+    piece[1] = ids[start:start + 12]
+    logits, kp, vp = ds.decode_chunk_paged(
+        CFG, params, jnp.asarray(piece), kp, vp, tables, jnp.asarray([64, start, 64]),
+        jnp.asarray([False, True, False]), jnp.asarray([0, 64, 0]))
+    return np.asarray(logits[1]), kp, vp
+
+
+def filled(seed=9):
+    """Both pools of three rows filled with finite values: a context."""
+    kp, vp, tables = paged(CFG, 3, 16)
+    kp = jax.random.normal(jax.random.PRNGKey(seed), kp.shape, kp.dtype)
+    vp = jax.random.normal(jax.random.PRNGKey(seed + 1), vp.shape, vp.dtype)
+    return kp, vp, tables
+
+
+@pytest.mark.parametrize("start", [0, 12, 24, 36])
+def test_a_chunk_reads_the_pages_up_to_its_end_alone(plain, start):
+    """Both pools' pages of the row past the context that holds the
+    chunk's end (12, 24, 48, 48 positions) are NaN: the chunk's logits and
+    every page it may read or write come out as with clean pools."""
+    n = next(c for c in ds.chunk_contexts(12, PAGE, 64) if c >= start + 12)
+    ids = ids_of(48)
+    kp, vp, tables = filled()
+    got, kg, vg = one_chunk(plain, ids, start, poisoned(kp, tables[1], n), poisoned(vp, tables[1], n), tables)
+    clean, kc, vc = one_chunk(plain, ids, start, kp, vp, tables)  # the pools are donated: clean last
+    assert np.isfinite(got).all() and np.abs(got - clean).max() == 0.0
+    held = np.asarray(tables[1])[:n // PAGE]
+    for a, b in ((kc, kg), (vc, vg)):
+        assert np.array_equal(np.asarray(a[:, held]), np.asarray(b[:, held]))
 
 
 def test_the_int4_control_fails_the_same_tolerance(int8):
@@ -419,6 +491,28 @@ def test_engines_the_model_has_no_program_for_are_refused_at_construction(plain,
         ServingEngine(CFG, plain, engine_settings(**settings), ByteTokenizer(300), lora=lora)
 
 
+@pytest.mark.parametrize("start", [0, 12, 24, 36, 48])
+def test_the_engine_mirrors_the_context_a_chunk_reads(plain, start):
+    """The engine's ``chunk_ctx`` for a chunk row is the context the
+    program read: NaN from there on leaves the chunk's logits finite, and
+    NaN between the chunk's end and there (where they differ) reaches
+    them, so the program read that far and no further."""
+    engine = ServingEngine(CFG, plain, engine_settings(kv_page_size=PAGE, prefill_chunk_tokens=12), ByteTokenizer(300))
+    try:
+        n = engine._chunk_ctx(engine.paged_cache, [(1, None, None, start, 12)])
+    finally:
+        engine.stop()
+    assert n in ds.chunk_contexts(12, PAGE, 64) and n >= min(start + 12, 64)
+    ids = ids_of(60)
+    kp, vp, tables = filled()  # the pools are donated: each call takes its own
+    beyond, *_ = one_chunk(plain, ids, start, poisoned(kp, tables[1], n), poisoned(vp, tables[1], n), tables)
+    assert np.isfinite(beyond).all()
+    if start + 12 < n:
+        kp, vp, tables = filled()
+        inside, *_ = one_chunk(plain, ids, start, poisoned(kp, tables[1], start + 12, n), vp, tables)
+        assert np.isnan(inside).any()
+
+
 def test_the_seam_finds_the_module_and_its_counters():
     # 16 held experts' rows, the held experts read, the indexer's two
     assert batch_ops.model_of(CFG) is ds and ds.step_stats_len(CFG) == 16 + 1 + 2
@@ -429,7 +523,8 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters
     """POST /generate and the SSE route through a real App, a bucketed and
     a chunked prompt: the tokens are the reference's greedy choice, the
     commit spans carry ``dsa_scored``, ``dsa_selected``, ``moe_rows``,
-    ``moe_max`` and ``moe_reached``, the dispatch spans ``dsa_rows``, and /metrics counts the
+    ``moe_max`` and ``moe_reached``, the dispatch spans ``dsa_rows`` and, on
+    a chunk's, ``chunk_ctx``, and /metrics counts the
     positions by kind and the rows by expert."""
     import gofr_tpu
     from gofr_tpu.config import MapConfig
@@ -500,6 +595,9 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters
     assert all(kw["dsa_selected"] * 2 * CFG.top_k <= kw["moe_rows"] * 3 * CFG.index_topk for kw in commits)
     bound = [kw["dsa_rows"] for phase, kw in seen if phase == "dispatch" and "dsa_rows" in kw]
     assert bound and max(bound) >= 1  # rows decode past 8 positions
+    # the chunked prompt's three chunks (16 tokens, 8 a page, 64 a slot) end at 16, 32, 48: they read 16, 32, 64
+    ctx = [kw["chunk_ctx"] for phase, kw in seen if phase == "dispatch" and "chunk_ctx" in kw]
+    assert ds.chunk_contexts(16, 8, 64) == (16, 32, 64) and ctx and sorted(set(ctx)) == [16, 32, 64], ctx
     for name, key, label in (("app_moe_expert_rows_total", "moe_rows", 'expert="'),
                              ("app_moe_experts_read_total", "moe_reached", ""),
                              ("app_dsa_positions_total", None, 'kind="')):

@@ -462,7 +462,8 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_counters(plain, mo
     prompt: the tokens are the reference's greedy choice, and the commit
     spans carry ``mla_kv`` and ``mla_rows`` (the positions attention read
     and the live rows, over the 3 layers) beside ``moe_rows``, ``moe_max``
-    and ``moe_reached``, and no indexer's counters."""
+    and ``moe_reached``, and no indexer's counters; the chunks' dispatch
+    spans carry ``chunk_ctx``, the context each chunk read."""
     import gofr_tpu
     from gofr_tpu.config import MapConfig
     from gofr_tpu.serving import engine as engine_mod
@@ -516,6 +517,9 @@ def test_the_model_is_served_behind_an_app_over_http_with_its_counters(plain, mo
     assert all(kw["mla_rows"] % 3 == 0 and kw["moe_rows"] == kw["mla_rows"] // 3 * 2 * CFG.top_k for kw in commits)
     assert all(kw["mla_rows"] <= kw["mla_kv"] <= kw["mla_rows"] * 64 for kw in commits)
     assert sum(kw["mla_rows"] for kw in commits) > 0
+    # the chunked prompt's three chunks (16 tokens, 8 a page, 64 a slot) read 16, 32 and 64 positions
+    ctx = [kw["chunk_ctx"] for phase, kw in seen if phase == "dispatch" and "chunk_ctx" in kw]
+    assert sorted(ctx) == [16, 32, 64], ctx
 
 
 JOYAI_DIGESTS = {
