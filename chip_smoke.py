@@ -10,8 +10,7 @@ seed. It drives the main path once through the entry points a user calls —
 ``ServingEngine`` -> ``StepPlanner`` -> ``serving/batch.py`` -> ``ops/`` — at
 the full published widths of Llama-3-8B (``LlamaConfig()``: 32 layers,
 d 4096, 32/8 heads x 128, ff 14336, vocab 128256; int8 weights, 8.56 GB),
-once in the default engine configuration (dense bf16 KV) and once paged,
-over HTTP, SSE and gRPC, with prompts that take all three prefill routes.
+in the default engine configuration (bf16 KV in the page pool), over HTTP, SSE and gRPC, with prompts that take all three prefill routes.
 Every Pallas kernel is compiled by Mosaic at those head shapes and compared
 with its reference. Any failed check raises: the run ends non-zero and
 prints no result line. Without a TPU it fails at once.
@@ -30,7 +29,6 @@ from __future__ import annotations
 
 import argparse
 import concurrent.futures
-import gc
 import json
 import sys
 import threading
@@ -47,7 +45,7 @@ NEW_TOKENS = 12
 PROMPTS = {
     "short": "hello chip",       # 11 tokens -> bucket 32: dense attention
     "mid": "m" * 200,            # 201 tokens -> bucket 256: flash kernel
-    "long": "l" * 400,           # 401 tokens > 256: chunk cursors + ragged_step*
+    "long": "l" * 400,           # 401 tokens > 256: chunk cursors + ragged_step_paged
 }
 MOSAIC_CALL = "tpu_custom_call"  # what a Mosaic-compiled pallas_call lowers to
 
@@ -234,21 +232,14 @@ def attention_paths(engine: Any, self_test: bool) -> dict[str, int]:
     # decode_active, steps
     row = (vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(i32), vec(f32),
            vec(i32), vec(f32), vec(i32), key, vec(jnp.bool_), steps)
-    if engine.cache is not None:
-        cache = ab(engine.cache)
-        paths["decode_block"] = calls(batch_ops.decode_block.lower(
-            cfg, params, cache, state, vec(jnp.bool_), steps))
-        paths["ragged_step"] = calls(batch_ops.ragged_step.lower(
-            cfg, params, cache, state, vec(i32, B, C), *row))
-    else:
-        pc = engine.paged_cache
-        kp, vp = ab(pc.k_pool), ab(pc.v_pool)
-        tables = vec(i32, B, pc.max_pages_per_seq)
-        paths["decode_block_paged"] = calls(batch_ops.decode_block_paged.lower(
-            cfg, params, kp, vp, state, tables, vec(jnp.bool_), steps))
-        paths["ragged_step_paged"] = calls(batch_ops.ragged_step_paged.lower(
-            cfg, params, kp, vp, state, tables, vec(i32, B, C), vec(i32),
-            vec(jnp.bool_), *row))
+    pc = engine.paged_cache
+    kp, vp = ab(pc.k_pool), ab(pc.v_pool)
+    tables = vec(i32, B, pc.max_pages_per_seq)
+    paths["decode_block_paged"] = calls(batch_ops.decode_block_paged.lower(
+        cfg, params, kp, vp, state, tables, vec(jnp.bool_), steps))
+    paths["ragged_step_paged"] = calls(batch_ops.ragged_step_paged.lower(
+        cfg, params, kp, vp, state, tables, vec(i32, B, C), vec(i32),
+        vec(jnp.bool_), *row))
     for name, n in paths.items():
         say(f"  attention path: {name}: "
             + (f"Mosaic kernel ({n} custom call)" if n else "XLA attention (no kernel)"))
@@ -256,9 +247,8 @@ def attention_paths(engine: Any, self_test: bool) -> dict[str, int]:
         check(paths["prefill bucket 256"] > 0, "flash bucket lowered without the Mosaic kernel")
         check(paths["prefill bucket 32"] == 0, "bucket 32 should take dense attention")
         for name in ("decode_block_paged", "ragged_step_paged"):
-            if name in paths:
-                check(paths[name] > 0,
-                      f"{name} lowered to the gather reference, not the Mosaic kernel")
+            check(paths[name] > 0,
+                  f"{name} lowered to the gather reference, not the Mosaic kernel")
     else:
         check(not any(paths.values()), "a CPU program carries a TPU custom call")
     return paths
@@ -366,8 +356,7 @@ def call_grpc(target: str, name: str, vocab: int) -> dict:
 
 
 # ------------------------------------------------------------ one App, served
-def serve_and_check(label: str, cfg: Any, params: Any, engine_config: Any,
-                    self_test: bool) -> None:
+def serve_and_check(cfg: Any, params: Any, self_test: bool) -> None:
     """Boot a real App exactly as examples/serving-llama/main.py does (plus
     the gRPC service and the tpu datasource), answer requests over all
     three wires, read health and metrics, shut it down."""
@@ -377,7 +366,7 @@ def serve_and_check(label: str, cfg: Any, params: Any, engine_config: Any,
     from gofr_tpu.config import MapConfig
     from gofr_tpu.datasource.tpu import TPUClient
     from gofr_tpu.grpcx import InferenceService
-    from gofr_tpu.serving import ByteTokenizer, DeviceTelemetry, ServingEngine
+    from gofr_tpu.serving import ByteTokenizer, DeviceTelemetry, EngineConfig, ServingEngine
     from gofr_tpu.serving.handlers import register_generation_routes
     from gofr_tpu.testutil import get_free_port
 
@@ -386,13 +375,13 @@ def serve_and_check(label: str, cfg: Any, params: Any, engine_config: Any,
     http_port, metrics_port, grpc_port = (get_free_port() for _ in range(3))
     config = MapConfig({
         "HTTP_PORT": str(http_port), "METRICS_PORT": str(metrics_port),
-        "GRPC_PORT": str(grpc_port), "APP_NAME": f"chip-smoke-{label}",
+        "GRPC_PORT": str(grpc_port), "APP_NAME": "chip-smoke",
         "LOG_LEVEL": "WARN",
     }, use_env=False)
     app = gofr_tpu.App(config)
     app.add_tpu(TPUClient.from_config(config))
     engine = ServingEngine(
-        cfg, params, engine_config, ByteTokenizer(cfg.vocab_size),
+        cfg, params, EngineConfig(), ByteTokenizer(cfg.vocab_size),
         metrics=app.container.metrics_manager, logger=app.container.logger,
         tracer=app.container.tracer, seed=SEED,
     )
@@ -424,7 +413,7 @@ def serve_and_check(label: str, cfg: Any, params: Any, engine_config: Any,
         for name in PROMPTS:
             t = time.monotonic()
             call_http(base, name, vocab)
-            say(f"  [{label}] warm-up {name:5s} ({len(PROMPTS[name]) + 1} tokens): "
+            say(f"  warm-up {name:5s} ({len(PROMPTS[name]) + 1} tokens): "
                 f"{time.monotonic() - t:.1f}s (compilation included)")
         setup_s = time.monotonic() - t_setup
 
@@ -439,7 +428,7 @@ def serve_and_check(label: str, cfg: Any, params: Any, engine_config: Any,
             results = [f.result(timeout=900) for f in futures]
         request_s = time.monotonic() - t
         for r in results:
-            say(f"  [{label}] {r['wire']:4s} {r['prompt']:5s} ttft {r['ttft_ms']:.0f} ms, "
+            say(f"  {r['wire']:4s} {r['prompt']:5s} ttft {r['ttft_ms']:.0f} ms, "
                 f"done in {r['wall_ms']:.0f} ms")
 
         # health and metrics, through the App's own endpoints (the tpu
@@ -463,7 +452,7 @@ def serve_and_check(label: str, cfg: Any, params: Any, engine_config: Any,
             check(used, "no app_tpu_hbm_used_bytes > 0 in /metrics")
             check(tpu["native_pjrt"] and "pjrt_c_api" in tpu["native_pjrt"],
                   f"native PJRT binding did not load: {tpu['native_pjrt']}")
-        say(f"[{label}] platform={platform} kv_layout={engine_config.kv_layout} "
+        say(f"platform={platform} "
             f"native_runtime={serving['scheduler_backend']} set-up {setup_s:.1f}s "
             f"requests {request_s:.2f}s "
             + ("(CPU self-test: no device numbers)" if self_test else
@@ -599,14 +588,7 @@ def main(argv: list[str]) -> int:
     if args.replicas > 1:
         replicas_and_check(cfg, params, args.replicas, args.cpu_self_test)
     else:
-        # the default engine configuration, and the paged layout — dense
-        # never reaches ops/paged_attention.py
-        for label, engine_config in (
-            ("dense", EngineConfig()),
-            ("paged", EngineConfig(kv_layout="paged")),
-        ):
-            serve_and_check(label, cfg, params, engine_config, args.cpu_self_test)
-            gc.collect()  # the stopped engine's KV leaves the chip before the next one
+        serve_and_check(cfg, params, args.cpu_self_test)
 
     result: dict[str, Any] = {"ok": True}
     if args.cpu_self_test:

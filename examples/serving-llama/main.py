@@ -2,7 +2,7 @@
 KV and optional speculative decoding, behind /generate
 (JSON + SSE streaming) and /v1/models.
 
-Environment knobs (all optional): TPU_KV_LAYOUT=paged,
+Environment knobs (all optional): TPU_KV_PAGE_SIZE=16,
 TPU_SPEC_TOKENS=6, TPU_BATCH_MAX_SLOTS, ... (serving/engine.py
 EngineConfig.from_config). Swap init_params for
 ServingEngine.from_hf("/path/to/llama") to serve real weights."""

@@ -78,7 +78,7 @@ C++ TUs already run under ASan/UBSan/TSan (``make native-asan`` /
   ``--check-kernel-table`` verifies a runtime export against it.
 - :mod:`gofr_tpu.analysis.kerneltrace` — the runtime twin:
   ``jax.eval_shape``\\ s every contract entry across the config matrix
-  (dense/paged/quantized x base/LoRA x plain/ragged/spec) with zero
+  (base/LoRA x plain/ragged/spec over the page pools) with zero
   device execution, and a live-engine observer that records real
   dispatch signatures — both exports feed ``--check-kernel-table``.
 - :mod:`gofr_tpu.analysis.sarif` — SARIF 2.1.0 output for the unified

@@ -6,10 +6,11 @@ signatures.
 
 Everything the data plane trusts implicitly lives here explicitly:
 
-- ``decode_block*`` returns ONE packed ``int32 [B, steps+2]`` array
-  (tokens | done | n_valid — :func:`batch._pack_block`); ``ragged_step*``
-  appends a ``first`` column ([B, steps+3] — ``_pack_ragged``);
-  ``verify_and_sample*`` packs (out | n_accept) into ``[B, T+1]``. The
+- ``decode_block_paged`` returns ONE packed ``int32 [B, steps+2]`` array
+  (tokens | done | n_valid — :func:`batch._pack_block`);
+  ``ragged_step_paged`` appends a ``first`` column ([B, steps+3] —
+  ``_pack_ragged``); ``verify_and_sample_paged`` packs (out | n_accept)
+  into ``[B, T+1]``. The
   host unpack sites (``engine._consume_block``, ``engine._spec_step``)
   slice these columns by offset — a kernel-side pack edit without a
   matching unpack edit silently mis-binds ``done``/``n_valid``/``first``.
@@ -81,13 +82,13 @@ class PackedLayout:
 PACK_LAYOUTS: dict[str, PackedLayout] = {
     l.name: l
     for l in (
-        # decode_block*: _pack_block — [B, steps+2]
+        # decode_block_paged: _pack_block — [B, steps+2]
         PackedLayout("block", "steps", "tokens", ("done", "n_valid")),
-        # ragged_step*: _pack_ragged — [B, steps+3]
+        # ragged_step_paged: _pack_ragged — [B, steps+3]
         PackedLayout(
             "ragged", "steps", "tokens", ("done", "n_valid", "first")
         ),
-        # verify_and_sample*: inline concat — [B, T+1]
+        # verify_and_sample_paged: inline concat — [B, T+1]
         PackedLayout("spec", "T", "out", ("n_accept",)),
     )
 }
@@ -144,7 +145,7 @@ _KVC = "gofr_tpu/serving/kv_cache.py"
 _PAGED_ATTN = "gofr_tpu/ops/paged_attention.py"
 _FLASH = "gofr_tpu/ops/flash_attention.py"
 
-# The per-row sampling-parameter tail shared by the ragged entries.
+# The per-row sampling-parameter tail of the ragged entry.
 _RAGGED_TAIL = (
     "finish", "new_len", "budgets", "stops", "temps", "topks", "topps",
     "rids", "rng_root", "decode_active", "steps", "adapters", "lora",
@@ -163,12 +164,6 @@ KERNELS: tuple[KernelContract, ...] = (
         arg_shapes=(("tokens", "_,S"),),
     ),
     KernelContract(
-        "insert_slot", _BATCH,
-        params=("k_cache", "v_cache", "k_slab", "v_slab", "slot"),
-        donated=("k_cache", "v_cache"),
-        returns=(Ret("k_cache", like="k_cache"), Ret("v_cache", like="v_cache")),
-    ),
-    KernelContract(
         "admit_decode_state", _BATCH,
         params=(
             "state", "slots", "tokens", "lens", "budgets", "stops",
@@ -176,20 +171,6 @@ KERNELS: tuple[KernelContract, ...] = (
         ),
         donated=("state",),
         returns=(Ret("state", like="state"),),
-    ),
-    KernelContract(
-        "decode_block", _BATCH,
-        params=("cfg", "params", "cache", "state", "active", "steps", "lora"),
-        donated=("cache", "state"),
-        static=("cfg", "steps"),
-        packed="block",
-        pack_helper="_pack_block",
-        returns=(
-            Ret("packed", shape="B,steps+2", dtype="int32"),
-            Ret("cache", like="cache"),
-            Ret("state", like="state"),
-        ),
-        arg_shapes=(("active", "B"),),
     ),
     KernelContract(
         "decode_block_paged", _BATCH,
@@ -210,23 +191,6 @@ KERNELS: tuple[KernelContract, ...] = (
         arg_shapes=(("active", "B"),),
     ),
     KernelContract(
-        "ragged_step", _BATCH,
-        params=(
-            "cfg", "params", "cache", "state", "chunk", "chunk_start",
-        ) + _RAGGED_TAIL,
-        donated=("cache", "state"),
-        static=("cfg", "steps"),
-        packed="ragged",
-        pack_helper="_pack_ragged",
-        returns=(
-            Ret("packed", shape="B,steps+3", dtype="int32"),
-            Ret("last_logits", shape="B,V", dtype="float32"),
-            Ret("cache", like="cache"),
-            Ret("state", like="state"),
-        ),
-        arg_shapes=(("chunk", "B,C"),),
-    ),
-    KernelContract(
         "ragged_step_paged", _BATCH,
         params=(
             "cfg", "params", "k_pool", "v_pool", "state", "block_tables",
@@ -244,28 +208,6 @@ KERNELS: tuple[KernelContract, ...] = (
             Ret("state", like="state"),
         ),
         arg_shapes=(("chunk", "B,C"),),
-    ),
-    KernelContract(
-        "insert_chunk", _BATCH,
-        params=("k_cache", "v_cache", "k_slab", "v_slab", "slot", "start"),
-        donated=("k_cache", "v_cache"),
-        returns=(Ret("k_cache", like="k_cache"), Ret("v_cache", like="v_cache")),
-    ),
-    KernelContract(
-        "verify_and_sample", _BATCH,
-        params=(
-            "cfg", "params", "cache", "chunk", "start_len", "temperature",
-            "top_k", "top_p", "rng",
-        ),
-        donated=("cache",),
-        static=("cfg",),
-        packed="spec",
-        returns=(
-            Ret("packed", shape="B,T+1", dtype="int32"),
-            Ret("cache", like="cache"),
-            Ret("rng", like="rng"),
-        ),
-        arg_shapes=(("chunk", "B,T"),),
     ),
     KernelContract(
         "verify_and_sample_paged", _BATCH,

@@ -1,6 +1,6 @@
 """kernelcheck — device-contract analysis for the jitted kernel layer.
 
-The serving data plane runs on unwritten contracts: ``decode_block*``
+The serving data plane runs on unwritten contracts: ``decode_block_paged``
 returns ONE packed ``int32 [B, steps+2]`` array whose columns the host
 slices by offset, the donated ``DecodeState`` carry is constructed at
 three independent sites that must agree field-for-field, and every

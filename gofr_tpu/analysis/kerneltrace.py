@@ -3,8 +3,8 @@
 Two producers, one consumer:
 
 - :func:`run_matrix` ``jax.eval_shape``\\ s EVERY contract-table entry
-  across the config matrix (dense / paged caches x
-  base / LoRA x plain / ragged / speculative x B,N variants) and exports
+  across the config matrix (base / LoRA x plain / ragged / speculative
+  x B,N variants, over the page pools) and exports
   the observed (pytree, shape, dtype) signatures. Everything abstract is
   passed as an eval_shape ARGUMENT (``ShapeDtypeStruct`` pytrees); only
   true statics (the config dataclass, ``steps`` ints) are bound by
@@ -109,7 +109,7 @@ def run_matrix() -> dict:
     cfg = llama.LlamaConfig.tiny()
     L, Hkv, Dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
     V, D = cfg.vocab_size, cfg.d_model
-    S_MAX, S_BUCKET, PAGE, N_PAGES, M = 32, 8, 4, 6, 4
+    S_BUCKET, PAGE, N_PAGES, M = 8, 4, 6, 4
     RANK, ADAPTERS = 4, 2
 
     def sds(shape, dtype):
@@ -123,10 +123,6 @@ def run_matrix() -> dict:
         sds((ADAPTERS, D, RANK), jnp.float32),
         sds((ADAPTERS, RANK, V), jnp.float32),
     )
-
-    def dense_cache(B):
-        shape = (L, B, S_MAX, Hkv, Dh)
-        return llama.KVCache(sds(shape, cfg.dtype), sds(shape, cfg.dtype))
 
     kp = vp = sds((L, N_PAGES + 1, Hkv, PAGE, Dh), cfg.dtype)  # the pools
 
@@ -169,60 +165,37 @@ def run_matrix() -> dict:
         fn = raw[name]
         return getattr(fn, "__wrapped__", fn)
 
-    add("prefill_compute", "dense", unwrap("prefill_compute"),
+    add("prefill_compute", "prefill", unwrap("prefill_compute"),
         cfg=cfg, params=params,
         tokens=sds((1, S_BUCKET), jnp.int32), seq_len=vec(1))
-    cache = dense_cache(1)
-    add("insert_slot", "dense", unwrap("insert_slot"),
-        k_cache=cache.k, v_cache=cache.v,
-        k_slab=sds((L, S_BUCKET, Hkv, Dh), cfg.dtype),
-        v_slab=sds((L, S_BUCKET, Hkv, Dh), cfg.dtype),
-        slot=sds((), jnp.int32))
-    add("insert_chunk", "dense", unwrap("insert_chunk"),
-        k_cache=cache.k, v_cache=cache.v,
-        k_slab=sds((L, 4, Hkv, Dh), cfg.dtype),
-        v_slab=sds((L, 4, Hkv, Dh), cfg.dtype),
-        slot=sds((), jnp.int32), start=sds((), jnp.int32))
-    add("admit_decode_state", "dense", unwrap("admit_decode_state"),
+    add("admit_decode_state", "admit", unwrap("admit_decode_state"),
         state=state(3), slots=vec(2), tokens=vec(2), lens=vec(2),
         budgets=vec(2), stops=vec(2), temps=vec(2, jnp.float32),
         topks=vec(2), topps=vec(2, jnp.float32), adapters=vec(2))
 
     for variant, B, steps, lora in (
-        ("dense.b3n4", 3, 4, None),
-        ("dense.b2n2", 2, 2, None),
-        ("dense.lora", 3, 4, lora_tabs),
+        ("paged.b3n4", 3, 4, None),
+        ("paged.b2n2", 2, 2, None),
+        ("paged.lora", 3, 4, lora_tabs),
     ):
-        add("decode_block", variant, unwrap("decode_block"),
-            cfg=cfg, params=params, cache=dense_cache(B),
-            state=state(B), active=vec(B, jnp.bool_), steps=steps,
-            lora=lora)
-    for variant, lora in (("paged", None), ("paged.lora", lora_tabs)):
         add("decode_block_paged", variant, unwrap("decode_block_paged"),
-            cfg=cfg, params=params, k_pool=kp, v_pool=vp, state=state(3),
-            block_tables=sds((3, M), jnp.int32),
-            active=vec(3, jnp.bool_), steps=4, lora=lora)
+            cfg=cfg, params=params, k_pool=kp, v_pool=vp, state=state(B),
+            block_tables=sds((B, M), jnp.int32),
+            active=vec(B, jnp.bool_), steps=steps, lora=lora)
 
     for variant, B, chunk_c, steps, lora in (
-        ("dense.b3n4", 3, 4, 4, None),
-        ("dense.b2n2", 2, 2, 2, None),
-        ("dense.lora", 3, 4, 4, lora_tabs),
+        ("paged.b3n4", 3, 4, 4, None),
+        ("paged.b2n2", 2, 2, 2, None),
+        ("paged.lora", 3, 4, 4, lora_tabs),
     ):
-        add("ragged_step", variant, unwrap("ragged_step"),
-            cfg=cfg, params=params, cache=dense_cache(B), state=state(B),
+        add("ragged_step_paged", variant, unwrap("ragged_step_paged"),
+            cfg=cfg, params=params, k_pool=kp, v_pool=vp, state=state(B),
+            block_tables=sds((B, M), jnp.int32),
             chunk=sds((B, chunk_c), jnp.int32), chunk_start=vec(B),
+            chunk_active=vec(B, jnp.bool_), kv_capacity=vec(B),
             **ragged_tail(B, steps, lora))
-    add("ragged_step_paged", "paged", unwrap("ragged_step_paged"),
-        cfg=cfg, params=params, k_pool=kp, v_pool=vp, state=state(3),
-        block_tables=sds((3, M), jnp.int32),
-        chunk=sds((3, 4), jnp.int32), chunk_start=vec(3),
-        chunk_active=vec(3, jnp.bool_), kv_capacity=vec(3),
-        **ragged_tail(3, 4, None))
 
-    add("verify_and_sample", "spec.dense", unwrap("verify_and_sample"),
-        cfg=cfg, params=params, cache=dense_cache(3),
-        chunk=sds((3, 3), jnp.int32), start_len=vec(3), **spec_tail(3))
-    add("verify_and_sample_paged", "spec.paged",
+    add("verify_and_sample_paged", "spec",
         unwrap("verify_and_sample_paged"),
         cfg=cfg, params=params, k_pool=kp, v_pool=vp,
         block_tables=sds((3, M), jnp.int32),

@@ -57,7 +57,7 @@ families over the serving control plane:
     the engine-thread zone, between a blocking call (migration
     ``fetch_one``/``fetch_chain``, the monolithic ``prefill_compute``
     dispatch) and any commit into rebuilt state (cache ``put``,
-    ``write_span``/``write_prefill``/``insert_chunk``,
+    ``write_span``/``write_prefill``,
     ``_commit_prefilled``…) there must be a ``_check_retired()`` gate —
     a thread retired by a warm restart mid-fetch must never insert
     dead slabs into the state the restart just reset (the exact PR 11
@@ -220,8 +220,7 @@ RETIRE_GATE_ZONES: dict[str, set[str] | str] = {
 BLOCKING_FETCH_CALLS = {"fetch_one", "fetch_chain", "prefill_compute"}
 # commits into rebuilt state that a retired thread must never perform
 COMMIT_CALLS = {
-    "put", "write_span", "write_prefill", "insert_chunk",
-    "insert_slot", "advance_slot",
+    "put", "write_span", "write_prefill", "advance_slot",
     "_commit_prefilled", "_commit_first_token",
 }
 RETIRE_GATE_CALLS = {"_check_retired"}

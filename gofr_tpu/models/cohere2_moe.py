@@ -122,9 +122,6 @@ def step_stats_len(cfg: Cohere2MoeConfig) -> int:
 def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     """What an engine asks for that this model has no program for, in a
     sentence; None if it can be built."""
-    if engine_config.kv_layout != "paged":
-        return ("cohere2_moe is served from the paged KV layout only: its dense "
-                "decode programs wait for the one cache layout (ROADMAP D2)")
     if engine_config.spec_tokens > 0:
         return "cohere2_moe has no speculative verify program: set TPU_SPEC_TOKENS=0"
     if lora is not None:

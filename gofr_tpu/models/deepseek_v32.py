@@ -187,9 +187,6 @@ def page_shapes(cfg: DeepseekV32Config, page_size: int) -> tuple[tuple, tuple | 
 def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     """What an engine asks for that this model has no program for, in a
     sentence; None if it can be built."""
-    if engine_config.kv_layout != "paged":
-        return ("deepseek_v32 is served from the paged KV layout only: a dense cache of latent "
-                "rows has no decode program (ROADMAP D2)")
     if engine_config.spec_tokens > 0:
         return ("deepseek_v32 has no speculative verify program (its multi-token-prediction "
                 "module is not served): set TPU_SPEC_TOKENS=0")

@@ -180,9 +180,6 @@ def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     prefilling its prompt and what it emitted, which needs no snapshot of
     the conv tails and is served."""
     ec = engine_config
-    if ec.kv_layout != "paged":
-        return ("lfm2_moe is served from the paged KV layout only: a dense cache has no place for "
-                "the conv tails (ROADMAP D2)")
     if ec.spec_tokens > 0:
         return ("lfm2_moe has no speculative verify program: a rejected draft would have to roll "
                 "the conv tails back; set TPU_SPEC_TOKENS=0")

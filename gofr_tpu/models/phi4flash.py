@@ -187,9 +187,6 @@ def unserved(engine_config: Any, lora: Any, cfg: Any = None) -> str | None:
     prefilling its prompt and what it emitted, which needs no snapshot of
     the state and is served."""
     ec = engine_config
-    if ec.kv_layout != "paged":
-        return ("phi4flash is served from the paged KV layout only: a dense cache has no place for "
-                "the recurrent state or the shared layer (ROADMAP D2)")
     if ec.spec_tokens > 0:
         return ("phi4flash has no speculative verify program: a rejected draft would have to roll "
                 "the recurrent state back; set TPU_SPEC_TOKENS=0")

@@ -2,12 +2,14 @@
 
 All shapes are static (slot count, padded prompt buckets) so everything
 compiles once per bucket and never again — the XLA contract. Slots are rows
-of a persistent batch KV cache; requests come and go between steps by
-scattering into / masking out rows, with buffers donated end-to-end so the
-cache never copies.
+of the batch; each row's KV lives in pages of a persistent pool, addressed
+through its block table (``serving/kv_cache.py``). Requests come and go
+between steps by writing pages and masking rows, with the pools donated
+end-to-end so the cache never copies.
 
 Device-side state per engine:
-- ``SlotCache``: k/v [L, B_slots, S_max, Hkv, Dh]
+- the page pools: k/v [L, N_pages+1, Hkv, page, Dh] (+1: the trash page),
+  or the model's own pools by layer kind (``cache_spec``)
 - ``DecodeState``: the per-row decode carry (last token, resident length,
   done flag, remaining token budget, stop id, sampling params, PRNG key) —
   donated through every block dispatch and every admission scatter, so the
@@ -15,7 +17,7 @@ Device-side state per engine:
 
 The decode hot loop is CPU-free (Blink, arXiv:2604.07609): sampling AND
 stop-condition evaluation run inside the jitted N-step block
-(``decode_block*``), which returns ONE packed int32 [B, steps+2] array —
+(``decode_block_paged``), which returns ONE packed int32 [B, steps+2] array —
 ``steps`` token columns (-1 past each row's stop), a done column, and an
 n_valid column — so the engine's single host sync happens once per N
 tokens instead of once per token.
@@ -46,7 +48,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from gofr_tpu.models import llama
 from gofr_tpu.ops.sampling import sample_logits, sampler_path, stop_eval
 
 
@@ -58,7 +59,7 @@ def model_of(cfg: Any) -> Any:
     ``models/phi4flash.py``, ``Lfm2MoeConfig`` → ``models/lfm2_moe.py``).
     Every program here reaches its model
     through this one lookup, at trace time. What a served module holds:
-    ``KVCache`` (the dense cache, also a bucketed prefill's scratch),
+    ``KVCache`` (a bucketed prefill's one-row scratch),
     ``prefill``, ``decode_step_paged`` and ``decode_chunk_paged`` with
     ``llama``'s arguments, ``step_stats_len(cfg)`` — how many int32
     counters its paged step returns after the pools (0: none) —
@@ -76,8 +77,7 @@ def model_of(cfg: Any) -> Any:
     (after the experts' rows and reads for a config with ``held_experts``:
     the engine sets them on the commit span), and ``unserved(engine_config,
     lora, cfg)``, the sentence that refuses
-    an engine the model has no program for. The dense and speculative
-    programs call the functions ``llama`` has for them by the same names."""
+    an engine the model has no program for."""
     return sys.modules[type(cfg).__module__]
 
 
@@ -89,7 +89,7 @@ def prefill_compute(
     seq_len: jnp.ndarray,  # [1]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run prefill WITHOUT a persistent cache: returns (last_logits [1,V],
-    k_slab, v_slab [L, S_bucket, Hkv, Dh]) for scatter into a slot (a
+    k_slab, v_slab [L, S_bucket, Hkv, Dh]) for writing into a slot's pages (a
     model with a ``cache_spec``: what its ``prefill_slabs`` makes of the
     cache — slabs by pool name and the row's state)."""
     model = model_of(cfg)
@@ -101,31 +101,13 @@ def prefill_compute(
     return last, cache.k[:, 0], cache.v[:, 0]
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def insert_slot(
-    k_cache: jnp.ndarray,  # [L, B, S_max, Hkv, Dh] donated
-    v_cache: jnp.ndarray,
-    k_slab: jnp.ndarray,  # [L, S_bucket, Hkv, Dh]
-    v_slab: jnp.ndarray,
-    slot: jnp.ndarray,  # scalar int32
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter a prefilled slab into slot row [.., slot, :S_bucket]."""
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k_slab[:, None], (0, slot, 0, 0, 0)
-    )
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v_slab[:, None], (0, slot, 0, 0, 0)
-    )
-    return k_cache, v_cache
-
-
 # ------------------------------------------------------- CPU-free hot loop
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class DecodeState:
     """The per-row decode carry: everything the device needs to run N
-    steps without the host. Donated through every ``decode_block*``
-    dispatch and every :func:`admit_decode_state` scatter — the host NEVER
+    steps without the host. Donated through every block dispatch and
+    every :func:`admit_decode_state` scatter — the host NEVER
     reads these buffers (results come back only through the packed block
     output), so the donation can never alias a host-held reference: the
     aliasing that produced the round-4 on-TPU crash ("Array has been
@@ -136,11 +118,9 @@ class DecodeState:
     admission, so the device's stop evaluation covers both. ``stop_tok``
     is the row's EOS id (-1 disables). ``done`` rows are frozen: they stop
     spending budget and emit -1, and their garbage KV writes land where
-    they cannot matter — the trash page on the paged layout; PAST the
-    cache bound on dense (``.at[].set`` drops out-of-bounds writes).
-    Position 0 was the old dense target, which became a corruption bug
-    the moment rows could be frozen while still holding LIVE prompt KV
-    (mid-chunked-prefill cursor rows).
+    they cannot matter — the trash page. Never a live position: rows can
+    be frozen while still holding LIVE prompt KV (mid-chunked-prefill
+    cursor rows).
 
     ``adapter`` is the row's LoRA device-table slot (serving/lora.py; 0 =
     base model). It rides the donated carry like ``stop_tok`` so the
@@ -328,7 +308,7 @@ def sample_first_token(
 
 
 def _block_step(st: DecodeState, active, logits, params=None, lora=None):
-    """Shared per-step tail of every decode_block* scan body: apply the
+    """Shared per-step tail of every decode scan body: apply the
     per-row LoRA delta (heterogeneous-adapter batching, serving/lora.py),
     sample with the row's own params, evaluate stop conditions, advance
     the carry. Frozen (done/inactive) rows keep their token and length
@@ -355,45 +335,6 @@ def _block_step(st: DecodeState, active, logits, params=None, lora=None):
         st.adapter,
     )
     return new_st, jnp.where(live, nxt, -1)
-
-
-@partial(jax.jit, static_argnums=(0, 5), donate_argnums=(2, 3))
-def decode_block(
-    cfg: Any,  # a served model's config (model_of)
-    params: dict,
-    cache: llama.KVCache,  # donated
-    state: DecodeState,  # donated
-    active: jnp.ndarray,  # [B] bool — rows the host dispatched this block
-    steps: int,
-    lora: tuple | None = None,  # (a_table, b_table) — heterogeneous LoRA
-) -> tuple[jnp.ndarray, llama.KVCache, DecodeState]:
-    """``steps`` fused decode+sample+stop-eval iterations in ONE dispatch
-    over the dense slot cache. A row that stops mid-block freezes: no
-    further KV writes or budget spend, its remaining columns are -1.
-    Frozen rows aim their scatter PAST the cache bound (``.at[].set``
-    drops out-of-bounds writes) — position 0 would corrupt live prompt
-    KV for a row that is frozen because it is still mid-chunked-prefill.
-    ``lora`` (never donated) carries the stacked adapter factor tables;
-    each step gathers per-row slots out of the carry's ``adapter`` index
-    — one dispatch serves rows with DIFFERENT adapters (serving/lora.py).
-    Returns (packed [B, steps+2] — see :func:`_pack_block` — cache,
-    state); the packed array is the block's ONLY host-read value."""
-    oob = cache.k.shape[2] + 1  # static: one past the slot's last position
-
-    def step(carry, _):
-        cache, st = carry
-        live = active & ~st.done
-        step_len = jnp.where(live, st.seq_len + 1, oob)
-        logits, cache = model_of(cfg).decode_step(
-            cfg, params, st.last_token, cache, step_len
-        )
-        st, out = _block_step(st, active, logits, params, lora)
-        return (cache, st), out
-
-    (cache, state), toks = jax.lax.scan(
-        step, (cache, state), None, length=steps
-    )
-    return _pack_block(jnp.transpose(toks), state.done, active), cache, state
 
 
 def _paged_steps(cfg, params, k_pool, v_pool, state, block_tables, active,
@@ -436,9 +377,17 @@ def decode_block_paged(
     steps: int,
     lora: tuple | None = None,  # (a_table, b_table) — heterogeneous LoRA
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, DecodeState]:
-    """Paged twin of :func:`decode_block`: frozen rows' appends divert to
+    """``steps`` fused decode+sample+stop-eval iterations in ONE dispatch
+    over the page pools. A row that stops mid-block freezes: no further
+    budget spend, its remaining columns are -1, and its appends divert to
     the trash page (llama.decode_step_paged's ``active`` redirect), so a
-    mid-block stop never writes a live page."""
+    mid-block stop never writes a live page. ``lora`` (never donated)
+    carries the stacked adapter factor tables; each step gathers per-row
+    slots out of the carry's ``adapter`` index — one dispatch serves rows
+    with DIFFERENT adapters (serving/lora.py). Returns (packed — see
+    :func:`_pack_block`, with the model's counters below it — k_pool,
+    v_pool, state); the packed array is the block's ONLY host-read
+    value."""
 
     toks, k_pool, v_pool, state, stats = _paged_steps(
         cfg, params, k_pool, v_pool, state, block_tables, active, steps, lora
@@ -451,8 +400,8 @@ def decode_block_paged(
 #
 # Continuous batching (Ragged Paged Attention, arXiv:2604.15464): one
 # dispatch runs a ragged mix of PREFILL CHUNKS (the next <=C prompt tokens
-# of each partially-prefilled row, written into the same slot cache / page
-# pool decode reads) and an N-step DECODE BLOCK, returning ONE packed
+# of each partially-prefilled row, written into the same page pool decode
+# reads) and an N-step DECODE BLOCK, returning ONE packed
 # array so the host still pays exactly one sync per block. A row whose
 # chunk completes its prompt gets its first token sampled ON DEVICE (with
 # the same fold_in(root, request_id) key the host path uses) and is folded
@@ -539,67 +488,6 @@ def _pack_ragged(toks: jnp.ndarray, done: jnp.ndarray, active: jnp.ndarray,
     )
 
 
-@partial(jax.jit, static_argnums=(0, 16), donate_argnums=(2, 3))
-def ragged_step(
-    cfg: Any,  # a served model's config (model_of)
-    params: dict,
-    cache: llama.KVCache,      # donated
-    state: DecodeState,        # donated
-    chunk: jnp.ndarray,        # [B, C] next prompt tokens (pad past len)
-    chunk_start: jnp.ndarray,  # [B] resident length before the chunk;
-                               # NON-chunk rows pass max_seq_len so their
-                               # writes fall out of bounds and are dropped
-    finish: jnp.ndarray,       # [B] bool — chunk completes the prompt
-    new_len: jnp.ndarray,      # [B] resident length after the chunk
-    budgets: jnp.ndarray,      # [B] decode budget once admitted
-    stops: jnp.ndarray,        # [B]
-    temps: jnp.ndarray,        # [B]
-    topks: jnp.ndarray,        # [B]
-    topps: jnp.ndarray,        # [B]
-    rids: jnp.ndarray,         # [B] request ids (first-token keys)
-    rng_root: jax.Array,
-    decode_active: jnp.ndarray,  # [B] bool — rows decoding THIS block
-    steps: int,
-    adapters: jnp.ndarray | None = None,  # [B] LoRA slots for chunk rows
-    lora: tuple | None = None,  # (a_table, b_table) — never donated
-) -> tuple[jnp.ndarray, jnp.ndarray, llama.KVCache, DecodeState]:
-    """Unified ragged dispatch, dense cache: prefill-chunk forward for the
-    chunk rows, first-token fold for finishing rows, then the N-step
-    decode scan — one dispatch, one packed host read. Returns (packed
-    [B, steps+3] — see :func:`_pack_ragged` — last_logits [B, V], cache,
-    state); ``last_logits`` stays on device unless the engine retains it
-    for the chunk-prefix cache."""
-    logits_c, cache = model_of(cfg).decode_chunk.__wrapped__(
-        cfg, params, chunk, cache, chunk_start
-    )
-    state, first, last_logits = _fold_finished_prefill(
-        state, logits_c, chunk, chunk_start, finish, new_len, budgets,
-        stops, temps, topks, topps, rids, rng_root, adapters, params, lora,
-    )
-    # frozen rows include MID-PREFILL cursor rows whose low positions hold
-    # live prompt KV: their scatter must drop out of bounds, never land on
-    # position 0 (see decode_block)
-    oob = cache.k.shape[2] + 1
-
-    def step(carry, _):
-        cache, st = carry
-        live = decode_active & ~st.done
-        step_len = jnp.where(live, st.seq_len + 1, oob)
-        logits, cache = model_of(cfg).decode_step(
-            cfg, params, st.last_token, cache, step_len
-        )
-        st, out = _block_step(st, decode_active, logits, params, lora)
-        return (cache, st), out
-
-    (cache, state), toks = jax.lax.scan(
-        step, (cache, state), None, length=steps
-    )
-    packed = _pack_ragged(
-        jnp.transpose(toks), state.done, decode_active, first
-    )
-    return packed, last_logits, cache, state
-
-
 @partial(jax.jit, static_argnums=(0, 20), donate_argnums=(2, 3, 4))
 def ragged_step_paged(
     cfg: Any,  # a served model's config (model_of)
@@ -626,9 +514,14 @@ def ragged_step_paged(
     adapters: jnp.ndarray | None = None,  # [B] LoRA slots for chunk rows
     lora: tuple | None = None,  # (a_table, b_table) — never donated
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, DecodeState]:
-    """Paged twin of :func:`ragged_step`: chunk writes route through the
+    """Unified ragged dispatch: prefill-chunk forward for the chunk rows,
+    first-token fold for finishing rows, then the N-step decode scan —
+    one dispatch, one packed host read. Chunk writes route through the
     block tables (inactive rows and beyond-capacity positions divert to
-    the trash page), decode appends likewise."""
+    the trash page), decode appends likewise. Returns (packed
+    [B, steps+3] — see :func:`_pack_ragged` — last_logits [B, V], k_pool,
+    v_pool, state); ``last_logits`` stays on device unless the engine
+    retains it for the chunk-prefix cache."""
     model = model_of(cfg)
     # a model whose upper layers run on a prompt's last position alone is
     # told which rows finish, and returns logits [B, 1, V] at that position
@@ -651,27 +544,6 @@ def ragged_step_paged(
     return packed, last_logits, k_pool, v_pool, state
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def insert_chunk(
-    k_cache: jnp.ndarray,  # [L, B, S_max, Hkv, Dh] donated
-    v_cache: jnp.ndarray,
-    k_slab: jnp.ndarray,  # [L, C, Hkv, Dh] cached chunk-prefix slab
-    v_slab: jnp.ndarray,
-    slot: jnp.ndarray,  # scalar int32
-    start: jnp.ndarray,  # scalar int32 — token offset of the slab
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter a cached chunk-prefix slab into slot row
-    [.., slot, start:start+C] — :func:`insert_slot`'s offset twin, used
-    when a chunked admission skips already-cached chunk prefixes."""
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, k_slab[:, None], (0, slot, start, 0, 0)
-    )
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, v_slab[:, None], (0, slot, start, 0, 0)
-    )
-    return k_cache, v_cache
-
-
 # ----------------------------------------------------- speculative decoding
 def _accept_and_bonus(
     chunk: jnp.ndarray,  # [B, T] (pos 0 = last committed; 1.. = drafts, -1 pad)
@@ -682,7 +554,7 @@ def _accept_and_bonus(
     rng: jax.Array,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jax.Array]:
     """Greedy draft acceptance + per-row bonus sampling, fused device-side.
-    The verify_and_sample* wrappers pack (tokens, n_accept) into ONE
+    :func:`verify_and_sample_paged` packs (tokens, n_accept) into ONE
     [B, T+1] int32 array so the engine's spec path pays a single host
     sync per chunk (tokens in columns [0, T), n_accept in column T).
 
@@ -718,33 +590,6 @@ def _accept_and_bonus(
     return out, n_accept, rng
 
 
-@partial(jax.jit, static_argnums=0, donate_argnums=(2,))
-def verify_and_sample(
-    cfg: Any,  # a served model's config (model_of)
-    params: dict,
-    cache: llama.KVCache,  # donated
-    chunk: jnp.ndarray,  # [B, T]
-    start_len: jnp.ndarray,  # [B] committed length before the chunk
-    temperature: jnp.ndarray,
-    top_k: jnp.ndarray,
-    top_p: jnp.ndarray,
-    rng: jax.Array,
-) -> tuple[jnp.ndarray, jnp.ndarray, llama.KVCache, jax.Array]:
-    """Speculative engine step, dense cache: chunk-verify forward + draft
-    acceptance + bonus sampling in ONE dispatch. Returns
-    (tokens [B, T], n_accept [B], cache, rng)."""
-    logits, cache = model_of(cfg).decode_chunk.__wrapped__(
-        cfg, params, chunk, cache, start_len
-    )
-    out, n_accept, rng = _accept_and_bonus(
-        chunk, logits, temperature, top_k, top_p, rng
-    )
-    packed = jnp.concatenate(
-        [out.astype(jnp.int32), n_accept[:, None].astype(jnp.int32)], axis=1
-    )
-    return packed, cache, rng
-
-
 @partial(jax.jit, static_argnums=0, donate_argnums=(2, 3))
 def verify_and_sample_paged(
     cfg: Any,  # a served model's config (model_of)
@@ -761,7 +606,9 @@ def verify_and_sample_paged(
     top_p: jnp.ndarray,
     rng: jax.Array,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jax.Array]:
-    """Paged twin of :func:`verify_and_sample`."""
+    """Speculative engine step: chunk-verify forward through the block
+    tables + draft acceptance + bonus sampling in ONE dispatch. Returns
+    (packed [B, T+1] — tokens | n_accept — k_pool, v_pool, rng)."""
     logits, k_pool, v_pool = model_of(cfg).decode_chunk_paged.__wrapped__(
         cfg, params, chunk, k_pool, v_pool, block_tables, start_len,
         active, kv_capacity,

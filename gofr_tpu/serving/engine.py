@@ -81,16 +81,18 @@ class EngineConfig:
     # a slot that holds a waiting cursor decodes nothing).
     step_token_budget: int = 0
     idle_sleep_s: float = 0.002
-    # KV layout: "dense" reserves [slots, max_seq] rows; "paged" commits HBM
-    # by resident tokens through the pooled page table (serving/kv_cache.py)
-    kv_layout: str = "dense"
+    # the KV layout: "paged" is the only value, checked at construction —
+    # HBM is committed by resident tokens through the pooled page table
+    # (serving/kv_cache.py). The field exists because the benchmark's cell
+    # files name the key (ROADMAP D11)
+    kv_layout: str = "paged"
     kv_page_size: int = 16
     kv_num_pages: int | None = None  # default: slots*max_seq worth of pages
     # the KV element type: "bf16" is the only value, checked at
     # construction. The field exists because the benchmark's cell files
     # name the key (ROADMAP D11)
     kv_dtype: str = "bf16"
-    # decode tokens per device dispatch (dense AND paged layouts), i.e.
+    # decode tokens per device dispatch, i.e.
     # the N of the CPU-free N-step block: sampling + stop-condition
     # evaluation run on device, so a row that stops mid-block freezes
     # there and the host syncs ONCE per block. None = default (4; 1 when
@@ -202,7 +204,7 @@ class EngineConfig:
                 config.get_or_default("TPU_STEP_TOKEN_BUDGET", "0")
             ),
             idle_sleep_s=float(config.get_or_default("TPU_IDLE_SLEEP_S", "0.002")),
-            kv_layout=config.get_or_default("TPU_KV_LAYOUT", "dense"),
+            kv_layout=config.get_or_default("TPU_KV_LAYOUT", "paged"),
             kv_page_size=int(config.get_or_default("TPU_KV_PAGE_SIZE", "16")),
             kv_num_pages=int(num_pages) if num_pages else None,
             kv_dtype=config.get_or_default("TPU_KV_DTYPE", "bf16"),
@@ -475,7 +477,7 @@ class _StepPhase:
 
 
 class ServingEngine:
-    """Owns model params + slot cache + the step loop thread."""
+    """Owns model params + the KV page pool + the step loop thread."""
 
     def __init__(
         self,
@@ -574,13 +576,19 @@ class ServingEngine:
                 "adapter gather (serve adapters from non-spec replicas)"
             )
 
-        if self.config.kv_dtype != "bf16":
-            raise ValueError(
-                f"TPU_KV_DTYPE={self.config.kv_dtype!r}: must be bf16 — the "
-                "KV cache has one element type, the model's; int8 KV left "
-                "in PR 31 (ROADMAP R10) and is refused here, not served "
-                "at twice the memory"
-            )
+        # the two one-value fields (ROADMAP D11): anything else is refused
+        # with a sentence before any KV is built
+        for field, env, only, why in (
+            ("kv_dtype", "TPU_KV_DTYPE", "bf16",
+             "the KV cache has one element type, the model's; int8 KV is "
+             "refused here, not served at twice the memory (ROADMAP R10)"),
+            ("kv_layout", "TPU_KV_LAYOUT", "paged",
+             "the KV cache has one layout, the page pool every model "
+             "serves from; there is no dense slot cache"),
+        ):
+            value = getattr(self.config, field)
+            if value != only:
+                raise ValueError(f"{env}={value!r}: must be {only} — {why}")
         # the model's module (batch_ops.model_of) says what it has no
         # program for yet, in a sentence, before anything is built
         model = batch_ops.model_of(cfg)
@@ -631,12 +639,11 @@ class ServingEngine:
         # unpipelined by design.
         self._chunk_enabled = self.config.spec_tokens == 0
         chunk = max(1, int(self.config.prefill_chunk_tokens))
-        if self.config.kv_layout == "paged":
-            # chunk boundaries double as chunk-prefix-cache boundaries,
-            # and cached slabs scatter through whole pages — align the
-            # chunk size down to the page grid
-            page = max(1, int(self.config.kv_page_size))
-            chunk = max(page, (chunk // page) * page)
+        # chunk boundaries double as chunk-prefix-cache boundaries, and
+        # cached slabs scatter through whole pages — align the chunk size
+        # down to the page grid
+        page = max(1, int(self.config.kv_page_size))
+        chunk = max(page, (chunk // page) * page)
         self._chunk_tokens = min(chunk, self.config.max_seq_len)
         self._planner = StepPlanner(
             chunk_tokens=self._chunk_tokens,
@@ -940,6 +947,7 @@ class ServingEngine:
         try:
             self._sched.close()  # fallible: destroy status is checked
         finally:
+            # None: a warm restart released the pool and its rebuild failed
             if self.paged_cache is not None:
                 self.paged_cache.close()
 
@@ -1348,6 +1356,9 @@ class ServingEngine:
                             "engine restarting; retry", retry_after=1.0,
                         ))
                 old_sched, old_paged = self._sched, self.paged_cache
+                # released here, whatever the rebuild below does: a restart
+                # after a failed one finds None, and so do stop and probe
+                self.paged_cache = None
                 if joined:
                     self._thread = None
                     try:
@@ -1589,7 +1600,7 @@ class ServingEngine:
         }
         if self._running:
             details["heartbeat_age_s"] = round(self.heartbeat_age(), 3)
-        if self.paged_cache is not None and self._running:
+        if self._running:
             details["kv_pages"] = self.paged_cache.stats()
         if self._prefix_cache is not None:
             details["prefix_cache"] = self._prefix_cache.stats()
@@ -2512,11 +2523,10 @@ class ServingEngine:
                 self._lora_release(req)
                 self.slots[slot] = None
                 self.cache_len[slot] = 0
-                if self.paged_cache is not None:
-                    try:
-                        self.paged_cache.free_slot(slot)
-                    except Exception:
-                        pass
+                try:
+                    self.paged_cache.free_slot(slot)
+                except Exception:
+                    pass
                 try:
                     sched.release(slot)
                 except KeyError:
@@ -2531,8 +2541,8 @@ class ServingEngine:
                 if not isinstance(
                     exc, (ErrorTooManyRequests, ErrorRequestEntityTooLarge)
                 ) and self._kv_unhealthy():
-                    # the failing call donated the SHARED cache (insert_slot*/
-                    # write_prefill) and died after donation committed: every
+                    # the failing call donated the SHARED pools
+                    # (write_prefill) and died after donation committed: every
                     # active slot's KV is gone, not just this request's —
                     # isolated cleanup would leave the engine raising
                     # "Array has been deleted" on every future step
@@ -2653,27 +2663,26 @@ class ServingEngine:
             tokens[0, :S] = ids
             seq_len = jnp.array([S], jnp.int32)
 
-            if self.paged_cache is not None:
-                # page reservation first: OutOfBlocks must requeue BEFORE any
-                # device work (the request keeps its place; pool pressure is a
-                # transient, not an error) — unless the prompt can NEVER fit,
-                # which must fail the request, not livelock the admit loop
-                from gofr_tpu.serving.kv_cache import OutOfBlocks
+            # page reservation first: OutOfBlocks must requeue BEFORE any
+            # device work (the request keeps its place; pool pressure is a
+            # transient, not an error) — unless the prompt can NEVER fit,
+            # which must fail the request, not livelock the admit loop
+            from gofr_tpu.serving.kv_cache import OutOfBlocks
 
-                if self.paged_cache.pages_needed(bucket) > self.paged_cache.num_pages:
-                    # permanent, not transient: however empty the pool gets,
-                    # this prompt can NEVER fit — a 429 would invite clients to
-                    # retry forever; 413 / FAILED_PRECONDITION says "shrink it"
-                    raise ErrorRequestEntityTooLarge(
-                        f"prompt needs {self.paged_cache.pages_needed(bucket)} KV pages; "
-                        f"pool has {self.paged_cache.num_pages} in total"
-                    )
-                try:
-                    self.paged_cache.alloc_slot(
-                        slot, seq_id=req.id, prompt_len=S, reserve_tokens=bucket
-                    )
-                except OutOfBlocks:
-                    raise _RequeueRequest() from None
+            if self.paged_cache.pages_needed(bucket) > self.paged_cache.num_pages:
+                # permanent, not transient: however empty the pool gets,
+                # this prompt can NEVER fit — a 429 would invite clients to
+                # retry forever; 413 / FAILED_PRECONDITION says "shrink it"
+                raise ErrorRequestEntityTooLarge(
+                    f"prompt needs {self.paged_cache.pages_needed(bucket)} KV pages; "
+                    f"pool has {self.paged_cache.num_pages} in total"
+                )
+            try:
+                self.paged_cache.alloc_slot(
+                    slot, seq_id=req.id, prompt_len=S, reserve_tokens=bucket
+                )
+            except OutOfBlocks:
+                raise _RequeueRequest() from None
 
             cache_key = None
             cached = None
@@ -2746,12 +2755,12 @@ class ServingEngine:
                     pspan.set_attribute("prefill.prefix_hit", cached is not None)
                     pspan.set_attribute("prefix_tier", prefix_tier or "miss")
                     pspan.set_attribute("tokens.prompt", S)
-            # bind the KV storage ONCE, before the long dispatch: a warm
+            # bind the KV pool ONCE, before the long dispatch: a warm
             # restart that replaces this thread mid-compute swaps
-            # self.paged_cache/self.cache for rebuilt ones — re-reading them
-            # after the dispatch would donate the REPLACEMENT engine's pools
-            # from a quarantined thread
-            pc, dense = self.paged_cache, self.cache
+            # self.paged_cache for a rebuilt one — re-reading it after the
+            # dispatch would donate the REPLACEMENT engine's pools from a
+            # quarantined thread
+            pc = self.paged_cache
             with span, self._cold_dispatch("prefill", bucket, cached is not None):
                 if cached is not None:
                     last_logits, k_slab, v_slab = cached
@@ -2767,12 +2776,7 @@ class ServingEngine:
                 if cached is None and cache_key is not None:
                     # slabs are fresh, never-donated arrays: safe to retain
                     self._prefix_cache.put(cache_key, (last_logits, k_slab, v_slab))
-                if pc is not None:
-                    pc.write_prefill(slot, k_slab, v_slab)
-                else:
-                    dense.k, dense.v = batch_ops.insert_slot(
-                        dense.k, dense.v, k_slab, v_slab, jnp.int32(slot)
-                    )
+                pc.write_prefill(slot, k_slab, v_slab)
                 # sample the first token with this request's params, keyed by
                 # request id (NOT the shared stream — see _rng_root above).
                 # The row's LoRA delta applies HERE, at the sampling site —
@@ -2872,7 +2876,7 @@ class ServingEngine:
         with self._phase("prefill", rid=req.id, bucket=0,
                          tokens=total) as phase:
             pc = self.paged_cache
-            if pc is not None and pc.pages_needed(total) > pc.num_pages:
+            if pc.pages_needed(total) > pc.num_pages:
                 raise ErrorRequestEntityTooLarge(
                     f"prompt needs {pc.pages_needed(total)} KV pages; "
                     f"pool has {pc.num_pages} in total"
@@ -2966,7 +2970,7 @@ class ServingEngine:
 
             from gofr_tpu.serving.kv_cache import OutOfBlocks
 
-            if hits and pc is not None:
+            if hits:
                 try:
                     pc.alloc_slot(slot, seq_id=req.id, prompt_len=0,
                                   reserve_tokens=pos)
@@ -2977,17 +2981,9 @@ class ServingEngine:
             if tl is not None:
                 tl.stamp("prefill_start")
             for start, end, (_logits, k_slab, v_slab) in hits:
-                if pc is not None:
-                    pc.write_span(slot, start, k_slab, v_slab)
-                else:
-                    dense = self.cache
-                    dense.k, dense.v = batch_ops.insert_chunk(
-                        dense.k, dense.v, k_slab, v_slab,
-                        jnp.int32(slot), jnp.int32(start),
-                    )
+                pc.write_span(slot, start, k_slab, v_slab)
             if hits:
-                if pc is not None:
-                    pc.advance_slot(slot, pos)
+                pc.advance_slot(slot, pos)
                 if tl is not None:
                     tl.chunk(0, pos, prefix_hit=True)
                 if self._metrics:
@@ -3025,7 +3021,7 @@ class ServingEngine:
             cursor.committed = cursor.dispatched = pos
             cursor.prefix_hit = pos
             cursor.chunk_index = 1 if hits else 0
-            cursor.allocated = bool(hits and pc is not None)
+            cursor.allocated = bool(hits)
             req.slot = slot
             self.slots[slot] = req
             self.cache_len[slot] = pos
@@ -3048,11 +3044,10 @@ class ServingEngine:
         self.cache_len[slot] = 0
         req.slot = None
         self._lora_release(req)
-        if self.paged_cache is not None:
-            try:
-                self.paged_cache.free_slot(slot)
-            except Exception:
-                pass
+        try:
+            self.paged_cache.free_slot(slot)
+        except Exception:
+            pass
         sched = self._sched
         try:
             sched.release(slot)
@@ -3090,7 +3085,7 @@ class ServingEngine:
     def _maybe_preempt(self) -> bool:
         """The preemption ladder: when a STRICTLY higher class (lower
         priority number) waits and the batch cannot take it — no free
-        slot, or (paged) the pool cannot cover its prompt — pause the
+        slot, or the pool cannot cover its prompt — pause the
         lowest-priority decode row. Its committed KV pages out through
         the prefix-cache/host-spill tier (:meth:`_preempt`), the slot
         frees, and the row resumes warm later with its emitted tokens
@@ -3113,7 +3108,7 @@ class ServingEngine:
         best = min(r.priority for r in waiting)
         slot_pressure = all(s is not None for s in self.slots)
         page_pressure = False
-        if not slot_pressure and self.paged_cache is not None:
+        if not slot_pressure:
             need = min(
                 self.paged_cache.pages_needed(len(r.serve_ids))
                 for r in waiting if r.priority == best
@@ -3203,13 +3198,7 @@ class ServingEngine:
             for start, end, key in boundaries:
                 if end > resident or end >= len(ids):
                     break
-                if self.paged_cache is not None:
-                    k_slab, v_slab = self.paged_cache.read_span(
-                        slot, start, end
-                    )
-                else:
-                    k_slab = self.cache.k[:, slot, start:end]
-                    v_slab = self.cache.v[:, slot, start:end]
+                k_slab, v_slab = self.paged_cache.read_span(slot, start, end)
                 # the span entry's logits column is never read: the walk
                 # stops before the prompt's end by construction (see
                 # above), so a placeholder keeps the (logits, k, v)
@@ -3244,11 +3233,10 @@ class ServingEngine:
         req.slot = None
         req.dispatched = max(len(req.tokens) - 1, 0)
         self._lora_release(req)
-        if self.paged_cache is not None:
-            try:
-                self.paged_cache.free_slot(slot)
-            except Exception:
-                pass
+        try:
+            self.paged_cache.free_slot(slot)
+        except Exception:
+            pass
         sched = self._sched
         try:
             sched.release(slot)
@@ -3307,8 +3295,7 @@ class ServingEngine:
         equality); sampled rows ride the same executable as plain steps.
         Unpipelined by design — drafting needs the newest consumed tokens,
         and the chunk already amortizes dispatch latency the way
-        multi_step does, multiplied by accepted drafts. Works on both
-        cache layouts (dense and paged); ref
+        multi_step does, multiplied by accepted drafts. Ref
         models/llama.py:speculative_generate for the library-level twin.
 
         Declared unpack site (kernel_contracts.UNPACK_SITES): the
@@ -3363,30 +3350,28 @@ class ServingEngine:
                         chunk[slot, 1 : 1 + len(draft)] = draft
 
                 pc = self.paged_cache
-                if pc is not None:
-                    slot_ids = [s for s, _ in rows]
-                    if not pc.try_reserve_chunk(slot_ids, T):
-                        # pool pressure: fall back to single-position coverage per
-                        # row (chunk tails spill to the trash page; zero drafts
-                        # still verify position 0 = a plain decode step). A row
-                        # that can't even cover one more token retires with what
-                        # it has, like the non-spec path.
-                        kept = []
-                        for slot, req in rows:
-                            if pc.try_reserve_chunk([slot], 1):
-                                chunk[slot, 1:] = -1
-                                kept.append((slot, req))
-                            else:
-                                if self._logger:
-                                    self._logger.warn(
-                                        f"KV pool exhausted; retiring request "
-                                        f"{req.id} early"
-                                    )
-                                req.kv_exhausted = True
-                                self._retire(slot, "kv_exhausted")
-                        rows = kept
-                        if not rows:
-                            return True
+                if not pc.try_reserve_chunk([s for s, _ in rows], T):
+                    # pool pressure: fall back to single-position coverage per
+                    # row (chunk tails spill to the trash page; zero drafts
+                    # still verify position 0 = a plain decode step). A row
+                    # that can't even cover one more token retires with what
+                    # it has, like the non-spec path.
+                    kept = []
+                    for slot, req in rows:
+                        if pc.try_reserve_chunk([slot], 1):
+                            chunk[slot, 1:] = -1
+                            kept.append((slot, req))
+                        else:
+                            if self._logger:
+                                self._logger.warn(
+                                    f"KV pool exhausted; retiring request "
+                                    f"{req.id} early"
+                                )
+                            req.kv_exhausted = True
+                            self._retire(slot, "kv_exhausted")
+                    rows = kept
+                    if not rows:
+                        return True
 
             mask = np.zeros(B, bool)
             for slot, _ in rows:
@@ -3407,32 +3392,23 @@ class ServingEngine:
 
             with self._phase("dispatch.launch"):
                 t0 = time.perf_counter()
-                with self._cold_dispatch(
-                    "spec", "paged" if pc is not None else "dense",
-                ) as cold:
-                    if pc is not None:
-                        cap = np.zeros(B, np.int32)
-                        for slot, _ in rows:
-                            cap[slot] = pc.owned_capacity(slot)
-                        cap_d = jnp.asarray(cap)
-                        # unpack into LOCALS (and the pre-bound pc, which a
-                        # restart never mutates): a retired thread's unpack must
-                        # not clobber the replacement engine's state — self.*
-                        # commits happen only after the retirement check below
-                        (packed, pc.k_pool, pc.v_pool, new_rng) = (
-                            batch_ops.verify_and_sample_paged(
-                                cfg, self.params, pc.k_pool, pc.v_pool,
-                                pc.tables_device(), chunk_d, start_d,
-                                self._mask_dev, cap_d,
-                                temp_d, topk_d, topp_d, self.rng,
-                            )
-                        )
-                        new_cache = self.cache  # dense path untouched
-                    else:
-                        packed, new_cache, new_rng = batch_ops.verify_and_sample(
-                            cfg, self.params, self.cache, chunk_d, start_d,
+                with self._cold_dispatch("spec") as cold:
+                    cap = np.zeros(B, np.int32)
+                    for slot, _ in rows:
+                        cap[slot] = pc.owned_capacity(slot)
+                    cap_d = jnp.asarray(cap)
+                    # unpack into LOCALS (and the pre-bound pc, which a
+                    # restart never mutates): a retired thread's unpack must
+                    # not clobber the replacement engine's state — self.*
+                    # commits happen only after the retirement check below
+                    (packed, pc.k_pool, pc.v_pool, new_rng) = (
+                        batch_ops.verify_and_sample_paged(
+                            cfg, self.params, pc.k_pool, pc.v_pool,
+                            pc.tables_device(), chunk_d, start_d,
+                            self._mask_dev, cap_d,
                             temp_d, topk_d, topp_d, self.rng,
                         )
+                    )
 
             with self._phase("dispatch.count"):
                 self._blk_seq += 1
@@ -3453,7 +3429,7 @@ class ServingEngine:
             self._check_retired()
             out_np = packed_np[:, :-1]
             na_np = packed_np[:, -1]
-            self.cache, self.rng = new_cache, new_rng
+            self.rng = new_rng
             self.heartbeat = time.monotonic()  # the sync returned: progress
             step_time = time.perf_counter() - t0
 
@@ -3480,8 +3456,7 @@ class ServingEngine:
                 # the slot was freed)
                 if self.slots[slot] is req:
                     self.cache_len[slot] += committed
-                    if pc is not None:
-                        pc.advance_slot(slot, committed)
+                    pc.advance_slot(slot, committed)
             commit.set(tokens=emitted_total, retired=self._retires - retires)
             self.spec_stats["dispatches"] += 1
             self.spec_stats["accepted"] += accepted_total
@@ -3610,30 +3585,20 @@ class ServingEngine:
             last_logits = None
             lora = self._lora.tables() if self._lora is not None else None
             if chunk_rows:
-                (packed, last_logits, new_cache, new_state, prefill_rows,
+                (packed, last_logits, new_state, prefill_rows,
                  cold) = self._dispatch_ragged(
                     cfg, pc, state, mask_d, chunk_rows, N)
-            elif pc is not None:
+            else:
                 tables_d = pc.tables_device()
-                with self._cold_dispatch("decode", "paged", N,
-                                         lora is not None) as cold:
+                with self._cold_dispatch("decode", N, lora is not None) as cold:
                     (packed, pc.k_pool, pc.v_pool, new_state) = (
                         batch_ops.decode_block_paged(
                             cfg, self.params, pc.k_pool, pc.v_pool, state,
                             tables_d, mask_d, N, lora=lora,
                         )
                     )
-                new_cache = self.cache  # dense path untouched
-            else:
-                with self._cold_dispatch("decode", "dense", N,
-                                         lora is not None) as cold:
-                    packed, new_cache, new_state = batch_ops.decode_block(
-                        cfg, self.params, self.cache, state, mask_d, N,
-                        lora=lora,
-                    )
         with self._phase("dispatch.count"):
             self._check_retired()  # commit to self only as the loop's owner
-            self.cache = new_cache
             self._dec_state = new_state
             for _, req in rows:
                 req.dispatched += N
@@ -3656,7 +3621,7 @@ class ServingEngine:
             window = getattr(cfg, "sliding_window", None)
             if window:  # rows whose window layers no longer see their first key
                 span.set(win_rows=int((self.cache_len[mask] > window).sum()))
-            for pool in (pc.ring_pools if pc is not None else ()):
+            for pool in pc.ring_pools:
                 # a window pool kept as a ring: pages the rows' tables address,
                 # and pages that fell behind the window since the last dispatch
                 held, freed = pc.window_turnover(pool, mask)
@@ -3665,7 +3630,7 @@ class ServingEngine:
                 self._count_prefill_positions(
                     span, chunk_tokens, sum(1 for row in prefill_rows if row[5]),
                     resets=sum(1 for _, _, _, start_pos, _ in chunk_rows if start_pos == 0))
-            if chunk_rows and pc is not None and self._chunk_contexts is not None:
+            if chunk_rows and self._chunk_contexts is not None:
                 span.set(chunk_ctx=self._chunk_ctx(pc, chunk_rows))
             topk = getattr(cfg, "index_topk", None)
             if topk:  # rows whose sparse selection binds: attention reads index_topk of them
@@ -3735,7 +3700,7 @@ class ServingEngine:
 
         N = self._block_steps
         pc = self.paged_cache
-        if pc is not None and rows:
+        if rows:
             # page coverage for the whole block up front, per row and
             # INCLUDING the dispatched-not-yet-consumed gap (the device
             # runs ahead of the committed host mirror by the in-flight
@@ -3777,20 +3742,17 @@ class ServingEngine:
                 n = min(grant, cursor.remaining)
                 if n <= 0:
                     continue
-                if pc is not None:
-                    if not cursor.allocated:
-                        try:
-                            pc.alloc_slot(slot, seq_id=req.id, prompt_len=0,
-                                          reserve_tokens=n)
-                            cursor.allocated = True
-                        except OutOfBlocks:
-                            cursor.blocked = True
-                            continue
-                    elif not pc.try_reserve_slot(
-                        slot, cursor.in_flight + n
-                    ):
+                if not cursor.allocated:
+                    try:
+                        pc.alloc_slot(slot, seq_id=req.id, prompt_len=0,
+                                      reserve_tokens=n)
+                        cursor.allocated = True
+                    except OutOfBlocks:
                         cursor.blocked = True
                         continue
+                elif not pc.try_reserve_slot(slot, cursor.in_flight + n):
+                    cursor.blocked = True
+                    continue
                 chunk_rows.append((slot, cursor, req, cursor.dispatched, n))
 
         return rows, chunk_rows
@@ -3876,7 +3838,7 @@ class ServingEngine:
         """Assemble and launch ONE unified ragged dispatch: the granted
         prefill chunks (per-row slices of their prompts, ragged within the
         fixed [B, C] chunk buffer) plus the N-step decode block, against
-        the same slot cache / page pool — batch_ops.ragged_step*. Rows
+        the same page pool — batch_ops.ragged_step_paged. Rows
         whose chunk completes the prompt get their first token sampled on
         device and are folded into the donated DecodeState inside the
         dispatch; the host reads everything back at the block's single
@@ -3885,9 +3847,8 @@ class ServingEngine:
         B = self.config.max_slots
         C = self._chunk_tokens
         chunk = np.full((B, C), -1, np.int32)
-        # non-chunk rows aim their (masked/inactive) chunk writes past the
-        # dense cache bound so the scatter drops them; paged rows divert
-        # to the trash page via the active mask instead
+        # non-chunk rows start past every position; their chunk writes
+        # divert to the trash page through the active mask
         start = np.full(B, self.config.max_seq_len, np.int32)
         finish = np.zeros(B, bool)
         cactive = np.zeros(B, bool)
@@ -3910,8 +3871,7 @@ class ServingEngine:
             )
             rids[slot] = req.id
             adapters[slot] = req.adapter_slot
-            if pc is not None:
-                kvcap[slot] = pc.owned_capacity(slot)
+            kvcap[slot] = pc.owned_capacity(slot)
         chunk_d = jnp.asarray(chunk)
         start_d = jnp.asarray(start)
         finish_d = jnp.asarray(finish)
@@ -3927,31 +3887,18 @@ class ServingEngine:
         topps_d = jnp.asarray(self.top_p.copy())
         adapters_d = jnp.asarray(adapters)
         lora = self._lora.tables() if self._lora is not None else None
-        if pc is not None:
-            tables_d = pc.tables_device()
-            cactive_d = jnp.asarray(cactive)
-            kvcap_d = jnp.asarray(kvcap)
-            with self._cold_dispatch("ragged", "paged", N,
-                                     lora is not None) as cold:
-                (packed, last_logits, pc.k_pool, pc.v_pool,
-                 new_state) = batch_ops.ragged_step_paged(
-                    cfg, self.params, pc.k_pool, pc.v_pool, state,
-                    tables_d, chunk_d, start_d, cactive_d, kvcap_d,
-                    finish_d, newlen_d, budgets_d, stops_d, temps_d,
-                    topks_d, topps_d, rids_d, self._rng_root,
-                    mask_d, N, adapters=adapters_d, lora=lora,
-                )
-            new_cache = self.cache  # dense path untouched
-        else:
-            with self._cold_dispatch("ragged", "dense", N,
-                                     lora is not None) as cold:
-                (packed, last_logits, new_cache,
-                 new_state) = batch_ops.ragged_step(
-                    cfg, self.params, self.cache, state, chunk_d, start_d,
-                    finish_d, newlen_d, budgets_d, stops_d, temps_d,
-                    topks_d, topps_d, rids_d, self._rng_root, mask_d, N,
-                    adapters=adapters_d, lora=lora,
-                )
+        tables_d = pc.tables_device()
+        cactive_d = jnp.asarray(cactive)
+        kvcap_d = jnp.asarray(kvcap)
+        with self._cold_dispatch("ragged", N, lora is not None) as cold:
+            (packed, last_logits, pc.k_pool, pc.v_pool,
+             new_state) = batch_ops.ragged_step_paged(
+                cfg, self.params, pc.k_pool, pc.v_pool, state,
+                tables_d, chunk_d, start_d, cactive_d, kvcap_d,
+                finish_d, newlen_d, budgets_d, stops_d, temps_d,
+                topks_d, topps_d, rids_d, self._rng_root,
+                mask_d, N, adapters=adapters_d, lora=lora,
+            )
         prefill_rows = []
         for slot, cursor, req, start_pos, n in chunk_rows:
             idx = cursor.chunk_index
@@ -3972,7 +3919,7 @@ class ServingEngine:
                 span.set_attribute(
                     "prefix_tier", req.timeline.prefix_tier or "miss"
                 )
-        return packed, last_logits, new_cache, new_state, prefill_rows, cold
+        return packed, last_logits, new_state, prefill_rows, cold
 
     def _consume_block(self, rec: _Inflight) -> None:
         # declared unpack site (kernel_contracts.UNPACK_SITES): the
@@ -4022,8 +3969,7 @@ class ServingEngine:
                     # committed residency advances by what the device actually
                     # emitted (the device carry already did)
                     self.cache_len[slot] += n_valid
-                    if self.paged_cache is not None:
-                        self.paged_cache.advance_slot(slot, n_valid)
+                    self.paged_cache.advance_slot(slot, n_valid)
                     if req.kv_exhausted:
                         # clamped at dispatch time: retire with the pool-pressure
                         # reason, but only once NO younger in-flight block still
@@ -4056,8 +4002,7 @@ class ServingEngine:
                         n_active += 1
                         cursor.committed = start_pos + n
                         self.cache_len[slot] = cursor.committed
-                        if self.paged_cache is not None:
-                            self.paged_cache.advance_slot(slot, n)
+                        self.paged_cache.advance_slot(slot, n)
                         tl = req.timeline
                         if tl is not None:
                             tl.chunk(idx, n, prefix_hit=False, start=start_pos)
@@ -4082,13 +4027,9 @@ class ServingEngine:
                             # the slices/gathers are fresh buffers safe to retain) and
                             # store it with the prefix's last-position logits, so a
                             # later prompt sharing this prefix skips the chunk
-                            if self.paged_cache is not None:
-                                k_slab, v_slab = self.paged_cache.read_span(
-                                    slot, start_pos, start_pos + n
-                                )
-                            else:
-                                k_slab = self.cache.k[:, slot, start_pos : start_pos + n]
-                                v_slab = self.cache.v[:, slot, start_pos : start_pos + n]
+                            k_slab, v_slab = self.paged_cache.read_span(
+                                slot, start_pos, start_pos + n
+                            )
                             self._prefix_cache.put(
                                 put_key,
                                 (rec.last_logits[slot : slot + 1], k_slab, v_slab),
@@ -4113,16 +4054,15 @@ class ServingEngine:
                     self._metrics.set_gauge(
                         "app_batch_occupancy", n_active / self.config.max_slots
                     )
-                    if self.paged_cache is not None:
-                        kv = self.paged_cache.stats()
-                        self._metrics.set_gauge(
-                            "app_kv_cache_pages_used",
-                            kv["total_blocks"] - kv["free_blocks"],
-                        )
-                        for pool, n in kv.get("pools", {}).items():
-                            for state in ("used", "total"):
-                                self._metrics.set_gauge(
-                                    "app_kv_pool_pages", n[state], pool=pool, state=state)
+                    kv = self.paged_cache.stats()
+                    self._metrics.set_gauge(
+                        "app_kv_cache_pages_used",
+                        kv["total_blocks"] - kv["free_blocks"],
+                    )
+                    for pool, n in kv.get("pools", {}).items():
+                        for state in ("used", "total"):
+                            self._metrics.set_gauge(
+                                "app_kv_pool_pages", n[state], pool=pool, state=state)
                     self._metrics.set_gauge("app_decode_block_size", rec.steps)
                     with self._detok_mu:
                         depth = self._detok_depth
@@ -4273,19 +4213,15 @@ class ServingEngine:
                     sum(1 for s in self.slots if s is not None),
                 )
                 dspan.set_attribute("kv.resident_tokens", resident)
-                if self.paged_cache is not None:
-                    page = self.config.kv_page_size
-                    dspan.set_attribute(
-                        "kv.pages", (resident + page - 1) // page
-                    )
+                page = self.config.kv_page_size
+                dspan.set_attribute("kv.pages", (resident + page - 1) // page)
         self._retires += 1
         self.slots[slot] = None
         self.cache_len[slot] = 0
         self.adapter_idx[slot] = 0
         self._preempt_pending.discard(slot)
         self._cursors.pop(slot, None)  # a mid-chunked-prefill retire
-        if self.paged_cache is not None:
-            self.paged_cache.free_slot(slot)
+        self.paged_cache.free_slot(slot)
         try:
             self._sched.release(slot)
         except KeyError:
@@ -4478,11 +4414,7 @@ class ServingEngine:
 
     def _poison_device(self) -> None:
         try:
-            if self.cache is not None:
-                self.cache.k.delete()
-                self.cache.v.delete()
-            elif self.paged_cache is not None:
-                self.paged_cache.delete_pools()
+            self.paged_cache.delete_pools()
         except Exception:
             pass  # already deleted / backend gone: the poison took either way
 
@@ -4495,13 +4427,9 @@ class ServingEngine:
         detector). Either way every subsequent step would raise forever.
         CPU runs delete donated buffers too (jax 0.9), so tests exercise
         the donation half for real."""
-        arr = None
-        if self.cache is not None:
-            arr = self.cache.k
-        elif self.paged_cache is not None:
-            arr = self.paged_cache.probe()
-        if arr is None:
-            return False
+        if self.paged_cache is None:
+            return False  # released by a warm restart whose rebuild failed
+        arr = self.paged_cache.probe()
         try:
             if arr.is_deleted():
                 return True
@@ -4509,15 +4437,6 @@ class ServingEngine:
             return False
         except Exception:
             return True
-
-    def _make_dense_cache(self) -> llama.KVCache:
-        """The one dense slot-cache constructor, shared by __init__ and
-        donation-failure recovery so the rebuilt cache can never drift
-        from the one the engine started with."""
-        return batch_ops.model_of(self.model_cfg).KVCache.create(
-            self.model_cfg, self.config.max_slots,
-            max_len=self.config.max_seq_len,
-        )
 
     def _make_paged_cache(self):
         """The one paged pool constructor, shared by __init__ and the
@@ -4563,12 +4482,7 @@ class ServingEngine:
         rebuilds, advanced at each consume."""
         B = self.config.max_slots
         with self._device_scope():
-            if self.config.kv_layout == "paged":
-                self.paged_cache = self._make_paged_cache()
-                self.cache = None
-            else:
-                self.paged_cache = None
-                self.cache = self._make_dense_cache()
+            self.paged_cache = self._make_paged_cache()
         self.cache_len = np.zeros(B, np.int32)  # host mirror (committed tokens)
         self.last_token = np.zeros(B, np.int32)
         self.temperature = np.ones(B, np.float32)
@@ -4614,10 +4528,7 @@ class ServingEngine:
         lost mid-dispatch. Every slot's residency is gone, so this only
         runs on the _fail_all path where all active requests already
         failed; fresh zeroed storage restores a servable engine."""
-        if self.cache is not None:
-            self.cache = self._make_dense_cache()
-        elif self.paged_cache is not None:
-            self.paged_cache.reset_pools()
+        self.paged_cache.reset_pools()
         if self._logger:
             self._logger.warn(
                 "KV storage rebuilt after a failed dispatch deleted the "
@@ -4657,11 +4568,10 @@ class ServingEngine:
                 self.slots[slot] = None
                 self.cache_len[slot] = 0
                 self._lora_release(req)
-                if self.paged_cache is not None:
-                    try:
-                        self.paged_cache.free_slot(slot)
-                    except Exception:
-                        pass
+                try:
+                    self.paged_cache.free_slot(slot)
+                except Exception:
+                    pass
                 try:
                     self._sched.release(slot)
                 except KeyError:

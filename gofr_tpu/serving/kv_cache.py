@@ -1,7 +1,6 @@
 """Paged KV cache: pooled pages + native block-table accounting.
 
-Replaces per-slot dense KV rows ([slots, max_seq] preallocation) with a
-shared page pool ([L, N_pages+1, Hkv, page, Dh] — the +1 is a trash page
+The engine's one KV layout, a shared page pool ([L, N_pages+1, Hkv, page, Dh] — the +1 is a trash page
 for inactive rows' redirected writes): sequences own pages through the
 native BlockAllocator (native/runtime/gofr_runtime.cc — the refcounted
 allocator with copy-on-write forks), so HBM is committed by tokens

@@ -23,8 +23,8 @@ multi-tier KV pooling arXiv:2504.03648), in two halves:
   slabs are already device arrays), serialized page transfer over the
   HTTP surface otherwise (``/kv/fetch``, serving/handlers.py +
   ``HTTPReplica.fetch_kv``). Fetched entries admit through the existing
-  chunk-prefix commit path (``kv_cache.write_span`` /
-  ``batch_ops.insert_chunk``) and land in the local cache, so the
+  chunk-prefix commit path (``kv_cache.write_span``) and land in the
+  local cache, so the
   migration pays once per replica, not once per request.
 
 The ``kv.migrate`` chaos point sits on every peer fetch: a fault there

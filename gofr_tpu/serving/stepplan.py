@@ -16,7 +16,7 @@ that mixes
 
 The mechanism half — running the granted chunks and the decode block in
 one unified ragged dispatch against the KV pool — lives in
-``serving/batch.py`` (``ragged_step*``) and ``serving/engine.py``
+``serving/batch.py`` (``ragged_step_paged``) and ``serving/engine.py``
 (Ragged Paged Attention, arXiv:2604.15464).
 
 Budget policy (docs/performance.md "Continuous batching"):

@@ -164,7 +164,7 @@ def test_host_sync_int_propagates_through_unpack_and_copy(tmp_path):
         "gofr_tpu/serving/engine.py": (
             "from gofr_tpu.serving import batch as batch_ops\n\n"
             "def _dispatch_decode(self):\n"
-            "    packed, cache, state = batch_ops.decode_block(self.p)\n"
+            "    packed, k_pool, v_pool, state = batch_ops.decode_block_paged(self.p)\n"
             "    alias = packed\n"
             "    return bool(alias[0, 0])\n"
         ),

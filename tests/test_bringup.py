@@ -146,8 +146,7 @@ def tiny_model():
     return cfg, llama.init_params(cfg, jax.random.PRNGKey(0))
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_engine_lives_on_the_device_it_was_given(tiny_model, layout):
+def test_engine_lives_on_the_device_it_was_given(tiny_model):
     cfg, params = tiny_model
     dev = jax.devices()[3]
     lora = AdapterRegistry(max_active=2, device=dev)
@@ -155,14 +154,12 @@ def test_engine_lives_on_the_device_it_was_given(tiny_model, layout):
     engine = ServingEngine(
         cfg, params,
         EngineConfig(max_slots=2, max_seq_len=64, prefill_buckets=(16,),
-                     prefill_chunk_tokens=16, kv_layout=layout, kv_page_size=8,
+                     prefill_chunk_tokens=16, kv_page_size=8,
                      prefix_cache_entries=8),
         ByteTokenizer(), lora=lora, device=dev,
     )
 
     def kv_state():
-        if engine.cache is not None:
-            return engine.cache
         pc = engine.paged_cache
         return (pc.k_pool, pc.v_pool)
 
@@ -201,7 +198,8 @@ def test_two_engines_on_two_devices_share_no_buffers(tiny_model):
         assert _leaf_devices(e1.params) == {d1}
         assert _leaf_devices(e2.params) == {d2}
         assert not _buffers(e1.params) & _buffers(e2.params)
-        assert not _buffers(e1.cache) & _buffers(e2.cache)
+        pools = lambda e: (e.paged_cache.k_pool, e.paged_cache.v_pool)  # noqa: E731
+        assert not _buffers(pools(e1)) & _buffers(pools(e2))
         # neither aliases the caller's tree (it lives on device 0)
         assert not _buffers(params) & (_buffers(e1.params) | _buffers(e2.params))
     finally:

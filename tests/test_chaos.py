@@ -264,17 +264,15 @@ def _assert_reclaimed(eng: ServingEngine) -> None:
             break
         time.sleep(0.02)
     assert all(s is None for s in eng.slots)
-    if eng.paged_cache is not None:
-        stats = eng.paged_cache.stats()
-        assert stats["free_blocks"] == stats["total_blocks"], stats
-        assert stats["sequences"] == 0
+    stats = eng.paged_cache.stats()
+    assert stats["free_blocks"] == stats["total_blocks"], stats
+    assert stats["sequences"] == 0
 
 
 @pytest.mark.chaos
 @pytest.mark.slow
 @pytest.mark.parametrize("seed", CHAOS_SEEDS)
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_lifecycle_invariant_under_faults(seed, kv_layout, monkeypatch):
+def test_lifecycle_invariant_under_faults(seed, monkeypatch):
     from gofr_tpu.analysis import leaktrace
     from gofr_tpu.tracing import Tracer
 
@@ -286,10 +284,7 @@ def test_lifecycle_invariant_under_faults(seed, kv_layout, monkeypatch):
     # (GOFR_LEAK_EXPORT, docs/static-analysis.md#leakcheck)
     leak_mon = leaktrace.install()
     try:
-        kw = dict(kv_layout=kv_layout)
-        if kv_layout == "paged":
-            kw.update(kv_page_size=8)
-        eng = make_engine(tracer=tracer, **kw)
+        eng = make_engine(tracer=tracer, kv_page_size=8)
 
         # pin "expired requests are never prefilled": track born-dead
         # requests
@@ -316,9 +311,8 @@ def test_lifecycle_invariant_under_faults(seed, kv_layout, monkeypatch):
             "sched.submit": 0.08,
             "sched.admit": 0.04,
             "decode.dispatch": 0.04,
+            "kv.alloc": 0.10,
         }
-        if kv_layout == "paged":
-            rates["kv.alloc"] = 0.10
         inj = chaos.ChaosInjector(seed, rates, max_faults=3)
 
         eng.start()
@@ -403,28 +397,23 @@ def _assert_chunk_spans_never_double_prefill(eng: ServingEngine) -> None:
 @pytest.mark.chaos
 @pytest.mark.slow
 @pytest.mark.parametrize("seed", CHAOS_SEEDS)
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_mid_chunk_faults_preserve_lifecycle(seed, kv_layout):
+def test_mid_chunk_faults_preserve_lifecycle(seed):
     """Faults landing MID-CHUNKED-PREFILL — while a step plan is being
     assembled (the sched.plan point) and while the paged pool is under
     pressure — must preserve the lifecycle invariant for partially-
     prefilled requests: slots+pages reclaimed, exactly one terminal per
     request, and a chunk cursor never double-prefills committed KV."""
-    kw = dict(
-        kv_layout=kv_layout, max_seq_len=128, prefill_buckets=(16,),
-        prefill_chunk_tokens=8, max_slots=2,
+    eng = make_engine(
+        max_seq_len=128, prefill_buckets=(16,), prefill_chunk_tokens=8,
+        max_slots=2, kv_page_size=8, kv_num_pages=20,  # tight: real pressure
     )
-    if kv_layout == "paged":
-        kw.update(kv_page_size=8, kv_num_pages=20)  # tight: real pressure
-    eng = make_engine(**kw)
 
     rates = {
         "sched.plan": 0.05,
         "sched.admit": 0.04,
         "decode.dispatch": 0.04,
+        "kv.alloc": 0.10,
     }
-    if kv_layout == "paged":
-        rates["kv.alloc"] = 0.10
     inj = chaos.ChaosInjector(seed, rates, max_faults=3)
 
     outcomes: list = []

@@ -278,16 +278,15 @@ def test_weight_only_layer_norm_subtracts_the_mean():
 # ------------------------------------------- (f), (g): the engine and the App
 def engine_settings(**kw):
     settings = dict(max_slots=3, max_seq_len=64, prefill_buckets=(16,), multi_step=4,
-                    kv_layout="paged", kv_page_size=8, prefill_chunk_tokens=16)
+                    kv_page_size=8, prefill_chunk_tokens=16)
     settings.update(kw)
     return EngineConfig(**settings)
 
 
 @pytest.mark.parametrize("settings, lora, sentence", [
-    (dict(kv_layout="dense"), None, "paged KV layout only"),
     (dict(spec_tokens=2, multi_step=None), None, "no speculative verify program"),
     (dict(), object(), "serves no LoRA adapters"),
-], ids=["dense", "speculative", "lora"])
+], ids=["speculative", "lora"])
 def test_engines_the_model_has_no_program_for_are_refused_at_construction(plain, settings, lora, sentence):
     with pytest.raises(ValueError, match=sentence):
         ServingEngine(CFG, plain, engine_settings(**settings), ByteTokenizer(300), lora=lora)

@@ -214,17 +214,15 @@ def test_planner_final_ragged_chunk_grant():
 
 # -- chunked prefill correctness ---------------------------------------------
 
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_chunked_prefill_matches_monolithic_greedy(engine_setup, kv_layout):
+def test_chunked_prefill_matches_monolithic_greedy(engine_setup):
     """Chunked prefill is a SCHEDULING change: greedy tokens must match
     the monolithic bucketed path exactly (the on-device first-token
     sample uses the same fold_in(root, request_id) key)."""
     cfg, params = engine_setup
-    kw = {} if kv_layout == "dense" else dict(kv_layout="paged", kv_page_size=8)
     mono = make_engine(cfg, params, prefill_chunk_tokens=128,
-                       prefill_buckets=(64,), **kw)
+                       prefill_buckets=(64,), kv_page_size=8)
     chunked = make_engine(cfg, params, prefill_chunk_tokens=16,
-                          prefill_buckets=(64,), **kw)
+                          prefill_buckets=(64,), kv_page_size=8)
     mono.start(), chunked.start()
     try:
         prompt = "the quick brown fox jumps over the lazy dog " * 1
@@ -253,7 +251,7 @@ def test_waiting_prompts_share_an_iteration_up_to_the_bound(engine_setup, tmp_pa
 
     cfg, params = engine_setup
     bound = 4  # the block's decode steps: chunk rows an iteration
-    kw = dict(kv_layout="paged", kv_page_size=8, max_slots=8, max_seq_len=192,
+    kw = dict(kv_page_size=8, max_slots=8, max_seq_len=192,
               prefill_buckets=(128,), multi_step=bound)
     prompts = [chr(ord("a") + i) * (97 + i) for i in range(6)]  # 7 chunks of 16 each
     mono = make_engine(cfg, params, prefill_chunk_tokens=128, **kw)
@@ -432,7 +430,7 @@ def test_mixed_load_decode_not_starved_and_ttft_bounded(engine_setup):
 
 def test_cancel_mid_chunked_prefill_reclaims_slot(engine_setup):
     cfg, params = engine_setup
-    engine = make_engine(cfg, params, kv_layout="paged", kv_page_size=8)
+    engine = make_engine(cfg, params, kv_page_size=8)
     engine.start()
     try:
         # warm so the cancel window is not dominated by compiles
@@ -467,7 +465,7 @@ def test_warm_restart_requeues_partially_prefilled_from_chunk_zero(
     engine = make_engine(cfg, params)
     hold = threading.Event()
     seen = threading.Event()
-    real = batch_ops.ragged_step
+    real = batch_ops.ragged_step_paged
 
     def stalling(*args, **kw):
         if not seen.is_set():
@@ -475,7 +473,7 @@ def test_warm_restart_requeues_partially_prefilled_from_chunk_zero(
             hold.wait(20)
         return real(*args, **kw)
 
-    monkeypatch.setattr(batch_ops, "ragged_step", stalling)
+    monkeypatch.setattr(batch_ops, "ragged_step_paged", stalling)
     engine.start()
     try:
         engine.submit("warm", max_new_tokens=2, temperature=0.0).result(timeout=300)
@@ -503,7 +501,7 @@ def test_kv_pool_pressure_requeues_cursor_from_chunk_zero(engine_setup):
     transient, not an error, and no pages leak."""
     cfg, params = engine_setup
     engine = make_engine(
-        cfg, params, max_slots=2, kv_layout="paged", kv_page_size=8,
+        cfg, params, max_slots=2, kv_page_size=8,
         kv_num_pages=24,  # 192 tokens of pool: two 80-token prompts contend
     )
     engine.start()
@@ -524,11 +522,9 @@ def test_kv_pool_pressure_requeues_cursor_from_chunk_zero(engine_setup):
 
 # -- chunk-prefix cache -------------------------------------------------------
 
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_chunk_prefix_cache_skips_cached_chunks(engine_setup, kv_layout):
+def test_chunk_prefix_cache_skips_cached_chunks(engine_setup):
     cfg, params = engine_setup
-    kw = {} if kv_layout == "dense" else dict(kv_layout="paged", kv_page_size=8)
-    engine = make_engine(cfg, params, prefix_cache_entries=64, **kw)
+    engine = make_engine(cfg, params, prefix_cache_entries=64, kv_page_size=8)
     engine.start()
     try:
         prompt = "shared prefix " * 5  # 70 tokens -> 5 chunks
@@ -578,8 +574,7 @@ def test_deprecated_knobs_feed_the_planner(engine_setup):
     size aligns down to the page grid on the paged layout."""
     cfg, params = engine_setup
     eng = make_engine(cfg, params, admission_per_step=3,
-                      prefill_chunk_tokens=30, kv_layout="paged",
-                      kv_page_size=8)
+                      prefill_chunk_tokens=30, kv_page_size=8)
     assert eng._planner.max_admissions == 3
     assert eng._chunk_tokens == 24  # 30 aligned down to page 8
     eng2 = make_engine(cfg, params, spec_tokens=2, multi_step=None)

@@ -129,15 +129,13 @@ def test_prefill_only_retires_with_handoff_and_emits_nothing(engine_setup):
         eng.stop()
 
 
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_handoff_token_identity_zero_prefill_compute(engine_setup, kv_layout):
+def test_handoff_token_identity_zero_prefill_compute(engine_setup):
     """THE handoff acceptance: the decode replica admits the handed-off
     chain with zero prefill-compute dispatches, token-identical to a
     unified replica serving the same prompt."""
     cfg, params = engine_setup
-    kw = {} if kv_layout == "dense" else dict(kv_layout="paged", kv_page_size=8)
-    _index, a, b, migrator = wire_pair(cfg, params, **kw)
-    ref = mk(cfg, params, **kw)
+    _index, a, b, migrator = wire_pair(cfg, params, kv_page_size=8)
+    ref = mk(cfg, params, kv_page_size=8)
     a.start(); b.start(); ref.start()
     try:
         for prompt, max_new in ((CHUNKED_PROMPT, 5), (SHORT_PROMPT, 4)):

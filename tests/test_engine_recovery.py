@@ -62,26 +62,25 @@ def test_cpu_enforces_donation():
         _ = a[0]
 
 
-@pytest.mark.parametrize("kv_layout, entry, n_donated", [
-    ("dense", "decode_block", 1), ("paged", "decode_block_paged", 2),
-], ids=["dense", "paged"])
-def test_decode_failure_after_donation_recovers(monkeypatch, kv_layout, entry, n_donated):
-    """A decode dispatch that deletes its donated cache (dense) or its two
-    donated pools (paged) and then raises (transport failure after donation
-    committed) fails the in-flight requests but leaves the engine servable:
-    the recovery path detects the deleted KV storage and rebuilds it."""
-    eng = make_engine(kv_layout=kv_layout, kv_page_size=8, multi_step=2)
-    real_block = getattr(batch_ops, entry)
+@pytest.mark.parametrize("multi_step", [2, None], ids=["2", "default"])
+def test_decode_failure_after_donation_recovers(monkeypatch, multi_step):
+    """A decode dispatch that deletes its two donated pools and then
+    raises (transport failure after donation committed) fails the
+    in-flight requests but leaves the engine servable: the recovery path
+    detects the deleted KV storage and rebuilds the pools
+    (PagedKVCache.reset_pools), at a set block length and the default."""
+    eng = make_engine(kv_page_size=8, multi_step=multi_step)
+    real_block = batch_ops.decode_block_paged
     boom = {"n": 0}
 
-    def wrapper(cfg, params, *args, **kw):
+    def wrapper(cfg, params, k_pool, v_pool, *args, **kw):
         if boom["n"] == 0:
             boom["n"] += 1
-            _delete_leaves(args[:n_donated])
+            _delete_leaves((k_pool, v_pool))
             raise RuntimeError("transient transport failure post-donation")
-        return real_block(cfg, params, *args, **kw)
+        return real_block(cfg, params, k_pool, v_pool, *args, **kw)
 
-    monkeypatch.setattr(batch_ops, entry, wrapper)
+    monkeypatch.setattr(batch_ops, "decode_block_paged", wrapper)
     eng.start()
     try:
         fut = eng.submit("hello world", max_new_tokens=8, temperature=0.0)
@@ -97,27 +96,34 @@ def test_decode_failure_after_donation_recovers(monkeypatch, kv_layout, entry, n
         res = eng.submit("try again", max_new_tokens=4, temperature=0.0).result(
             timeout=60
         )
+        # random-init weights may emit EOS first (filtered from the
+        # output), so a served-and-finished result with zero kept
+        # tokens is a valid recovery outcome
         assert res.finish_reason in ("stop", "length")
+        assert not eng.paged_cache.k_pool.is_deleted()
     finally:
         eng.stop()
 
 
 def test_prefill_failure_after_donation_recovers(monkeypatch):
-    """The prefill insert donates the SHARED cache; when it dies post-
-    donation the per-request error handling must escalate to full recovery
-    (isolated cleanup would leave every later step raising)."""
+    """The prefill write (PagedKVCache.write_prefill) donates the SHARED
+    pools; when it dies post-donation the per-request error handling must
+    escalate to full recovery (isolated cleanup would leave every later
+    step raising)."""
+    from gofr_tpu.serving import kv_cache
+
     eng = make_engine()
-    real = batch_ops.insert_slot
+    real = kv_cache._write_pages
     boom = {"n": 0}
 
-    def wrapper(k_cache, v_cache, *args, **kw):
+    def wrapper(k_pool, v_pool, *args, **kw):
         if boom["n"] == 0:
             boom["n"] += 1
-            _delete_leaves((k_cache, v_cache))
+            _delete_leaves((k_pool, v_pool))
             raise RuntimeError("transient transport failure post-donation")
-        return real(k_cache, v_cache, *args, **kw)
+        return real(k_pool, v_pool, *args, **kw)
 
-    monkeypatch.setattr(batch_ops, "insert_slot", wrapper)
+    monkeypatch.setattr(kv_cache, "_write_pages", wrapper)
     eng.start()
     try:
         fut = eng.submit("doomed", max_new_tokens=4, temperature=0.0)
@@ -134,39 +140,6 @@ def test_prefill_failure_after_donation_recovers(monkeypatch):
         eng.stop()
 
 
-def test_paged_pool_failure_recovers(monkeypatch):
-    """Paged twin: a paged decode dispatch that deletes the donated pools
-    and raises must trigger a pool rebuild (PagedKVCache.reset_pools)."""
-    eng = make_engine(kv_layout="paged", kv_page_size=8)
-    real = batch_ops.decode_block_paged
-    boom = {"n": 0}
-
-    def wrapper(cfg, params, k_pool, v_pool, *args, **kw):
-        if boom["n"] == 0:
-            boom["n"] += 1
-            k_pool.delete()
-            v_pool.delete()
-            raise RuntimeError("transient transport failure post-donation")
-        return real(cfg, params, k_pool, v_pool, *args, **kw)
-
-    monkeypatch.setattr(batch_ops, "decode_block_paged", wrapper)
-    eng.start()
-    try:
-        fut = eng.submit("doomed", max_new_tokens=8, temperature=0.0)
-        with pytest.raises(RuntimeError):
-            fut.result(timeout=60)
-        res = eng.submit("alive", max_new_tokens=4, temperature=0.0).result(
-            timeout=60
-        )
-        # random-init weights may emit EOS first (filtered from the
-        # output), so a served-and-finished result with zero kept
-        # tokens is a valid recovery outcome
-        assert res.finish_reason in ("stop", "length")
-        assert not eng.paged_cache.k_pool.is_deleted()
-    finally:
-        eng.stop()
-
-
 def test_block_output_survives_donated_carry_redispatch():
     """Regression pin for the round-4 crash shape ("Array has been deleted
     with shape=int32[32]"): the packed block output the host reads must be
@@ -175,9 +148,15 @@ def test_block_output_survives_donated_carry_redispatch():
     must leave block k's packed result readable. CPU jax enforces donation
     (test_cpu_enforces_donation), so an aliasing regression raises here
     without TPU hardware."""
+    from gofr_tpu.serving.kv_cache import PagedKVCache
+
     cfg = tiny_cfg(32)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    cache = llama.KVCache.create(cfg, 2, max_len=32)
+    pc = PagedKVCache(cfg, num_pages=8, page_size=8, max_slots=2, max_seq_len=32,
+                      page_shapes=llama.page_shapes(cfg, 8))
+    for slot in range(2):
+        pc.alloc_slot(slot, seq_id=slot, prompt_len=4, reserve_tokens=12)
+    tables = pc.tables_device()
     state = batch_ops.make_decode_state(
         np.array([5, 7], np.int32), np.array([4, 4], np.int32),
         np.array([False, False]), np.array([8, 8], np.int32),
@@ -186,17 +165,18 @@ def test_block_output_survives_donated_carry_redispatch():
         jax.random.PRNGKey(1),
     )
     active = jnp.ones(2, bool)
-    packed_k, cache, state = batch_ops.decode_block(
-        cfg, params, cache, state, active, 4
+    packed_k, pc.k_pool, pc.v_pool, state = batch_ops.decode_block_paged(
+        cfg, params, pc.k_pool, pc.v_pool, state, tables, active, 4
     )
-    # block k+1 donates BOTH the cache and the state that produced packed_k
-    packed_k1, cache, state = batch_ops.decode_block(
-        cfg, params, cache, state, active, 4
+    # block k+1 donates the pools AND the state that produced packed_k
+    packed_k1, pc.k_pool, pc.v_pool, state = batch_ops.decode_block_paged(
+        cfg, params, pc.k_pool, pc.v_pool, state, tables, active, 4
     )
     got = np.asarray(packed_k)  # must not raise "Array has been deleted"
     assert got.shape == (2, 6)
     assert int(got[0, 5]) >= 1  # n_valid column populated
     assert np.asarray(packed_k1).shape == (2, 6)
+    pc.close()
 
 
 @pytest.mark.parametrize("multi_step", [1, 4])

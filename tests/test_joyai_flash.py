@@ -412,18 +412,17 @@ def test_the_pager_keeps_one_latent_pool_and_no_second():
 
 def engine_settings(**kw):
     settings = dict(max_slots=3, max_seq_len=64, prefill_buckets=(16,), multi_step=4,
-                    kv_layout="paged", kv_page_size=8, prefill_chunk_tokens=16, prefix_cache_entries=0)
+                    kv_page_size=8, prefill_chunk_tokens=16, prefix_cache_entries=0)
     settings.update(kw)
     return EngineConfig(**settings)
 
 
 @pytest.mark.parametrize("settings, lora, sentence", [
-    (dict(kv_layout="dense"), None, "paged KV layout only"),
     (dict(spec_tokens=2, multi_step=None), None, "multi-token-prediction module is not served"),
     (dict(), object(), "serves no LoRA adapters"),
     (dict(kv_spill_bytes=1 << 20), None, "TPU_KV_SPILL_BYTES=0"),
     (dict(role="prefill"), None, "unified replicas"),
-], ids=["dense", "speculative", "lora", "spill", "disaggregated"])
+], ids=["speculative", "lora", "spill", "disaggregated"])
 def test_engines_the_model_has_no_program_for_are_refused_at_construction(plain, settings, lora, sentence):
     with pytest.raises(ValueError, match=sentence):
         ServingEngine(CFG, plain, engine_settings(**settings), ByteTokenizer(300), lora=lora)

@@ -141,19 +141,21 @@ def test_pack_helper_column_swap_flagged(tmp_path):
 
 
 def test_spec_kernel_missing_scalar_column_flagged(tmp_path):
-    # verify_and_sample that forgets the n_accept tail column
+    # verify_and_sample_paged that forgets the n_accept tail column
     findings = lint_tree(tmp_path, {
         "gofr_tpu/serving/batch.py": (
             "import jax\n"
             "import jax.numpy as jnp\n"
             "from functools import partial\n"
             "\n"
-            "@partial(jax.jit, static_argnums=0, donate_argnums=(2,))\n"
-            "def verify_and_sample(cfg, params, cache, chunk, start_len,\n"
-            "                      temperature, top_k, top_p, rng):\n"
+            "@partial(jax.jit, static_argnums=0, donate_argnums=(2, 3))\n"
+            "def verify_and_sample_paged(cfg, params, k_pool, v_pool,\n"
+            "                            block_tables, chunk, start_len, active,\n"
+            "                            kv_capacity, temperature, top_k,\n"
+            "                            top_p, rng):\n"
             "    out = chunk\n"
             "    packed = jnp.concatenate([out.astype(jnp.int32)], axis=1)\n"
-            "    return packed, cache, rng\n"
+            "    return packed, k_pool, v_pool, rng\n"
         ),
     }, rules=[PackLayoutRule()])
     assert any(
@@ -169,10 +171,10 @@ def test_decode_block_wrong_helper_flagged(tmp_path):
             "import jax\n"
             "from functools import partial\n"
             "\n"
-            "@partial(jax.jit, static_argnums=(0, 5), donate_argnums=(2, 3))\n"
-            "def decode_block(cfg, params, cache, state, active, steps,\n"
-            "                 lora=None):\n"
-            "    return _pack_ragged(None, None, active, None), cache, state\n"
+            "@partial(jax.jit, static_argnums=(0, 7), donate_argnums=(2, 3, 4))\n"
+            "def decode_block_paged(cfg, params, k_pool, v_pool, state,\n"
+            "                       block_tables, active, steps, lora=None):\n"
+            "    return _pack_ragged(None, None, active, None), k_pool, v_pool, state\n"
         ),
     }, rules=[PackLayoutRule()])
     msgs = [f.message for f in findings]
@@ -188,10 +190,10 @@ def test_decode_block_declared_helper_clean(tmp_path):
             "import jax\n"
             "from functools import partial\n"
             "\n"
-            "@partial(jax.jit, static_argnums=(0, 5), donate_argnums=(2, 3))\n"
-            "def decode_block(cfg, params, cache, state, active, steps,\n"
-            "                 lora=None):\n"
-            "    return _pack_block(None, None, active), cache, state\n"
+            "@partial(jax.jit, static_argnums=(0, 7), donate_argnums=(2, 3, 4))\n"
+            "def decode_block_paged(cfg, params, k_pool, v_pool, state,\n"
+            "                       block_tables, active, steps, lora=None):\n"
+            "    return _pack_block(None, None, active), k_pool, v_pool, state\n"
         ),
     }, rules=[PackLayoutRule()])
     assert findings == [], rules_of(findings)
@@ -631,15 +633,14 @@ def _decode_block_case(**over):
         * 2 + [[[3], "float32"]] + [[[3], "int32"]] + [[[3], "float32"]]
         + [[[2], "uint32"]] + [[[3], "int32"]],
     }
-    cache = {"tree": "KVCache", "leaves": [[[2, 3, 32, 2, 16],
-                                            "float32"]] * 2}
+    pool = _sig((2, 7, 2, 4, 16), "float32")
     case = {
-        "kernel": "decode_block",
+        "kernel": "decode_block_paged",
         "variant": "t",
-        "inputs": {"active": _sig((3,), "bool"), "cache": cache,
-                   "state": state},
+        "inputs": {"active": _sig((3,), "bool"), "k_pool": pool,
+                   "v_pool": pool, "state": state},
         "statics": {"steps": 4},
-        "outputs": [_sig((3, 6), "int32"), cache, state],
+        "outputs": [_sig((3, 6), "int32"), pool, pool, state],
     }
     case.update(over)
     return case
@@ -668,9 +669,9 @@ def test_check_kernel_table_packed_dtype_drift():
 
 def test_check_kernel_table_donated_carry_drift():
     bad = _decode_block_case()
-    drifted = dict(bad["outputs"][2])
+    drifted = dict(bad["outputs"][3])
     drifted["leaves"] = drifted["leaves"][:-1]  # adapter leaf dropped
-    bad["outputs"][2] = drifted
+    bad["outputs"][3] = drifted
     div = check_kernel_table({"mode": "observed", "cases": [bad]})
     assert any("donated-carry drift" in d for d in div), div
 
@@ -679,7 +680,7 @@ def test_check_kernel_table_output_arity_drift():
     bad = _decode_block_case()
     bad["outputs"] = bad["outputs"][:2]
     div = check_kernel_table({"mode": "observed", "cases": [bad]})
-    assert any("returned 2 output(s); the contract declares 3" in d
+    assert any("returned 2 output(s); the contract declares 4" in d
                for d in div)
 
 
@@ -688,7 +689,7 @@ def test_check_kernel_table_unknown_kernel_and_violations():
         "mode": "observed",
         "cases": [{"kernel": "mystery_kernel", "variant": "x",
                    "inputs": {}, "statics": {}, "outputs": []}],
-        "violations": ["decode_block: dispatched with undeclared kw"],
+        "violations": ["decode_block_paged: dispatched with undeclared kw"],
     })
     assert any("no declared contract" in d for d in div)
     assert any(d.startswith("runtime violation:") for d in div)
@@ -698,7 +699,7 @@ def test_check_kernel_table_matrix_requires_full_batch_coverage():
     div = check_kernel_table(
         {"mode": "matrix", "cases": [_decode_block_case()]}
     )
-    assert any("'ragged_step' was never exercised" in d for d in div)
+    assert any("'ragged_step_paged' was never exercised" in d for d in div)
     # observed mode is a real workload: partial coverage is fine
     assert check_kernel_table(
         {"mode": "observed", "cases": [_decode_block_case()]}
@@ -833,4 +834,4 @@ def test_cli_kernel_table_emits_table(capsys):
 
     assert main(["--kernel-table"]) == 0
     blob = json.loads(capsys.readouterr().out)
-    assert "decode_block" in {k["name"] for k in blob["kernels"]}
+    assert "decode_block_paged" in {k["name"] for k in blob["kernels"]}

@@ -88,8 +88,8 @@ def test_matrix_covers_every_batch_kernel(matrix_payload):
     assert declared <= exercised, declared - exercised
     # and the config matrix axes actually vary
     variants = {c["variant"] for c in matrix_payload["cases"]
-                if c["kernel"] == "decode_block"}
-    assert {"dense.b3n4", "dense.b2n2", "dense.lora"} <= variants
+                if c["kernel"] == "decode_block_paged"}
+    assert {"paged.b3n4", "paged.b2n2", "paged.lora"} <= variants
 
 
 def test_table_and_live_jitted_entries_are_the_same_set(matrix_payload):
@@ -100,7 +100,7 @@ def test_table_and_live_jitted_entries_are_the_same_set(matrix_payload):
     every one of them."""
     import importlib
 
-    assert len(kc.KERNELS) == len(kc.CONTRACTS) == 17
+    assert len(kc.KERNELS) == len(kc.CONTRACTS) == 12
     for rel in kc.KERNEL_FILES:
         mod = importlib.import_module(rel[:-3].replace("/", "."))
         live = {
@@ -139,13 +139,13 @@ def test_check_kernel_table_flags_a_doctored_export(tmp_path):
     payload = kerneltrace.run_matrix()
     doctored = json.loads(json.dumps(payload))
     for case in doctored["cases"]:
-        if case["kernel"] == "decode_block":
+        if case["kernel"] == "decode_block_paged":
             # widen the packed block by one column
             shape = case["outputs"][0]["leaves"][0][0]
             shape[-1] += 1
             break
     divergences = check_kernel_table(doctored)
-    assert any("decode_block" in d and "by the contract" in d
+    assert any("decode_block_paged" in d and "by the contract" in d
                for d in divergences), divergences
 
     from gofr_tpu.analysis.__main__ import main as analysis_main
@@ -175,7 +175,7 @@ def test_observer_live_engine_matches_contract_table():
 
     obs = kerneltrace.KernelObserver().install()
     try:
-        assert getattr(batch.decode_block, "__kerneltrace_wrapped__",
+        assert getattr(batch.decode_block_paged, "__kerneltrace_wrapped__",
                        None) is not None
         engine.start()
         try:
@@ -192,14 +192,14 @@ def test_observer_live_engine_matches_contract_table():
         obs.uninstall()
 
     # passthrough restored
-    assert getattr(batch.decode_block, "__kerneltrace_wrapped__",
+    assert getattr(batch.decode_block_paged, "__kerneltrace_wrapped__",
                    None) is None
 
     payload = obs.export()
     assert payload["violations"] == []
     exercised = {c["kernel"] for c in payload["cases"]}
     assert "prefill_compute" in exercised
-    assert "decode_block" in exercised
+    assert "decode_block_paged" in exercised
     divergences = check_kernel_table(payload)
     assert divergences == [], "\n".join(divergences)
 

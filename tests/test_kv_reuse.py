@@ -114,10 +114,9 @@ def test_spill_chaos_fault_drops_entry_degrades_to_miss():
 
 # -- spill tier (engine round trip: evict → host → re-upload) ------------------
 
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_engine_spill_round_trip_serves_from_host_tier(engine_setup, kv_layout):
+def test_engine_spill_round_trip_serves_from_host_tier(engine_setup):
     cfg, params = engine_setup
-    kw = {} if kv_layout == "dense" else dict(kv_layout="paged", kv_page_size=8)
+    kw = dict(kv_page_size=8)
     # device tier: 4 entries — one chunked prompt's chain exactly; the
     # flood prompt's chain evicts it into the host tier
     engine = make_engine(cfg, params, prefix_cache_entries=4,
@@ -234,16 +233,14 @@ def _advertise(index, engine, replica_id="A", seq=1):
     assert index.observe(replica_id, seq, adv)
 
 
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
 def test_acceptance_second_replica_serves_migrated_prefix_zero_prefill_dispatches(
-        engine_setup, kv_layout):
+        engine_setup):
     """THE acceptance test (ISSUE 12): with two in-process replicas, a
     request whose prefix is cached only on the first admits on the
     second via warm migration with ZERO prefill-compute dispatches —
     token-identical to the source replica's output."""
     cfg, params = engine_setup
-    kw = {} if kv_layout == "dense" else dict(kv_layout="paged", kv_page_size=8)
-    index, a, b, migrator = _wire_pair(cfg, params, **kw)
+    index, a, b, migrator = _wire_pair(cfg, params, kv_page_size=8)
     a.start()
     b.start()
     try:

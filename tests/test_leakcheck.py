@@ -719,7 +719,7 @@ def test_runtime_pairs_covered_by_static_table():
             cfg, params,
             EngineConfig(max_slots=2, max_seq_len=64, prefill_buckets=(16,),
                          admission_per_step=2, max_queue=16,
-                         kv_layout="paged", kv_page_size=8, kv_num_pages=64),
+                         kv_page_size=8, kv_num_pages=64),
             ByteTokenizer(cfg.vocab_size),
         )
         eng.start()

@@ -292,20 +292,19 @@ def test_the_five_families_lower_the_programs_they_lowered_before():
 
 # ------------------------------------------------------------- the engine
 def engine_settings(**kw):
-    settings = dict(max_slots=3, max_seq_len=96, prefill_buckets=(16,), multi_step=4, kv_layout="paged",
+    settings = dict(max_slots=3, max_seq_len=96, prefill_buckets=(16,), multi_step=4,
                     kv_page_size=PAGE, prefill_chunk_tokens=8)
     settings.update(kw)
     return EngineConfig(**settings)
 
 
 @pytest.mark.parametrize("settings, lora, sentence", [
-    (dict(kv_layout="dense"), None, "paged KV layout only"),
     (dict(spec_tokens=2, multi_step=None), None, "no speculative verify program"),
     (dict(), object(), "serves no LoRA adapters"),
     (dict(prefix_cache_entries=4), None, "keeps no prefix cache"),
     (dict(kv_spill_bytes=1 << 20), None, "spills no KV"),
     (dict(role="prefill"), None, "unified replicas only"),
-], ids=["dense", "speculative", "lora", "prefix_cache", "spill", "role"])
+], ids=["speculative", "lora", "prefix_cache", "spill", "role"])
 def test_engines_the_model_has_no_program_for_are_refused_at_construction(plain, settings, lora, sentence):
     with pytest.raises(ValueError, match=sentence):
         ServingEngine(CFG, plain, engine_settings(**settings), ByteTokenizer(300), lora=lora)

@@ -297,7 +297,7 @@ def test_never_fit_prompt_is_413_not_429():
     from gofr_tpu.http.errors import ErrorRequestEntityTooLarge
 
     # bucket 32 -> 4 pages of 8; the pool holds 3 in total
-    eng = make_engine(kv_layout="paged", kv_page_size=8, kv_num_pages=3,
+    eng = make_engine(kv_page_size=8, kv_num_pages=3,
                       prefill_buckets=(16, 32))
     eng.start()
     try:
@@ -351,7 +351,7 @@ def test_kv_exhaustion_reports_its_own_reason_and_metric():
     eng = ServingEngine(
         cfg, params,
         EngineConfig(max_slots=1, max_seq_len=64, prefill_buckets=(16,),
-                     kv_layout="paged", kv_page_size=8, kv_num_pages=3),
+                     kv_page_size=8, kv_num_pages=3),
         ByteTokenizer(cfg.vocab_size), metrics=metrics,
     )
     eng.start()
@@ -371,7 +371,7 @@ def test_kv_exhaustion_reaches_stream_consumers():
     on_result — kv_exhausted must arrive there, end-to-end."""
     import asyncio
 
-    eng = make_engine(kv_layout="paged", kv_page_size=8, kv_num_pages=3,
+    eng = make_engine(kv_page_size=8, kv_num_pages=3,
                       max_slots=1)
     eng.start()
     try:

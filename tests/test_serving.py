@@ -302,7 +302,6 @@ def test_int8_kv_is_refused_at_construction(engine_setup, monkeypatch, make, set
     def no_pool(*a, **kw):
         raise AssertionError("a KV cache was allocated for a refused engine")
 
-    monkeypatch.setattr(llama.KVCache, "create", no_pool)
     monkeypatch.setattr(kv_cache.PagedKVCache, "reset_pools", no_pool)
     cfg, params = engine_setup
     engine_config = make(**settings)
@@ -311,7 +310,41 @@ def test_int8_kv_is_refused_at_construction(engine_setup, monkeypatch, make, set
         ServingEngine(cfg, params, engine_config, ByteTokenizer())
     monkeypatch.undo()
     built = make_engine(cfg, params, kv_dtype="bf16")  # the one value builds as before
-    assert built.cache.k.dtype == cfg.dtype and built.paged_cache is None
+    assert built.paged_cache.k_pool.dtype == cfg.dtype
+
+
+def _dense_from_field():
+    return EngineConfig(max_slots=2, max_seq_len=32, kv_layout="dense")
+
+
+def _dense_from_env():
+    from gofr_tpu.config import MapConfig
+
+    return EngineConfig.from_config(MapConfig({
+        "TPU_BATCH_MAX_SLOTS": "2", "TPU_BATCH_MAX_TOKENS": "32", "TPU_KV_LAYOUT": "dense",
+    }, use_env=False))
+
+
+@pytest.mark.parametrize("make", [_dense_from_field, _dense_from_env], ids=["field", "env"])
+def test_dense_kv_layout_is_refused_at_construction(engine_setup, monkeypatch, make):
+    """The KV cache has one layout, the page pool. ``kv_layout`` /
+    ``TPU_KV_LAYOUT`` other than paged is an operator's input and is
+    refused with a sentence before any pool is built — never served,
+    silently, from a layout the operator did not ask for."""
+    from gofr_tpu.serving import kv_cache
+
+    def no_pool(*a, **kw):
+        raise AssertionError("a KV pool was allocated for a refused engine")
+
+    monkeypatch.setattr(kv_cache.PagedKVCache, "reset_pools", no_pool)
+    cfg, params = engine_setup
+    engine_config = make()
+    assert engine_config.kv_layout == "dense"  # the field carries what was set
+    with pytest.raises(ValueError, match=r"TPU_KV_LAYOUT='dense': must be paged"):
+        ServingEngine(cfg, params, engine_config, ByteTokenizer())
+    monkeypatch.undo()
+    built = make_engine(cfg, params, kv_layout="paged")  # the one value builds as before
+    assert built.paged_cache.k_pool.dtype == cfg.dtype
 
 
 def test_engine_multi_step_matches_single(engine_setup):

@@ -1,11 +1,13 @@
-"""Serving engine in paged-KV mode: same correctness contract as the dense
-layout (outputs must match the dense engine greedily), plus page-pool
-behaviors the dense layout cannot express — token-level admission, pool
-exhaustion requeue, and early retirement when decode outgrows the pool."""
+"""Serving engine over the KV page pool: greedy outputs match the model's
+plain path (llama.greedy_generate over a KVCache), plus the page-pool
+behaviors — token-level admission, pool exhaustion requeue, and early
+retirement when decode outgrows the pool."""
 
 import time
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from gofr_tpu.models import llama
@@ -22,33 +24,34 @@ def setup():
 def make_engine(cfg, params, **kw):
     defaults = dict(
         max_slots=4, max_seq_len=64, prefill_buckets=(16, 32), max_queue=64,
-        kv_layout="paged", kv_page_size=8,
+        kv_page_size=8,
     )
     defaults.update(kw)
     return ServingEngine(cfg, params, EngineConfig(**defaults), ByteTokenizer())
 
 
-def test_paged_matches_dense_outputs(setup):
+def test_paged_matches_reference_outputs(setup):
     cfg, params = setup
-    dense = ServingEngine(
-        cfg, params,
-        EngineConfig(max_slots=4, max_seq_len=64, prefill_buckets=(16, 32)),
-        ByteTokenizer(),
-    )
+    tok = ByteTokenizer()
     paged = make_engine(cfg, params)
     prompts = ["hello paged world", "a", "the quick brown fox jumps"]
     try:
-        dense.start()
         paged.start()
-        futs_d = [dense.submit(p, max_new_tokens=12) for p in prompts]
         futs_p = [paged.submit(p, max_new_tokens=12) for p in prompts]
-        for fd, fp in zip(futs_d, futs_p):
-            rd = fd.result(timeout=120)
+        for prompt, fp in zip(prompts, futs_p):
+            ids = tok.encode(prompt)
+            ref = llama.greedy_generate(
+                cfg, params, jnp.asarray([ids], jnp.int32), jnp.asarray([len(ids)]), 12
+            )
+            expect = []
+            for t in np.asarray(ref[0]).tolist():
+                if t == tok.eos_id:
+                    break
+                expect.append(t)
             rp = fp.result(timeout=120)
-            assert rp.token_ids == rd.token_ids, (rp.text, rd.text)
-            assert rp.finish_reason == rd.finish_reason
+            assert rp.token_ids == expect, (prompt, rp.token_ids, expect)
+            assert rp.finish_reason == ("length" if len(expect) == 12 else "stop")
     finally:
-        dense.stop()
         paged.stop()
 
 

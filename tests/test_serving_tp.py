@@ -82,7 +82,7 @@ def test_tp_engine_paged_layout(tp_setup):
     """Paged KV on top of tp-sharded weights: same greedy tokens."""
     cfg, params, sharded, _ = tp_setup
     ref = _make_engine(cfg, params)
-    tp = _make_engine(cfg, sharded, kv_layout="paged", kv_page_size=8)
+    tp = _make_engine(cfg, sharded, kv_page_size=8)
     ref.start(), tp.start()
     try:
         assert _greedy_tokens(tp, "paged tp") == _greedy_tokens(ref, "paged tp")

@@ -45,7 +45,7 @@ def model():
 
 def make_engine(model, metrics=None, **kw):
     settings = dict(max_slots=3, max_seq_len=128, prefill_buckets=(16,), multi_step=STEPS,
-                    kv_layout="paged", kv_page_size=8)
+                    kv_page_size=8)
     settings.update(kw)
     cfg, params = model
     return ServingEngine(cfg, params, EngineConfig(**settings), ByteTokenizer(), metrics=metrics)
@@ -380,19 +380,68 @@ def test_phase_account_covers_the_loop_and_busy_leaves_wait_out(model):
 def test_a_busy_phase_reads_cpu_as_wall_and_a_sleeping_one_reads_none(model):
     engine = make_engine(model)  # never started: the caller owns the account
     with engine._phase("plan"):
-        until = time.monotonic() + 0.2
-        while time.monotonic() < until:
+        # busy until the thread itself has burnt 0.2 s of CPU: beside other
+        # processes' compiles a wall-clock loop may get a third of a core,
+        # and what is checked is the thread's work, not the machine's share
+        cpu0 = time.thread_time()
+        while time.thread_time() - cpu0 < 0.2:
             pass
+        burnt = time.thread_time() - cpu0
     with engine._phase("wait"):
         time.sleep(0.2)
     wall, cpu = engine._phase_s, engine._phase_cpu_s
     assert wall["plan"] >= 0.2 and wall["wait"] >= 0.2
     # a thread's CPU clock may tick in steps of 10 ms: a tick of room on either side
-    assert 0.5 * wall["plan"] <= cpu["plan"] <= wall["plan"] + 0.011
+    assert burnt - 0.011 <= cpu["plan"] <= wall["plan"] + 0.011
     assert cpu["wait"] <= 0.011 + 0.05 * wall["wait"]
     account = engine.loop_account()
     assert account["phase_s"] == wall and account["cpu_s"] == cpu and account["phase_s"] is not wall
     assert account["blocks"] == 0 and account["t"] <= time.monotonic()
+
+
+class _Clocks:
+    """The two clocks the loop's account reads (``time.monotonic``, the
+    thread's ``time.thread_time_ns``), advanced by the test: a busy
+    second moves both, a sleeping one the wall clock alone. Every other
+    name is the real ``time`` module's."""
+
+    def __init__(self) -> None:
+        self.wall, self.cpu_ns = 1000.0, 0
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+    def monotonic(self) -> float:
+        return self.wall
+
+    def thread_time_ns(self) -> int:
+        return self.cpu_ns
+
+    def busy(self, seconds: float) -> None:
+        self.wall += seconds
+        self.cpu_ns += int(seconds * 1e9)
+
+    def sleep(self, seconds: float) -> None:
+        self.wall += seconds
+
+
+def test_the_account_charges_each_phase_the_two_clocks_it_spanned(model, monkeypatch):
+    # the arithmetic alone, on scripted clocks: a busy phase is charged its
+    # CPU seconds in full, a sleeping one none, and both their wall seconds
+    clock = _Clocks()
+    monkeypatch.setattr(engine_mod, "time", clock)
+    engine = make_engine(model)
+    with engine._phase("plan"):
+        clock.busy(0.2)
+    with engine._phase("wait"):
+        clock.sleep(0.3)
+    wall, cpu = engine._phase_s, engine._phase_cpu_s
+    assert wall["plan"] == pytest.approx(0.2) and cpu["plan"] == pytest.approx(0.2)
+    assert wall["wait"] == pytest.approx(0.3) and cpu["wait"] == 0
+    assert sum(wall.values()) == pytest.approx(0.5) and sum(cpu.values()) == pytest.approx(0.2)
+    account = engine.loop_account()
+    assert account["phase_s"] == wall and account["cpu_s"] == cpu
+    assert account["t"] == clock.monotonic()
 
 
 @pytest.mark.parametrize("newest, said, counted", [("ready", 1, "idle"), ("running", 0, "queued"), (None, 2, "none")])

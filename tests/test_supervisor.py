@@ -120,10 +120,9 @@ def assert_reclaimed(eng: ServingEngine) -> None:
         lambda: all(s is None for s in eng.slots) and not eng._by_id,
         msg="slots/requests not reclaimed",
     )
-    if eng.paged_cache is not None:
-        stats = eng.paged_cache.stats()
-        assert stats["free_blocks"] == stats["total_blocks"], stats
-        assert stats["sequences"] == 0
+    stats = eng.paged_cache.stats()
+    assert stats["free_blocks"] == stats["total_blocks"], stats
+    assert stats["sequences"] == 0
 
 
 # -- warm restart mechanics ---------------------------------------------------
@@ -156,7 +155,7 @@ def test_warm_restart_quarantines_hung_thread_and_fails_inflight(monkeypatch):
     test stopped hanging anything."""
     from gofr_tpu.serving import batch as batch_ops
 
-    eng = make_engine(kv_layout="paged", kv_page_size=8)
+    eng = make_engine(kv_page_size=8)
     hold = threading.Event()
     pinned = threading.Event()
     real_block = batch_ops.decode_block_paged
@@ -240,7 +239,7 @@ def test_warm_restart_rebuild_failure_settles_requeued():
     def broken_rebuild():
         raise RuntimeError("device still refusing allocations")
 
-    eng._make_dense_cache = broken_rebuild
+    eng._make_paged_cache = broken_rebuild
     with pytest.raises(RuntimeError):
         eng.warm_restart()
     for f in futs:
@@ -507,16 +506,12 @@ def test_device_poisoning_escalates_to_restart(seed):
 @pytest.mark.chaos
 @pytest.mark.slow
 @pytest.mark.parametrize("seed", CHAOS_SEEDS)
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_lifecycle_invariant_across_restart(seed, kv_layout):
+def test_lifecycle_invariant_across_restart(seed):
     """PR 3's lifecycle invariant stays green when a warm restart lands in
     the middle of a mixed workload: every request reaches exactly one
     terminal state, slots and pages are re-founded cleanly, the engine
     drains within its deadline."""
-    kw = dict(kv_layout=kv_layout)
-    if kv_layout == "paged":
-        kw.update(kv_page_size=8)
-    eng = make_engine(**kw)
+    eng = make_engine(kv_page_size=8)
     sup = make_supervisor(eng, stall_s=0.3, poll_s=0.03, join_timeout=0.3)
     # compile everything before the storm: stall detection is for hangs,
     # not first-call jit compiles
@@ -629,7 +624,7 @@ def test_stall_inside_warmed_dispatch_heals_without_corruption():
     error."""
     from gofr_tpu.serving import batch as batch_ops
 
-    eng = make_engine(kv_layout="paged", kv_page_size=8)
+    eng = make_engine(kv_page_size=8)
     sup = make_supervisor(eng, stall_s=0.3, poll_s=0.03, join_timeout=0.2)
     sup.start()
     eng.submit("warmup", max_new_tokens=3).result(timeout=120)
