@@ -16,6 +16,9 @@ The serving framework's model zoo (BASELINE.json configs):
 - lfm2_moe: gated short convolutions and per-head QK-normed attention in an
   irregular order, two dense layers then sigmoid experts with an expert bias
   (served on the paged path: conv tails a slot beside one pool)
+- nemotron_h: Mamba-2 mixers, relu² experts beside a shared one and GQA with
+  no positional encoding in the published pattern (served on the paged path:
+  SSD states and conv tails a slot beside one pool, also as one chip's share)
 - bert: encoder embedder (/embed endpoint)
 - whisper: encoder-decoder ASR (async Pub/Sub path)
 
@@ -25,6 +28,6 @@ scanned (lax.scan) so compile time is flat in depth; weights are bf16 by
 default with f32 accumulation inside ops.
 """
 
-from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, lfm2_moe, llama, phi4flash
+from gofr_tpu.models import bert, cohere2_moe, deepseek_v32, lfm2_moe, llama, nemotron_h, phi4flash
 
-__all__ = ["llama", "cohere2_moe", "deepseek_v32", "phi4flash", "lfm2_moe", "bert"]
+__all__ = ["llama", "cohere2_moe", "deepseek_v32", "phi4flash", "lfm2_moe", "nemotron_h", "bert"]

@@ -14,7 +14,9 @@ in one ``pallas_call``:
   bytes stream while expert e multiplies — and adds
   ``g_e ⊙ (silu(x W_g s_g) ⊙ (x W_u s_u)) W_d s_d`` into the float32 output
   block, which stays in VMEM for the whole call and is written once. Tiling
-  F is exact: the down scale is per output channel.
+  F is exact: the down scale is per output channel. A relu² expert (stacks
+  without a W_gate) is the same call over two matrices:
+  ``g_e ⊙ relu(x W_u s_u)² W_d s_d``.
 - x, the gates and the layer's scale rows are fetched once a call.
 - the stacks go in whole, as stored (``[L, n, D, F]`` with a ``layer``,
   ``[n, D, F]`` without), and the layer is a scalar prefetch that the
@@ -56,28 +58,35 @@ def serves(stacks: dict) -> bool:
     """Whether the kernel computes the sum over ``stacks`` on this
     platform: not on the CPU's reference path; only int8 ``{"q", "s"}``
     matrices; D and F whole lane tiles (the weight tiles' DMAs and the
-    products need 128-lane columns)."""
-    w = stacks.get("w_gate")
+    products need 128-lane columns: a relu² expert of a width that is not
+    is stored zero-padded to one, ``models/nemotron_h.pad_experts``)."""
+    w = stacks.get("w_up")
     if not isinstance(w, dict) or kernel_mode() == REFERENCE:
         return False
     D, F = w["q"].shape[-2:]
     return w["q"].dtype == jnp.int8 and D % _LANES == 0 and F % _LANES == 0
 
 
-def f_tile(D: int, F: int, budget: int) -> int:
+def f_tile(D: int, F: int, budget: int, mats: int = 3) -> int:
     """Columns of F a grid step takes: the widest multiple of 128 that
-    divides F and keeps both slots of three int8 tiles of ``D x tile``
-    within ``budget``; 128 where none does."""
-    fit = budget // (2 * 3 * D)
+    divides F and keeps both slots of ``mats`` int8 tiles of ``D x tile``
+    (three for a SwiGLU expert, two for a relu² one) within ``budget``;
+    128 where none does."""
+    fit = budget // (2 * mats * D)
     return max([t for t in range(_LANES, F + 1, _LANES) if F % t == 0 and t <= fit] or [_LANES])
 
 
-def _kernel(layer_ref, x_ref, g_ref, wg_ref, sg_ref, wu_ref, su_ref, wd_ref, sd_ref, o_ref,
-            sg_row, su_row, sd_row, weight):
+def _kernel(layer_ref, x_ref, g_ref, *refs):
     """Grid step (e, f): expert e's f-th tile of F, added into ``o_ref``
-    with the gate column e as the weight. ``layer_ref`` is read by the
-    index maps alone."""
+    with the gate column e as the weight. ``refs`` are the column
+    matrices' tiles and scales (W_gate, W_up of a SwiGLU expert; W_up of
+    a relu² one), W_down's, the output, then the scratch rows of those
+    scales in the same order and the gate column. ``layer_ref`` is read
+    by the index maps alone."""
     del layer_ref
+    n_cols = (len(refs) - 5) // 3
+    mats, (o_ref,), rows, (weight,) = refs[:2 * n_cols + 2], refs[2 * n_cols + 2:2 * n_cols + 3], \
+        refs[2 * n_cols + 3:-1], refs[-1:]
     e, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when((e == 0) & (f == 0))
@@ -93,13 +102,12 @@ def _kernel(layer_ref, x_ref, g_ref, wg_ref, sg_ref, wu_ref, su_ref, wd_ref, sd_
             at = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis) == e
             return jnp.sum(jnp.where(at, a, 0.0), axis=axis, keepdims=True)
 
-        sg_row[...] = pick(sg_ref[...], 0)
-        su_row[...] = pick(su_ref[...], 0)
-        sd_row[...] = pick(sd_ref[...], 0)
+        for row, scale_ref in zip(rows, mats[1::2]):
+            row[...] = pick(scale_ref[...], 0)
         weight[...] = pick(g_ref[...], 1)
 
     x = x_ref[...]  # [T, D]
-    tf = wg_ref.shape[-1]
+    tf = mats[0].shape[-1]
     cols = pl.ds(pl.multiple_of(f * tf, _LANES), tf)
 
     def product(w_ref, s):
@@ -107,12 +115,16 @@ def _kernel(layer_ref, x_ref, g_ref, wg_ref, sg_ref, wu_ref, su_ref, wd_ref, sd_
         y = jnp.dot(x, w_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)
         return (y * s).astype(x.dtype)
 
-    gate = product(wg_ref, sg_row[:, cols])  # [T, tf]
-    up = product(wu_ref, su_row[:, cols])
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
-    h = (act.astype(jnp.float32) * up.astype(jnp.float32)).astype(x.dtype)
-    down = jnp.dot(h, wd_ref[...].astype(x.dtype), preferred_element_type=jnp.float32)  # [T, D]
-    o_ref[...] += weight[...] * (down * sd_row[...])
+    if n_cols == 2:  # SwiGLU
+        gate = product(mats[0], rows[0][:, cols])  # [T, tf]
+        up = product(mats[2], rows[1][:, cols])
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
+        h = (act.astype(jnp.float32) * up.astype(jnp.float32)).astype(x.dtype)
+    else:  # relu², moe._ffn's rounding
+        up = product(mats[0], rows[0][:, cols])
+        h = jnp.square(jnp.maximum(up.astype(jnp.float32), 0.0)).astype(x.dtype)
+    down = jnp.dot(h, mats[-2][...].astype(x.dtype), preferred_element_type=jnp.float32)  # [T, D]
+    o_ref[...] += weight[...] * (down * rows[-1][...])
 
 
 def _call(x: jnp.ndarray, g: jnp.ndarray, stacks: dict, layer: jnp.ndarray, interpret: bool,
@@ -121,9 +133,11 @@ def _call(x: jnp.ndarray, g: jnp.ndarray, stacks: dict, layer: jnp.ndarray, inte
     weight tiles' VMEM is :data:`_WEIGHT_VMEM` unless ``budget`` says
     otherwise (the probe's)."""
     T, D = x.shape
-    wg, wu, wd = (stacks[k] for k in ("w_gate", "w_up", "w_down"))
-    n, F = wg["q"].shape[1], wg["q"].shape[3]
-    tf = f_tile(D, F, _WEIGHT_VMEM if budget is None else budget)
+    columns = [stacks[k] for k in ("w_gate", "w_up") if k in stacks]  # W_gate, W_up; W_up alone: relu²
+    wd = stacks["w_down"]
+    n, F = columns[0]["q"].shape[1], columns[0]["q"].shape[3]
+    mats = len(columns) + 1
+    tf = f_tile(D, F, _WEIGHT_VMEM if budget is None else budget, mats)
 
     def whole(shape):
         return pl.BlockSpec(shape, lambda e, f, layer: (0,) * len(shape))
@@ -134,18 +148,17 @@ def _call(x: jnp.ndarray, g: jnp.ndarray, stacks: dict, layer: jnp.ndarray, inte
     column_tile = pl.BlockSpec((None, None, D, tf), lambda e, f, layer: (layer[0], e, 0, f))
     row_tile = pl.BlockSpec((None, None, tf, D), lambda e, f, layer: (layer[0], e, f, 0))
     cost = pl.CostEstimate(
-        flops=2 * 3 * T * D * F * n,
-        transcendentals=T * F * n,
-        bytes_accessed=3 * n * D * F + 4 * n * (2 * F + D) + T * D * (x.dtype.itemsize + 4),
+        flops=2 * mats * T * D * F * n,
+        transcendentals=T * F * n if mats == 3 else 0,
+        bytes_accessed=mats * n * D * F + 4 * n * ((mats - 1) * F + D) + T * D * (x.dtype.itemsize + 4),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # the layer
         grid=(n, F // tf),
-        in_specs=[whole((T, D)), whole((T, n)), column_tile, scales(F), column_tile, scales(F),
-                  row_tile, scales(D)],
+        in_specs=[whole((T, D)), whole((T, n))] + [column_tile, scales(F)] * len(columns) + [row_tile, scales(D)],
         out_specs=whole((T, D)),
-        scratch_shapes=[pltpu.VMEM((1, F), jnp.float32), pltpu.VMEM((1, F), jnp.float32),
-                        pltpu.VMEM((1, D), jnp.float32), pltpu.VMEM((T, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, F), jnp.float32)] * len(columns) + [
+            pltpu.VMEM((1, D), jnp.float32), pltpu.VMEM((T, 1), jnp.float32)],
     )
     return pl.pallas_call(
         _kernel,
@@ -159,13 +172,13 @@ def _call(x: jnp.ndarray, g: jnp.ndarray, stacks: dict, layer: jnp.ndarray, inte
         cost_estimate=cost,
         interpret=interpret,
         name="expert_rows",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), x, g, wg["q"], wg["s"], wu["q"], wu["s"], wd["q"], wd["s"])
+    )(jnp.asarray(layer, jnp.int32).reshape(1), x, g, *(a for w in columns + [wd] for a in (w["q"], w["s"])))
 
 
 def expert_rows(
     x: jnp.ndarray,  # [T, D] the activations (bf16)
     g: jnp.ndarray,  # [T, n] float32: each row's weight of each expert of the stacks
-    stacks: dict,  # w_gate / w_up {"q": int8 [(L,) n, D, F], "s": f32 [(L,) n, F]}, w_down [(L,) n, F, D]
+    stacks: dict,  # w_gate / w_up {"q": int8 [(L,) n, D, F], "s": f32 [(L,) n, F]}, w_down [(L,) n, F, D]; no w_gate: relu²
     layer: jnp.ndarray | int | None = None,  # the stacks are [L, n, ...] and this (traced) layer's is meant
     *,
     interpret: bool | None = None,

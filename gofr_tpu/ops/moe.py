@@ -303,8 +303,8 @@ def path(T: int, n_experts: int, top_k: int | None, experts: dict) -> str:
 def held_experts(
     h: jnp.ndarray,  # [T, D]
     gates: jnp.ndarray,  # [T, E] over every published expert
-    experts: dict,  # w_gate/w_up [held, D, F], w_down [held, F, D]
-    shared: dict,  # the same three, [n_shared, ...]
+    experts: dict,  # w_gate/w_up [held, D, F], w_down [held, F, D] (no w_gate: relu² experts)
+    shared: dict,  # the same matrices, [n_shared, ...]
     first: Any,  # index of the first held expert among the published ones
     mm: Any = jnp.matmul,  # the product for the weights' storage (llama._mm for int8)
     layer: Any = None,  # the stacks are [L, held, ...] and this (traced) layer's is meant
@@ -347,7 +347,11 @@ def held_experts(
 
 
 def _ffn(x: jnp.ndarray, w: dict, e: Any, mm: Any, layer: Any) -> jnp.ndarray:
-    """Expert ``e``'s SwiGLU over x [n, D], float32."""
+    """Expert ``e`` over x [n, D], float32: SwiGLU where the stacks have a
+    ``w_gate``, else the ungated relu² expert ``relu(x W_up)² W_down``."""
+    if "w_gate" not in w:
+        act = jnp.square(jax.nn.relu(mm(x, _at(w["w_up"], e, layer)).astype(jnp.float32))).astype(x.dtype)
+        return mm(act, _at(w["w_down"], e, layer)).astype(jnp.float32)
     gate = jax.nn.silu(mm(x, _at(w["w_gate"], e, layer)).astype(jnp.float32)).astype(x.dtype)
     return mm(gate * mm(x, _at(w["w_up"], e, layer)), _at(w["w_down"], e, layer)).astype(jnp.float32)
 
@@ -424,7 +428,7 @@ def _over_own_rows(h: jnp.ndarray, g: jnp.ndarray, experts: dict, mm: Any, layer
 
 def _experts_in(stacks: dict, layer: Any) -> int:
     """How many experts a tree of stacks [n, ...] — or [L, n, ...] with a ``layer`` — holds."""
-    return jax.tree.leaves(stacks["w_gate"])[0].shape[0 if layer is None else 1]
+    return jax.tree.leaves(stacks["w_up"])[0].shape[0 if layer is None else 1]
 
 
 def _at(w: Any, e: Any, layer: Any) -> Any:

@@ -56,7 +56,8 @@ def model_of(cfg: Any) -> Any:
     (``LlamaConfig`` → ``models/llama.py``, ``Cohere2MoeConfig`` →
     ``models/cohere2_moe.py``, ``DeepseekV32Config`` →
     ``models/deepseek_v32.py``, ``Phi4FlashConfig`` →
-    ``models/phi4flash.py``, ``Lfm2MoeConfig`` → ``models/lfm2_moe.py``).
+    ``models/phi4flash.py``, ``Lfm2MoeConfig`` → ``models/lfm2_moe.py``,
+    ``NemotronHConfig`` → ``models/nemotron_h.py``).
     Every program here reaches its model
     through this one lookup, at trace time. What a served module holds:
     ``KVCache`` (a bucketed prefill's one-row scratch),

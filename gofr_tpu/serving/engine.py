@@ -612,6 +612,9 @@ class ServingEngine:
         # a model whose chunk reads only the context that holds its end:
         # the contexts it may read, mirrored as chunk_ctx on ragged spans
         self._chunk_contexts = getattr(model, "chunk_contexts", None)
+        # a model with a scan over prompt positions names the attribute its
+        # prefill and chunk spans carry: the positions the scan advanced
+        self._scan_positions = getattr(model, "SCAN_POSITIONS", None)
         if self.config.spec_tokens < 0:
             raise ValueError("TPU_SPEC_TOKENS must be >= 0")
         if (self.config.multi_step is not None and self.config.multi_step > 1
@@ -2737,6 +2740,8 @@ class ServingEngine:
                         self._prefix_cache.put(cache_key, cached)
                 self._record_prefix_tier(req, prefix_tier)
             phase.set(route="bucketed" if cached is None else "prefix_hit")
+            if self._scan_positions:
+                phase.set(**{self._scan_positions: S})
             if self._upper_on_last:
                 self._count_prefill_positions(phase, S, 1, resets=1)
 
@@ -3632,6 +3637,8 @@ class ServingEngine:
                     resets=sum(1 for _, _, _, start_pos, _ in chunk_rows if start_pos == 0))
             if chunk_rows and self._chunk_contexts is not None:
                 span.set(chunk_ctx=self._chunk_ctx(pc, chunk_rows))
+            if chunk_rows and self._scan_positions:
+                span.set(**{self._scan_positions: chunk_tokens})
             topk = getattr(cfg, "index_topk", None)
             if topk:  # rows whose sparse selection binds: attention reads index_topk of them
                 span.set(dsa_rows=int((self.cache_len[mask] > topk).sum()))

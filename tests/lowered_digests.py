@@ -28,9 +28,10 @@ def families() -> dict:
     benchmark serves (the two dense cells share ``llama``: GQA and MHA;
     ``joyai_llm_flash`` is ``deepseek_v32`` without an indexer)."""
     from benchmarks.harness import (
-        cohere2_moe_family, deepseek_v32_family, joyai_flash_family, llama_family, phi4flash_family,
+        cohere2_moe_family, deepseek_v32_family, joyai_flash_family, lfm2_moe_family, llama_family,
+        nemotron_h_family, phi4flash_family,
     )
-    from gofr_tpu.models import cohere2_moe, deepseek_v32, llama, phi4flash
+    from gofr_tpu.models import cohere2_moe, deepseek_v32, lfm2_moe, llama, nemotron_h, phi4flash
 
     paged = dict(kv_layout="paged", kv_page_size=4, max_slots=3, max_seq_len=64, prefill_buckets=(16, 32))
     return {
@@ -46,6 +47,10 @@ def families() -> dict:
         "joyai_llm_flash": (deepseek_v32.DeepseekV32Config.tiny(
             index_n_heads=0, index_head_dim=0, index_topk=0, n_group=1, topk_group=1, rope_factor=1.0,
             rope_theta=3.2e7), joyai_flash_family.lowered_programs, dict(paged, prefill_chunk_tokens=16)),
+        "lfm2_moe": (lfm2_moe.Lfm2MoeConfig.tiny(), lfm2_moe_family.lowered_programs,
+                     dict(paged, prefill_chunk_tokens=16)),
+        "nemotron_h": (nemotron_h.NemotronHConfig.tiny(), nemotron_h_family.lowered_programs,
+                       dict(paged, prefill_chunk_tokens=16)),
     }
 
 

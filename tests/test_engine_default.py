@@ -6,7 +6,7 @@ file builds them."""
 import jax
 import pytest
 
-from gofr_tpu.models import cohere2_moe, deepseek_v32, lfm2_moe, llama, phi4flash
+from gofr_tpu.models import cohere2_moe, deepseek_v32, lfm2_moe, llama, nemotron_h, phi4flash
 from gofr_tpu.serving import ByteTokenizer, EngineConfig, ServingEngine
 from gofr_tpu.serving import batch as batch_ops
 from gofr_tpu.serving.kv_cache import PagedKVCache
@@ -26,6 +26,7 @@ FAMILIES = {
     "phi4flash": phi4flash.Phi4FlashConfig.tiny(
         vocab_size=300, sliding_window=DEFAULTS.prefill_chunk_tokens),
     "lfm2_moe": lfm2_moe.Lfm2MoeConfig.tiny(vocab_size=300),
+    "nemotron_h": nemotron_h.NemotronHConfig.tiny(vocab_size=300),
 }
 
 

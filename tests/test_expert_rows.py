@@ -10,6 +10,7 @@ import pytest
 
 from gofr_tpu.models import cohere2_moe as cm
 from gofr_tpu.models import lfm2_moe as lm
+from gofr_tpu.models import nemotron_h as nh
 from gofr_tpu.models.llama import _mm, quantize_weight
 from gofr_tpu.ops import backend, expert_rows
 from gofr_tpu.ops import moe as moe_ops
@@ -102,6 +103,47 @@ def test_the_kernel_is_the_loop_over_every_row(T, whole, in_the_interpreter):
     assert (routed[np.asarray(g).sum(axis=1) == 0] == 0).all()
 
 
+def _relu2_stacks(key, lead, n, f):
+    """Two stacks [*lead, n, ...] of relu² experts of width ``f``, int8 and
+    zero-padded to whole lane tiles as ``models/nemotron_h`` stores them."""
+    ks = jax.random.split(key, 2)
+    w = {"w_up": jax.random.normal(ks[0], lead + (n, D, f)) / np.sqrt(D),
+         "w_down": jax.random.normal(ks[1], lead + (n, f, D)) / np.sqrt(f)}
+    return nh.pad_experts({k: quantize_weight(v, axis=-2) for k, v in w.items()})
+
+
+@pytest.mark.parametrize("T", [1, 5, 64])
+@pytest.mark.parametrize("whole", [False, True], ids=["a-layer-s-stacks", "the-stacks-whole-in-a-scan"])
+def test_the_relu2_kernel_is_the_loop_over_every_row(T, whole, in_the_interpreter):
+    """Stacks without a W_gate — relu² experts of width 200, stored padded
+    to 256, and a shared one of 128 — through the kernel's two-matrix form
+    against ``_loop_over_every_row``: within bf16's rounding of the down
+    product, as the SwiGLU form; the padding adds exactly nothing, so
+    the kernel equals the loop over the published width's columns."""
+    n, L = 6, 3
+    lead = (L,) if whole else ()
+    experts = _relu2_stacks(jax.random.PRNGKey(7), lead, n, 200)
+    shared = _relu2_stacks(jax.random.PRNGKey(8), lead, 1, 128)
+    assert experts["w_up"]["q"].shape[-1] == 256 and expert_rows.serves(experts) and expert_rows.serves(shared)
+    assert expert_rows.f_tile(D, 256, expert_rows._WEIGHT_VMEM, mats=2) == 128
+    h = jax.random.normal(jax.random.PRNGKey(9), (T, D), F32).astype(BF16)
+    g = _gates(T, n)
+
+    def each(fn):
+        if not whole:
+            return jax.jit(lambda h, g: fn(h, g, None))(h, g)[None]
+        return jax.jit(lambda h, g: jax.lax.scan(lambda c, i: (c, fn(h, g, i)), 0, jnp.arange(L))[1])(h, g)
+
+    got = np.asarray(each(lambda h, g, i: moe_ops._over_every_row(h, g, experts, shared, _mm, i)))
+    loop = np.asarray(each(lambda h, g, i: moe_ops._loop_over_every_row(h, g, experts, shared, _mm, i)))
+    unpadded = {k: {"q": v["q"][..., :200], "s": v["s"][..., :200]} if k == "w_up" else
+                {"q": v["q"][..., :200, :], "s": v["s"]} for k, v in experts.items()}
+    narrow = np.asarray(each(lambda h, g, i: moe_ops._loop_over_every_row(h, g, unpadded, shared, _mm, i)))
+    scale = np.abs(loop).max()
+    assert scale > 0.1 and np.abs(got - loop).max() <= 1e-2 * scale
+    assert np.abs(narrow - loop).max() <= 1e-6 * scale
+
+
 def test_one_tile_and_many_give_the_same_sum(in_the_interpreter, monkeypatch):
     """Splitting F is exact up to the order of float32 sums: the down
     scale is per output channel."""
@@ -122,6 +164,9 @@ def test_tiles_come_from_the_shapes():
     assert expert_rows.f_tile(4096, 4096, budget) == 256  # commandaplus.wide: 16
     assert expert_rows.f_tile(2048, 1792, 24 << 20) == 1792  # the whole of F where it fits
     assert expert_rows.f_tile(8192, 1920, 1 << 20) == 128  # none fits: the narrowest
+    # relu² experts, two matrices a step: nemotron3nano.solve's routed experts (1856 stored as 1920) and shared one
+    assert expert_rows.f_tile(2688, 1920, budget, mats=2) == 640  # 3 tiles an expert
+    assert expert_rows.f_tile(2688, 3712, budget, mats=2) == 128  # 29 tiles: 3712 is 29 x 128
 
 
 def _held(cfg, params, stacks, T):
