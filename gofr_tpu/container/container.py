@@ -241,6 +241,13 @@ class Container:
             "models only)",
         )
         m.new_counter(
+            "app_dsa_read_blocks_total",
+            "Blocks dispatched by a model with a learned sparse selection, by "
+            "how their decode steps read it (label path=masked|gather: the "
+            "row's pages whole under the selection's mask, or the selected "
+            "rows alone)",
+        )
+        m.new_counter(
             "app_moe_expert_rows_total",
             "Rows routed to each routed expert this replica holds, over "
             "decode steps and layers, read with each block's tokens (label "

@@ -34,9 +34,16 @@ What a token caches, per layer: one latent row ``[c_kv | k_r | 0]`` (576
 values padded to 640, ``ops/mla.latent_row_width``) and one indexer key.
 The engine's two pools hold them (:func:`page_shapes`): ``k_pool`` the
 latent rows, ``v_pool`` the indexer keys, under one block table. A decode
-step appends with ``ops/paged_attention.paged_kv_append``, scores the
-whole context with ``ops/mla.paged_index_scores`` and reads only the
-selected rows with ``ops/mla.sparse_decode_attention``. A chunk of a
+step appends with ``ops/paged_attention.paged_kv_append`` and scores the
+whole context with ``ops/mla.paged_index_scores``; then it reads the
+selection one of two ways, chosen from the slot's shape
+(:func:`sparse_read`). Where the selection is a large part of the slot,
+the row's owned pages are read whole by
+``ops/latent_attention.paged_latent_attention``, a Mosaic call a layer at
+the rate of a page DMA, masked to the selection
+(``ops/mla.selection_mask``); where it is a small part, only the
+selected rows are read, by ``ops/mla.sparse_decode_attention``'s gather
+by token. Both attend to the same positions. A chunk of a
 prompt runs one row at a time under a ``cond``: rows without a chunk cost
 nothing, and a row's scores ([heads, chunk, context]) fit. Its reads,
 index scores, selection and attention stop at the pages up to the
@@ -81,7 +88,7 @@ from gofr_tpu.ops.rope import (
 __all__ = [
     "DeepseekV32Config", "KVCache", "init_params", "quantize_params", "prefill",
     "decode_step_paged", "decode_chunk_paged", "step_stats_len", "step_stats", "page_shapes", "unserved", "prefill_slabs",
-    "chunk_contexts",
+    "chunk_contexts", "sparse_read",
 ]
 
 
@@ -175,6 +182,25 @@ def step_stats_len(cfg: DeepseekV32Config) -> int:
     routed to each held expert, the held experts whose matrices were read
     (``ops/moe.held_experts``), then :func:`step_stats`."""
     return cfg.held_experts + 1 + len(step_stats(cfg))
+
+
+# the masked read costs each resident position of a row, the gather each
+# of index_topk selected ones: on the v5e at 128 heads about 4.4 ns against
+# 17.6 (tools/masked_probe.py), so they break even near 4 x index_topk
+# resident positions, which no row of a slot of 4 x index_topk passes
+_MASKED_READ_SLOTS = 4
+
+
+def sparse_read(cfg: DeepseekV32Config, slot_positions: int) -> str | None:
+    """How a decode step reads the selection from a slot of
+    ``slot_positions``: ``masked`` — the row's owned pages whole, through
+    the latent kernel under the selection's mask — where the slot holds at
+    most 4 x ``index_topk`` positions, else ``gather`` — the selected rows
+    alone, a gather by token. None without an indexer. The engine sets it
+    on every block's dispatch span as ``dsa_read``."""
+    if not cfg.index_topk:
+        return None
+    return "masked" if slot_positions <= _MASKED_READ_SLOTS * cfg.index_topk else "gather"
 
 
 def page_shapes(cfg: DeepseekV32Config, page_size: int) -> tuple[tuple, tuple | None]:
@@ -498,13 +524,16 @@ def decode_step_paged(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step over the paged pools, with ``llama.decode_step_paged``'s
     arguments: the pools ride the layers whole, written by the append's
-    kernel alone and read by two gathers — every page of a row's indexer
-    keys, and of the latent rows only the selected ones. Without an
+    kernel alone. The indexer reads every page of a row's keys; the latent
+    rows are read as :func:`sparse_read` says for the tables' slot — the
+    row's owned pages through ``paged_latent_attention`` under the
+    selection's mask, or the selected rows alone by a gather. Without an
     indexer ``v_pool`` is None and every cached latent row of a row is read
     by ``paged_latent_attention``, a Mosaic call a layer. After the pools,
     the step's counters (:func:`step_stats_len`)."""
     B = tokens.shape[0]
     page = k_pool.shape[3]
+    masked = sparse_read(cfg, block_tables.shape[1] * page) == "masked"
     trash_page = k_pool.shape[1] - 1  # reserved by PagedKVCache
     x = params["embedding"][tokens][:, None, :].astype(jnp.float32)  # [B, 1, D]
     pos = jnp.maximum(seq_lens - 1, 0)
@@ -526,12 +555,18 @@ def decode_step_paged(
             return _absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[:, None], (kp, vp), read
         kp, vp = paged_kv_append(kp, vp, row[:, 0, None], ki[:, 0, None], layer, pages, offsets)
         scores, seen = mla.paged_index_scores(qi[:, 0], wi[:, 0], vp, block_tables, seq_lens, layer)
-        rows, valid = mla.select_topk(
-            scores, seen, cfg.index_topk, mla.pool_rows(block_tables, kp.shape[1], page, layer))
-        q = _absorb_query(cfg, q_nope[:, 0], q_rope[:, 0], lp["wkv_b"])
-        o_lat = mla.sparse_decode_attention(
-            q, kp, rows, valid, scale=cfg.softmax_scale, kv_lora_rank=cfg.kv_lora_rank)
-        counts = _count(seen & active[:, None], valid & active[:, None])
+        if masked:
+            keep = mla.selection_mask(scores, seen, cfg.index_topk)
+            q = _absorb_query(cfg, q_nope[:, 0], q_rope[:, 0], lp["wkv_b"])
+            o_lat = paged_latent_attention(q, kp, block_tables, seq_lens, layer, scale=cfg.softmax_scale,
+                                           kv_lora_rank=cfg.kv_lora_rank, keep=keep)
+        else:
+            rows, keep = mla.select_topk(
+                scores, seen, cfg.index_topk, mla.pool_rows(block_tables, kp.shape[1], page, layer))
+            q = _absorb_query(cfg, q_nope[:, 0], q_rope[:, 0], lp["wkv_b"])
+            o_lat = mla.sparse_decode_attention(
+                q, kp, rows, keep, scale=cfg.softmax_scale, kv_lora_rank=cfg.kv_lora_rank)
+        counts = _count(seen & active[:, None], keep & active[:, None])
         return _absorb_output(cfg, o_lat, lp["wkv_b"], h.dtype)[:, None], (kp, vp), counts
 
     x, (k_pool, v_pool), stats = _run_layers(cfg, params, x, (k_pool, v_pool), attend, active[:, None])

@@ -26,8 +26,19 @@ returns o_lat ``[B, H,
 kv_lora_rank]`` float32 and writes no pool, so XLA gives the pool no
 layout of its own (``ops/paged_attention.py``'s header says why).
 
+With ``keep`` — a mask over the slot's positions, a row's selection
+(``models/deepseek_v32.py``'s indexer, ``mla.selection_mask``) — the
+kernel reads the same pages and attends to the kept positions alone: the
+block's slice of the row's mask is ANDed into the length's before the
+online softmax, so an unselected position weighs zero. The mask enters
+as ``[B, 1, S]`` int32, a ``(1, 1, S)`` block a row (a ``(1, S)`` block of
+``[B, S]`` would break the rule on a block's second-to-last dimension),
+and a block's slice of it starts at a multiple of the block's
+positions. Without it the kernel is the unmasked one, op for op.
+
 On the CPU the entry computes :func:`paged_latent_attention_ref` —
-``mla.latent_attention`` over ``mla.row_pages`` masked to ``seq_len`` —
+``mla.latent_attention`` over ``mla.row_pages`` masked to ``seq_len`` and
+``keep`` —
 unless a test asks for the Pallas interpreter (``ops/backend.py``). The
 kernel's device events are ``%paged_latent_attention.<n>`` custom calls
 (the jitted wrapper and the ``pallas_call`` share the name).
@@ -63,12 +74,14 @@ def paged_latent_attention_ref(
     *,
     scale: float,
     kv_lora_rank: int,
+    keep: jnp.ndarray | None = None,  # [B, M*page] bool: the positions attended, if not all seen
 ) -> jnp.ndarray:
     """A gather of the row's pages and dense latent attention under the
-    length's mask: the oracle the kernel is tested against, and the CPU
-    path. Returns o_lat [B, H, kv_lora_rank] float32."""
+    length's mask (and ``keep``): the oracle the kernel is tested against,
+    and the CPU path. Returns o_lat [B, H, kv_lora_rank] float32."""
     rows = mla.row_pages(latent_pool, block_tables, layer)  # [B, M*page, W]
-    keep = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
+    seen = jnp.arange(rows.shape[1])[None, :] < seq_lens[:, None]
+    keep = seen if keep is None else seen & keep
     return mla.latent_attention(q[:, None], rows, keep[:, None], scale, kv_lora_rank)[:, 0]
 
 
@@ -128,23 +141,23 @@ def _kernel(
     layer_ref,  # SMEM [1] (scalar prefetch)
     q_ref,  # VMEM [1, H, W]: this row's queries
     pool_hbm,  # HBM [L, N+1, 1, page, W]: the whole pool, never copied or sliced
-    o_ref,  # VMEM [1, H, R] float32
-    buf,  # VMEM [2, ppb, 1, page, W]: the double buffer
-    sem,  # DMA (2,): one a slot
-    slot_ref,  # SMEM [1]: the slot the next row starts in
-    m_scr,  # VMEM [H, 128] float32
-    l_scr,
-    acc_scr,  # VMEM [H, R] float32
-    *,
+    *refs,  # with a mask, first VMEM [1, 1, S] int32: this row's kept positions; then:
+    # o_ref VMEM [1, H, R] float32; buf VMEM [2, ppb, 1, page, W], the double buffer;
+    # sem DMA (2,), one a slot; slot_ref SMEM [1], the slot the next row starts in;
+    # m_scr, l_scr VMEM [H, 128] float32; acc_scr VMEM [H, R] float32
     scale: float,
     ppb: int,
+    masked: bool,
 ):
     """One program a row. Its pages arrive ``ppb`` at a time by DMAs this
     kernel starts, into the slot of the double buffer that is not being
     computed on; the last block of a row starts the first block of the
     next row. The loop runs ``cdiv(seq_len, ppb * page)`` times: a row
     costs the pages it owns up to its length (a row of length 0 is read as
-    one of length 1)."""
+    one of length 1). With ``masked`` a position counts only where the
+    row's mask keeps it."""
+    keep_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
+    o_ref, buf, sem, slot_ref, m_scr, l_scr, acc_scr = refs
     layer = layer_ref[0]
     page, W = pool_hbm.shape[3:]
     H, R = acc_scr.shape
@@ -189,6 +202,8 @@ def _kernel(
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale  # [H, bk]
         seen = i * bk + jax.lax.broadcasted_iota(jnp.int32, (H, bk), 1) < seq_len
+        if masked:  # the block's slice of the row's mask, [1, bk]
+            seen = jnp.logical_and(seen, keep_ref[0, :, pl.ds(pl.multiple_of(i * bk, bk), bk)] != 0)
         s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -210,17 +225,24 @@ def _kernel(
     o_ref[0] = acc_scr[...] / jnp.where(denom == 0.0, 1.0, denom)
 
 
-def _call(q, latent_pool, block_tables, seq_lens, layer, scale, kv_lora_rank, interpret):
+def _call(q, latent_pool, block_tables, seq_lens, layer, scale, kv_lora_rank, interpret, keep=None):
     B, H, W = q.shape
     page = latent_pool.shape[3]
     M = block_tables.shape[1]
     # a slot's pages never outnumber the pool's: the whole slot's wait is described by a run of them
     ppb = max(1, min(_BLOCK_TOKENS // page, M, latent_pool.shape[1]))
-    kernel = functools.partial(_kernel, scale=scale, ppb=ppb)
+    kernel = functools.partial(_kernel, scale=scale, ppb=ppb, masked=keep is not None)
+    in_specs = [pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)), pl.BlockSpec(memory_space=pl.ANY)]
+    masks = ()
+    if keep is not None:
+        # whole blocks: a row's last block is computed whole, and reads its mask whole
+        S = pl.cdiv(M, ppb) * ppb * page
+        masks = (jnp.pad(keep, ((0, 0), (0, S - keep.shape[1]))).astype(jnp.int32).reshape(B, 1, S),)
+        in_specs.append(pl.BlockSpec((1, 1, S), lambda b, *_: (b, 0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # seq_lens, block_tables, layer
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, H, W), lambda b, *_: (b, 0, 0)), pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, H, kv_lora_rank), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ppb) + latent_pool.shape[2:], latent_pool.dtype),
@@ -242,7 +264,7 @@ def _call(q, latent_pool, block_tables, seq_lens, layer, scale, kv_lora_rank, in
         interpret=interpret,
         name="paged_latent_attention",
     )(seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-      q.astype(latent_pool.dtype), latent_pool)
+      q.astype(latent_pool.dtype), latent_pool, *masks)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "kv_lora_rank", "interpret"))
@@ -256,14 +278,15 @@ def paged_latent_attention(
     scale: float,
     kv_lora_rank: int,
     interpret: bool | None = None,
+    keep: jnp.ndarray | None = None,  # [B, M*page] bool: the positions attended, if not every one seen
 ) -> jnp.ndarray:
     """Absorbed latent attention of one query a row over every position
-    the row holds, ``[0, seq_len)``, read from layer ``layer`` of the
-    whole pool through the row's block table. Returns o_lat [B, H,
-    kv_lora_rank] float32; contract identical to
-    :func:`paged_latent_attention_ref`."""
+    the row holds, ``[0, seq_len)`` — with ``keep``, over those of them it
+    keeps — read from layer ``layer`` of the whole pool through the row's
+    block table. Returns o_lat [B, H, kv_lora_rank] float32; contract
+    identical to :func:`paged_latent_attention_ref`."""
     mode = kernel_mode(interpret)
     if mode == REFERENCE:
         return paged_latent_attention_ref(q, latent_pool, block_tables, seq_lens, layer,
-                                          scale=scale, kv_lora_rank=kv_lora_rank)
-    return _call(q, latent_pool, block_tables, seq_lens, layer, scale, kv_lora_rank, mode == INTERPRET)
+                                          scale=scale, kv_lora_rank=kv_lora_rank, keep=keep)
+    return _call(q, latent_pool, block_tables, seq_lens, layer, scale, kv_lora_rank, mode == INTERPRET, keep)

@@ -22,9 +22,13 @@ keys ``[L, N+1, 1, page, 128]``): :func:`paged_index_scores` gathers a
 row's pages of indexer keys (every position is scored), and
 :func:`sparse_decode_attention` reads from the latent pool ONLY the rows
 of the selected positions — an XLA gather by token, feeding one dense
-product a row. Both are jitted under their own names so that a device
-trace shows them (``jit(paged_index_scores)``,
-``jit(sparse_decode_attention)``); neither writes a pool, and the
+product a row. Where the selection is a large part of the slot, the
+model reads the row's pages whole instead, through
+``ops/latent_attention.paged_latent_attention`` under
+:func:`selection_mask` (``models/deepseek_v32.sparse_read``). Both
+(:func:`paged_index_scores`, :func:`sparse_decode_attention`) are jitted
+under their own names so that a device trace shows them
+(``jit(paged_index_scores)``, ``jit(sparse_decode_attention)``); neither writes a pool, and the
 gathers read the pool through a bitcast, so XLA gives it no layout of
 its own (PERF.md §6, PR 30). The selection is one sort a layer that
 carries each position's row in the pool (:func:`pool_rows`), so the read
@@ -83,12 +87,13 @@ def select_topk(
 
 def selection_mask(scores: jnp.ndarray, seen: jnp.ndarray, k: int) -> jnp.ndarray:
     """:func:`select_topk` as a mask over the context [..., S]: the form
-    dense scores take (prefill and chunks). A position is kept iff it is
+    dense scores take (prefill and chunks), and a decode step that reads
+    the row's pages whole. A position is kept iff it is
     seen and among the k best; of positions that tie with the k-th best
     the earlier ones are kept, as ``top_k`` lists them (a score is a sum
     of ReLUs, so ties at zero do occur with few heads). Two forms of one
     selection, held to each other by a test, because each path needs its
-    own: a decode step gathers by a LIST, and a mask made from a list is
+    own: a gather by token takes a LIST, and a mask made from a list is
     a scatter of [T, K] into [T, S], which the TPU runs element by
     element; made from the k-th best score it is two comparisons."""
     if k >= scores.shape[-1]:

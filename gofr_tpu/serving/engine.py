@@ -605,6 +605,12 @@ class ServingEngine:
         # a sparse-expert model's branch of held_experts at a block's
         # decode rows, set as moe_path on every block's dispatch span
         self._moe_path = _moe_path(cfg, self.params, self.config.max_slots)
+        # a model with a learned sparse selection: how a decode step reads
+        # it from a slot (the model's own rule of the slot's shape), set as
+        # dsa_read on every block's dispatch span
+        read = getattr(model, "sparse_read", None)
+        page = max(1, int(self.config.kv_page_size))
+        self._dsa_read = read(cfg, -(-self.config.max_seq_len // page) * page) if read else None
         # a model whose layers above its one shared cache run on a prompt's
         # last position alone: its prefill spans say how many positions
         # each half ran, and an admission resets a recurrent state
@@ -3648,6 +3654,10 @@ class ServingEngine:
                 span.set(moe_path=self._moe_path)
                 if self._metrics:
                     self._metrics.add_counter("app_moe_path_blocks_total", 1, path=self._moe_path)
+            if self._dsa_read is not None:
+                span.set(dsa_read=self._dsa_read)
+                if self._metrics:
+                    self._metrics.add_counter("app_dsa_read_blocks_total", 1, path=self._dsa_read)
             self._count_step_tokens(
                 len(rows) * N, chunk_tokens,
                 self.config.max_slots * (N + (self._chunk_tokens if chunk_rows else 0)),

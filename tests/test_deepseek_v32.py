@@ -20,6 +20,7 @@ reference break them alike, the earlier position first
 """
 
 import dataclasses
+import functools
 import json
 import math
 import threading
@@ -106,14 +107,15 @@ def write_slab(pool, slab, table, start=0):
     return pool
 
 
-def serve_through_the_cache(cfg, params, ids, n_prompt, bucket):
+def serve_through_the_cache(cfg, params, ids, n_prompt, bucket, pages=16):
     """Bucketed prefill of the first ``n_prompt`` tokens, then the rest one
-    decode step at a time through the paged pools (teacher-forced): the
-    logits at positions n_prompt-1 .. len(ids)-1, and each step's counters."""
+    decode step at a time through the paged pools of ``pages`` a slot
+    (teacher-forced): the logits at positions n_prompt-1 .. len(ids)-1, and
+    each step's counters."""
     tokens = np.zeros((1, bucket), np.int32)
     tokens[0, :n_prompt] = ids[:n_prompt]
     last, k_slab, v_slab = batch_ops.prefill_compute(cfg, params, jnp.asarray(tokens), jnp.asarray([n_prompt]))
-    kp, vp, tables = paged(cfg, 2, 16)
+    kp, vp, tables = paged(cfg, 2, pages)
     kp, vp = write_slab(kp, k_slab[:, :n_prompt], tables[0]), write_slab(vp, v_slab[:, :n_prompt], tables[0])
     out, counted = [np.asarray(last[0])], []
     for pos in range(n_prompt, len(ids)):
@@ -338,6 +340,36 @@ def test_the_sparse_read_gathers_the_selected_rows_alone():
 
 
 # ------------------------------------------------------------ closed forms
+@pytest.mark.parametrize("pages, read, interpret", [
+    pytest.param(8, "masked", False, id="32-positions-masked"),
+    pytest.param(8, "masked", True, id="32-positions-masked-through-the-kernel"),
+    pytest.param(9, "gather", False, id="36-positions-gather"),
+    pytest.param(16, "gather", False, id="64-positions-gather"),
+])
+def test_the_masked_read_and_the_gather_attend_to_the_same_selection(plain, pages, read, interpret, monkeypatch):
+    """The read a decode step takes comes from the slot's shape alone
+    (``ds.sparse_read``: the row's pages whole under the selection's mask
+    up to 4 x ``index_topk`` = 32 positions, the gather by token past it).
+    Over the same tokens and positions — a selection of 8 that binds from
+    position 8 on, ties at its edge — each form's logits are the gather's
+    at 16 pages a slot and the reference's, and each counts the same
+    positions selected; the masked form also through the latent kernel in
+    the interpreter."""
+    from gofr_tpu.ops import latent_attention as la
+
+    assert ds.sparse_read(CFG, pages * PAGE) == read and ds.sparse_read(ds.DeepseekV32Config.tiny(index_topk=0), 8) is None
+    cfg = CFG
+    if interpret:  # a config of its own: the jitted step traces afresh with the kernel in the interpreter
+        cfg = dataclasses.replace(CFG, max_seq_len=CFG.max_seq_len + 1)
+        monkeypatch.setattr(ds, "paged_latent_attention", functools.partial(la.paged_latent_attention, interpret=True))
+    ids = ids_of(32)
+    got, counted = serve_through_the_cache(cfg, plain, ids, n_prompt=12, bucket=16, pages=pages)
+    gathered, counted_by_gather = serve_through_the_cache(CFG, plain, ids, n_prompt=12, bucket=16)
+    want = np.asarray(reference.logits(as_file(CFG), plain, ids))
+    assert np.abs(got - gathered).max() < TOL and np.abs(got - want[11:]).max() < TOL
+    assert (counted[:, -2:] == counted_by_gather[:, -2:]).all() and (counted[:, -1] == 3 * 8).all()
+
+
 def test_yarn_angles_and_temperature_against_closed_forms():
     """The published shapes: 64 rotary dims, base 10000, factor 40 over
     4096. Pairs 0..10 turn more than 32 times in 4096 positions and keep
@@ -516,6 +548,48 @@ def test_the_seam_finds_the_module_and_its_counters():
     # 16 held experts' rows, the held experts read, the indexer's two
     assert batch_ops.model_of(CFG) is ds and ds.step_stats_len(CFG) == 16 + 1 + 2
     assert CFG.n_kv_heads == 1 and CFG.head_dim == CFG.qk_rope_head_dim
+
+
+@pytest.mark.parametrize("slot, read", [(32, "masked"), (64, "gather")])
+def test_every_block_names_the_read_its_decode_steps_take(plain, slot, read, monkeypatch):
+    """The engine asks the model once how a decode step reads the
+    selection from its slot (``ds.sparse_read``: 32 positions are 4 x
+    ``index_topk``, 64 are more) and names it as ``dsa_read`` on every
+    block's dispatch span, decode and ragged alike; /metrics counts the
+    blocks under that path in ``app_dsa_read_blocks_total`` and under no
+    other. A model without an indexer sets neither."""
+    from gofr_tpu.config import MapConfig
+    from gofr_tpu.container.container import Container
+    from gofr_tpu.serving import engine as engine_mod
+
+    container = Container(MapConfig({"APP_NAME": "dsa-read", "LOG_LEVEL": "WARN"}, use_env=False))
+    seen = []
+    real = engine_mod._StepPhase.set
+    monkeypatch.setattr(engine_mod._StepPhase, "set", lambda self, **kw: (seen.append((self._phase, kw)), real(self, **kw))[1])
+    engine = ServingEngine(CFG, plain, engine_settings(max_seq_len=slot), ByteTokenizer(300),
+                           metrics=container.metrics_manager, logger=container.logger)
+    try:
+        engine.start()
+        # a bucketed prompt and one that chunks (20 tokens with BOS, chunks of 16): decode and ragged blocks
+        for prompt in ("short", "a prompt that chunks"):
+            assert len(engine.submit(prompt, max_new_tokens=10, temperature=0.0).result(timeout=300).token_ids) == 10
+        metrics = container.metrics_manager.expose_prometheus()
+    finally:
+        engine.stop()
+        container.close()
+    blocks = [kw for phase, kw in seen if phase == "dispatch" and "blk" in kw]
+    assert {kw["kind"] for kw in blocks} == {"decode", "ragged"}
+    assert [kw["dsa_read"] for phase, kw in seen if phase == "dispatch" and "dsa_read" in kw] == [read] * len(blocks)
+    counted = {line.split("{", 1)[1].split("}")[0]: int(float(line.rsplit(" ", 1)[1]))
+               for line in metrics.splitlines() if line.startswith("app_dsa_read_blocks_total{")}
+    assert counted == {f'path="{read}"': len(blocks)}
+    index_free = dataclasses.replace(CFG, index_n_heads=0, index_head_dim=0, index_topk=0)
+    engine = ServingEngine(index_free, ds.init_params(index_free, jax.random.PRNGKey(7)),
+                           engine_settings(max_seq_len=slot), ByteTokenizer(300))
+    try:
+        assert engine._dsa_read is None
+    finally:
+        engine.stop()
 
 
 def test_the_model_is_served_behind_an_app_over_http_with_its_spans_and_counters(plain, monkeypatch):

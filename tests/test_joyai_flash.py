@@ -269,6 +269,74 @@ def test_the_kernel_reads_the_pages_a_row_owns_up_to_its_length_alone(blocks_of_
     assert np.abs(np.asarray(want - direct)).max() == 0.0
 
 
+# a row's selection as the kernel's mask (``models/deepseek_v32``'s masked
+# read): (lengths of six rows, the selection's k, the scores' kind, the
+# block's pages). Blocks of two pages hold 8 positions; tables 24
+MASKS = {
+    "four-heads": ([0, 1, 9, 16, 17, 24], 5, "random", 2),
+    "shorter-than-the-selection": ([3, 9, 16, 17, 24, 5], 30, "random", 2),
+    "ties-at-the-kth-score": ([24, 17, 16, 9, 24, 12], 6, "ties", 2),
+    "lengths-0-and-1": ([0, 1, 0, 1, 1, 0], 5, "random", 2),
+    "an-inactive-row": ([24, 17, 9, 24, 16, 1], 5, "none-in-row-3", 2),
+    "kept-across-a-block-edge": ([24, 17, 12, 24, 16, 11], 5, "around-8", 2),
+    "the-entry-s-block": ([0, 1, 9, 16, 17, 24], 5, "random", None),
+}
+
+
+@pytest.mark.parametrize("heads", [4, 128], ids=["H4", "H128"])
+@pytest.mark.parametrize("case", list(MASKS))
+def test_the_kernel_under_a_mask_is_latent_attention_over_the_kept_positions(case, heads, monkeypatch):
+    """``keep`` — a row's selection, ``mla.selection_mask`` over indexer
+    scores — in the interpreter against :func:`paged_latent_attention_ref`
+    given the same mask (``keep & seen`` over ``mla.row_pages``), at 4 and
+    at 128 heads: a selection wider than the row keeps all it sees, ties
+    at the k-th score keep the earlier positions, a row that sees nothing
+    or keeps nothing (an inactive row) returns zeros, and a selection on
+    both sides of a block's edge is summed across it. Positions the mask
+    drops hold rows of 1e4 in the row's own pages: they weigh exactly
+    nothing."""
+    lengths, k, kind, pages = MASKS[case]
+    if pages:
+        monkeypatch.setattr(la, "_BLOCK_TOKENS", pages * PAGE)
+    pool, _, tables, _, R = kernel_case()
+    B, S = tables.shape[0], tables.shape[1] * PAGE
+    q = jax.random.normal(jax.random.PRNGKey(5), (B, heads, pool.shape[-1]), jnp.float32).astype(pool.dtype)
+    lens = jnp.asarray(lengths)
+    seen = jnp.arange(S)[None] < lens[:, None]
+    scores = jax.random.normal(jax.random.PRNGKey(6), (B, S), jnp.float32)
+    if kind == "ties":
+        scores = jnp.maximum(scores, 0.0).at[:, 2:].set(0.0).at[:, 9].set(0.5)
+    elif kind == "around-8":  # the best positions lie at 6..10, across the edge at 8
+        scores = scores.at[:, 6:11].add(10.0)
+    keep = mla.selection_mask(scores, seen, k)
+    if kind == "none-in-row-3":
+        keep = keep.at[3].set(False)
+    if kind == "ties":
+        assert (np.asarray(keep[0, :8]) == [True] * 2 + [True] * 3 + [False] * 3).all()  # 0, 1, 9 and 2..4
+    if k > max(lengths):
+        assert (np.asarray(keep) == np.asarray(seen)).all()
+    if kind == "around-8":
+        assert np.asarray(keep[:, 6:11]).all() and not np.asarray(keep[:, :6]).any()
+    # what the mask drops, in the row's own pages up to its length, is loud
+    dropped = np.asarray(seen & ~keep)
+    loud = np.asarray(pool).copy()
+    for b, pos in zip(*np.nonzero(dropped)):
+        loud[1, int(tables[b, pos // PAGE]), 0, pos % PAGE] = 1e4
+    loud = jnp.asarray(loud)
+    want = la.paged_latent_attention_ref(q.astype(jnp.float32), pool.astype(jnp.float32), tables, lens, jnp.int32(1),
+                                         scale=0.3, kv_lora_rank=R, keep=keep)
+    got = np.asarray(la._call(q, loud, tables, lens, jnp.int32(1), 0.3, R, True, keep))
+    assert got.shape == (B, heads, R) and np.isfinite(got).all()
+    assert np.abs(got - np.asarray(want)).max() < 2e-5 and np.abs(np.asarray(want)).max() > 0.5
+    nothing = ~np.asarray(keep).any(axis=1)
+    assert bool(nothing.any()) == (0 in lengths or kind == "none-in-row-3")
+    assert not got[nothing].any()  # a row that keeps nothing sums nothing
+    # the reference is dense latent attention over the row's pages under keep & seen
+    rows = mla.row_pages(pool, tables, jnp.int32(1))
+    direct = mla.latent_attention(q.astype(jnp.float32)[:, None], rows.astype(jnp.float32), (keep & seen)[:, None], 0.3, R)
+    assert np.abs(np.asarray(want - direct[:, 0])).max() == 0.0
+
+
 # ---------------------------------------------------- against the reference
 @pytest.mark.parametrize("weights", ["plain", "int8"])
 def test_prefill_then_decode_over_every_position_agrees_with_the_reference(weights, request):

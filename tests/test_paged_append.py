@@ -16,6 +16,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import hashlib
 import re
 
 import hlo_text
@@ -1015,6 +1016,100 @@ def test_joyai_latent_kernel_compiles_at_the_cell_s_widths(one_chip, no_compile_
     copies = [line for line in text.splitlines() if " copy(" in line]
     assert not any("bf16[" in line.split(" copy(")[0] for line in copies), copies  # the pool and queries go in as they are
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+# the Mosaic module of the unmasked latent kernel at joyai.think's shapes, its
+# text without debug locations, as the tree before the mask was added lowered it
+UNMASKED_MOSAIC_SHA256 = "b753c356cdc3b6c57b6a4380a6ad92cda5894d1af8c3f81620bd5d575369f55d"
+
+
+def test_the_latent_kernel_without_a_mask_lowers_as_it_did_before_the_mask(one_chip, monkeypatch):
+    """``paged_latent_attention`` without ``keep`` — JoyAI's decode read,
+    and the index-free V3 layer's — lowers to the Mosaic module it lowered
+    to before the masked form was added, op for op: the text of the module
+    (debug locations stripped, so that lines moved in the source do not
+    count) hashes as recorded. With ``keep`` it is another module, which
+    takes the mask as one more operand."""
+    from jax._src import tpu_custom_call
+
+    from gofr_tpu.ops import latent_attention as la
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(la, "kernel_mode", lambda interpret=None: COMPILED)
+    texts = []
+    real = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def spy(module, **kw):
+        texts.append(module.operation.get_asm(enable_debug_info=False))
+        return real(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", spy)
+    B, M, page = 64, 512, 16
+    pool = jax.ShapeDtypeStruct((13, B * M + 1, 1, page, 640), jnp.bfloat16, sharding=one_chip)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    args = (vec(jnp.bfloat16, B, 32, 640), pool, vec(jnp.int32, B, M), vec(jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    jax.clear_caches()
+    with jax.default_matmul_precision("default"):
+        jax.jit(lambda *a: la.paged_latent_attention(*a, scale=192 ** -0.5, kv_lora_rank=512)).lower(*args)
+        jax.jit(lambda *a: la.paged_latent_attention(*a[:5], scale=192 ** -0.5, kv_lora_rank=512, keep=a[5])).lower(
+            *args, vec(jnp.bool_, B, M * page))
+    jax.clear_caches()
+    assert len(texts) == 2 and hashlib.sha256(texts[0].encode()).hexdigest() == UNMASKED_MOSAIC_SHA256
+    assert "memref<1x1x8192xi32" in texts[1] and "memref<1x1x8192xi32" not in texts[0]
+
+
+def test_v32_compiled_decode_block_reads_the_selection_through_the_latent_kernel(one_chip, no_compile_cache, monkeypatch):
+    """``decode_block_paged`` of ``deepseekv32.long``'s configuration (7
+    layers, 128 heads, a selection of 2,048) at the cell's shapes — 32
+    rows of 256 pages of 16 — by the chip's compiler: the slot's 4,096
+    positions are at most 4 x 2,048, so each layer body reads its latent
+    rows by ONE ``paged_latent_attention`` call under the selection's mask
+    and nothing gathers the 65,536 selected rows (``[65536,640]``) or
+    holds them (``[32,2048,640]``). The kernel takes the mask as
+    ``[32,1,4096]``: no operand of its call has the indexer's
+    ``[32,4096]``."""
+    from gofr_tpu.ops import expert_rows
+    from gofr_tpu.ops import latent_attention as la
+    from gofr_tpu.ops.backend import COMPILED
+    from benchmarks.harness import deepseek_v32_family
+    from benchmarks.harness.manifest import Manifest
+
+    for module in (pa, la, expert_rows):
+        monkeypatch.setattr(module, "kernel_mode", lambda interpret=None: COMPILED)
+    config = Manifest(deepseek_v32_family.__file__.rsplit("/benchmarks/", 1)[0]).config("deepseek-v3.2-exp-ep8-int8")
+    cfg = deepseek_v32_family.program_config(config)
+    B, M, page = 32, 256, 16
+    assert ds.sparse_read(cfg, M * page) == "masked" and ds.sparse_read(cfg, 2 * M * page + page) == "gather"
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (B,), dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(lambda: deepseek_v32_family.make_weights(config, 0)))
+    k_pool, v_pool = (jax.ShapeDtypeStruct((cfg.n_layers, B * M + 1) + shape, cfg.dtype, sharding=one_chip)
+                      for shape in ds.page_shapes(cfg, page))
+    i32, f32 = jnp.int32, jnp.float32
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    dec = batch_ops.DecodeState(vec(i32), vec(i32), vec(jnp.bool_), vec(i32), vec(i32), vec(f32),
+                                vec(i32), vec(f32), key, vec(i32))
+    jax.clear_caches()
+    with jax.default_matmul_precision("default"):
+        text = batch_ops.decode_block_paged.lower(
+            cfg, params, k_pool, v_pool, dec, vec(i32, B, M), vec(jnp.bool_), STEPS).compile().as_text()
+    jax.clear_caches()
+    calls = [line for line in text.splitlines() if re.match(r"\s*(ROOT )?%paged_latent_attention[\w.\-]* = ", line)]
+    # a layer body of each stack: the dense layers' and the expert layers'
+    assert len(calls) == 2 and all("tpu_custom_call" in line for line in calls)
+    assert "[65536,640]" not in text and "[32,2048,640]" not in text
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\w+\[[\d,]*\])", text, re.M))
+    masks = [shapes[name] for line in calls for name in re.findall(r"%[\w.\-]+", line.split("custom-call(", 1)[1].split(")")[0])]
+    assert masks.count("s32[32,1,4096]") == 2 and "s32[32,4096]" not in masks
 
 
 def test_joyai_compiled_decode_block_leaves_its_pool_to_the_kernels(one_chip, no_compile_cache, monkeypatch):
