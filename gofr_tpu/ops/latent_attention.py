@@ -19,7 +19,8 @@ lengths and block tables), fetches each page by a DMA of its own into a
 double buffer in VMEM — block *i + 1*, at a row's end the next row's
 first block, is in flight while block *i* is computed; the starts are
 unrolled over the block's static pages and a full block lands with ONE
-wait (:func:`_start_pages`, :func:`_land_pages`) — and keeps an
+wait (``ops/paged_attention``'s ``_start_pages``, ``_land_pages``, over
+one stream) — and keeps an
 online softmax in float32 for all heads: ``[H, W] x [W, block]`` to score,
 ``[H, block] x [block, kv_lora_rank]`` to sum, one float32 product. It
 returns o_lat ``[B, H,
@@ -55,6 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gofr_tpu.ops import mla
 from gofr_tpu.ops.backend import INTERPRET, REFERENCE, kernel_mode
+from gofr_tpu.ops.paged_attention import _land_pages, _start_pages
 
 NEG_INF = -1e30
 # positions one compute block and one DMA batch hold (640 KiB a slot at 640
@@ -85,56 +87,6 @@ def paged_latent_attention_ref(
     return mla.latent_attention(q[:, None], rows, keep[:, None], scale, kv_lora_rank)[:, 0]
 
 
-def _start_pages(pool_hbm, tables_ref, buf, sem, layer, row, first, n, slot):
-    """Starts the DMAs of the ``n`` pages from entry ``first`` of ``row``'s
-    block table into slot ``slot`` of the double buffer, a DMA a page,
-    unrolled over the slot's static pages: a page costs its descriptor and
-    no loop trip. A full block — every block of a row but its last —
-    starts them under no predicate; a partial one puts each under its own."""
-    ppb = buf.shape[1]
-
-    def start(j):
-        pid = tables_ref[row, first + j]
-        pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
-
-    @pl.when(n == ppb)
-    def _whole():
-        for j in range(ppb):
-            start(j)
-
-    @pl.when(n < ppb)
-    def _partial():
-        for j in range(ppb):
-            pl.when(j < n)(functools.partial(start, j))
-
-
-def _land_pages(pool_hbm, buf, sem, n, slot):
-    """Waits for the ``n`` pages :func:`_start_pages` started into slot
-    ``slot`` and zeroes the slot's pages past them. The DMAs count the
-    bytes they land on the slot's one semaphore, so a full slot — every
-    block of a row but its last — is ONE wait, described as the whole
-    slot (its source a run of pages of the pool, for its shape alone); a
-    partial block waits page by page."""
-    ppb = buf.shape[1]
-
-    @pl.when(n == ppb)
-    def _whole():
-        pltpu.make_async_copy(pool_hbm.at[0, pl.ds(0, ppb)], buf.at[slot], sem.at[slot]).wait()
-
-    @pl.when(n < ppb)
-    def _partial():
-        for j in range(ppb):
-            @pl.when(j < n)
-            def _wait():
-                pltpu.make_async_copy(pool_hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
-
-            # a page past the row's last holds what the slot held before: its
-            # scores are masked, but a zero weight times a stale NaN is NaN
-            @pl.when(j >= n)
-            def _clear():
-                buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
-
-
 def _kernel(
     seq_lens_ref,  # SMEM [B] (scalar prefetch)
     tables_ref,  # SMEM [B, M] (scalar prefetch)
@@ -158,6 +110,7 @@ def _kernel(
     row's mask keeps it."""
     keep_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
     o_ref, buf, sem, slot_ref, m_scr, l_scr, acc_scr = refs
+    streams = ((pool_hbm, buf),)
     layer = layer_ref[0]
     page, W = pool_hbm.shape[3:]
     H, R = acc_scr.shape
@@ -172,7 +125,7 @@ def _kernel(
         return jnp.minimum(row_pages(row) - blk * ppb, ppb)
 
     def start_block(row, blk, slot):
-        _start_pages(pool_hbm, tables_ref, buf, sem, layer, row, blk * ppb, block_pages(row, blk), slot)
+        _start_pages(streams, tables_ref, sem, layer, row, blk * ppb, block_pages(row, blk), slot)
 
     @pl.when(b == 0)
     def _prime():
@@ -196,7 +149,7 @@ def _kernel(
             row = jnp.where(last, jnp.minimum(b + 1, B - 1), b)
             start_block(row, jnp.where(last, 0, i + 1), 1 - slot)
 
-        _land_pages(pool_hbm, buf, sem, block_pages(b, i), slot)
+        _land_pages(streams, sem, block_pages(b, i), slot, buf)
 
         rows = buf[slot, :, 0].reshape(bk, W)  # [bk, W]
         s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),

@@ -19,10 +19,13 @@ Reading, two implementations with one contract:
   over the blocks of pages the row owns
   (``cdiv(seq_len, pages_per_block * page)``
   trips, read from the scalar-prefetched lengths and block tables) and
-  fetches each block itself, one DMA a page — a page of the pool is
-  contiguous for all KV heads — into a double buffer in VMEM, so that
-  block *i + 1* (at a row's end, the next row's first block) is in
-  flight while block *i* is computed. The online softmax runs in float32
+  fetches each block itself, one DMA a page a stream — a page of the
+  pool is contiguous for all KV heads — into a double buffer in VMEM, so
+  that block *i + 1* (at a row's end, the next row's first block) is in
+  flight while block *i* is computed; the starts are unrolled over the
+  block's static pages and a full block lands with one wait a stream
+  (:func:`_start_pages`, :func:`_land_pages`, which the latent kernel
+  shares). The online softmax runs in float32
   over every KV head of the block. A row of length 1 (an empty slot)
   costs one page. ``pages_per_block`` is worked out from the shapes the
   call sees (:func:`_pages_per_block`). The kernel addresses
@@ -125,6 +128,65 @@ def _pages_per_block(Hkv: int, page: int, Dh: int, itemsize: int, M: int) -> int
     return max(1, min(fit, _BLOCK_TOKENS // page, M))
 
 
+def _start_pages(streams, tables_ref, sem, layer, row, first, n, slot):
+    """Starts the DMAs of the ``n`` pages from entry ``first`` of ``row``'s
+    block table into slot ``slot`` of each stream's double buffer, a DMA a
+    page a stream (``streams``: pairs of the HBM pool and its VMEM buffer),
+    all on the slot's one semaphore. The starts are unrolled over the
+    slot's static pages: a page costs its descriptors and no loop trip. A
+    full block — every block of a row but its last — starts them under no
+    predicate; a partial one puts each page under its own."""
+    ppb = streams[0][1].shape[1]
+
+    def start(j):
+        pid = tables_ref[row, first + j]
+        for hbm, buf in streams:
+            pltpu.make_async_copy(hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
+
+    @pl.when(n == ppb)
+    def _whole():
+        for j in range(ppb):
+            start(j)
+
+    @pl.when(n < ppb)
+    def _partial():
+        for j in range(ppb):
+            pl.when(j < n)(functools.partial(start, j))
+
+
+def _land_pages(streams, sem, n, slot, cleared):
+    """Waits for the ``n`` pages :func:`_start_pages` started into slot
+    ``slot`` of each stream and zeroes ``cleared``'s pages of the slot past
+    them. The DMAs count the bytes they land on the slot's one semaphore,
+    so a full block — every block of a row but its last — waits once a
+    stream, each wait described as that stream's whole slot (its source a
+    run of pages of the pool, for its shape alone). One such wait proves
+    nothing about its own stream, since the streams' DMAs share the
+    semaphore; the waits together count every stream's bytes, and all of
+    them return before any page of the block is read. A partial block
+    waits page by page."""
+    ppb = streams[0][1].shape[1]
+
+    @pl.when(n == ppb)
+    def _whole():
+        for hbm, buf in streams:
+            pltpu.make_async_copy(hbm.at[0, pl.ds(0, ppb)], buf.at[slot], sem.at[slot]).wait()
+
+    @pl.when(n < ppb)
+    def _partial():
+        for j in range(ppb):
+            @pl.when(j < n)
+            def _wait():
+                for hbm, buf in streams:
+                    pltpu.make_async_copy(hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
+
+            # a page past the row's last holds what the slot held before: its
+            # scores are masked, but a zero weight times a stale NaN is NaN
+            @pl.when(j >= n)
+            def _clear():
+                cleared[slot, j] = jnp.zeros(cleared.shape[2:], cleared.dtype)
+
+
 def _paged_kernel(
     seq_lens_ref,  # SMEM [B] (scalar prefetch)
     tables_ref,  # SMEM [B, M] (scalar prefetch)
@@ -158,9 +220,11 @@ def _paged_body(
     ppb: int,
 ):
     """One program a row. The row's pages arrive ``ppb`` at a time by DMAs
-    this kernel issues (one a page: a page is contiguous for all KV heads),
-    into the slot of a double buffer that is not being computed on; the
-    last block of a row starts the first block of the next row. The loop
+    this kernel issues (one a page a stream: a page is contiguous for all
+    KV heads), into the slot of a double buffer that is not being computed
+    on; a full block lands with two waits, one a stream
+    (:func:`_start_pages`, :func:`_land_pages`); the last block of a row
+    starts the first block of the next row. The loop
     runs ``cdiv(seq_len, ppb * page)`` times: a row costs the pages it
     owns. With a ``window`` the loop starts at the block that holds
     position ``seq_len - window`` and that block masks below it: a row
@@ -189,22 +253,7 @@ def _paged_body(
         return lo // bk
 
     def start_block(row, blk, slot):
-        def one(j, _):
-            pid = tables_ref[row, blk * ppb + j]
-            for hbm, buf in streams:
-                pltpu.make_async_copy(hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
-            return _
-        jax.lax.fori_loop(0, block_pages(row, blk), one, None)
-
-    def wait_block(n, slot):
-        # every copy of the block before any is read: the streams share the
-        # slot's semaphore, so a count of one stream's bytes proves nothing
-        # about that stream
-        def one(j, _):
-            for hbm, buf in streams:
-                pltpu.make_async_copy(hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
-            return _
-        jax.lax.fori_loop(0, n, one, None)
+        _start_pages(streams, tables_ref, sem, layer, row, blk * ppb, block_pages(row, blk), slot)
 
     @pl.when(b == 0)
     def _prime():
@@ -228,16 +277,8 @@ def _paged_body(
             row = jnp.where(last, jnp.minimum(b + 1, B - 1), b)
             start_block(row, jnp.where(last, first_block(row), i + 1), 1 - slot)
 
-        n = block_pages(b, i)
-        wait_block(n, slot)
-
-        # the pages of the block this row does not own were not fetched and
-        # hold whatever the slot held before: K's are masked below, but a
-        # zero weight times a stale NaN in V is NaN
-        def clear(j, _):
-            v_buf[slot, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
-            return _
-        jax.lax.fori_loop(n, ppb, clear, None)
+        # K's pages past the row's last are masked below; V's are zeroed
+        _land_pages(streams, sem, block_pages(b, i), slot, v_buf)
 
         k_pos = i * bk + jax.lax.broadcasted_iota(jnp.int32, (group, bk), 1)
         nn = (((1,), (0,)), ((), ()))  # [group, bk] x [bk, Dh]
@@ -309,7 +350,8 @@ def _paged_attention_call(
     Hkv, page = k_pool.shape[-3], k_pool.shape[-2]
     M = block_tables.shape[1]
     group = H // Hkv
-    ppb = _pages_per_block(Hkv, page, Dh, k_pool.dtype.itemsize, M)
+    # a slot's pages never outnumber the pool's: the whole slot's wait is described by a run of them
+    ppb = min(_pages_per_block(Hkv, page, Dh, k_pool.dtype.itemsize, M), k_pool.shape[-4])
 
     # [B, Hkv, group, Dh]: a program sees its row's queries by kv head
     q_t = q.reshape(B, Hkv, group, Dh)

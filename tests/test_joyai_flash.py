@@ -24,9 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 
 import lowered_digests
+from committed_fetch import ON_WAIT, committed_land, committed_start
 from benchmarks.harness import joyai_flash_reference as reference
 from gofr_tpu.models import deepseek_v32 as ds
 from gofr_tpu.ops import latent_attention as la
@@ -135,10 +135,6 @@ LENGTHS = {
     "whole-tables": [24] * 6,  # every block full at both sizes
     "empty-and-one": [0, 1, 0, 1, 1, 0],
 }
-# the TPU interpreter that lands a DMA only when a wait on its semaphore
-# needs its bytes, into buffers that start as NaN, and prints a semaphore
-# left with a count at the kernel's exit
-ON_WAIT = pltpu.InterpretParams(dma_execution_mode="on_wait", uninitialized_memory="nan")
 
 
 def kernel_case(dtype=jnp.bfloat16, lengths="ragged"):
@@ -165,29 +161,6 @@ def poisoned(pool, tables, lens):
             page = int(tables[b, j])
             out[1, page] = np.asarray(pool)[1, page]
     return jnp.asarray(out)
-
-
-def committed_start(pool_hbm, tables_ref, buf, sem, layer, row, first, n, slot):
-    """The fetch the kernel had before one wait a block, kept as the
-    oracle of the fetch: a loop of dynamic length starts a DMA a page ..."""
-    def one(j, _):
-        pid = tables_ref[row, first + j]
-        pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
-        return _
-    jax.lax.fori_loop(0, n, one, None)
-
-
-def committed_land(pool_hbm, buf, sem, n, slot):
-    """... another waits for them page by page, a third zeroes the rest."""
-    def wait(j, _):
-        pltpu.make_async_copy(pool_hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
-        return _
-    jax.lax.fori_loop(0, n, wait, None)
-
-    def clear(j, _):
-        buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
-        return _
-    jax.lax.fori_loop(n, buf.shape[1], clear, None)
 
 
 @pytest.fixture

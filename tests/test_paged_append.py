@@ -1018,6 +1018,50 @@ def test_joyai_latent_kernel_compiles_at_the_cell_s_widths(one_chip, no_compile_
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+@pytest.mark.parametrize("rows,heads,kv_heads", [(64, 32, 2), (32, 40, 10)], ids=["solve", "reason-full"])
+def test_the_paged_kernel_lands_a_full_block_with_one_wait_a_stream(rows, heads, kv_heads, one_chip, no_compile_cache,
+                                                                  monkeypatch):
+    """``paged_decode_attention`` by the chip's compiler at the shapes of
+    ``nemotron3nano.solve`` (2 KV heads of 128, 8 KiB a page) and of
+    ``phi4flash.reason``'s full layer (10 KV pairs of 128, 40 KiB), 512
+    pages of 16 a row, 8 pages a block: one Mosaic call, whose only loop
+    is the one over a row's blocks — the fetch's starts and waits are
+    unrolled — and whose waits are a full block's two, each described as
+    one stream's whole slot, beside a partial block's one a page a
+    stream."""
+    from jax._src import tpu_custom_call
+
+    from gofr_tpu.ops.backend import COMPILED
+
+    monkeypatch.setattr(pa, "kernel_mode", lambda interpret=None: COMPILED)
+    texts = []
+    real = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def spy(module, **kw):
+        texts.append(module.operation.get_asm(enable_debug_info=False))
+        return real(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", spy)
+    M, page, Dh, ppb = 512, 16, 128, 8
+    pool = jax.ShapeDtypeStruct((6, rows * M + 1, kv_heads, page, Dh), jnp.bfloat16, sharding=one_chip)
+
+    def vec(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape or (rows,), dtype, sharding=one_chip)
+
+    jax.clear_caches()
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(lambda q, k, v, t, n, layer: pa.paged_decode_attention(q, k, v, t, n, layer=layer)).lower(
+            vec(jnp.bfloat16, rows, heads, Dh), pool, pool, vec(jnp.int32, rows, M), vec(jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    jax.clear_caches()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    (text,) = texts
+    assert text.count("scf.for") == 1 and "scf.while" not in text
+    waits = re.findall(r"tpu\.wait_dma2 .* src\(%\w+ : memref<([0-9x]+)xbf16", text)
+    whole, page_dma = f"{ppb}x{kv_heads}x{page}x{Dh}", f"{kv_heads}x{page}x{Dh}"
+    assert sorted(waits) == sorted([whole] * 2 + [page_dma] * 2 * ppb), waits
+
+
 # the Mosaic module of the unmasked latent kernel at joyai.think's shapes, its
 # text without debug locations, as the tree before the mask was added lowered it
 UNMASKED_MOSAIC_SHA256 = "b753c356cdc3b6c57b6a4380a6ad92cda5894d1af8c3f81620bd5d575369f55d"

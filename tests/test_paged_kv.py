@@ -9,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from committed_fetch import ON_WAIT, committed_land, committed_start
 from gofr_tpu.models import llama
+from gofr_tpu.ops import paged_attention as pa
 from gofr_tpu.ops.attention import decode_attention
 from gofr_tpu.ops.paged_attention import (
     paged_decode_attention,
@@ -187,6 +189,108 @@ class TestPagedKernelShapes:
         (call,) = calls(jaxpr.jaxpr)
         grid = tuple(call.params["grid_mapping"].grid)
         assert grid == (B,) and M not in grid
+
+
+# ------------------------------------------------------------- the fetch
+PAGE = 4
+# six rows over tables of six pages (24 positions): a block holds 8
+# positions at two pages, and the whole table at the entry's block
+FETCH_LENGTHS = {
+    "0": lambda bk: [0] * 6,
+    "1": lambda bk: [1] * 6,
+    "a-block": lambda bk: [bk] * 6,  # every block whole
+    "one-past-a-block": lambda bk: [bk + 1] * 6,  # a whole block, then one of one page (past the table at 24)
+    "ragged": lambda bk: [0, 1, 9, 16, 17, 24],
+}
+WINDOW = 6  # at two pages a block, the rows of 16, 17 and 24 start reading at their second or third block
+
+
+def fetch_case(lengths, bk):
+    """Two layers of bf16 pools of 4-position pages, 2 KV heads of 2
+    queries each, under permuted tables; the kernel reads layer 1."""
+    B, M, Hkv, group, Dh = 6, 6, 2, 2, 32
+    N = B * M
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    k_pool, v_pool = (jax.random.normal(key, (2, N + 1, Hkv, PAGE, Dh), jnp.float32).astype(jnp.bfloat16)
+                      for key in ks[:2])
+    q = jax.random.normal(ks[2], (B, Hkv * group, Dh), jnp.float32).astype(jnp.bfloat16)
+    tables = jnp.asarray(np.random.default_rng(11).permutation(N).reshape(B, M), jnp.int32)
+    return q, k_pool, v_pool, tables, jnp.asarray(FETCH_LENGTHS[lengths](bk), jnp.int32)
+
+
+def unowned_poisoned(pool, tables, lens):
+    """The pool with NaN in every page no row holds below its length, and
+    in the other layer."""
+    out = np.full(pool.shape, np.nan, np.float32)
+    for b, n in enumerate(np.asarray(lens)):
+        for j in range(max(1, -(-int(n) // PAGE))):  # a row of length 0 is read as one of length 1
+            out[1, int(tables[b, j])] = np.asarray(pool[1, int(tables[b, j])], np.float32)
+    return jnp.asarray(out, pool.dtype)
+
+
+@pytest.mark.parametrize("window", [None, WINDOW], ids=["whole-rows", "windowed"])
+@pytest.mark.parametrize("lengths", FETCH_LENGTHS)
+@pytest.mark.parametrize("block", ["blocks-of-two-pages", "the-entry-s-block"])
+def test_a_block_lands_as_the_committed_fetch_landed_it(block, lengths, window, monkeypatch, capsys):
+    """In the interpreter's ``ON_WAIT`` mode the kernel's output equals,
+    bit for bit, the committed fetch's (``tests/committed_fetch.py``: a
+    loop starts a DMA a page a stream, another waits page by page), over
+    the pools as they are and over pools with every page no row owns up
+    to its length poisoned. ``ON_WAIT`` lands the DMAs queued on a slot's
+    semaphore only until a wait's bytes are counted, into buffers that
+    start as NaN: if a full block's two waits, each described as one
+    stream's whole slot, counted fewer bytes than both streams' pages,
+    a page would be read before it landed. No semaphore is left with a
+    count at the kernel's exit. The reference agrees to a bf16 rounding
+    on every row that sees a position."""
+    if block == "blocks-of-two-pages":
+        monkeypatch.setattr(pa, "_BLOCK_TOKENS", 2 * PAGE)
+    bk = PAGE * pa._pages_per_block(2, PAGE, 32, 2, 6)
+    assert bk == (8 if block == "blocks-of-two-pages" else 24)
+    q, k_pool, v_pool, tables, lens = fetch_case(lengths, bk)
+    win = None if window is None else jnp.int32(window)
+
+    def call(k, v, interpret):
+        return np.asarray(pa._paged_attention_call(q, k, v, tables, lens, 0.3, interpret, window=win,
+                                                   layer=jnp.int32(1)), np.float32)
+
+    got = call(k_pool, v_pool, ON_WAIT)
+    ref = pa.paged_decode_attention_ref(q, k_pool, v_pool, tables, lens, scale=0.3, window=win, layer=1)
+    seen = np.asarray(lens) > 0  # a row of length 0 is an empty slot, whose output nothing reads
+    np.testing.assert_allclose(got[seen], np.asarray(ref, np.float32)[seen], atol=2e-2)
+    with monkeypatch.context() as m:
+        m.setattr(pa, "_start_pages", committed_start)
+        m.setattr(pa, "_land_pages", committed_land)
+        committed = call(k_pool, v_pool, ON_WAIT)
+    assert np.array_equal(got, committed, equal_nan=True)
+    unowned = call(unowned_poisoned(k_pool, tables, lens), unowned_poisoned(v_pool, tables, lens), ON_WAIT)
+    assert np.array_equal(unowned, committed, equal_nan=True)
+    assert "non-zero count" not in capsys.readouterr().out
+
+
+def test_a_pool_of_fewer_pages_than_a_block_holds_is_read_in_blocks_of_the_pool_s_pages(monkeypatch):
+    """Five pages in the pool (four and the trash page) under tables of six
+    entries, rows sharing pages, at the entry's block (the table's six
+    pages): a block holds the pool's five, since a run of the pool's pages
+    describes a whole slot's wait — a bound that neither the interpreter
+    nor the chip's compiler checks for a wait."""
+    q, k_pool, v_pool, _, lens = fetch_case("ragged", 24)
+    k_pool, v_pool = k_pool[:, :5], v_pool[:, :5]
+    tables = jnp.asarray(np.random.default_rng(5).integers(0, 4, (6, 6)), jnp.int32)
+    slots = []
+
+    real_land = pa._land_pages
+
+    def land(streams, *args):
+        slots.append(streams[0][1].shape[1])
+        return real_land(streams, *args)
+
+    monkeypatch.setattr(pa, "_land_pages", land)
+    got = pa._paged_attention_call(q, k_pool, v_pool, tables, lens, 0.3, ON_WAIT, layer=jnp.int32(1))
+    ref = pa.paged_decode_attention_ref(q, k_pool, v_pool, tables, lens, scale=0.3, layer=1)
+    assert set(slots) == {5}
+    seen = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(got, np.float32)[seen], np.asarray(ref, np.float32)[seen], atol=2e-2)
 
 
 class TestPagedKVCache:

@@ -41,7 +41,8 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +50,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from committed_fetch import committed_land, committed_start
 from gofr_tpu.ops import latent_attention as la
 
 ROW_BYTES = 1152  # a position's 576 bf16 values as the roofline metric counts them (the pad to 640 is the kernel's)
@@ -56,32 +58,17 @@ HBM_BPS = 819e9
 
 
 # ------------------------------------------------------------------ the fetches
-def loop_start(pool_hbm, tables_ref, buf, sem, layer, row, first, n, slot):
-    def one(j, _):
-        pid = tables_ref[row, first + j]
-        pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
-        return _
-    jax.lax.fori_loop(0, n, one, None)
+# ``a``: the committed loops, the tests' oracle of the fetch
+loop_start, loop_land = committed_start, committed_land
 
 
-def loop_land(pool_hbm, buf, sem, n, slot):
-    def wait(j, _):
-        pltpu.make_async_copy(pool_hbm.at[0, 0], buf.at[slot, j], sem.at[slot]).wait()
-        return _
-    jax.lax.fori_loop(0, n, wait, None)
-
-    def clear(j, _):
-        buf[slot, j] = jnp.zeros(buf.shape[2:], buf.dtype)
-        return _
-    jax.lax.fori_loop(n, buf.shape[1], clear, None)
-
-
-def when_start(pool_hbm, tables_ref, buf, sem, layer, row, first, n, slot):
-    for j in range(buf.shape[1]):
+def when_start(streams, tables_ref, sem, layer, row, first, n, slot):
+    for j in range(streams[0][1].shape[1]):
         @pl.when(j < n)
         def _start():
             pid = tables_ref[row, first + j]
-            pltpu.make_async_copy(pool_hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
+            for hbm, buf in streams:
+                pltpu.make_async_copy(hbm.at[layer, pid], buf.at[slot, j], sem.at[slot]).start()
 
 
 SHIPPED = (la._start_pages, la._land_pages)
